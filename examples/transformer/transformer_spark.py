@@ -74,7 +74,11 @@ def parse_mesh(spec):
     return axes
 
 
-def main_fun(args, ctx):
+def main_fun(args, ctx, observer=None):
+    """The jax child's training program. ``observer`` is ``chip_smoke.py``'s
+    probe: it is shown the trainer once it is built
+    (``observer.built(mesh, state, step_fn)``) and every dispatched step
+    (``observer.step(i, batch, metrics)``) — the run itself is the same."""
     import time
 
     import jax
@@ -93,7 +97,7 @@ def main_fun(args, ctx):
         vocab_size=args.vocab_size, d_model=args.d_model,
         n_layers=args.n_layers, n_heads=args.n_heads, d_ff=args.d_ff,
         max_seq_len=args.seq_len, dtype=args.dtype, remat=args.remat,
-        moe_experts=args.moe_experts,
+        moe_experts=args.moe_experts, attention=args.attention,
     )
     strategy = SyncDataParallel(
         mesh, param_spec_fn=transformer.param_specs if "tp" in mesh.axis_names else None
@@ -160,15 +164,20 @@ def main_fun(args, ctx):
             yield strategy.shard_batch(batch)
 
     batches = packed_batches()
+    if observer is not None:
+        observer.built(mesh, state, run)
     t0, metrics = time.perf_counter(), {}
     i = start_step
     while i < args.train_steps:
         if steps_per_loop > 1 and i + steps_per_loop <= args.train_steps:
-            state, metrics = run(state, [next(batches) for _ in range(steps_per_loop)])
+            batch = [next(batches) for _ in range(steps_per_loop)]
             i += steps_per_loop
         else:
-            state, metrics = run(state, next(batches))
+            batch = next(batches)
             i += 1
+        state, metrics = run(state, batch)
+        if observer is not None:
+            observer.step(i, batch, metrics)
         if i % args.log_steps == 0 or i >= args.train_steps:
             jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
@@ -189,8 +198,10 @@ def main_fun(args, ctx):
     )
 
 
-def main(argv=None, sc=None):
+def build_parser():
     parser = argparse.ArgumentParser()
+    parser.add_argument("--attention", default="auto",
+                        help="auto (flash kernel on TPU, ring over sp, plain elsewhere), flash, flash_interpret, plain or ring")
     parser.add_argument("--batch_size", type=int, default=8)
     parser.add_argument("--cluster_size", type=int, default=None,
                         help="explicit cluster size (default: from the Spark conf/parallelism under Spark; 1 on the local backend)")
@@ -210,7 +221,8 @@ def main(argv=None, sc=None):
     parser.add_argument("--n_layers", type=int, default=2)
     parser.add_argument("--pack_workers", type=int, default=0,
                         help="0 = in-process thread packing, N = forked pack-plane workers")
-    parser.add_argument("--platform", default=None)
+    parser.add_argument("--platform", default=None,
+                        help="tpu to demand the chip, cpu for a test run; unset, the jax child inherits JAX_PLATFORMS")
     parser.add_argument("--remat", action="store_true")
     parser.add_argument("--seq_len", type=int, default=256)
     parser.add_argument("--slab_cache_dir", default=None,
@@ -219,7 +231,11 @@ def main(argv=None, sc=None):
     parser.add_argument("--tokenizer", default="byte", choices=("byte", "word"))
     parser.add_argument("--train_steps", type=int, default=20)
     parser.add_argument("--vocab_size", type=int, default=1024)
-    args = parser.parse_args(argv)
+    return parser
+
+
+def main(argv=None, sc=None):
+    args = build_parser().parse_args(argv)
 
     if not args.data_dir:
         args.data_dir = os.path.join("/tmp", "tos_transformer_corpus")

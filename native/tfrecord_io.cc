@@ -1392,13 +1392,20 @@ done:
 
 extern "C" {
 
-// Compile-time build fingerprint: which decode backend this .so carries.
-// Asserted by tests so a stale scalar build on a libjpeg host is visible.
+// Compile-time build fingerprint: which decode backend this .so carries
+// and which source it was built from. The Makefile passes the first 12 hex
+// digits of this file's sha256 as TFR_SOURCE_ID; native_io.py looks for
+// "src=<id>" in the library before loading it, so a .so left over from
+// other source is rebuilt, not trusted.
+#ifndef TFR_SOURCE_ID
+#define TFR_SOURCE_ID unknown
+#endif
 const char* tfr_build_info() {
 #ifdef TFR_USE_LIBJPEG
-  return "tfrecord_io jpeg=libjpeg-turbo api=" TFR_STRINGIZE(JPEG_LIB_VERSION);
+  return "tfrecord_io jpeg=libjpeg-turbo api=" TFR_STRINGIZE(JPEG_LIB_VERSION)
+         " src=" TFR_STRINGIZE(TFR_SOURCE_ID);
 #else
-  return "tfrecord_io jpeg=scalar";
+  return "tfrecord_io jpeg=scalar src=" TFR_STRINGIZE(TFR_SOURCE_ID);
 #endif
 }
 

@@ -1,13 +1,13 @@
 """BN-slice experiment (VERDICT r4 item 3): flax BN vs fused pallas BN.
 
-The r4 breakdown (docs/perf.md) measured the full ResNet-50 train step at
+The r4 breakdown measured the full ResNet-50 train step at
 106.4 ms/iter with BatchNorm costing 28% of it (77.4 ms/iter with BN deleted).
 This script times the SAME guarded harness with ``bn_impl="flax"`` vs
 ``bn_impl="pallas"`` (ops/fused_bn.py) interleaved, and prints one JSON line
 per variant. Guards carried over from r4 (each one was a measured trap):
 
-* K=16 steps fused in one ``lax.scan`` dispatch — the ~100 ms relay
-  dispatch+fence cost amortizes to <1%;
+* K=16 steps fused in one ``lax.scan`` dispatch — a per-dispatch
+  dispatch+fence cost (~100 ms when this was written) amortizes to <1%;
 * the input batch is CARRY-CHAINED through the loss (x += loss * 1e-6), so
   XLA can neither hoist batch-invariant work out of the scan nor dead-code
   steps (naive scan microbenches here read 400+ TFLOP/s);

@@ -23,7 +23,7 @@ import secrets
 import threading
 import time as _time
 
-from tensorflowonspark_tpu import TFSparkNode, TFManager, chaos, reservation, resilience
+from tensorflowonspark_tpu import TFSparkNode, TFManager, chaos, reservation, resilience, util
 from tensorflowonspark_tpu import registry as membership
 from tensorflowonspark_tpu.obs import aggregate as obs_aggregate
 from tensorflowonspark_tpu.obs import flight as obs_flight
@@ -1055,10 +1055,15 @@ def run(
         # an explicit user-provided TOS_CHAOS_PLAN in env wins. The trace
         # context (TOS_TRACE_ID / parent span / TOS_TRACE_DIR) rides the
         # same lane: mint() is idempotent, so a ladder relaunch reuses the
-        # trace_id and the whole recovery stays one causal timeline.
+        # trace_id and the whole recovery stays one causal timeline. A
+        # compile-cache directory placed on the driver rides it too.
         "env": {
             **obs_tracing.mint(proc="driver"),
             **({chaos.ENV_VAR: chaos.plan().to_json()} if chaos.active else {}),
+            **(
+                {util.COMPILE_CACHE_ENV: os.environ[util.COMPILE_CACHE_ENV]}
+                if os.environ.get(util.COMPILE_CACHE_ENV) else {}
+            ),
             **dict(env or {}),
         },
         "jax_distributed": bool(jax_distributed),

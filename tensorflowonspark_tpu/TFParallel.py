@@ -42,21 +42,10 @@ class _ParallelTask:
         )
 
         # partition this host's chips across co-resident instances — the
-        # reference placed workers on GPUs by local index (gpu_info.py:102);
-        # without this, concurrent children would each claim ALL chips and
-        # collide on libtpu's process-owns-chips rule
-        chip_ids = None
-        n_chips = tpu_info.detect_local_chips()
-        if n_chips and self.env.get("JAX_PLATFORMS") != "cpu":
-            local_rank, num_local = self._local_placement(executor_id)
-            if num_local > n_chips:
-                raise RuntimeError(
-                    "{} TFParallel instances on this host but only {} chips — "
-                    "reduce num_executors or instances per host".format(num_local, n_chips)
-                )
-            per = n_chips // num_local
-            start = local_rank * per
-            chip_ids = list(range(start, start + per))
+        # reference placed workers on GPUs by local index (gpu_info.py:102)
+        chip_ids = tpu_info.local_chip_share(
+            *self._local_placement(executor_id), platform=self.env.get("JAX_PLATFORMS")
+        )
 
         def _entry():
             try:
@@ -70,6 +59,7 @@ class _ParallelTask:
                     util.force_platform(
                         self.env["JAX_PLATFORMS"], self.env.get("TOS_NUM_CPU_DEVICES")
                     )
+                util.place_compile_cache()
                 self.fn(self.tf_args, ctx)
             except BaseException:
                 logger.error("TFParallel fn failed:\n%s", traceback.format_exc())

@@ -152,15 +152,17 @@ class TFNodeContext:
             return
         import jax
 
+        if jax.distributed.is_initialized():
+            # the jax child joins the world before main_fun runs, and every
+            # main_fun calls this again by contract; a second
+            # jax.distributed.initialize is an error once a backend is up
+            return
         platforms = str(getattr(jax.config, "jax_platforms", None) or "")
         if platforms.split(",")[0] == "cpu":
             # CPU multi-process worlds (tests, dev boxes) federate their
             # devices through gloo collectives; on TPU the ICI/DCN transport
             # is native and needs no selection
-            try:
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # older jax: single implementation only
-                pass
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=self.coordinator_address,
             num_processes=self.num_processes,
@@ -226,12 +228,14 @@ def _child_entry(fn, tf_args, ctx, cluster_meta, error_queue_spec):
         obs_tracing.install_from_env(
             "jax-{}-{}".format(ctx.job_name, ctx.task_index)
         )
-        os.environ.update(tpu_info.visibility_env(platform=env.get("JAX_PLATFORMS")))
+        os.environ.update(
+            tpu_info.visibility_env(
+                chip_ids=ctx.topology.get("chip_ids"), platform=env.get("JAX_PLATFORMS")
+            )
+        )
         if env.get("JAX_PLATFORMS"):
-            # config-API forcing: on TPU-pod images the site setup pins the
-            # platform via jax.config in every interpreter, which overrides
-            # the env var we just set (see util.force_platform)
             util.force_platform(env["JAX_PLATFORMS"], env.get("TOS_NUM_CPU_DEVICES"))
+        util.place_compile_cache()
         # re-connect our own IPC channel from inside the child
         addr, authkey = error_queue_spec
         ctx.mgr = TFManager.connect(addr, authkey)
@@ -601,6 +605,26 @@ class _NodeLaunchTask:
         if len(set(ids)) != len(ids):
             raise RuntimeError("duplicate executor ids in cluster: {}".format(sorted(ids)))
 
+        # one owner per chip: every node on this host spawns a jax child, so
+        # split the host's chips among them — and refuse, before anything is
+        # spawned, a launch with more children than chips (the second child
+        # would otherwise fail or hang inside libtpu)
+        co_located = sorted(r["executor_id"] for r in cluster_info if r["host"] == host)
+        chip_ids = tpu_info.local_chip_share(
+            co_located.index(executor_id), len(co_located),
+            platform=(meta.get("env") or {}).get("JAX_PLATFORMS"),
+        )
+        if chip_ids is not None and meta.get("jax_distributed", False):
+            # children pinned to disjoint chips each start a one-process TPU
+            # runtime; asked to form one world they die or hang inside
+            # backend start-up (seen on a four-chip v5e host)
+            raise RuntimeError(
+                "{} executors share this TPU host in one jax.distributed world — "
+                "not supported: run one executor per host (its jax child drives "
+                "all of the host's chips), or pass jax_distributed=False for "
+                "independent replicas on disjoint chips".format(len(co_located))
+            )
+
         self._maybe_start_aggregator(mgr, cluster_info, executor_id, authkey, meta)
 
         cluster_spec = {}
@@ -633,7 +657,7 @@ class _NodeLaunchTask:
             coordinator_address=coord,
             num_processes=num_procs if meta.get("jax_distributed", False) else 1,
             process_id=proc_id,
-            topology=tpu_info.local_topology(),
+            topology=dict(tpu_info.local_topology(), chip_ids=chip_ids),
             cluster_meta={
                 k: meta[k]
                 for k in ("id", "server_addr", "input_mode", "feed_shm", "obs")

@@ -24,6 +24,7 @@ import logging
 import os
 import queue
 import shutil
+import signal
 import tempfile
 import threading
 import time
@@ -46,6 +47,29 @@ _mp = __import__("multiprocessing").get_context("spawn")
 #: module-global registry, inside each executor process, of background
 #: child processes started by node-launch tasks (reaped at executor stop)
 _executor_children = []
+
+
+def _descendants(pid):
+    """pids of every live descendant of ``pid`` (children first), read from
+    ``/proc``; empty where there is no ``/proc``."""
+    children = {}
+    try:
+        entries = [e for e in os.listdir("/proc") if e.isdigit()]
+    except OSError:
+        return []
+    for entry in entries:
+        try:
+            with open("/proc/{}/stat".format(entry)) as f:
+                # "pid (comm) state ppid ...": comm may contain spaces
+                ppid = int(f.read().rsplit(")", 1)[1].split()[1])
+        except (OSError, IndexError, ValueError):
+            continue
+        children.setdefault(ppid, []).append(int(entry))
+    found, frontier = [], [pid]
+    while frontier:
+        frontier = [c for p in frontier for c in children.get(p, [])]
+        found.extend(frontier)
+    return found
 
 
 def register_child_process(proc):
@@ -387,9 +411,21 @@ class LocalSparkContext:
         for proc in self._procs:
             proc.join(timeout=10)
             if proc.is_alive():
+                # its children (IPC manager server, jax child) would be
+                # orphaned by the kill and keep the driver's resource
+                # tracker — and so the driver's exit — waiting on them
                 logger.warning("killing unresponsive executor %s", proc.name)
-                proc.kill()
+                for pid in _descendants(proc.pid) + [proc.pid]:
+                    try:
+                        os.kill(pid, signal.SIGKILL)
+                    except OSError:
+                        pass
                 proc.join(timeout=5)
+        # a failed job leaves its remaining partitions queued for executors
+        # that are gone now: without this the interpreter's exit would block
+        # forever flushing the queues' feeder threads into full pipes
+        for q in [self._shared_q] + self._private_qs:
+            q.cancel_join_thread()
         # collector re-checks _stop_ev every 0.2s result-queue timeout
         self._collector.join(timeout=5)
         if cleanup and self._own_workdir:

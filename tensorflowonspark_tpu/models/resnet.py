@@ -24,7 +24,7 @@ def _norm_factory(bn_impl, train, dtype):
     """BatchNorm constructor for ``bn_impl``: ``"flax"`` = ``nn.BatchNorm``
     (global sync-BN under pjit), ``"pallas"`` = the fused-kernel
     :class:`~tensorflowonspark_tpu.ops.fused_bn.FusedBatchNorm` (per-shard
-    stats — the r5 BN-slice experiment, docs/perf.md)."""
+    stats — the BN-slice experiment, PERF.md Findings)."""
     if bn_impl == "pallas":
         import jax
 
@@ -161,7 +161,7 @@ def resnet50(num_classes=1000, dtype=jnp.float32, stem="imagenet", bn_impl="flax
     """ResNet-50 v1.5 (reference resnet_model.py layer spec [3,4,6,3]).
     ``stem="imagenet_s2d"`` opts into the space-to-depth stem (TPU MXU
     occupancy — see ResNet.__call__); ``bn_impl="pallas"`` into the fused
-    BatchNorm kernels (per-shard stats — docs/perf.md r5)."""
+    BatchNorm kernels (per-shard stats — PERF.md Findings)."""
     return ResNet(
         stage_sizes=(3, 4, 6, 3), filters=(64, 128, 256, 512),
         num_classes=num_classes, bottleneck=True, stem=stem, dtype=dtype,

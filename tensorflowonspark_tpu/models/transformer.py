@@ -41,7 +41,8 @@ class TransformerConfig:
     dtype: str = "float32"  # compute dtype; params stay float32
     remat: bool = False  # jax.checkpoint each block: FLOPs for HBM
     #: "auto" — ring over sp when the mesh has it, else the pallas flash
-    #: kernel on TPU, else plain XLA attention; or force "flash"/"plain"
+    #: kernel on TPU, else plain XLA attention; or force "flash" (TPU only),
+    #: "flash_interpret" (the kernel in the Pallas interpreter), "plain", "ring"
     attention: str = "auto"
     #: >0 switches every block's MLP to a switch-routed mixture of experts
     #: sharded over the mesh's ``ep`` axis (expert parallelism)
@@ -78,14 +79,75 @@ def _rope(x, positions, base=10000.0):
     ).astype(x.dtype)
 
 
-_ATTENTION_IMPLS = ("auto", "flash", "plain", "ring")
+_ATTENTION_IMPLS = ("auto", "flash", "flash_interpret", "plain", "ring")
 
 #: below this sequence length ``auto`` dispatch uses plain XLA attention on
-#: TPU instead of pad-to-128 + flash. Measured on-chip (docs/perf.md r3,
-#: B=4 H=8 D=64 bf16 causal, best-of-3 fenced): flash ≥ plain at every
-#: L ∈ {256, 512, 2048, 4096} and within relay noise at 1024, so the floor
-#: only guards the tiny-sequence regime where padding overhead dominates.
+#: TPU instead of pad-to-128 + flash: the floor only guards the
+#: tiny-sequence regime where the padding is most of the work (the init
+#: probe batch, unit-test shapes). Not re-measured on the bare chip.
 _FLASH_MIN_SEQ = int(os.environ.get("TOS_FLASH_MIN_SEQ", "256"))
+
+
+def _batch_axes(mesh, batch):
+    """The data axes (``dp``, ``fsdp``) of ``mesh`` that ``batch`` rows shard
+    over, as a PartitionSpec entry. An axis whose size does not divide is
+    dropped (degrade-to-replicated, same contract as :func:`param_specs`) —
+    ``module.init`` probes run a batch of one."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    axes, div = [], 1
+    for a in ("dp", "fsdp"):
+        if a in sizes and batch % (div * sizes[a]) == 0:
+            axes.append(a)
+            div *= sizes[a]
+    if not axes:
+        return None
+    return axes[0] if len(axes) == 1 else tuple(axes)
+
+
+def _flash(q, k, v, segment_ids, mesh, interpret):
+    """The pallas flash kernel on ``[B, H, L, D]``, padded to the kernel's
+    128-row granule and run per shard.
+
+    A Mosaic custom call has no partitioning rule, so under pjit XLA would
+    gather q/k/v and run the whole global batch's attention on every chip.
+    On a multi-device mesh the call therefore goes through ``shard_map``:
+    batch over the data axes, heads over ``tp`` — attention is independent
+    across both, so no collective is needed inside."""
+    from tensorflowonspark_tpu.ops.flash_attention import flash_attention
+
+    seq = q.shape[2]
+    pad = (-seq) % 128
+    if pad:
+        # causal masking means queries < seq never attend to the zero
+        # padding appended after them, so pad-run-slice is exact; with
+        # segments the appended columns get id 0, which never equals a
+        # real (>= 1) segment — exact for the same reason
+        q, k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) for t in (q, k, v))
+        if segment_ids is not None:
+            segment_ids = jnp.pad(segment_ids, ((0, 0), (0, pad)))
+
+    def local(q, k, v, seg=None):
+        return flash_attention(q, k, v, causal=True, segment_ids=seg, interpret=interpret)
+
+    if mesh is None or mesh.size == 1:
+        out = local(q, k, v, segment_ids)
+    else:
+        from jax.sharding import PartitionSpec as P
+
+        from tensorflowonspark_tpu.parallel.collectives import shard_map
+
+        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+        batch = _batch_axes(mesh, q.shape[0])
+        heads = "tp" if "tp" in sizes and q.shape[1] % sizes["tp"] == 0 else None
+        spec = P(batch, heads, None, None)
+        operands, in_specs = (q, k, v), (spec, spec, spec)
+        if segment_ids is not None:
+            operands, in_specs = operands + (segment_ids,), in_specs + (P(batch, None),)
+        # check_vma off: pallas_call outputs carry no varying-axes type
+        out = shard_map(
+            local, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False
+        )(*operands)
+    return out[:, :, :seq] if pad else out
 
 
 def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None):
@@ -94,6 +156,10 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None):
     ``TOS_FLASH_MIN_SEQ``), else plain XLA attention. Forcing
     ``plain``/``flash``/``ring`` always wins (``plain`` on an sp mesh is the
     debugging escape hatch — correct, just unsharded math).
+
+    No path is taken quietly in place of the one asked for: ``flash`` off
+    TPU is an error, not an interpreted kernel — the Pallas interpreter is
+    what ``flash_interpret`` names, for CPU tests of the kernel's math.
 
     ``segment_ids`` (``int32 [B, L]``, 0 = padding) is the text plane's
     packed-sequence fence — every path turns it into the same
@@ -108,28 +174,17 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None):
     has_sp = mesh is not None and "sp" in mesh.axis_names
     if impl == "ring" or (impl == "auto" and has_sp):
         return ring_attention_sharded(q, k, v, mesh, causal=True, segment_ids=segment_ids)
-    if impl == "flash" or jax.default_backend() == "tpu":
-        seq = q.shape[2]
-        if impl != "flash" and seq < _FLASH_MIN_SEQ:
-            return plain_attention(q, k, v, causal=True, segment_ids=segment_ids)
-        from tensorflowonspark_tpu.ops.flash_attention import flash_attention
-
-        pad = (-seq) % 128
-        if pad:
-            # causal masking means queries < seq never attend to the zero
-            # padding appended after them, so pad-run-slice is exact; with
-            # segments the appended columns get id 0, which never equals a
-            # real (>= 1) segment — exact for the same reason
-            q, k, v = (
-                jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) for t in (q, k, v)
-            )
-            if segment_ids is not None:
-                segment_ids = jnp.pad(segment_ids, ((0, 0), (0, pad)))
-        out = flash_attention(
-            q, k, v, causal=True, segment_ids=segment_ids,
-            interpret=jax.default_backend() != "tpu",
+    if impl == "flash_interpret":
+        return _flash(q, k, v, segment_ids, mesh, interpret=True)
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "flash" and not on_tpu:
+        raise RuntimeError(
+            "attention='flash' needs a TPU backend (got {!r}); use "
+            "'flash_interpret' to run the kernel in the Pallas "
+            "interpreter, or 'auto'/'plain'".format(jax.default_backend())
         )
-        return out[:, :, :seq] if pad else out
+    if on_tpu and (impl == "flash" or q.shape[2] >= _FLASH_MIN_SEQ):
+        return _flash(q, k, v, segment_ids, mesh, interpret=False)
     return plain_attention(q, k, v, causal=True, segment_ids=segment_ids)
 
 
@@ -269,14 +324,7 @@ class Transformer(nn.Module):
 
         names = self.mesh.axis_names
         sizes = dict(zip(names, self.mesh.devices.shape))
-        batch, div = [], 1
-        for a in ("dp", "fsdp"):
-            if a in names and x.shape[0] % (div * sizes[a]) == 0:
-                batch.append(a)
-                div *= sizes[a]
-        batch = tuple(batch) or None
-        if batch is not None and len(batch) == 1:
-            batch = batch[0]
+        batch = _batch_axes(self.mesh, x.shape[0])
         seq = "sp" if "sp" in names and x.shape[1] % sizes["sp"] == 0 else None
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, P(batch, seq, None))

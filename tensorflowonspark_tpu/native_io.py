@@ -2,9 +2,10 @@
 
 The bulk-ingest hot path: one FFI call loads and CRC-verifies a whole shard
 (``native/tfrecord_io.cc``), and records are sliced out of a single
-contiguous buffer — no per-record Python framing work. Falls back silently
-to the pure-Python codec in :mod:`tensorflowonspark_tpu.tfrecord` when the
-shared library is missing and cannot be built (no compiler).
+contiguous buffer — no per-record Python framing work. Falls back, with a
+warning, to the pure-Python codec in :mod:`tensorflowonspark_tpu.tfrecord`
+when the shared library cannot be built (no compiler); :func:`build_info`
+says which one is live.
 
 This replaces the native layer the reference borrowed from others: the
 tensorflow-hadoop InputFormat jar (/root/reference/lib/) and TensorFlow's
@@ -12,6 +13,8 @@ C++ record_reader — here it is part of the framework itself.
 """
 
 import ctypes
+import fcntl
+import hashlib
 import logging
 import os
 import subprocess
@@ -132,33 +135,71 @@ def _bind(lib):
     return lib
 
 
-def _try_build():
-    """Build the library with make/g++ if a toolchain is present."""
-    src = os.path.join(_NATIVE_DIR, "tfrecord_io.cc")
-    if not os.path.exists(src):
-        return False
+def _source_id():
+    """First 12 hex digits of the sha256 of ``native/tfrecord_io.cc`` — the
+    fingerprint the Makefile bakes into the library — or None when the
+    source is not there (an installed package ships only the library)."""
     try:
-        subprocess.run(
-            ["make", "-s", "libtfrecord_io.so"],
-            cwd=_NATIVE_DIR,
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        return os.path.exists(_LIB_PATH)
-    except Exception as e:
-        logger.info("native tfrecord_io build unavailable (%s); using Python codec", e)
+        with open(os.path.join(_NATIVE_DIR, "tfrecord_io.cc"), "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()[:12]
+    except OSError:
+        return None
+
+
+def _is_current(source_id):
+    """True when the in-tree library was built from the checked-out source.
+    The library is git-ignored, so a copy of the tree can carry one built
+    from other source; its ``src=`` fingerprint string is read from the
+    file (loading it first would pin the stale image in this process)."""
+    try:
+        with open(_LIB_PATH, "rb") as f:
+            return "src={}".format(source_id).encode() in f.read()
+    except OSError:
         return False
+
+
+def _build_if_stale(source_id):
+    """Make the in-tree library current with the source; False when it
+    cannot be built. Check and build run under a file lock, so co-starting
+    processes (executors, decode workers) neither build twice nor read a
+    half-written library."""
+    with open(os.path.join(_NATIVE_DIR, "Makefile")) as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _is_current(source_id):
+            return True
+        try:
+            subprocess.run(
+                ["make", "-s", "-B", "libtfrecord_io.so"],
+                cwd=_NATIVE_DIR, check=True, capture_output=True, timeout=120,
+            )
+        except (OSError, subprocess.SubprocessError) as e:
+            stderr = (getattr(e, "stderr", None) or b"").decode(errors="replace")
+            logger.warning(
+                "native tfrecord_io build failed (%s %s); using the Python codec / PIL",
+                e, stderr.strip()[-500:],
+            )
+            return False
+        return _is_current(source_id)
 
 
 def load_library():
-    """The bound ctypes library, or None when native IO is unavailable."""
+    """The bound ctypes library, or None when native IO is unavailable.
+
+    The in-tree library is built on first use from ``native/tfrecord_io.cc``
+    and rebuilt when its fingerprint is not that source's. A library named
+    by ``TOS_NATIVE_LIB``, or one shipped without the source, is loaded as
+    it is."""
     global _lib, _load_attempted
     with _lib_lock:
         if _lib is not None or _load_attempted:
             return _lib
         _load_attempted = True
-        if not os.path.exists(_LIB_PATH) and not _try_build():
+        source_id = None if "TOS_NATIVE_LIB" in os.environ else _source_id()
+        if source_id is None:
+            usable = os.path.exists(_LIB_PATH)
+        else:
+            usable = _build_if_stale(source_id)
+        if not usable:
             return None
         try:
             _lib = _bind(ctypes.CDLL(_LIB_PATH))

@@ -21,34 +21,13 @@ all_to_all (a2a SP/EP)    → lax.all_to_all
 broadcast                 → implicit (replicated sharding)
 """
 
+import jax
 from jax import lax
 
 
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-    """Version-portable ``shard_map``.
-
-    ``jax.shard_map`` only exists as a top-level export on newer jax; on the
-    0.4.x line it lives in ``jax.experimental.shard_map`` and spells the
-    replication-check kwarg ``check_rep`` instead of ``check_vma``. Every
-    shard_map in this package goes through here so the version probe (and the
-    kwarg translation) happens in one place.
-    """
-    import inspect
-
-    import jax
-
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-    try:
-        accepted = inspect.signature(impl).parameters
-    except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-        accepted = None
-    if accepted is not None:
-        for old, new in (("check_vma", "check_rep"), ("check_rep", "check_vma")):
-            if old in kwargs and old not in accepted and new in accepted:
-                kwargs[new] = kwargs.pop(old)
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+#: per-shard bodies (ring attention, the in-jit pipeline, the flash kernel)
+#: take ``shard_map`` from here, beside the collectives they call
+shard_map = jax.shard_map
 
 
 def psum(x, axis_name):
@@ -96,11 +75,5 @@ def axis_index(axis_name):
 
 
 def axis_size(axis_name):
-    """Static size of a mesh axis from inside a collective body.
-
-    ``lax.axis_size`` is a late addition to jax; ``psum`` of a python ``1``
-    constant-folds to the same static int on every version in between.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a mesh axis from inside a collective body."""
+    return lax.axis_size(axis_name)

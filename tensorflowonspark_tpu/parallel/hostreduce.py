@@ -5,8 +5,7 @@ inside the device program stream, and the CPU PJRT client runs enqueued
 programs strictly in order — a collective waiting on a straggler peer blocks
 every later program, so collective/compute overlap at *program* granularity
 is impossible device-side (measured on this runtime: a 0.5 s peer skew adds
-the full 0.5 s to the fenced and unfenced schedules alike; docs/perf.md
-"Multi-host scaling"). A gather-sum-broadcast over host TCP sockets, driven
+the full 0.5 s to the fenced and unfenced schedules alike). A gather-sum-broadcast over host TCP sockets, driven
 from a dedicated comm thread, waits in ``epoll`` instead: the device stream
 keeps executing the next microbatch's backprop while the socket wait and
 bucket sum happen beside it (jit execution releases the GIL). This is the

@@ -179,8 +179,8 @@ def build_hybrid_mesh(axes=None, devices=None, dcn_axes=("dp",), drop_trivial=Fa
 
     Single-slice (or slice-unaware) device sets delegate straight to
     :func:`build_mesh`. On TPU the placement goes through
-    ``mesh_utils.create_hybrid_device_mesh``; elsewhere (and as the TPU
-    fallback) the grid is assembled slice-major by :func:`_hybrid_device_grid`.
+    ``mesh_utils.create_hybrid_device_mesh`` (a refusal raises); elsewhere
+    the grid is assembled slice-major by :func:`_hybrid_device_grid`.
     """
     import jax
     from jax.sharding import Mesh
@@ -203,21 +203,17 @@ def build_hybrid_mesh(axes=None, devices=None, dcn_axes=("dp",), drop_trivial=Fa
         factors = {a: factors[a] for a in shape}
 
     platform = getattr(devices[0], "platform", "cpu") if len(devices) else "cpu"
-    mesh_devices = None
     if platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        # a placement the physical topology refuses is an error, not a
+        # reason to fall back to an order that ignores ICI
+        from jax.experimental import mesh_utils
 
-            mesh_devices = mesh_utils.create_hybrid_device_mesh(
-                tuple(shape[a] // factors[a] for a in shape),
-                tuple(factors[a] for a in shape),
-                devices=devices,
-            )
-        except Exception as e:  # pragma: no cover - depends on physical topology
-            logger.warning(
-                "create_hybrid_device_mesh failed (%s); using slice-major order", e
-            )
-    if mesh_devices is None:
+        mesh_devices = mesh_utils.create_hybrid_device_mesh(
+            tuple(shape[a] // factors[a] for a in shape),
+            tuple(factors[a] for a in shape),
+            devices=devices,
+        )
+    else:
         mesh_devices = _hybrid_device_grid(shape, factors, groups)
     logger.info(
         "hybrid mesh: %s over %d slice(s), dcn factors %s", shape, n_slices, factors
@@ -230,8 +226,8 @@ def build_mesh(axes=None, devices=None, drop_trivial=False):
 
     On real TPU hardware the physical layout comes from
     ``mesh_utils.create_device_mesh`` so that neighbouring mesh coordinates are
-    ICI neighbours and XLA collectives ride the torus; on CPU/virtual devices a
-    plain reshape is used.
+    ICI neighbours and XLA collectives ride the torus (a shape the topology
+    refuses raises); on CPU/virtual devices a plain reshape is used.
 
     ``axes``: dict of axis name → size; one size may be -1 ("use remaining
     devices"); default ``{"dp": -1}``. ``drop_trivial`` removes size-1 axes.
@@ -258,15 +254,11 @@ def build_mesh(axes=None, devices=None, drop_trivial=False):
     dims = tuple(shape.values())
     platform = devices[0].platform if devices else "cpu"
     if platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        # a shape the physical topology refuses is an error: device order
+        # would put mesh neighbours on non-adjacent chips without saying so
+        from jax.experimental import mesh_utils
 
-            mesh_devices = mesh_utils.create_device_mesh(dims, devices=devices)
-        except Exception as e:  # pragma: no cover - depends on physical topology
-            logger.warning("create_device_mesh failed (%s); using device order", e)
-            import numpy as np
-
-            mesh_devices = np.asarray(devices).reshape(dims)
+        mesh_devices = mesh_utils.create_device_mesh(dims, devices=devices)
     else:
         import numpy as np
 

@@ -1027,6 +1027,7 @@ def main(argv=None):
     from tensorflowonspark_tpu import util
 
     util.setup_logging()
+    util.place_compile_cache()
 
     if args.command == "infer":
         if args.server is None and args.export_dir is None:

@@ -1,8 +1,9 @@
-"""Shared harness utilities for multi-process test/dryrun worlds.
+"""Shared harness utilities for multi-process test worlds.
 
-One place for the CPU-world bootstrap used by the test suite and the driver
-dryrun (__graft_entry__), so fixes to world wiring (platform forcing, gloo
-selection, coordinator addressing) cannot drift between copies.
+One place for the CPU-world bootstrap used by the test suite and
+``bench.py``'s multichip members, so fixes to world wiring (platform
+forcing, gloo selection, coordinator addressing) cannot drift between
+copies.
 """
 
 import os
@@ -11,7 +12,7 @@ import os
 def join_cpu_world(pid, num_procs, coord_port, local_devices=2):
     """Join a local multi-process jax.distributed world on CPU devices.
 
-    Forces the CPU platform (config-API, see util.force_platform), builds the
+    Forces the CPU platform (see util.force_platform), builds the
     reservation-shaped :class:`~tensorflowonspark_tpu.TFSparkNode.TFNodeContext`
     for process ``pid`` of ``num_procs`` with a loopback coordinator, and
     initializes the distributed runtime (gloo collectives). Returns the ctx;

@@ -14,6 +14,7 @@ process before the jax child is forked.
 
 import glob
 import logging
+import math
 import os
 
 logger = logging.getLogger(__name__)
@@ -49,34 +50,39 @@ def parse_accelerator_type(accel_type):
     return gen, int(num)
 
 
+def _device_file_chips():
+    """Chips this machine can open: ``/dev/accel*`` (PCIe TPU driver) or the
+    numbered VFIO groups under ``/dev/vfio`` (one per passed-through chip)."""
+    accels = glob.glob("/dev/accel*")
+    if accels:
+        return len(accels)
+    return sum(1 for p in glob.glob("/dev/vfio/*") if os.path.basename(p).isdigit())
+
+
 def detect_local_chips():
     """Best-effort count of TPU chips attached to this host.
 
-    Order: explicit override env → TPU runtime env hints → accel device files
-    (``/dev/accel*`` for PCIe-attached TPU, ``/dev/vfio``) → 0 (no TPU).
+    Order: explicit override env → device files → TPU runtime env hints →
+    0 (no TPU). Device files come before the env hints because the hints
+    describe the host *type*, not what this machine was handed: a VM given
+    one chip of a 2x2 v5e host still carries
+    ``TPU_CHIPS_PER_HOST_BOUNDS=2,2,1`` while ``/dev/vfio`` holds one group
+    and the runtime opens one device.
     """
     override = os.environ.get(ENV_CHIP_COUNT)
     if override:
         return int(override)
+    chips = _device_file_chips()
+    if chips:
+        return chips
     # Cloud TPU VM runtime exports these
     for var in ("TPU_CHIPS_PER_HOST_BOUNDS", "TPU_CHIPS_PER_PROCESS_BOUNDS"):
         bounds = os.environ.get(var)
         if bounds:
             try:
-                dims = [int(x) for x in bounds.split(",")]
-                count = 1
-                for d in dims:
-                    count *= d
-                return count
+                return math.prod(int(x) for x in bounds.split(","))
             except ValueError:
                 pass
-    accels = glob.glob("/dev/accel*")
-    if accels:
-        return len(accels)
-    if os.path.isdir("/dev/vfio"):
-        vfio = [p for p in glob.glob("/dev/vfio/*") if os.path.basename(p).isdigit()]
-        if vfio:
-            return len(vfio)
     return 0
 
 
@@ -173,6 +179,36 @@ def visibility_env(chip_ids=None, platform=None):
         env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = _chip_grid_bounds(len(chip_ids))
         env["TPU_PROCESS_BOUNDS"] = "1,1,1"
     return env
+
+
+def local_chip_share(local_rank, num_local, platform=None):
+    """Chip ids for the ``local_rank``-th of ``num_local`` jax processes
+    that share this host, or None when no pinning applies (CPU platform, no
+    chips detected, or a sole process, which owns them all). ``platform`` is
+    what the children are told to use; unset, they inherit ``JAX_PLATFORMS``.
+
+    A chip belongs to one process: co-resident children that each claimed
+    every chip would collide inside libtpu — the second one fails or hangs
+    at backend start-up. So the host's chips are split evenly and
+    contiguously, and more processes than chips is refused here, before
+    anything is spawned.
+    """
+    n_chips = detect_local_chips()
+    platform = platform or os.environ.get("JAX_PLATFORMS", "")
+    if not n_chips or platform.split(",")[0] == "cpu":
+        return None
+    if num_local > n_chips:
+        raise RuntimeError(
+            "{} jax processes placed on this host but it has {} TPU chip(s) — "
+            "a chip belongs to one process; run one executor per host (it "
+            "drives all of the host's chips) or at most one per chip".format(
+                num_local, n_chips
+            )
+        )
+    if num_local == 1:
+        return None
+    per = n_chips // num_local
+    return list(range(local_rank * per, (local_rank + 1) * per))
 
 
 def _chip_grid_bounds(n):

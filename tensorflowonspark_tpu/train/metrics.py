@@ -18,9 +18,9 @@ class TimeHistory:
     """Record per-log-interval throughput during a training loop.
 
     The reference's Keras callback counted batches between ``on_batch_end``
-    hooks; a jax loop calls :meth:`batch_end` itself (after fencing the
-    step's result when honest timing matters — see docs/perf.md on relay
-    fencing)::
+    hooks; a jax loop calls :meth:`batch_end` itself (after waiting for the
+    step's result — dispatch is asynchronous, so an unfenced loop times the
+    enqueue)::
 
         th = TimeHistory(batch_size, log_steps=20)
         for batch in batches:

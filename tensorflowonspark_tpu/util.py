@@ -131,12 +131,10 @@ def read_executor_state(cwd=None):
 
 
 def force_platform(platform, num_cpu_devices=None):
-    """Force the jax platform for THIS process, config-API-first.
-
-    Env vars alone are not enough on hosts whose site setup pre-imports jax
-    and pins a platform through ``jax.config`` (the config value wins over
-    ``JAX_PLATFORMS``) — e.g. TPU pods whose runtime registers the PJRT
-    plugin in every interpreter. Must run before the first jax backend use.
+    """Force the jax platform for THIS process: the env var for a jax not
+    yet imported (and for children), the config API for one that is — pytest
+    and other hosts have jax imported before they get here, and by then the
+    env var has been read. Must run before the first jax backend use.
     ``num_cpu_devices`` forces that many virtual CPU devices (test worlds).
     """
     os.environ["JAX_PLATFORMS"] = platform
@@ -154,6 +152,40 @@ def force_platform(platform, num_cpu_devices=None):
     import jax
 
     jax.config.update("jax_platforms", platform)
+
+
+#: the variable JAX itself reads for its persistent compilation cache; the
+#: driver forwards it on the env lane when set (``TFCluster.run``)
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def place_compile_cache():
+    """Give this process a persistent compilation cache; returns its path
+    (None when it gets none).
+
+    Called at the top of every process that compiles (the jax child, a
+    TFParallel instance, the serving CLI, ``bench.py``).
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and
+    nothing is set in code. Otherwise the cache goes to ``.jax_cache`` beside
+    the package — a fixed path, because the path is what lets the next
+    process (an elastic relaunch, the next run) find the entries again; a
+    temporary directory, a pid or a time in it would never hit. A process
+    pinned to the CPU platform (the test worlds) gets none unless the
+    variable names one: XLA:CPU logs a multi-kilobyte machine-feature
+    complaint for every executable it loads back.
+    """
+    placed = os.environ.get(COMPILE_CACHE_ENV)
+    if placed:
+        return placed
+    import jax
+
+    if (jax.config.jax_platforms or "").split(",")[0] == "cpu":
+        return None
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+    )
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def single_node_env(num_cpu_devices=None, platform=None):

@@ -80,7 +80,7 @@ def test_partition_pairs_all_valid():
 def test_partition_pairs_band_is_symmetric():
     # a train block 12% SLOWER than its paired input-path block is just as
     # impossible under the pairing model as 12% faster (the r05 0.881 pair:
-    # a relay mood swing landed between the two half-blocks) — both sides
+    # the link's rate swung between the two half-blocks) — both sides
     # of the band discard
     valid, invalid = bench.partition_pairs([100.0, 100.0], [88.1, 95.0])
     assert valid == [(100.0, 95.0)]

@@ -327,8 +327,8 @@ class TestGilRelease:
         """The multi-core demonstration the plane has waited on: with >= 4
         real cores the 4-process pool must clear 3x the 1-thread pool on a
         GIL-bound parse (the ``BENCH_MODE=decode`` gil leg records the same
-        ratio). Skipped below 4 cores, where the recorded status quo is the
-        single-core ~1x of docs/perf.md."""
+        ratio). Skipped below 4 cores, where the recorded status quo is a
+        single-core ~1x."""
         if (os.cpu_count() or 1) < 4:
             pytest.skip("needs >= 4 cores to demonstrate 3x GIL-free decode")
         from tensorflowonspark_tpu import tfrecord

@@ -47,6 +47,19 @@ def test_mnist_spark_mode(tmp_path):
     assert os.path.isdir(export_dir)
 
 
+@pytest.mark.slow
+def test_mnist_spark_mode_two_process_world():
+    """Two executors form one jax.distributed world: the jax child joins it
+    before main_fun runs and main_fun calls ctx.initialize_distributed()
+    again by contract — which must be a no-op, not a second
+    jax.distributed.initialize (an error once a backend is up)."""
+    out = _run(
+        "mnist/mnist_spark.py", "--cluster_size", "2", "--epochs", "1",
+        "--num_examples", "512", "--batch_size", "64", "--platform", "cpu",
+    )
+    assert "training complete" in out
+
+
 def test_mnist_spark_mode_auto_recover(tmp_path):
     """--auto_recover routes the SPARK feed through run_with_recovery's
     feed_fn path (clean run here; the kill-mid-feed path is proven in

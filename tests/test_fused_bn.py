@@ -1,6 +1,6 @@
 """Fused pallas BatchNorm numerics vs flax.linen.BatchNorm (interpret mode).
 
-The kernels are the r5 BN-slice experiment (docs/perf.md): whatever the
+The kernels are the BN-slice experiment (PERF.md Findings): whatever the
 on-chip timing says, the math must be exactly training-mode batch norm —
 forward, batch statistics, and the full custom VJP (dx folds the statistics'
 dependency on x; dgamma/dbeta are the usual reductions)."""

@@ -109,3 +109,35 @@ def test_concurrent_jobs(sc):
     t1.start(), t2.start()
     t1.join(30), t2.join(30)
     assert results["a"] == results["b"] == sum(range(4))
+
+
+def test_exit_does_not_hang_on_partitions_left_queued_by_a_failed_job(tmp_path):
+    """A job that fails leaves its remaining partitions in the task queue;
+    after ``stop()`` nobody reads them, and the interpreter's exit used to
+    block forever flushing the queue's feeder thread into a full pipe (seen
+    as a driver that never ended after a refused launch)."""
+    import subprocess
+    import sys
+
+    script = tmp_path / "driver.py"
+    script.write_text(
+        "from tensorflowonspark_tpu.backends.local import LocalSparkContext, TaskError\n"
+        "def boom(it):\n"
+        "    raise RuntimeError('first task fails')\n"
+        "if __name__ == '__main__':\n"
+        "    sc = LocalSparkContext(num_executors=1)\n"
+        "    big = [b'x' * 300_000] * 16\n"
+        "    try:\n"
+        "        sc.parallelize(big, 16).foreachPartition(boom)\n"
+        "    except TaskError:\n"
+        "        print('failed as expected')\n"
+        "    finally:\n"
+        "        sc.stop()\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, str(script)], capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, PYTHONPATH=root),
+    )
+    assert "failed as expected" in out.stdout, out.stderr
+    assert out.returncode == 0

@@ -88,7 +88,7 @@ class TestResNet:
         assert logits.shape == (2, 1000)
 
     def test_resnet50_s2d_stem_matches_shapes_and_trains(self):
-        """The space-to-depth stem (docs/perf.md r4 breakdown) halves the
+        """The space-to-depth stem halves the
         spatial dims exactly like the 7x7/2 stem, so every downstream stage
         sees identical shapes; one train step must run and mutate stats."""
         model = resnet.resnet50(num_classes=1000, stem="imagenet_s2d")
@@ -198,9 +198,10 @@ class TestTransformer:
         )
 
     def test_flash_attention_impl_matches_plain(self):
-        """attention='flash' (interpret on CPU) must match the plain path."""
+        """attention='flash_interpret' (the kernel in the Pallas interpreter)
+        must match the plain path."""
         cfg = dict(vocab_size=64, d_model=32, n_layers=1, n_heads=4, d_ff=64)
-        model_flash = transformer.create_model(attention="flash", **cfg)
+        model_flash = transformer.create_model(attention="flash_interpret", **cfg)
         model_plain = transformer.create_model(attention="plain", **cfg)
         tokens = jnp.asarray(np.random.default_rng(3).integers(0, 64, (2, 128)))
         variables = model_plain.init(jax.random.PRNGKey(0), tokens)
@@ -214,7 +215,7 @@ class TestTransformer:
         """make_loss_fn slices tokens[:, :-1] producing odd seq lengths; the
         flash path must pad-and-slice, matching plain exactly (causality)."""
         cfg = dict(vocab_size=64, d_model=32, n_layers=1, n_heads=4, d_ff=64)
-        model_flash = transformer.create_model(attention="flash", **cfg)
+        model_flash = transformer.create_model(attention="flash_interpret", **cfg)
         model_plain = transformer.create_model(attention="plain", **cfg)
         tokens = jnp.asarray(np.random.default_rng(5).integers(0, 64, (1, 515)))
         variables = model_plain.init(jax.random.PRNGKey(0), tokens)
@@ -223,6 +224,42 @@ class TestTransformer:
             np.asarray(model_flash.apply(variables, tokens)),
             atol=3e-5,
         )
+
+    def test_flash_off_tpu_is_an_error_not_an_interpreted_kernel(self):
+        """A run that asked for the chip's kernel must not quietly get the
+        interpreter: attention='flash' on a CPU backend raises."""
+        model = transformer.create_model(
+            attention="flash", vocab_size=16, d_model=8, n_layers=1, n_heads=2, d_ff=16
+        )
+        with pytest.raises(RuntimeError, match="needs a TPU backend"):
+            model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+    def test_flash_runs_per_shard_on_a_mesh(self):
+        """On a multi-device mesh the kernel goes through shard_map (batch
+        over dp, heads over tp): a Mosaic call has no partitioning rule.
+        Logits and grads must match the plain path under the same mesh."""
+        if jax.device_count() < 8:
+            pytest.skip("needs 8 cpu devices")
+        from tensorflowonspark_tpu import parallel
+
+        mesh = parallel.local_mesh({"dp": 4, "tp": 2})
+        cfg = dict(vocab_size=64, d_model=32, n_layers=1, n_heads=4, d_ff=64)
+        flash = transformer.create_model(mesh=mesh, attention="flash_interpret", **cfg)
+        plain = transformer.create_model(mesh=mesh, attention="plain", **cfg)
+        rng = np.random.default_rng(7)
+        tokens = jnp.asarray(rng.integers(0, 64, (8, 96)))
+        seg = jnp.asarray(np.repeat([[1] * 40 + [2] * 50 + [0] * 6], 8, 0), jnp.int32)
+        params = plain.init(jax.random.PRNGKey(0), tokens)["params"]
+
+        def loss(model):
+            return jax.jit(jax.value_and_grad(
+                lambda p: (model.apply({"params": p}, tokens, segment_ids=seg) ** 2).mean()
+            ))(params)
+
+        (l_flash, g_flash), (l_plain, g_plain) = loss(flash), loss(plain)
+        np.testing.assert_allclose(float(l_flash), float(l_plain), rtol=1e-5)
+        for a, b in zip(jax.tree.leaves(g_flash), jax.tree.leaves(g_plain)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
 
     def test_unknown_attention_impl_raises(self):
         model = transformer.create_model(

@@ -293,7 +293,34 @@ def test_build_info_reports_jpeg_variant():
     if not native_io.load_library().tfr_has_jpeg:
         assert info is None
         return
-    assert re.fullmatch(r"tfrecord_io jpeg=(libjpeg-turbo api=\d+|scalar)", info)
+    assert re.fullmatch(
+        r"tfrecord_io jpeg=(libjpeg-turbo api=\d+|scalar) src=[0-9a-f]{12}", info
+    )
+    assert info.endswith("src=" + native_io._source_id())
+
+
+def test_stale_in_tree_library_is_rebuilt(tmp_path, monkeypatch):
+    """A git-ignored .so left in the tree from other source must not be
+    trusted: the loader compares its ``src=`` fingerprint with the checked
+    out ``tfrecord_io.cc`` and rebuilds on mismatch."""
+    import shutil
+
+    if shutil.which("g++") is None or shutil.which("make") is None:
+        pytest.skip("no toolchain to rebuild with")
+    native = tmp_path / "native"
+    native.mkdir()
+    src_dir = os.path.join(os.path.dirname(__file__), "..", "native")
+    for name in ("Makefile", "tfrecord_io.cc"):
+        shutil.copy(os.path.join(src_dir, name), native / name)
+    lib = native / "libtfrecord_io.so"
+    lib.write_bytes(b"\x7fELF stale src=000000000000")
+    monkeypatch.setattr(native_io, "_NATIVE_DIR", str(native))
+    monkeypatch.setattr(native_io, "_LIB_PATH", str(lib))
+    source_id = native_io._source_id()
+    assert not native_io._is_current(source_id)
+    assert native_io._build_if_stale(source_id)
+    assert native_io._is_current(source_id)
+    assert lib.stat().st_size > 10_000
 
 
 def test_decode_env_var_vetoes_native_path(monkeypatch):

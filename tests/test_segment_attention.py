@@ -208,7 +208,7 @@ class TestTransformerPacked:
         pos[:, 11:18] = np.arange(7)
         return s1, s2, tokens, seg, pos
 
-    @pytest.mark.parametrize("impl", ["plain", "flash", "ring"])
+    @pytest.mark.parametrize("impl", ["plain", "flash_interpret", "ring"])
     def test_packed_logits_match_unpacked(self, impl):
         from tensorflowonspark_tpu import parallel
         from tensorflowonspark_tpu.models import transformer

@@ -90,3 +90,83 @@ def test_validate_against_runtime(monkeypatch, caplog):
     assert not tpu_info.validate_against_runtime(12)
     monkeypatch.setenv(tpu_info.ENV_CHIP_COUNT, "0")
     assert tpu_info.validate_against_runtime(8)  # no detection -> trust runtime
+
+
+def test_device_files_win_over_host_type_hints(monkeypatch):
+    """A machine handed one chip of a 2x2 host still carries the host's
+    TPU_CHIPS_PER_HOST_BOUNDS (seen on the v5e machine this was brought up
+    on): what can be opened — the VFIO groups — is the count, and the
+    runtime agrees with it."""
+    monkeypatch.delenv(tpu_info.ENV_CHIP_COUNT, raising=False)
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    files = {"/dev/accel*": [], "/dev/vfio/*": ["/dev/vfio/3", "/dev/vfio/vfio"]}
+    monkeypatch.setattr(tpu_info.glob, "glob", files.__getitem__)
+    assert tpu_info.detect_local_chips() == 1
+    files["/dev/accel*"] = ["/dev/accel0", "/dev/accel1"]
+    assert tpu_info.detect_local_chips() == 2
+
+
+class TestLocalChipShare:
+    """One owner per chip: co-resident jax children split the host's chips;
+    more children than chips is refused before anything is spawned."""
+
+    def test_sole_process_or_cpu_or_no_chips_is_not_pinned(self, monkeypatch):
+        monkeypatch.setenv(tpu_info.ENV_CHIP_COUNT, "4")
+        assert tpu_info.local_chip_share(0, 1, "tpu") is None
+        assert tpu_info.local_chip_share(1, 8, "cpu") is None
+        monkeypatch.setenv(tpu_info.ENV_CHIP_COUNT, "0")
+        assert tpu_info.local_chip_share(1, 2, "tpu") is None
+
+    def test_chips_split_evenly_and_disjointly(self, monkeypatch):
+        monkeypatch.setenv(tpu_info.ENV_CHIP_COUNT, "4")
+        assert tpu_info.local_chip_share(0, 2, "tpu") == [0, 1]
+        # no platform named: the children inherit the environment's
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        assert tpu_info.local_chip_share(1, 2) == [2, 3]
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert tpu_info.local_chip_share(1, 2) is None
+        assert [tpu_info.local_chip_share(r, 4, "tpu,cpu") for r in range(4)] == [
+            [0], [1], [2], [3]
+        ]
+
+    def test_more_processes_than_chips_is_refused(self, monkeypatch):
+        monkeypatch.setenv(tpu_info.ENV_CHIP_COUNT, "1")
+        with pytest.raises(RuntimeError, match="a chip belongs to one process"):
+            tpu_info.local_chip_share(1, 2, "tpu")
+
+
+def _never_runs(args, ctx):
+    raise AssertionError("a jax child was spawned")
+
+
+def test_executors_that_cannot_share_a_tpu_host_are_refused_before_spawning(monkeypatch):
+    """README's first quick-start line (--cluster_size 2) on a one-chip
+    host: the launch fails with the placement error instead of a second
+    child hanging inside libtpu. The chip count is faked through the
+    override variable, which the spawned executors inherit."""
+    from tensorflowonspark_tpu import TFCluster
+    from tensorflowonspark_tpu.backends.local import LocalSparkContext
+
+    monkeypatch.setenv(tpu_info.ENV_CHIP_COUNT, "1")
+    sc = LocalSparkContext(num_executors=2, task_timeout=120)
+    try:
+        cluster = TFCluster.run(
+            sc, _never_runs, {}, 2, input_mode=TFCluster.InputMode.TENSORFLOW,
+            env={"JAX_PLATFORMS": "tpu"},
+        )
+        with pytest.raises(RuntimeError, match="2 jax processes placed on this host"):
+            cluster.shutdown(timeout=120)
+        # enough chips to split, but one jax.distributed world across the
+        # co-located children: their one-process TPU runtimes cannot form
+        # it (they died or hung on a four-chip v5e host) — refused as well
+        monkeypatch.setenv(tpu_info.ENV_CHIP_COUNT, "4")
+        sc.stop()
+        sc = LocalSparkContext(num_executors=2, task_timeout=120)
+        cluster = TFCluster.run(
+            sc, _never_runs, {}, 2, input_mode=TFCluster.InputMode.TENSORFLOW,
+            env={"JAX_PLATFORMS": "tpu"},
+        )
+        with pytest.raises(RuntimeError, match="share this TPU host in one jax.distributed world"):
+            cluster.shutdown(timeout=120)
+    finally:
+        sc.stop()

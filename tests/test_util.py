@@ -1,3 +1,4 @@
+import json
 import os
 import subprocess
 import sys
@@ -81,3 +82,45 @@ def test_read_executor_state_missing(tmp_path):
 def test_find_free_port():
     p = util.find_free_port()
     assert 0 < p < 65536
+
+
+_CACHE_PROBE = (
+    "from tensorflowonspark_tpu import util\n"
+    "placed = util.place_compile_cache()\n"
+    "import jax, json\n"
+    "print(json.dumps([placed, jax.config.jax_compilation_cache_dir]))\n"
+)
+
+
+def _cache_probe(**env):
+    base = {k: v for k, v in os.environ.items() if k != util.COMPILE_CACHE_ENV}
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE],
+        capture_output=True, text=True, timeout=120, env=dict(base, **env),
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX uses it, the helper sets no other
+    directory in code (the config value is exactly the variable's)."""
+    placed, in_use = _cache_probe(JAX_COMPILATION_CACHE_DIR=str(tmp_path), JAX_PLATFORMS="tpu")
+    assert placed == in_use == str(tmp_path)
+
+
+def test_compile_cache_default_is_one_fixed_in_checkout_path():
+    """Unset: <checkout>/.jax_cache, derived from the package's location —
+    the same string in every process (the path is part of what makes the
+    next process find the entries), never a temp dir, a pid or a time.
+    No backend is initialized, so asking for the TPU platform needs none."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(util.__file__)))
+    first = _cache_probe(JAX_PLATFORMS="tpu")
+    second = _cache_probe(JAX_PLATFORMS="tpu,cpu")
+    assert first == second == [os.path.join(root, ".jax_cache")] * 2
+
+
+def test_compile_cache_not_placed_for_cpu_pinned_processes():
+    """The CPU test worlds get no cache unless the variable names one."""
+    assert _cache_probe(JAX_PLATFORMS="cpu") == [None, None]
