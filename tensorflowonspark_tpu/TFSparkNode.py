@@ -233,6 +233,13 @@ def _child_entry(fn, tf_args, ctx, cluster_meta, error_queue_spec):
                 chip_ids=ctx.topology.get("chip_ids"), platform=env.get("JAX_PLATFORMS")
             )
         )
+        # the two parts of a child's start that are not this package's code,
+        # each timed where it happens (a user's main_fun starts after both)
+        with obs_trace.span("child_import_jax") as imported:
+            import jax
+        obs_registry.gauge(
+            "node_import_jax_seconds", help="seconds the jax child spent in `import jax`"
+        ).set(imported.dur_s)
         if env.get("JAX_PLATFORMS"):
             util.force_platform(env["JAX_PLATFORMS"], env.get("TOS_NUM_CPU_DEVICES"))
         util.place_compile_cache()
@@ -248,18 +255,20 @@ def _child_entry(fn, tf_args, ctx, cluster_meta, error_queue_spec):
         # from here a preemption warning (SIGTERM, driver preempt key, or
         # the node.preempt chaos site) drains instead of dying abruptly
         _arm_preemption(ctx.mgr, ctx, publisher)
-        if cluster_meta.get("jax_distributed", True):
-            ctx.initialize_distributed()
-        try:
-            import jax
-
-            tpu_info.validate_against_runtime(jax.local_device_count())
-        except Exception:  # validation is advisory
-            pass
+        with obs_trace.span("child_backend_start") as started:
+            if cluster_meta.get("jax_distributed", True):
+                ctx.initialize_distributed()
+            try:
+                tpu_info.validate_against_runtime(jax.local_device_count())
+            except Exception:  # validation is advisory
+                pass
+        obs_registry.gauge(
+            "node_backend_start_seconds",
+            help="seconds the jax child spent joining the jax.distributed world "
+            "and starting the backend (the accelerator runtime's start)",
+        ).set(started.dur_s)
         if cluster_meta.get("log_dir") and ctx.process_id == 0:
             try:
-                import jax
-
                 profiler_port = util.find_free_port()
                 jax.profiler.start_server(profiler_port)
                 logger.info("jax profiler server on port %d", profiler_port)

@@ -454,14 +454,12 @@ class ImagePipeline:
             return
         caching = self.cache is not None
         acc = [] if caching else None
-        t0 = time.monotonic()
-        it = SHARD_READ_RETRY.call(self._open_shard, path, cs)
-        read_c.inc(time.monotonic() - t0)
+        with obs.span("producer_read", seconds_total=read_c):
+            it = SHARD_READ_RETRY.call(self._open_shard, path, cs)
         base = 0
         while True:
-            t0 = time.monotonic()
-            chunk = next(it, None)
-            read_c.inc(time.monotonic() - t0)
+            with obs.span("producer_read", seconds_total=read_c):
+                chunk = next(it, None)
             if chunk is None:
                 break
             if caching:
@@ -793,15 +791,14 @@ class ImagePipeline:
                 if chaos.active:
                     chaos.delay("data.producer_delay")
                 batch = {"image": img_out, "label": lbl_out}
-                t0 = time.monotonic()
-                while True:
-                    try:
-                        out_q.put(batch, timeout=0.5)
-                        break
-                    except queue.Full:
-                        if stop.is_set():
-                            raise _Stopped()
-                emit_c.inc(time.monotonic() - t0)
+                with obs.span("producer_emit", seconds_total=emit_c):
+                    while True:
+                        try:
+                            out_q.put(batch, timeout=0.5)
+                            break
+                        except queue.Full:
+                            if stop.is_set():
+                                raise _Stopped()
                 produced_c.inc()
                 depth_g.set(out_q.qsize())
 
@@ -938,12 +935,11 @@ class ImagePipeline:
                 if not pending:
                     return
                 slots = free_slots[: len(pending)]
-                t0 = time.monotonic()
-                if plane is not None:
-                    results = _plane_round(pending, slots)
-                else:
-                    results = _thread_round(pending, slots)
-                parse_c.inc(time.monotonic() - t0)
+                with obs.span("producer_parse", seconds_total=parse_c):
+                    if plane is not None:
+                        results = _plane_round(pending, slots)
+                    else:
+                        results = _thread_round(pending, slots)
                 if ra_tuner is not None:
                     target = ra_tuner.tick(self._ra_depth[0])
                     if target is not None:
@@ -1065,9 +1061,8 @@ class ImagePipeline:
                     # next next()" contract) — its buffers go back in the pool
                     free_q.put((prev["image"], prev["label"]))
                 prev = None
-                t0 = time.monotonic()
-                item = out_q.get()
-                wait_c.inc(time.monotonic() - t0)
+                with obs.span("batch_wait", seconds_total=wait_c):
+                    item = out_q.get()
                 if item is _END:
                     return
                 if isinstance(item, BaseException):

@@ -345,15 +345,14 @@ class TextPipeline(ImagePipeline):
             def _emit(batch):
                 if chaos.active:
                     chaos.delay("data.producer_delay")
-                t0 = time.monotonic()
-                while True:
-                    try:
-                        out_q.put(batch, timeout=0.5)
-                        break
-                    except queue.Full:
-                        if stop.is_set():
-                            raise _Stopped()
-                emit_c.inc(time.monotonic() - t0)
+                with obs.span("producer_emit", seconds_total=emit_c):
+                    while True:
+                        try:
+                            out_q.put(batch, timeout=0.5)
+                            break
+                        except queue.Full:
+                            if stop.is_set():
+                                raise _Stopped()
                 produced_c.inc()
                 depth_g.set(out_q.qsize())
 
@@ -381,51 +380,50 @@ class TextPipeline(ImagePipeline):
                 thread pool, fresh rows staged back into the cache."""
                 rows = len(bins)
                 buf, labels = _acquire()
-                t0 = time.monotonic()
-                if chaos.active:
-                    tc = time.monotonic()
-                    if chaos.delay("data.pack_stall"):
-                        stall_c.inc(time.monotonic() - tc)
-                plans = []  # (slot, plan tuple) for rows with cache misses
-                puts = []  # (crc, slot, offset, eff_len) staged after the round
-                for slot, entries in enumerate(bins):
-                    offset = 0
-                    plan = []
-                    for seg_id, (rec, eff_len) in enumerate(entries, start=1):
-                        ids, crc = _cache_hit(rec, eff_len)
-                        if ids is not None:
-                            tokenizer_mod.write_segment(buf[slot], offset, seg_id, ids)
-                        else:
-                            plan.append((offset, seg_id, eff_len, rec))
-                            if crc is not None:
-                                puts.append((crc, slot, offset, eff_len))
-                        offset += eff_len
-                    labels[slot] = len(entries)
-                    if plan:
-                        plans.append((slot, tuple(plan)))
-                if plane is not None:
-                    if plans:
-                        try:
-                            failures = plane.run_round(
-                                buf, labels, plans, should_stop=stop.is_set
-                            )
-                        except decode_plane.Stopped:
-                            raise _Stopped()
-                        if failures:
-                            # token_length already validated every record —
-                            # a worker-side encode failure is a real bug,
-                            # not a budget event
-                            raise failures[0][1]
-                else:
-                    list(pool.map(lambda sp: into(sp[1], buf[sp[0]]), plans))
-                cache = cache_box[0]
-                if cache is not None:
-                    padded = np.zeros((L,), np.int32)
-                    for crc, slot, offset, eff_len in puts:
-                        padded[...] = 0
-                        padded[:eff_len] = buf[slot, 0, offset : offset + eff_len]
-                        cache.put(crc, padded, eff_len)
-                parse_c.inc(time.monotonic() - t0)
+                with obs.span("producer_parse", seconds_total=parse_c):
+                    if chaos.active:
+                        tc = time.monotonic()
+                        if chaos.delay("data.pack_stall"):
+                            stall_c.inc(time.monotonic() - tc)
+                    plans = []  # (slot, plan tuple) for rows with cache misses
+                    puts = []  # (crc, slot, offset, eff_len) staged after the round
+                    for slot, entries in enumerate(bins):
+                        offset = 0
+                        plan = []
+                        for seg_id, (rec, eff_len) in enumerate(entries, start=1):
+                            ids, crc = _cache_hit(rec, eff_len)
+                            if ids is not None:
+                                tokenizer_mod.write_segment(buf[slot], offset, seg_id, ids)
+                            else:
+                                plan.append((offset, seg_id, eff_len, rec))
+                                if crc is not None:
+                                    puts.append((crc, slot, offset, eff_len))
+                            offset += eff_len
+                        labels[slot] = len(entries)
+                        if plan:
+                            plans.append((slot, tuple(plan)))
+                    if plane is not None:
+                        if plans:
+                            try:
+                                failures = plane.run_round(
+                                    buf, labels, plans, should_stop=stop.is_set
+                                )
+                            except decode_plane.Stopped:
+                                raise _Stopped()
+                            if failures:
+                                # token_length already validated every record —
+                                # a worker-side encode failure is a real bug,
+                                # not a budget event
+                                raise failures[0][1]
+                    else:
+                        list(pool.map(lambda sp: into(sp[1], buf[sp[0]]), plans))
+                    cache = cache_box[0]
+                    if cache is not None:
+                        padded = np.zeros((L,), np.int32)
+                        for crc, slot, offset, eff_len in puts:
+                            padded[...] = 0
+                            padded[:eff_len] = buf[slot, 0, offset : offset + eff_len]
+                            cache.put(crc, padded, eff_len)
                 n_tokens = sum(n for entries in bins for _, n in entries)
                 tokens_c.inc(n_tokens)
                 seqs_c.inc(sum(len(entries) for entries in bins))
@@ -533,9 +531,8 @@ class TextPipeline(ImagePipeline):
         thread.start()
         try:
             while True:
-                t0 = time.monotonic()
-                item = out_q.get()
-                wait_c.inc(time.monotonic() - t0)
+                with obs.span("batch_wait", seconds_total=wait_c):
+                    item = out_q.get()
                 if item is _END:
                     return
                 if isinstance(item, BaseException):

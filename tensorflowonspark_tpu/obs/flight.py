@@ -327,6 +327,12 @@ def current(create=True):
         return _recorder
 
 
+def is_open():
+    """True while this process has a flight shard open (no lock, nothing
+    created: per-step spans ask on the hot path)."""
+    return _recorder is not None
+
+
 def dump(reason):
     """Dump the process-global recorder, if the tracing plane is active."""
     rec = current()

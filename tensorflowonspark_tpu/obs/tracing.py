@@ -53,6 +53,21 @@ tracing analogue of chaos-obs-coverage):
 ``elastic_relaunch``       recovery-ladder relaunch attempt
 ``elastic_regrow``         scaler-initiated regrow restart (drain → relaunch)
 ``control_decision``       marker span for a Controller knob move
+``child_import_jax``       the jax child's ``import jax`` (gauge ``node_import_jax_seconds``)
+``child_backend_start``    the jax child joining the world and starting the backend (gauge ``node_backend_start_seconds``)
+``h2d_place``              ``shard_batch`` placing one host batch on the mesh (per step)
+``batch_wait``             the training loop waiting on the input pipeline's queue (per step)
+``producer_read``          the input pipeline's reader opening a shard or reading a chunk
+``producer_parse``         the input pipeline's producer decoding / packing one batch
+``producer_emit``          the input pipeline's producer waiting on a full prefetch queue
+``step_dispatch``          one call of a compiled train step (per step; the trace's Steps line)
+
+The last six are opened every step (or every chunk): they pass their own
+``*_seconds_total`` counter to ``span(..., seconds_total=)``, which is all
+the registry keeps of them — no event, no histogram — and they take a span
+id only while a flight shard is open. Every span, of either kind, is also a
+``tos.<name>`` ``TraceAnnotation`` in a ``jax.profiler`` trace when the
+process has jax imported (:mod:`~tensorflowonspark_tpu.obs.trace`).
 
 ``comm_allreduce``/``comm_window`` and ``pipeline_stage``/
 ``pipeline_transfer`` are *retroactive* spans (:func:`record_span`): the
@@ -300,15 +315,3 @@ def observe_clock(server_ts, t0, t1):
     if rec is not None:
         rec.set_clock_offset(offset, rtt=rtt)
     return offset
-
-
-# -- convenience -------------------------------------------------------------
-
-
-def span(name, registry=None, **attrs):
-    """Alias for :func:`tensorflowonspark_tpu.obs.trace.span` (the single
-    span implementation — every span participates in tracing when a context
-    is active)."""
-    from tensorflowonspark_tpu.obs import trace as _trace
-
-    return _trace.span(name, registry=registry, **attrs)

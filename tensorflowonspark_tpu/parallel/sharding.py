@@ -147,6 +147,10 @@ def shard_params(params, mesh, specs=None):
     )
 
 
+#: shard_batch's two counters, taken from the registry at its first call
+_place_counters = None
+
+
 def shard_batch(batch, mesh):
     """Place a host-local batch pytree onto the mesh, sharded over data axes.
 
@@ -156,12 +160,36 @@ def shard_batch(batch, mesh):
     of the reference's per-executor feed queues (each executor fed only its own
     partition; here each host's partition becomes its shard of the global
     batch).
+
+    The call is the ``h2d_place`` span: its host seconds and the bytes it
+    hands to the device are counted (``h2d_place_seconds_total``,
+    ``h2d_place_bytes_total``). The copy itself is asynchronous; what the
+    span holds is what the loop's thread pays for it.
     """
     import jax
 
+    from tensorflowonspark_tpu import obs
+
     sharding = batch_sharding(mesh)
     if jax.process_count() == 1:
-        return jax.tree.map(lambda x: jax.device_put(x, sharding), batch)
-    return jax.tree.map(
-        lambda x: jax.make_array_from_process_local_data(sharding, x), batch
-    )
+        def place(x):
+            return jax.device_put(x, sharding)
+    else:
+        def place(x):
+            return jax.make_array_from_process_local_data(sharding, x)
+    if not obs.enabled():
+        return jax.tree.map(place, batch)
+    global _place_counters
+    if _place_counters is None:
+        _place_counters = (
+            obs.counter(
+                "h2d_place_seconds_total",
+                help="host seconds inside shard_batch (placing batches on the mesh)",
+            ),
+            obs.counter("h2d_place_bytes_total", help="bytes shard_batch handed to the device"),
+        )
+    seconds, placed_bytes = _place_counters
+    with obs.span("h2d_place", seconds_total=seconds):
+        placed = jax.tree.map(place, batch)
+    placed_bytes.inc(sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(batch)))
+    return placed

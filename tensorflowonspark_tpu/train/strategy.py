@@ -12,13 +12,16 @@ hand and no PS; sync DP over ICI serves both of the reference's modes
 (SURVEY.md §2.6).
 """
 
+import collections
 import logging
+import statistics
 import threading
 import time
 
 import jax
 import jax.numpy as jnp
 
+from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.parallel import (
     batch_sharding,
     build_mesh,
@@ -69,6 +72,104 @@ class TrainState:
 jax.tree_util.register_pytree_node(
     TrainState, TrainState.tree_flatten, TrainState.tree_unflatten
 )
+
+#: how many dispatch intervals a step callable remembers, and how far over
+#: their median one has to be to count as a stall
+STALL_WINDOW = 32
+STALL_FACTOR = 2.0
+
+
+class StallMeter:
+    """The last ``STALL_WINDOW`` device-paced intervals between dispatches of
+    one step callable. Under a loop that keeps a bounded number of steps in
+    flight the host dispatches at the device's pace, so an interval far over
+    the median is a gap between steps, read without a fence."""
+
+    def __init__(self):
+        self._recent = collections.deque(maxlen=STALL_WINDOW)
+
+    def note(self, interval):
+        """Remember ``interval``; returns how far it is over the median of
+        those before it when it is over ``STALL_FACTOR`` times that median,
+        else 0."""
+        excess = 0.0
+        if self._recent:
+            median = statistics.median(self._recent)
+            if interval > STALL_FACTOR * median:
+                excess = interval - median
+        self._recent.append(interval)
+        return excess
+
+
+class TrainStep:
+    """What :meth:`SyncDataParallel.compile_train_step` returns: the jitted
+    step behind the ``step_dispatch`` span. ``lower``, ``trace`` and every
+    other attribute are the jitted function's own.
+
+    Each call is one ``tos.step_dispatch`` step annotation in a profiler
+    trace and is counted (``train_steps_dispatched_total``,
+    ``train_step_dispatch_seconds_total``). The interval since the call
+    before goes to a :class:`StallMeter` only when both calls found the step
+    before them still running: the device's queue was never empty, so the
+    loop came back at the device's pace, and an interval the meter finds long
+    means the device was late (``train_step_stalls_total``,
+    ``train_step_stall_seconds_total``). Any other interval is the host's —
+    the first call's compilation, a fence, a slow input, the short one after
+    any of them — and is neither remembered nor booked, so the median holds
+    device-paced intervals alone. (A loop that bounds nothing itself paces
+    with the device only once JAX's own queue is full: until then its short
+    intervals fill the history.) With collection off (``TOS_OBS=0``) a call
+    is the jitted function's.
+    """
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self._meter = StallMeter()
+        self._dispatched = 0
+        self._last_at = None
+        self._last_loss = None
+        self._found_running = False
+        # registered here, not at the first stall: a reader has to tell a
+        # clean window (0) from a program that does not count (absent)
+        self._steps = obs.counter(
+            "train_steps_dispatched_total", help="calls of a compiled train step"
+        )
+        self._seconds = obs.counter(
+            "train_step_dispatch_seconds_total",
+            help="host seconds inside calls of a compiled train step (dispatch, "
+            "and the first call's compilation or cache load)",
+        )
+        self._stalls = obs.counter(
+            "train_step_stalls_total",
+            help="dispatch intervals that found the step before still running and "
+            "were over twice the median of the last 32 such (the device was late)",
+        )
+        self._stall_seconds = obs.counter(
+            "train_step_stall_seconds_total",
+            help="seconds by which those stalls exceeded the median interval",
+        )
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+    def __call__(self, state, batch):
+        if not obs.enabled():
+            return self._jitted(state, batch)
+        now = time.monotonic()
+        running = self._last_loss is not None and not self._last_loss.is_ready()
+        if running and self._found_running:
+            excess = self._meter.note(now - self._last_at)
+            if excess:
+                self._stalls.inc()
+                self._stall_seconds.inc(excess)
+        self._found_running = running
+        self._last_at = now
+        self._dispatched += 1
+        self._steps.inc()
+        with obs.span("step_dispatch", seconds_total=self._seconds, step_num=self._dispatched):
+            out = self._jitted(state, batch)
+        self._last_loss = out[1]["loss"]
+        return out
 
 
 class SyncDataParallel:
@@ -282,7 +383,19 @@ class SyncDataParallel:
         A ``loss_fn`` that declares a ``step`` keyword receives the current
         ``state.step`` — the supported way to vary per-step randomness
         (dropout rngs) without smuggling counters through the batch.
+
+        The callable is a :class:`TrainStep`: the jitted function behind the
+        ``step_dispatch`` span and its counters.
         """
+        return TrainStep(self._jit_train_step(loss_fn, optimizer, has_aux, mutable, donate))
+
+    def _jit_train_step(self, loss_fn, optimizer, has_aux, mutable, donate):
+        """The bare jitted step. Its two halves carry ``jax.named_scope``s,
+        so every operation's ``op_name`` in the compiled program and in a
+        profiler trace says its phase: under ``tos.optimizer`` the optimizer;
+        under ``tos.loss_and_grad`` the forward pass, or with
+        ``transpose(jvp(`` in it the backward pass, or with
+        ``rematted_computation`` a block computed again for it."""
         import inspect
 
         import optax
@@ -292,25 +405,27 @@ class SyncDataParallel:
         except (TypeError, ValueError):
             wants_step = False
 
-        def train_step(state, batch):
+        def tos_train_step(state, batch):
             kw = {"step": state.step} if wants_step else {}
-            if mutable:
-                (loss, (model_state, aux)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(state.params, state.model_state, batch, **kw)
-            else:
-                out = jax.value_and_grad(loss_fn, has_aux=has_aux)(state.params, batch, **kw)
-                (loss, aux), grads = out if has_aux else ((out[0], None), out[1])
-                model_state = state.model_state
-            updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("tos.loss_and_grad"):
+                if mutable:
+                    (loss, (model_state, aux)), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True
+                    )(state.params, state.model_state, batch, **kw)
+                else:
+                    out = jax.value_and_grad(loss_fn, has_aux=has_aux)(state.params, batch, **kw)
+                    (loss, aux), grads = out if has_aux else ((out[0], None), out[1])
+                    model_state = state.model_state
+            with jax.named_scope("tos.optimizer"):
+                updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
             new_state = TrainState(state.step + 1, params, opt_state, model_state)
             metrics = {"loss": loss, "step": new_state.step}
             if aux:
                 metrics.update(aux)
             return new_state, metrics
 
-        return jax.jit(train_step, donate_argnums=(0,) if donate else ())
+        return jax.jit(tos_train_step, donate_argnums=(0,) if donate else ())
 
     def compile_train_loop(self, loss_fn, optimizer, num_steps, has_aux=False, mutable=False, donate=True, packed=False):
         """Compile ``loop(state, batches) -> (state, last_metrics)`` running
@@ -353,9 +468,7 @@ class SyncDataParallel:
         measured on a host that was not co-located with its device, which
         dwarfs per-batch pipelining).
         """
-        step = self.compile_train_step(
-            loss_fn, optimizer, has_aux=has_aux, mutable=mutable, donate=False
-        )
+        step = self._jit_train_step(loss_fn, optimizer, has_aux, mutable, donate=False)
 
         def loop(state, batches):
             if packed:

@@ -14,8 +14,10 @@ import logging
 import multiprocessing
 import os
 import socket
+import sys
+import threading
 
-from tensorflowonspark_tpu import durable
+from tensorflowonspark_tpu import durable, obs
 
 logger = logging.getLogger(__name__)
 
@@ -173,12 +175,21 @@ def place_compile_cache():
     pinned to the CPU platform (the test worlds) gets none unless the
     variable names one: XLA:CPU logs a multi-kilobyte machine-feature
     complaint for every executable it loads back.
+
+    Where jax is imported by then, the process also starts keeping what JAX
+    reports of its compilations and cache loads (``compile_cache_*`` and
+    ``compile_backend_seconds`` gauges).
     """
     placed = os.environ.get(COMPILE_CACHE_ENV)
     if placed:
+        # nothing to set: a process that has not imported jax (the thin
+        # serving client) is not made to, and has no compilations to report
+        if "jax" in sys.modules:
+            _listen_to_compiles(sys.modules["jax"])
         return placed
     import jax
 
+    _listen_to_compiles(jax)
     if (jax.config.jax_platforms or "").split(",")[0] == "cpu":
         return None
     path = os.path.join(
@@ -186,6 +197,63 @@ def place_compile_cache():
     )
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_thread = threading.local()
+_listening = False
+
+
+def _listen_to_compiles(jax):
+    """Register, once in a process, the listener that keeps what JAX reports
+    of its compilations in four gauges (gauges, because set-up is over before
+    anybody takes a window's delta of counters)."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    for gauge in _compile_gauges():  # a process that never loads reads 0, not nothing
+        gauge.inc(0)
+    jax.monitoring.register_event_duration_secs_listener(_note_compile_event)
+
+
+def _compile_gauges():
+    return (
+        obs.gauge(
+            "compile_cache_load_seconds",
+            help="seconds spent loading executables from the persistent compile cache",
+        ),
+        obs.gauge(
+            "compile_cache_hits", help="programs loaded from the persistent compile cache"
+        ),
+        obs.gauge(
+            "compile_backend_seconds",
+            help="seconds the backend spent compiling programs it did not load",
+        ),
+        obs.gauge(
+            "compile_cache_misses",
+            help="programs the backend compiled, not loaded from the cache",
+        ),
+    )
+
+
+def _note_compile_event(event, secs, **_kw):
+    """A program loaded from the persistent cache reports its retrieval and
+    then, on the same thread, a "backend compile" that holds nothing else;
+    one that was compiled reports the latter alone."""
+    if event not in (_CACHE_LOAD_EVENT, _BACKEND_COMPILE_EVENT):
+        return
+    load_seconds, hits, backend_seconds, misses = _compile_gauges()
+    if event == _CACHE_LOAD_EVENT:
+        _compile_thread.loaded = True
+        load_seconds.inc(secs)
+        hits.inc()
+    elif getattr(_compile_thread, "loaded", False):
+        _compile_thread.loaded = False
+    else:
+        backend_seconds.inc(secs)
+        misses.inc()
 
 
 def single_node_env(num_cpu_devices=None, platform=None):

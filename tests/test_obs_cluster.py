@@ -71,6 +71,11 @@ class TestClusterMetrics:
             assert snap["counters"]["reservation_registrations_total"]["value"] >= 2
             # per-node detail survives the merge
             assert set(snap["nodes"]) == {"worker:0", "worker:1"}
+            # each child timed its own start, where it happens (before main_fun)
+            for node_snap in snap["nodes"].values():
+                assert node_snap["gauges"]["node_import_jax_seconds"]["value"] > 0
+                assert node_snap["gauges"]["node_backend_start_seconds"]["value"] > 0
+                assert {"child_import_jax", "child_backend_start"} <= {e["span"] for e in node_snap["events"]}
             for node_snap in snap["nodes"].values():
                 assert node_snap["counters"]["child_marks_total"]["value"] == 1
             # the adaptive feed's five metrics cross the channel: gauges and

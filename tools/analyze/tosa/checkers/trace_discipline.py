@@ -20,8 +20,7 @@ from the code.  Three invariants:
 Checks 1 and 2 are per-file; check 3 is cross-file and is skipped when
 ``obs/tracing.py`` is not part of the scanned set (fixture runs).  The
 ``obs`` package's own internals are exempt throughout (the ``span()``
-factory and the lazy ``tracing.span`` alias pass names through as
-variables by design).
+factory passes names through as variables by design).
 """
 
 import ast
