@@ -81,6 +81,7 @@ MODULES = [
     "tensorflowonspark_tpu.models.segmentation",
     "tensorflowonspark_tpu.models.transformer",
     "tensorflowonspark_tpu.ops.flash_attention",
+    "tensorflowonspark_tpu.ops.flash_blocks",
     "tensorflowonspark_tpu.ops.fused_bn",
     "tensorflowonspark_tpu.backends",
     "tensorflowonspark_tpu.backends.local",

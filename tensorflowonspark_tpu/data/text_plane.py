@@ -58,6 +58,7 @@ from tensorflowonspark_tpu import chaos, obs
 from tensorflowonspark_tpu.data import decode_plane, slab_cache
 from tensorflowonspark_tpu.data import tokenizer as tokenizer_mod
 from tensorflowonspark_tpu.data.loader import ImagePipeline, _Stopped
+from tensorflowonspark_tpu.ops import flash_blocks
 
 logger = logging.getLogger(__name__)
 
@@ -249,6 +250,16 @@ class TextPipeline(ImagePipeline):
         pad_g = obs.gauge(
             "text_pad_fraction", help="cumulative pad fraction of emitted [B, L] slots"
         )
+        blocks_needed_c = obs.counter(
+            "flash_blocks_needed_total",
+            help="attention blocks the segmented flash kernels compute for the emitted "
+            "rows (some query shares a document with some key), per head and pass",
+        )
+        blocks_dense_c = obs.counter(
+            "flash_blocks_dense_total",
+            help="blocks of the causal triangle over the emitted rows: what the kernels "
+            "computed before they skipped by the packing",
+        )
 
         # the pack plane forks its workers HERE, before any pipeline thread
         # exists (fork-with-threads is the one mp lifecycle hazard)
@@ -432,6 +443,10 @@ class TextPipeline(ImagePipeline):
                 eff = emitted_tokens[0] / emitted_slots[0]
                 eff_g.set(eff)
                 pad_g.set(1.0 - eff)
+                # the columns the LM attends (make_loss_fn feeds [:, :-1])
+                needed, dense = flash_blocks.attended_blocks(buf[:rows, 1, :-1])
+                blocks_needed_c.inc(needed)
+                blocks_dense_c.inc(dense)
                 if plane is not None:
                     # slab views are copied out and the slab returns to the
                     # pool at once (yielded batches are retainable)

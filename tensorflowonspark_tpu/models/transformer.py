@@ -114,9 +114,10 @@ def _flash(q, k, v, segment_ids, mesh, interpret):
     batch over the data axes, heads over ``tp`` — attention is independent
     across both, so no collective is needed inside."""
     from tensorflowonspark_tpu.ops.flash_attention import flash_attention
+    from tensorflowonspark_tpu.ops.flash_blocks import GRANULE
 
     seq = q.shape[2]
-    pad = (-seq) % 128
+    pad = (-seq) % GRANULE
     if pad:
         # causal masking means queries < seq never attend to the zero
         # padding appended after them, so pad-run-slice is exact; with

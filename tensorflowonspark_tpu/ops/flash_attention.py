@@ -7,6 +7,25 @@ recomputes probabilities per block from the saved log-sum-exp and accumulates
 dq / dk / dv — three matmul-dominated kernels that keep the MXU busy while
 HBM traffic stays O(L·D).
 
+**The block map.** The kernels visit only the blocks the job needs. From the
+segment ids (in the jitted step, a few thousand integers in XLA) comes, per
+batch row, each block's [smallest, largest] id
+(:mod:`~tensorflowonspark_tpu.ops.flash_blocks` holds the rule) and from
+those the range of kv blocks every q block needs and the range of q blocks
+every kv block needs. The two range tables ride into the kernels as
+scalar-prefetch operands: the accumulating grid axis walks the range from
+its first block and then parks on its last, so a step with nothing to do
+names the block already resident and Pallas copies nothing, and only a new
+block of the range is computed (for the text plane's ids, which do not
+decrease along a row, the range holds needed blocks only). A skipped block
+would have contributed exactly 0 (``exp(_NEG_BIG - m)``), so outputs and
+gradients are bit-identical to the dense grid's. Without segment ids the map
+is the causal triangle (or everything). A computed block is masked as
+before, causal and fence both: choosing a body by what a block needs (no
+mask below the diagonal inside one document) was built and read on the chip
+in PR 25, where it ran 2% slower than masking always and cost three times
+the tracing (PERF.md §6).
+
 This is the single-device analogue of
 :mod:`tensorflowonspark_tpu.parallel.ring_attention` (same math, blocks
 streamed from local HBM instead of rotated over ICI). ``interpret=True`` runs
@@ -21,17 +40,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
+from tensorflowonspark_tpu.ops import flash_blocks
+from tensorflowonspark_tpu.ops.flash_blocks import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q
 
-# tuned on v5e (L=4096, d=64, bf16): 512/512 runs ~1.3x faster than XLA's
-# fused attention; 128/128 only ties it
-DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_K = 512
+_NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 #: row-statistics (lse/delta) are stored [BH, L, _STAT_W]: TPU block shapes
 #: need a tileable trailing dim, and a trailing dim equal to the full array
 #: dim is allowed, so 8 lanes is the cheapest legal width
 _STAT_W = 8
+
+
+def _walk(lo_ref, hi_ref, at, j):
+    """Step ``j`` of a kernel's accumulating grid axis: the block it visits,
+    walking the outer block's needed range ``[lo, hi]`` (entry ``at`` of the
+    map) from ``lo`` and parking on ``hi``, and whether that block is new
+    (False once parked, and for an empty range, ``hi < lo``).
+
+    Scalar code here and in :func:`_here` is written in ``lax`` primitives:
+    it is traced into every index map of every call (96 a step in a 24-layer
+    model), and a ``jnp`` wrapper or a floor division costs milliseconds of
+    lowering each time (PERF.md §6, PR 25)."""
+    lo, hi = lo_ref[at], hi_ref[at]
+    return jax.lax.max(jax.lax.min(lo + j, hi), 0), lo + j <= hi
+
+
+def _row(b, heads):
+    """The batch row of grid index ``b`` over batch·heads (``b`` ≥ 0, so the
+    truncating division is the floor)."""
+    return jax.lax.div(b, jnp.int32(heads))
+
+
+def _here(tabs, heads, n_outer):
+    """This grid step's place: ``(outer, inner, needed)`` — the block of
+    grid dim 1, the block that :func:`_walk` visits along the accumulating
+    dim, and whether to compute it."""
+    outer = pl.program_id(1)
+    at = _row(pl.program_id(0), heads) * n_outer + outer
+    return (outer,) + _walk(*tabs, at, pl.program_id(2))
 
 
 def _causal_mask(s, iq, ik, block_q, block_k):
@@ -44,37 +90,45 @@ def _segment_mask(s, sq_ref, sk_ref):
     """Packed-sequence fence: scores survive only where the query's segment
     id equals the key's. ``sq_ref`` blocks are [block_q, _STAT_W] (the same
     broadcast-lane trick as the row statistics); ``sk_ref`` blocks come from
-    the pre-transposed [BH, _STAT_W, L] layout so the kernel reads a
+    the pre-transposed [rows, _STAT_W, L] layout so the kernel reads a
     [1, block_k] row directly — no in-kernel transpose."""
     seg_q = sq_ref[0][:, :1]  # [bq, 1]
     seg_k = sk_ref[0][:1, :]  # [1, bk]
     return jnp.where(seg_q == seg_k, s, _NEG_BIG)
 
 
-def _fwd_kernel(*refs, scale, causal, segmented, block_q, block_k):
+def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k):
+    s = jax.lax.dot_general(
+        q_ref[0], k_ref[0],
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    if causal:
+        s = _causal_mask(s, iq, ik, block_q, block_k)
+    if sq_ref is not None:
+        s = _segment_mask(s, sq_ref, sk_ref)
+    return s
+
+
+def _fwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_outer):
+    tabs, refs = refs[:2], refs[2:]
     if segmented:
         q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref, acc, m, l = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l = refs
         sq_ref = sk_ref = None
-    iq, ik = pl.program_id(1), pl.program_id(2)
+    iq, ik, needed = _here(tabs, heads, n_outer)
+    j = pl.program_id(2)
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m[:] = jnp.full_like(m, _NEG_BIG)
         l[:] = jnp.zeros_like(l)
 
+    @pl.when(needed)
     def _block():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
-        if segmented:
-            s = _segment_mask(s, sq_ref, sk_ref)
+        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k)
         m_new = jnp.maximum(m[:], jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m[:] - m_new)
         p = jnp.exp(s - m_new)
@@ -86,43 +140,31 @@ def _fwd_kernel(*refs, scale, causal, segmented, block_q, block_k):
         )
         m[:] = m_new
 
-    if causal:
-        # skip blocks strictly above the diagonal
-        @pl.when(ik * block_k <= iq * block_q + (block_q - 1))
-        def _():
-            _block()
-    else:
-        _block()
-
-    @pl.when(ik == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
+        # a q block that no kv block served still writes finite rows
         denom = jnp.maximum(l[:], 1e-30)
         o_ref[0] = (acc[:] / denom).astype(o_ref.dtype)
         lse_ref[0] = jnp.broadcast_to(m[:] + jnp.log(denom), (l.shape[0], _STAT_W))
 
 
-def _bwd_dq_kernel(*refs, scale, causal, segmented, block_q, block_k):
+def _bwd_dq_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_outer):
+    tabs, refs = refs[:2], refs[2:]
     if segmented:
         q_ref, k_ref, v_ref, sq_ref, sk_ref, do_ref, lse_ref, delta_ref, dq_ref, acc = refs
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc = refs
         sq_ref = sk_ref = None
-    iq, ik = pl.program_id(1), pl.program_id(2)
+    iq, ik, needed = _here(tabs, heads, n_outer)
+    j = pl.program_id(2)
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
+    @pl.when(needed)
     def _block():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
-        if segmented:
-            s = _segment_mask(s, sq_ref, sk_ref)
+        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k)
         p = jnp.exp(s - lse_ref[0][:, :1])
         dp = jax.lax.dot_general(
             do_ref[0], v_ref[0],
@@ -136,19 +178,13 @@ def _bwd_dq_kernel(*refs, scale, causal, segmented, block_q, block_k):
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        @pl.when(ik * block_k <= iq * block_q + (block_q - 1))
-        def _():
-            _block()
-    else:
-        _block()
-
-    @pl.when(ik == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, segmented, block_q, block_k):
+def _bwd_dkv_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_outer):
+    tabs, refs = refs[:2], refs[2:]
     if segmented:
         (q_ref, k_ref, v_ref, sq_ref, sk_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -156,23 +192,17 @@ def _bwd_dkv_kernel(*refs, scale, causal, segmented, block_q, block_k):
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         sq_ref = sk_ref = None
-    ik, iq = pl.program_id(1), pl.program_id(2)  # note: kv outer, q inner
+    ik, iq, needed = _here(tabs, heads, n_outer)  # note: kv outer, q inner
+    j = pl.program_id(2)
 
-    @pl.when(iq == 0)
+    @pl.when(j == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    @pl.when(needed)
     def _block():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
-        if segmented:
-            s = _segment_mask(s, sq_ref, sk_ref)
+        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k)
         p = jnp.exp(s - lse_ref[0][:, :1])  # [bq, bk]
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[0],
@@ -191,109 +221,89 @@ def _bwd_dkv_kernel(*refs, scale, causal, segmented, block_q, block_k):
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        # q blocks strictly above this kv block contribute nothing
-        @pl.when(iq * block_q + (block_q - 1) >= ik * block_k)
-        def _():
-            _block()
-    else:
-        _block()
-
-    @pl.when(iq == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _specs(block_rows, head_dim, outer_fixed=True):
-    """BlockSpec over [BH, L, D] arrays: (1, block_rows, D) blocks; the row
-    index comes from grid dim 1 when ``outer_fixed`` else grid dim 2."""
-    if outer_fixed:
-        return pl.BlockSpec((1, block_rows, head_dim), lambda b, i, j: (b, i, 0))
-    return pl.BlockSpec((1, block_rows, head_dim), lambda b, i, j: (b, j, 0))
+def _ranges(needed, axis):
+    """First and last True along ``axis`` of ``needed`` ``[rows, n_q, n_k]``
+    as flat int32 tables; (0, -1) where there is none."""
+    n = needed.shape[axis]
+    some = needed.any(axis)
+    lo = jnp.where(some, jnp.argmax(needed, axis), 0)
+    hi = jnp.where(some, n - 1 - jnp.argmax(jnp.flip(needed, axis), axis), -1)
+    return lo.reshape(-1).astype(jnp.int32), hi.reshape(-1).astype(jnp.int32)
 
 
-def _row_specs(block_rows, outer_fixed=True):
-    if outer_fixed:
-        return pl.BlockSpec((1, block_rows, _STAT_W), lambda b, i, j: (b, i, 0))
-    return pl.BlockSpec((1, block_rows, _STAT_W), lambda b, i, j: (b, j, 0))
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _block_map(seg, n_q, n_k, block_q, block_k, causal):
+    """The block map of ``seg`` (``int32 [rows, L]``, or None: one row of one
+    segment): ``(kv_range, q_range)`` — per q block the (first, last) kv
+    block it needs, per kv block the (first, last) q block that needs it;
+    flat int32 tables, entry ``row * n + block``.
+
+    Jitted on its own so that a model's layers, which all call it on the same
+    shapes, trace and lower its few dozen integer operations once and not
+    three times a layer."""
+    if seg is None:
+        zq, zk = jnp.zeros((1, n_q), jnp.int32), jnp.zeros((1, n_k), jnp.int32)
+        bounds = (zq, zq, zk, zk)
+    else:
+        bounds = flash_blocks.block_bounds(seg, block_q, block_k, xp=jnp)
+    needed = flash_blocks.blocks_needed(bounds, block_q, block_k, causal, xp=jnp)
+    return _ranges(needed, 2), _ranges(needed, 1)
 
 
-def _seg_inputs(seg, bh, l_q, l_k):
-    """Segment-id operands for the kernels: query ids broadcast onto the
-    [BH, L, _STAT_W] row-statistics layout, key ids pre-transposed to
-    [BH, _STAT_W, L] so a kv block is a directly-loadable row vector."""
+class _Specs:
+    """BlockSpecs of one kernel's operands. ``outer`` blocks follow grid dim
+    1; ``inner`` blocks follow :func:`_walk` over the outer block's range
+    (the kernel's two scalar-prefetch tables). Segment ids are per batch
+    row, not per head: ``ids=True`` indexes them by ``b // heads``."""
+
+    def __init__(self, heads, n_outer):
+        self.heads, self.n_outer = heads, n_outer
+
+    def _index(self, inner, ids, transposed):
+        heads, n_outer = self.heads, self.n_outer
+
+        def index_map(b, o, j, lo, hi):
+            row = _row(b, heads) if ids or inner else None
+            at = _walk(lo, hi, row * n_outer + o, j)[0] if inner else o
+            first = row if ids else b
+            return (first, 0, at) if transposed else (first, at, 0)
+
+        return index_map
+
+    def rows(self, block_rows, width, inner=False, ids=False):
+        """Blocks of ``block_rows`` rows of a [·, L, width] operand."""
+        return pl.BlockSpec((1, block_rows, width), self._index(inner, ids, False))
+
+    def seg_k(self, block_k, inner=False):
+        """Blocks of the transposed [rows, _STAT_W, L] key-segment layout."""
+        return pl.BlockSpec((1, _STAT_W, block_k), self._index(inner, True, True))
+
+
+def _seg_inputs(seg):
+    """Segment-id operands for the kernels, one set per batch row: query ids
+    broadcast onto the [rows, L, _STAT_W] row-statistics layout, key ids
+    pre-transposed to [rows, _STAT_W, L] so a kv block is a
+    directly-loadable row vector."""
+    rows, seq = seg.shape
     seg = seg.astype(jnp.int32)
-    seg_q = jnp.broadcast_to(seg[:, :, None], (bh, l_q, _STAT_W))
-    seg_k = jnp.broadcast_to(seg[:, None, :], (bh, _STAT_W, l_k))
+    seg_q = jnp.broadcast_to(seg[:, :, None], (rows, seq, _STAT_W))
+    seg_k = jnp.broadcast_to(seg[:, None, :], (rows, _STAT_W, seq))
     return seg_q, seg_k
 
 
-def _seg_k_spec(block_k, outer_fixed=False):
-    """BlockSpec over the transposed [BH, _STAT_W, L] key-segment layout;
-    the kv index comes from grid dim 2 unless ``outer_fixed``."""
-    if outer_fixed:
-        return pl.BlockSpec((1, _STAT_W, block_k), lambda b, i, j: (b, 0, i))
-    return pl.BlockSpec((1, _STAT_W, block_k), lambda b, i, j: (b, 0, j))
-
-
-def _pick_block(seq, preferred):
-    """Largest power-of-two block ≤ preferred that divides seq (whole-array
-    block for short sequences); pallas pads ragged trailing blocks with
-    garbage, so blocks must tile the sequence exactly."""
-    if seq <= preferred:
-        return seq
-    b = preferred
-    while b >= 8:  # 8 = minimum sublane tile
-        if seq % b == 0:
-            return b
-        b //= 2
-    raise ValueError(
-        "sequence length {} has no 8..{} block divisor; pad the sequence "
-        "or use plain attention".format(seq, preferred)
-    )
-
-
-def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
-    bh, l_q, d = q.shape
-    l_k = k.shape[1]
-    block_q = _pick_block(l_q, block_q)
-    block_k = _pick_block(l_k, block_k)
-    grid = (bh, pl.cdiv(l_q, block_q), pl.cdiv(l_k, block_k))
-    segmented = seg is not None
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, segmented=segmented,
-        block_q=block_q, block_k=block_k,
-    )
-    in_specs = [
-        _specs(block_q, d, True),
-        _specs(block_k, d, False),
-        _specs(block_k, d, False),
-    ]
-    operands = [q, k, v]
-    if segmented:
-        seg_q, seg_k = _seg_inputs(seg, bh, l_q, l_k)
-        in_specs += [_row_specs(block_q, True), _seg_k_spec(block_k, False)]
-        operands += [seg_q, seg_k]
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[_specs(block_q, d, True), _row_specs(block_q, True)],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, l_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, l_q, _STAT_W), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=_kernel_name("fwd", segmented),
-    )(*operands)
-    return o, lse
+def _geometry(q, k, seg, block_q, block_k):
+    """``(block_q, block_k, n_q, n_k, heads)`` of a call on ``[BH, L, D]``
+    operands whose ids, if any, are ``[B, L]``."""
+    block_q = flash_blocks.pick_block(q.shape[1], block_q)
+    block_k = flash_blocks.pick_block(k.shape[1], block_k)
+    heads = q.shape[0] // (1 if seg is None else seg.shape[0])
+    return block_q, block_k, q.shape[1] // block_q, k.shape[1] // block_k, heads
 
 
 def _kernel_name(which, segmented):
@@ -305,8 +315,9 @@ def _kernel_name(which, segmented):
 
 
 def _compiler_params(interpret):
-    """batch/q-block grid dims run in any order; only the kv dim carries the
-    accumulator, so mark it 'arbitrary' and the rest 'parallel' for pipelining."""
+    """batch/outer-block grid dims run in any order; only the walked dim
+    carries the accumulator, so mark it 'arbitrary' and the rest 'parallel'
+    for pipelining."""
     if interpret:
         return None
     return pltpu.CompilerParams(
@@ -314,70 +325,93 @@ def _compiler_params(interpret):
     )
 
 
+def _call(kernel, which, tabs, grid, in_specs, out_specs, out_shape, scratch_shapes,
+          operands, segmented, interpret):
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tabs), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name=_kernel_name(which, segmented),
+    )(*tabs, *operands)
+
+
+def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
+    bh, l_q, d = q.shape
+    block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
+    segmented = seg is not None
+    kv_range, _ = _block_map(seg, n_q, n_k, block_q, block_k, causal)
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, causal=causal, segmented=segmented,
+        block_q=block_q, block_k=block_k, heads=heads, n_outer=n_q,
+    )
+    at = _Specs(heads, n_q)
+    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d, inner=True)]
+    operands = [q, k, v]
+    if segmented:
+        in_specs += [at.rows(block_q, _STAT_W, ids=True), at.seg_k(block_k, inner=True)]
+        operands += _seg_inputs(seg)
+    o, lse = _call(
+        kernel, "fwd", kv_range, (bh, n_q, n_k), in_specs,
+        out_specs=[at.rows(block_q, d), at.rows(block_q, _STAT_W)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, l_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, l_q, _STAT_W), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+        ],
+        operands=operands, segmented=segmented, interpret=interpret,
+    )
+    return o, lse
+
+
 def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interpret):
     bh, l_q, d = q.shape
     l_k = k.shape[1]
-    block_q = _pick_block(l_q, block_q)
-    block_k = _pick_block(l_k, block_k)
+    block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
     segmented = seg is not None
+    kv_range, q_range = _block_map(seg, n_q, n_k, block_q, block_k, causal)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[:, :, None], (bh, l_q, _STAT_W))
-    if segmented:
-        seg_q, seg_k = _seg_inputs(seg, bh, l_q, l_k)
+    static = dict(
+        scale=scale, causal=causal, segmented=segmented, block_q=block_q, block_k=block_k, heads=heads,
+    )
+    seg_operands = _seg_inputs(seg) if segmented else ()
 
-    dq_in_specs = [
-        _specs(block_q, d, True),
-        _specs(block_k, d, False),
-        _specs(block_k, d, False),
-    ]
-    dq_operands = [q, k, v]
+    at = _Specs(heads, n_q)  # q outer, kv walked
+    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d, inner=True)]
     if segmented:
-        dq_in_specs += [_row_specs(block_q, True), _seg_k_spec(block_k, False)]
-        dq_operands += [seg_q, seg_k]
-    dq_in_specs += [
-        _specs(block_q, d, True),
-        _row_specs(block_q, True),
-        _row_specs(block_q, True),
-    ]
-    dq_operands += [do, lse, delta]
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal, segmented=segmented,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(bh, pl.cdiv(l_q, block_q), pl.cdiv(l_k, block_k)),
-        in_specs=dq_in_specs,
-        out_specs=_specs(block_q, d, True),
+        in_specs += [at.rows(block_q, _STAT_W, ids=True), at.seg_k(block_k, inner=True)]
+    in_specs += [at.rows(block_q, d), at.rows(block_q, _STAT_W), at.rows(block_q, _STAT_W)]
+    dq = _call(
+        functools.partial(_bwd_dq_kernel, n_outer=n_q, **static), "bwd_dq", kv_range,
+        (bh, n_q, n_k), in_specs,
+        out_specs=at.rows(block_q, d),
         out_shape=jax.ShapeDtypeStruct((bh, l_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=_kernel_name("bwd_dq", segmented),
-    )(*dq_operands)
+        operands=[q, k, v, *seg_operands, do, lse, delta], segmented=segmented, interpret=interpret,
+    )
 
-    dkv_in_specs = [
-        _specs(block_q, d, False),  # q indexed by inner grid dim
-        _specs(block_k, d, True),  # k fixed per outer step
-        _specs(block_k, d, True),
-    ]
-    dkv_operands = [q, k, v]
+    at = _Specs(heads, n_k)  # kv outer, q walked
+    in_specs = [at.rows(block_q, d, inner=True), at.rows(block_k, d), at.rows(block_k, d)]
     if segmented:
-        dkv_in_specs += [_row_specs(block_q, False), _seg_k_spec(block_k, True)]
-        dkv_operands += [seg_q, seg_k]
-    dkv_in_specs += [
-        _specs(block_q, d, False),
-        _row_specs(block_q, False),
-        _row_specs(block_q, False),
+        in_specs += [at.rows(block_q, _STAT_W, inner=True, ids=True), at.seg_k(block_k)]
+    in_specs += [
+        at.rows(block_q, d, inner=True),
+        at.rows(block_q, _STAT_W, inner=True),
+        at.rows(block_q, _STAT_W, inner=True),
     ]
-    dkv_operands += [do, lse, delta]
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, segmented=segmented,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(bh, pl.cdiv(l_k, block_k), pl.cdiv(l_q, block_q)),
-        in_specs=dkv_in_specs,
-        out_specs=[_specs(block_k, d, True), _specs(block_k, d, True)],
+    dk, dv = _call(
+        functools.partial(_bwd_dkv_kernel, n_outer=n_k, **static), "bwd_dkv", q_range,
+        (bh, n_k, n_q), in_specs,
+        out_specs=[at.rows(block_k, d), at.rows(block_k, d)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, l_k, d), k.dtype),
             jax.ShapeDtypeStruct((bh, l_k, d), v.dtype),
@@ -386,10 +420,8 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=_kernel_name("bwd_dkv", segmented),
-    )(*dkv_operands)
+        operands=[q, k, v, *seg_operands, do, lse, delta], segmented=segmented, interpret=interpret,
+    )
     return dq, dk, dv
 
 
@@ -418,7 +450,7 @@ _flash_attention_bhld.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 def flash_attention(
     q, k, v, causal=False, scale=None, segment_ids=None,
-    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, interpret=False,
+    block_q=None, block_k=None, interpret=False,
 ):
     """Flash attention over ``[batch, heads, seq, head_dim]`` arrays.
 
@@ -430,17 +462,24 @@ def flash_attention(
     ``segment_ids`` (``int32 [batch, seq]``, 0 = padding) fences packed
     sequences: scores between positions with different ids are masked, so
     pack neighbours never cross-attend (the text plane's block-diagonal
-    contract). Ids are shared across heads and carry no gradient.
+    contract). Ids are shared across heads and carry no gradient. Blocks in
+    which no query shares an id with a key are neither fetched nor computed
+    (the module's text), whatever the ids; ids that do not decrease along a
+    row, as the text plane's, skip the most.
+
+    ``block_q`` / ``block_k`` default to the sizes read on the chip: the
+    segmented kernels' own when ``segment_ids`` is given.
     """
     b, h, l_q, d = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     merge = lambda t: t.reshape(b * h, t.shape[2], d)  # noqa: E731
-    seg = None
-    if segment_ids is not None:
-        seg = jnp.broadcast_to(
-            segment_ids.astype(jnp.int32)[:, None, :], (b, h, l_q)
-        ).reshape(b * h, l_q)
+    segmented = segment_ids is not None
+    if block_q is None:
+        block_q = flash_blocks.SEGMENTED_BLOCK_Q if segmented else DEFAULT_BLOCK_Q
+    if block_k is None:
+        block_k = flash_blocks.SEGMENTED_BLOCK_K if segmented else DEFAULT_BLOCK_K
+    seg = segment_ids.astype(jnp.int32) if segmented else None
     o = _flash_attention_bhld(
         merge(q), merge(k), merge(v), seg, float(scale), bool(causal),
         int(block_q), int(block_k), bool(interpret),

@@ -51,7 +51,7 @@ DEFAULT_BLOCK_R = 512
 def _pick_block_or_none(rows, preferred):
     """Largest power-of-two block ≤ preferred dividing rows exactly, or
     None when no 8..preferred divisor exists (pallas pads ragged trailing
-    blocks with garbage — same rule as flash attention's ``_pick_block``)."""
+    blocks with garbage — same rule as ``ops/flash_blocks.pick_block``)."""
     if rows <= preferred:
         return rows
     b = preferred

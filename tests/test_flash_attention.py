@@ -1,11 +1,15 @@
 """Flash-attention kernel numerics vs plain attention (pallas interpret mode)."""
 
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from tensorflowonspark_tpu.ops import flash_attention as fa
+from tensorflowonspark_tpu.ops import flash_blocks
 from tensorflowonspark_tpu.ops.flash_attention import flash_attention
 from tensorflowonspark_tpu.parallel.ring_attention import plain_attention
 
@@ -61,3 +65,301 @@ def test_bfloat16_forward():
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(expected, np.float32), atol=0.05
     )
+
+
+# -- the block map: segmented kernels compute only the blocks the packing needs
+
+_L, _BLOCK, _D = 256, 64, 32
+
+
+def _ids(lengths, seq=_L):
+    """Packed ids 1, 2, … for documents of ``lengths``, a zero tail after."""
+    row = np.zeros(seq, np.int32)
+    at = 0
+    for i, n in enumerate(lengths, start=1):
+        row[at:at + n] = i
+        at += n
+    assert at <= seq
+    return row
+
+
+def _arbitrary_ids(seed, seq=_L):
+    """Runs of ids in no order: repeats far apart, zeros inside, negatives
+    and the int32 extremes. The map may only over-approximate on these."""
+    rng = np.random.default_rng(seed)
+    pool = np.array([0, 1, 2, 3, 7, -1, -5, 2 ** 31 - 1, -(2 ** 31)], np.int64)
+    row, at = np.zeros(seq, np.int64), 0
+    while at < seq:
+        n = int(rng.integers(1, 90))
+        row[at:at + n] = rng.choice(pool)
+        at += n
+    return row.astype(np.int32)
+
+
+ROWS = {
+    "long_document": _ids([150, 40, 66]),  # one document over more than two blocks
+    "short_documents": _ids([20, 9, 31, 17, 25, 30, 12, 28, 22, 19, 27, 16]),
+    "boundary_on_block_edge": _ids([64, 128, 64]),
+    "padded_tail": _ids([70, 50, 8]),  # the tail fills more than a block
+    "all_padding": _ids([]),
+    "single_document": _ids([_L]),
+    "arbitrary_order": _arbitrary_ids(5),
+    "arbitrary_order_2": _arbitrary_ids(6),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_fn(causal, block_q=_BLOCK, block_k=_BLOCK):
+    """flash and its gradients for a cotangent ``do``, jitted once per
+    setting so that the cases of one shape share a compilation."""
+
+    def run(q, k, v, seg, do):
+        o, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, segment_ids=seg, block_q=block_q, block_k=block_k, interpret=True),
+            q, k, v)
+        return (o,) + vjp(do)
+
+    return jax.jit(run)
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_fn(causal):
+    def run(q, k, v, seg, do):
+        o, vjp = jax.vjp(lambda q, k, v: plain_attention(q, k, v, causal=causal, segment_ids=seg), q, k, v)
+        return (o,) + vjp(do)
+
+    return jax.jit(run)
+
+
+def _operands(rows, heads=2, seed=0, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    shape = (len(rows), heads, rows.shape[1], _D)
+    return tuple(jnp.asarray(rng.standard_normal(shape), dtype) for _ in range(4))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("kind", list(ROWS) + ["all_rows_in_one_batch"])
+def test_segmented_matches_plain(kind, causal):
+    """Forward, dq, dk and dv of the mapped kernels against the plain masked
+    reference, finite everywhere (an all-padding row included)."""
+    rows = np.stack(list(ROWS.values())) if kind == "all_rows_in_one_batch" else ROWS[kind][None]
+    q, k, v, do = _operands(rows, seed=len(kind))
+    got = _flash_fn(causal)(q, k, v, jnp.asarray(rows), do)
+    want = _plain_fn(causal)(q, k, v, jnp.asarray(rows), do)
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        assert np.isfinite(np.asarray(g)).all(), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", [(64, 64), (64, 32), (32, 64)])
+def test_segmented_matches_plain_at_unequal_blocks(blocks):
+    rows = np.stack([ROWS["long_document"], ROWS["short_documents"], ROWS["padded_tail"]])
+    q, k, v, do = _operands(rows, seed=11)
+    got = _flash_fn(True, *blocks)(q, k, v, jnp.asarray(rows), do)
+    want = _plain_fn(True)(q, k, v, jnp.asarray(rows), do)
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-4, err_msg=name)
+
+
+@pytest.fixture
+def patch_rule(monkeypatch):
+    """Replace ``flash_blocks.blocks_needed`` for one test. The device map is
+    jitted on its own and keeps its traces: without dropping them a patched
+    rule is never run (the test would compare the real map with itself), and
+    a later test would run the patched one."""
+
+    def patch(rule):
+        monkeypatch.setattr(flash_blocks, "blocks_needed", rule)
+        fa._block_map.clear_cache()
+
+    fa._block_map.clear_cache()
+    yield patch
+    monkeypatch.undo()
+    fa._block_map.clear_cache()
+
+
+def _block_rows(block, size=_BLOCK):
+    return slice(block * size, (block + 1) * size)
+
+
+@pytest.mark.parametrize("kind", ["long_document", "short_documents", "padded_tail", "arbitrary_order"])
+def test_skipped_blocks_are_not_read(kind):
+    """Poison: NaN in the K/V blocks a q block's map skips leaves that q
+    block's output and dq as they were; NaN in the Q/dO blocks a kv block's
+    map skips leaves its dk and dv. (A kernel that multiplied a skipped
+    block by p = 0 would spread the NaN.)"""
+    row = ROWS[kind]
+    needed = flash_blocks.needed_blocks(row[None], _BLOCK, _BLOCK)[0]
+    assert not needed.all() and needed.any(1).all()
+    # the kernels walk from the first needed block to the last: for ids in
+    # no order that range may hold a block between two needed ones
+    needed = (np.maximum.accumulate(needed, 1) & np.maximum.accumulate(needed[:, ::-1], 1)[:, ::-1]
+              if kind == "arbitrary_order" else needed)
+    q, k, v, do = _operands(row[None], heads=1, seed=3)
+    seg = jnp.asarray(row[None])
+    run = _flash_fn(True)
+    o, dq, dk, dv = (np.asarray(t) for t in run(q, k, v, seg, do))
+
+    def poisoned(t, blocks):
+        t = np.array(t)
+        for block in blocks:
+            t[:, :, _block_rows(block)] = np.nan
+        return jnp.asarray(t)
+
+    n = _L // _BLOCK
+    for iq in range(n):
+        skipped = [ik for ik in range(n) if not needed[iq, ik]]
+        o_p, dq_p, _, _ = run(q, poisoned(k, skipped), poisoned(v, skipped), seg, do)
+        np.testing.assert_array_equal(np.asarray(o_p)[:, :, _block_rows(iq)], o[:, :, _block_rows(iq)])
+        np.testing.assert_array_equal(np.asarray(dq_p)[:, :, _block_rows(iq)], dq[:, :, _block_rows(iq)])
+    for ik in range(n):
+        skipped = [iq for iq in range(n) if not needed[iq, ik]]
+        _, _, dk_p, dv_p = run(poisoned(q, skipped), k, v, seg, poisoned(do, skipped))
+        np.testing.assert_array_equal(np.asarray(dk_p)[:, :, _block_rows(ik)], dk[:, :, _block_rows(ik)])
+        np.testing.assert_array_equal(np.asarray(dv_p)[:, :, _block_rows(ik)], dv[:, :, _block_rows(ik)])
+
+
+def test_unsegmented_causal_does_not_read_above_the_diagonal():
+    q, k, v, do = _operands(np.zeros((1, _L), np.int32), heads=1, seed=4)
+    run = _flash_fn(True)
+    clean = run(q, k, v, None, do)
+    tail = np.array(k)
+    tail[:, :, _BLOCK:] = np.nan  # q block 0 needs kv block 0 alone
+    dirty = run(q, jnp.asarray(tail), v, None, do)
+    np.testing.assert_array_equal(np.asarray(dirty[0])[:, :, :_BLOCK], np.asarray(clean[0])[:, :, :_BLOCK])
+    np.testing.assert_array_equal(np.asarray(dirty[1])[:, :, :_BLOCK], np.asarray(clean[1])[:, :, :_BLOCK])
+
+
+def _random_packing(rng, seq):
+    lengths = []
+    while sum(lengths) < seq:
+        lengths.append(int(min(rng.lognormal(3.0, 1.1) + 1, seq - sum(lengths))))
+    if rng.random() < 0.7:  # most rows end in padding
+        lengths = lengths[:-1]
+    return _ids(lengths, seq)
+
+
+def _brute_force(rows, block_q, block_k, causal=True):
+    """Blocks that hold a pair the kernels' masks let through."""
+    seq = rows.shape[1]
+    pairs = rows[:, :, None] == rows[:, None, :]
+    if causal:
+        pairs &= np.tril(np.ones((seq, seq), bool))[None]
+    return pairs.reshape(len(rows), seq // block_q, block_q, seq // block_k, block_k).any((2, 4))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(64, 64), (64, 32), (32, 64), (16, 16)])
+def test_needed_blocks_against_brute_force(blocks, causal):
+    """Exact for packed ids; never short of a needed block for any ids."""
+    rng = np.random.default_rng(sum(blocks) + causal)
+    packed = np.stack([_random_packing(rng, 512) for _ in range(40)])
+    np.testing.assert_array_equal(
+        flash_blocks.needed_blocks(packed, *blocks, causal=causal), _brute_force(packed, *blocks, causal))
+    wild = np.stack([_arbitrary_ids(seed, 512) for seed in range(40)])
+    kept = flash_blocks.needed_blocks(wild, *blocks, causal=causal)
+    assert (kept | ~_brute_force(wild, *blocks, causal)).all()
+    assert not kept.all()
+
+
+def test_padding_sorts_after_every_real_id():
+    """Ids 1, 2, …, n, 0, 0: the padded last block needs itself alone, not
+    every block before it."""
+    needed = flash_blocks.needed_blocks(_ids([64, 64, 64])[None], 64, 64)[0]
+    np.testing.assert_array_equal(needed, np.eye(4, dtype=bool))
+
+
+@pytest.mark.parametrize("blocks", [(64, 64), (64, 32)])
+def test_device_map_is_the_host_rule(blocks):
+    """The jitted step's map and the host's counters state one rule: equal
+    block for block, and for packed ids the walked ranges hold exactly the
+    needed blocks."""
+    rng = np.random.default_rng(9)
+    rows = np.stack([_random_packing(rng, 512) for _ in range(8)] + [_ids([], 512), _ids([512], 512)])
+    n_q, n_k = 512 // blocks[0], 512 // blocks[1]
+    host = flash_blocks.needed_blocks(rows, *blocks)
+
+    @jax.jit
+    def device(seg):
+        bounds = flash_blocks.block_bounds(seg, *blocks, xp=jnp)
+        return flash_blocks.blocks_needed(bounds, *blocks, xp=jnp), fa._block_map(seg, n_q, n_k, *blocks, True)
+
+    needed, ((kv_lo, kv_hi), (q_lo, q_hi)) = device(jnp.asarray(rows))
+    np.testing.assert_array_equal(np.asarray(needed), host)
+    assert int((np.asarray(kv_hi) - np.asarray(kv_lo) + 1).sum()) == int(host.sum())
+    assert int((np.asarray(q_hi) - np.asarray(q_lo) + 1).sum()) == int(host.sum())
+    np.testing.assert_array_equal(np.asarray(kv_lo).reshape(len(rows), n_q), host.argmax(2))
+    np.testing.assert_array_equal(np.asarray(q_lo).reshape(len(rows), n_k), host.argmax(1))
+
+
+def test_attended_blocks_counts_what_the_kernels_run():
+    rows = np.stack([_ids([4097], 4097), _ids([600, 3000, 400], 4097)])[:, :-1]  # the columns the LM attends
+    needed, dense = flash_blocks.attended_blocks(rows)
+    block_q, block_k = flash_blocks.SEGMENTED_BLOCK_Q, flash_blocks.SEGMENTED_BLOCK_K
+    triangle = int(flash_blocks.causal_blocks(4096 // block_q, 4096 // block_k, block_q, block_k).sum())
+    assert dense == 2 * triangle
+    assert needed == triangle + int(flash_blocks.needed_blocks(rows[1:], block_q, block_k).sum()) < dense
+    # a length off the granule is padded as the model pads it
+    assert flash_blocks.attended_blocks(np.ones((3, 100), np.int32)) == (3, 3)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_map_is_bit_identical_to_the_dense_grid(causal, patch_rule):
+    """bf16 operands, float32 accumulation: skipping blocks changes no bit
+    of o, dq, dk or dv against the same kernels made to visit every block of
+    the dense grid (a fenced block adds exactly 0)."""
+    rows = np.stack([ROWS["long_document"], ROWS["short_documents"], ROWS["padded_tail"], ROWS["all_padding"]])
+    q, k, v, do = _operands(rows, seed=8, dtype=jnp.bfloat16)
+
+    def run():
+        o, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, segment_ids=jnp.asarray(rows),
+                block_q=_BLOCK, block_k=_BLOCK, interpret=True),
+            q, k, v)
+        return (o,) + vjp(do)
+
+    mapped = run()
+
+    def every_block(bounds, block_q, block_k, causal=True, xp=np):
+        rows, n_q, n_k = len(bounds[0]), bounds[0].shape[1], bounds[2].shape[1]
+        grid = flash_blocks.causal_blocks(n_q, n_k, block_q, block_k, xp) if causal else xp.ones((n_q, n_k), bool)
+        return xp.broadcast_to(grid[None], (rows, n_q, n_k))
+
+    calls = []
+
+    def counted(*args, **kwargs):
+        calls.append(1)
+        return every_block(*args, **kwargs)
+
+    patch_rule(counted)
+    dense = run()
+    assert calls, "the dense rule was never traced: the comparison would be the map against itself"
+    for m, d, name in zip(mapped, dense, ("o", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(
+            np.asarray(m, np.float32), np.asarray(d, np.float32), err_msg=name)
+
+
+def test_q_block_with_no_kv_block_writes_finite_rows(patch_rule):
+    """No ids reach this through the rule (a block always needs itself);
+    a map that leaves q block 1 nothing must still give finite o and zero
+    gradients there, forward and backward skipping alike."""
+    real = flash_blocks.blocks_needed
+
+    def starved(bounds, block_q, block_k, causal=True, xp=np):
+        needed = real(bounds, block_q, block_k, causal, xp)
+        return needed & (xp.arange(needed.shape[1]) != 1)[None, :, None]
+
+    patch_rule(starved)
+    rows = ROWS["short_documents"][None]
+    q, k, v, do = _operands(rows, seed=2)
+    o, vjp = jax.vjp(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, segment_ids=jnp.asarray(rows), block_q=_BLOCK, block_k=_BLOCK, interpret=True),
+        q, k, v)
+    grads = vjp(do)
+    for t in (o,) + grads:
+        assert np.isfinite(np.asarray(t)).all()
+    assert not np.asarray(o)[:, :, _block_rows(1)].any()
+    assert not np.asarray(grads[0])[:, :, _block_rows(1)].any()

@@ -1,0 +1,104 @@
+"""The flash kernels at the benchmark's real widths, compiled here for a
+described v5e (no chip attached; nothing runs, so this says nothing about
+results or times). It guards what the Pallas interpreter cannot see: the
+scalar-prefetch block map, its index maps and the SMEM reads lowering through
+Mosaic, the blocks fitting the chip's fast memory, and the three kernel names
+the trace reductions look for.
+
+The topology is described inside a fixture, never while a module is
+imported, and only in this file: one process at a time may hold the TPU
+library (the ``on-chip-measurement`` guide, section 2).
+"""
+
+import os
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from tensorflowonspark_tpu.ops import flash_blocks
+from tensorflowonspark_tpu.ops.flash_attention import flash_attention
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmarks import trace_reduce  # noqa: E402  (the reduction that has to find the kernels)
+
+ROWS, HEADS, SEQ, HEAD_DIM = 4, 16, 4096, 64  # lm1024.packed4k, one chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: {}".format(e))
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    # a compile for a described device is written to the persistent cache
+    # and cannot be read back without a chip: keep it out
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(one_chip, segmented, **sizes):
+    qkv = jax.ShapeDtypeStruct((ROWS, HEADS, SEQ, HEAD_DIM), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((ROWS, SEQ), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, seg=None):
+        o = flash_attention(q, k, v, causal=True, segment_ids=seg, **sizes)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    # recomputed in the backward pass, as the cell's blocks are (remat: true):
+    # under plain jax.grad the call's last scope reads jvp(flash_fwd_seg),
+    # the parent's kernels as well, and the reduction looks for the bare name
+    grad = jax.jit(jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2)))
+    lowered = grad.lower(qkv, qkv, qkv, ids) if segmented else grad.lower(qkv, qkv, qkv)
+    return lowered.compile().as_text()
+
+
+def _kernels(text):
+    """The Mosaic custom calls' kernel names, read as the benchmark's trace
+    reduction reads them from the compiled module."""
+    return sorted(trace_reduce.kernel_names(text).values())
+
+
+@pytest.mark.parametrize("blocks", [
+    {},  # the constants the program runs with
+    {"block_q": 512, "block_k": 512}, {"block_q": 512, "block_k": 256}, {"block_q": 256, "block_k": 256},
+], ids=["defaults", "512x512", "512x256", "256x256"])
+def test_segmented_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, blocks):
+    text = _compiled_text(one_chip, True, **blocks)
+    # exactly the three, one call each: a fourth kernel would be undercounted
+    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_bwd_dq_seg", "flash_fwd_seg"]
+    # per head the operands are the benchmark's; the ids ride per batch row
+    assert "bf16[{},{},{}]".format(ROWS * HEADS, SEQ, HEAD_DIM) in text
+    assert "s32[{},{},8]".format(ROWS * HEADS, SEQ) not in text
+    assert "s32[{},8,{}]".format(ROWS * HEADS, SEQ) not in text
+
+
+def test_unsegmented_kernels_compile_and_keep_their_names(one_chip, no_compile_cache):
+    text = _compiled_text(one_chip, False)
+    assert _kernels(text) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+def test_defaults_are_the_segmented_constants():
+    assert flash_blocks.pick_block(SEQ, flash_blocks.SEGMENTED_BLOCK_Q) == flash_blocks.SEGMENTED_BLOCK_Q
+    assert flash_blocks.pick_block(SEQ, flash_blocks.SEGMENTED_BLOCK_K) == flash_blocks.SEGMENTED_BLOCK_K

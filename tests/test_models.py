@@ -247,8 +247,21 @@ class TestTransformer:
         flash = transformer.create_model(mesh=mesh, attention="flash_interpret", **cfg)
         plain = transformer.create_model(mesh=mesh, attention="plain", **cfg)
         rng = np.random.default_rng(7)
-        tokens = jnp.asarray(rng.integers(0, 64, (8, 96)))
-        seg = jnp.asarray(np.repeat([[1] * 40 + [2] * 50 + [0] * 6], 8, 0), jnp.int32)
+        # rows of 1024 are 2 x 2 blocks of 512; two rows a dp shard, and the
+        # packing differs from shard to shard: shard 0's rows (and row 7) end
+        # a document on the block edge, so their lower-left block is
+        # skipped; the other rows need theirs. A block map built from the
+        # global batch, or indexed by global heads, reads another shard's
+        # rows and skips blocks these rows need
+        tokens = jnp.asarray(rng.integers(0, 64, (8, 1024)))
+        layouts = [[512, 512], [512, 100, 412], [1024], [300, 724], [100] * 10, [40, 900], [], [512, 500]]
+        seg = np.zeros((8, 1024), np.int32)
+        for row, lengths in zip(seg, layouts):
+            at = 0
+            for i, n in enumerate(lengths, start=1):
+                row[at:at + n] = i
+                at += n
+        seg = jnp.asarray(seg)
         params = plain.init(jax.random.PRNGKey(0), tokens)["params"]
 
         def loss(model):

@@ -181,6 +181,31 @@ class TestDeterminism:
                     assert list(span) == list(range(len(span)))
 
 
+    @pytest.mark.parametrize("pack_workers", [0, 2])
+    def test_flash_block_counters_follow_the_emitted_rows(self, tmp_path, pack_workers):
+        """flash_blocks_needed_total / _dense_total grow by what the
+        segmented kernels compute, and would have computed densely, for the
+        columns the LM attends of every emitted batch."""
+        from tensorflowonspark_tpu.ops import flash_blocks
+
+        def counts():
+            found = obs.snapshot()["counters"]
+            return [found.get(name, {"value": 0})["value"]
+                    for name in ("flash_blocks_needed_total", "flash_blocks_dense_total")]
+
+        # documents of 100-400 tokens in rows of 4096+1, the benchmark's rows
+        rng = np.random.default_rng(1)
+        texts = [" ".join(["w{}".format(i % 50)] * int(rng.integers(100, 400))) for i in range(200)]
+        files = _write_corpus(tmp_path, texts)
+        before = counts()
+        batches = _collect(self._pipe(files, tmp_path, seq_len=4097, epochs=1, pack_workers=pack_workers))
+        assert batches
+        want = np.sum([flash_blocks.attended_blocks(b["segment_ids"][:, :-1]) for b in batches], axis=0)
+        got = np.subtract(counts(), before)
+        assert list(got) == list(want)
+        assert 0 < got[0] < got[1]
+
+
 class TestBadRecords:
     def test_budget_charged_identically_in_every_mode(self, tmp_path):
         texts = _sample_texts(40)
