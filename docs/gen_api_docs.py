@@ -76,6 +76,7 @@ MODULES = [
     "tensorflowonspark_tpu.data.text_plane",
     "tensorflowonspark_tpu.data.imagenet",
     "tensorflowonspark_tpu.data.cifar",
+    "tensorflowonspark_tpu.models.decoder",
     "tensorflowonspark_tpu.models.mnist",
     "tensorflowonspark_tpu.models.resnet",
     "tensorflowonspark_tpu.models.segmentation",
@@ -83,6 +84,7 @@ MODULES = [
     "tensorflowonspark_tpu.ops.flash_attention",
     "tensorflowonspark_tpu.ops.flash_blocks",
     "tensorflowonspark_tpu.ops.fused_bn",
+    "tensorflowonspark_tpu.ops.grouped_matmul",
     "tensorflowonspark_tpu.backends",
     "tensorflowonspark_tpu.backends.local",
     "tosa",

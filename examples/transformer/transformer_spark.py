@@ -9,7 +9,16 @@ TPU-native capabilities the framework adds on top of reference parity:
   axis) for long context;
 * tensor parallelism (``--mesh tp=...``, `_TP_RULES` param placement);
 * mixture of experts (``--moe_experts N`` over an ``ep`` axis);
-* rematerialization (``--remat``) trading FLOPs for HBM.
+* rematerialization (``--remat``) trading FLOPs for HBM;
+* any registered LM from a configuration file: ``--model <name>
+  --model_config <file.json>`` builds the model through
+  ``models.get_model`` from the file's keys (the size flags above are then
+  unused) and trains it through the same pipeline, strategy and
+  checkpoints. ``--model decoder`` is the per-layer-plan decoder
+  (``models/decoder.py``: latent attention, routed + shared experts, of
+  which a chip may hold a share, hyper-connected residual streams); its
+  file holds the published ``config.json``'s keys, and
+  ``examples/transformer/decoder_toy.json`` is one at toy widths.
 
 Data is real: TFRecord text shards stream through the sequence-packing
 :class:`~tensorflowonspark_tpu.data.TextPipeline` (per-worker file shards,
@@ -22,6 +31,10 @@ Usage (single host):
     python examples/transformer/transformer_spark.py --train_steps 50 \
         --d_model 512 --n_layers 4 --seq_len 1024
     # 8-way CPU test: --platform cpu --mesh dp=2,tp=2,sp=2
+    # the plan-built decoder at toy widths:
+    python examples/transformer/transformer_spark.py --model decoder \
+        --model_config examples/transformer/decoder_toy.json --seq_len 128 \
+        --tokenizer word --platform cpu
 """
 
 import argparse
@@ -85,23 +98,28 @@ def main_fun(args, ctx, observer=None):
     import numpy as np
     import optax
 
-    from tensorflowonspark_tpu import parallel
-    from tensorflowonspark_tpu.models import transformer
+    from tensorflowonspark_tpu import models, parallel
+    from tensorflowonspark_tpu.models import decoder, transformer
     from tensorflowonspark_tpu.train import SyncDataParallel, checkpoint
 
     ctx.initialize_distributed()
     axes = parse_mesh(args.mesh) or {"dp": -1}
     mesh = parallel.local_mesh(axes) if ctx.num_processes == 1 else ctx.mesh(axes)
-    model = transformer.create_model(
-        mesh=mesh,
-        vocab_size=args.vocab_size, d_model=args.d_model,
-        n_layers=args.n_layers, n_heads=args.n_heads, d_ff=args.d_ff,
-        max_seq_len=args.seq_len, dtype=args.dtype, remat=args.remat,
-        moe_experts=args.moe_experts, attention=args.attention,
-    )
-    strategy = SyncDataParallel(
-        mesh, param_spec_fn=transformer.param_specs if "tp" in mesh.axis_names else None
-    )
+    if getattr(args, "model_cfg", None):
+        # a registered model from its configuration file (read on the driver)
+        model = models.get_model(args.model, mesh=mesh, **dict(
+            args.model_cfg, dtype=args.dtype, remat=args.remat, attention=args.attention))
+        args.vocab_size = model.cfg.vocab_size
+    else:
+        model = transformer.create_model(
+            mesh=mesh,
+            vocab_size=args.vocab_size, d_model=args.d_model,
+            n_layers=args.n_layers, n_heads=args.n_heads, d_ff=args.d_ff,
+            max_seq_len=args.seq_len, dtype=args.dtype, remat=args.remat,
+            moe_experts=args.moe_experts, attention=args.attention,
+        )
+    param_specs = decoder.make_param_specs(model) if isinstance(model, decoder.Decoder) else transformer.param_specs
+    strategy = SyncDataParallel(mesh, param_spec_fn=param_specs if "tp" in mesh.axis_names else None)
     optimizer = optax.adamw(args.learning_rate)
     state = strategy.create_state(
         transformer.make_init_fn(model, sample_len=8), optimizer, jax.random.PRNGKey(0)
@@ -185,6 +203,8 @@ def main_fun(args, ctx, observer=None):
             print("step {}: loss {:.3f} ({:.0f} tokens/s)".format(
                 i, float(metrics["loss"]), tps))
     stream.close()  # stop the producer (and the pack plane) before teardown
+    if steps_per_loop == 1:
+        run.drain()  # book what the model counted in the last steps
     if args.model_dir and (ctx.distributed or ctx.executor_id == 0):
         checkpoint.save_checkpoint(
             os.path.join(args.model_dir, "ckpt_{}".format(args.train_steps)),
@@ -215,6 +235,10 @@ def build_parser():
     parser.add_argument("--max_bad_records", type=int, default=0)
     parser.add_argument("--mesh", default=None,
                         help="e.g. dp=2,tp=2,sp=2 (default: all-dp)")
+    parser.add_argument("--model", default="transformer",
+                        help="a registered LM (models.get_model): transformer, or with --model_config decoder")
+    parser.add_argument("--model_config", default=None,
+                        help="JSON file of the model's configuration keys; replaces the size flags")
     parser.add_argument("--model_dir", default=None)
     parser.add_argument("--moe_experts", type=int, default=0)
     parser.add_argument("--n_heads", type=int, default=8)
@@ -236,6 +260,14 @@ def build_parser():
 
 def main(argv=None, sc=None):
     args = build_parser().parse_args(argv)
+    args.model_cfg = None
+    if args.model_config:
+        import json
+
+        with open(args.model_config) as f:
+            args.model_cfg = json.load(f)
+    elif args.model != "transformer":
+        raise SystemExit("--model {} needs --model_config".format(args.model))
 
     if not args.data_dir:
         args.data_dir = os.path.join("/tmp", "tos_transformer_corpus")

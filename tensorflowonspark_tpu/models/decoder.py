@@ -1,0 +1,541 @@
+"""Decoder LMs assembled from a per-layer plan.
+
+:mod:`~tensorflowonspark_tpu.models.transformer` is one block repeated; the
+open models of 2025-26 are not. Here a model is a list of layers, and a layer
+names three kinds — its attention, its feed-forward and its residual path —
+each a small module of its own with its own placement rules:
+
+===========  ==========  ======================================================
+part         kind        module
+===========  ==========  ======================================================
+attention    ``mla``     :class:`LatentAttention`: low-rank query and key/value
+                         paths, one rotary key shared by all heads (YaRN
+                         frequencies), queries and keys wider than values
+feed-forward ``swiglu``  :class:`SwiGLU`: the dense gated MLP
+feed-forward ``moe``     :class:`RoutedExperts`: sigmoid scores, top-k of the
+                         biased scores, the experts *held here* applied to the
+                         slots routed to them (nothing dropped), beside shared
+                         experts that see every token
+residual     ``add``     ``x + F(norm(x))``
+residual     ``mhc``     :class:`HyperConnection`: ``hc_mult`` residual
+                         streams, three learned maps per sub-layer, the
+                         stream-mixing map projected onto doubly stochastic
+                         matrices by Sinkhorn iterations (manifold-constrained
+                         hyper-connections, arXiv:2512.24880)
+===========  ==========  ======================================================
+
+The configuration is a dict with the published ``config.json``'s keys
+(:class:`DecoderConfig`); ``layer_plan`` lists the layers' kinds and defaults
+to ``first_k_dense_replace`` dense layers followed by routed ones. A chip
+that holds a share of a layer's experts says which (``experts_held``:
+first, count): the router stays as wide as the model's, and the layer adds
+only its own experts' terms — what expert parallelism asks of a layer, here
+without the exchange.
+
+Compute is ``dtype`` (bfloat16 on the chip) on float32 parameters; the
+router's scores, the hyper-connection maps, the Sinkhorn iterations and the
+softmax statistics are float32. The model plugs into
+``transformer.make_init_fn`` / ``make_loss_fn`` and ``SyncDataParallel`` as
+the dense LM does (``models.get_model("decoder", **config)``).
+
+Device scopes (``jax.named_scope``, in every operation's ``op_name``):
+``tos.mla``, ``tos.moe_route`` (router, top-k, sort, gather, combine),
+``tos.moe_experts`` (the grouped products), ``tos.moe_shared``,
+``tos.dense_mlp``, ``tos.mhc``. What the routed layers count in a step is
+sown into the ``counters`` collection (``moe_slots_routed``,
+``moe_slots_held``) and the ``gauges`` collection
+(``moe_expert_load_max_over_mean``), and registered where it is sown
+(``moe_slots_routed_total``, ``moe_slots_held_total``, the gauge);
+``make_loss_fn`` carries both out in the step's metrics and
+:class:`~tensorflowonspark_tpu.train.TrainStep` books them by name.
+"""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from tensorflowonspark_tpu import obs
+from tensorflowonspark_tpu.models import register, transformer
+from tensorflowonspark_tpu.ops import grouped_matmul as gm
+
+ATTENTION_KINDS = ("mla",)
+FEED_FORWARD_KINDS = ("swiglu", "moe")
+RESIDUAL_KINDS = ("add", "mhc")
+
+#: keys of a published ``config.json`` that say nothing this module computes
+#: from, and the values the ones it does not implement must have
+_IGNORED_KEYS = (
+    "model_type", "ep_size", "moe_layer_freq", "num_key_value_heads", "max_position_embeddings",
+    "tie_word_embeddings", "num_nextn_predict_layers",
+)
+_REQUIRED_VALUES = {
+    "attention_bias": False, "hidden_act": "silu", "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+    "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    # latent attention
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    #: the ``rope_scaling`` dict (``type: yarn``) as sorted items, or ()
+    rope_scaling: tuple = ()
+    # feed-forward
+    intermediate_size: int = 0
+    moe_intermediate_size: int = 0
+    #: the router's width: every expert of the model, held here or not
+    n_routed_experts: int = 0
+    #: (first, count) of the routed experts this chip holds; None = all
+    experts_held: tuple = None
+    num_experts_per_tok: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_k_dense_replace: int = 0
+    # residual path
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
+    rms_norm_eps: float = 1e-6
+    #: ((attention, feed-forward, residual), …), one entry per layer
+    layer_plan: tuple = None
+    dtype: str = "float32"  # compute dtype; params stay float32
+    remat: bool = False
+    attention: str = "auto"  # transformer._dispatch_attention's choices
+
+    @classmethod
+    def from_dict(cls, cfg):
+        cfg = dict(cfg)
+        for key in _IGNORED_KEYS:
+            cfg.pop(key, None)
+        for key, want in _REQUIRED_VALUES.items():
+            if cfg.pop(key, want) != want:
+                raise ValueError("decoder: {} must be {!r}".format(key, want))
+        scaling = cfg.pop("rope_scaling", None) or {}
+        if scaling and scaling.get("type") != "yarn":
+            raise ValueError("decoder: rope_scaling type {!r} is not implemented".format(scaling.get("type")))
+        cfg["rope_scaling"] = tuple(sorted(scaling.items()))
+        if cfg.get("experts_held") is not None:
+            cfg["experts_held"] = tuple(cfg["experts_held"])
+        if cfg.get("layer_plan") is not None:
+            cfg["layer_plan"] = tuple(tuple(layer) for layer in cfg["layer_plan"])
+        unknown = set(cfg) - {f.name for f in dataclasses.fields(cls)}
+        if unknown:
+            raise ValueError("decoder: unknown configuration keys {}".format(sorted(unknown)))
+        return cls(**cfg)
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def plan(self):
+        """The layers' kinds: ``layer_plan``, or the published pattern —
+        ``first_k_dense_replace`` dense layers, then routed ones."""
+        if self.layer_plan is not None:
+            plan = self.layer_plan
+        else:
+            residual = "mhc" if self.hc_mult > 1 else "add"
+            plan = tuple(
+                ("mla", "swiglu" if i < self.first_k_dense_replace or not self.n_routed_experts else "moe", residual)
+                for i in range(self.num_hidden_layers))
+        if len(plan) != self.num_hidden_layers:
+            raise ValueError("decoder: layer_plan has {} layers, num_hidden_layers is {}".format(
+                len(plan), self.num_hidden_layers))
+        for attention, feed_forward, residual in plan:
+            if (attention not in ATTENTION_KINDS or feed_forward not in FEED_FORWARD_KINDS
+                    or residual not in RESIDUAL_KINDS):
+                raise ValueError("decoder: unknown layer kinds {}".format((attention, feed_forward, residual)))
+            if residual == "add" and self.hc_mult != 1:
+                raise ValueError("decoder: an 'add' residual carries one stream (hc_mult 1)")
+        return plan
+
+    @property
+    def held(self):
+        """(first, count) of the routed experts held here."""
+        return self.experts_held if self.experts_held is not None else (0, self.n_routed_experts)
+
+
+def yarn_inv_freq(dim, theta, scaling):
+    """The rotary part's ``dim // 2`` inverse frequencies. Under YaRN the
+    slow ones are divided by ``factor``, the fast ones kept, and those in
+    the correction range (between ``beta_fast`` and ``beta_slow`` rotations
+    over the original length) blended by a linear ramp."""
+    exponents = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    kept = theta ** -exponents
+    if not scaling:
+        return kept
+
+    def correction_dim(rotations):
+        return dim * math.log(scaling["original_max_position_embeddings"] / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return (kept / scaling["factor"]) * ramp + kept * (1.0 - ramp)
+
+
+def yarn_mscale(factor, mscale):
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _rope_interleaved(x, positions, inv_freq, scale):
+    """Rotary positions over the last dim of ``x`` ``[B, L, …, D]``, its
+    pairs interleaved: (x0, x1), (x2, x3), … rotate together."""
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [B, L, D/2]
+    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3) + angles.shape[-1:])
+    cos, sin = jnp.cos(angles) * scale, jnp.sin(angles) * scale
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _norm(cfg, name):
+    return nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=cfg.compute_dtype, name=name)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention as trained: the query through a rank
+    ``q_lora_rank`` bottleneck, keys and values up from one normed latent of
+    rank ``kv_lora_rank``, and a rotary key of ``qk_rope_head_dim`` that all
+    heads share. Heads attend with queries and keys of width nope + rope
+    against values of ``v_head_dim``, through the flash kernels."""
+
+    cfg: DecoderConfig
+    mesh: object = None
+
+    PARAM_RULES = (
+        (r"attn/q_a/kernel$", ("fsdp", None)),  # [d, q_rank]
+        (r"attn/q_b/kernel$", (None, "tp", None)),  # [q_rank, H, nope + rope]
+        (r"attn/kv_a/kernel$", ("fsdp", None)),  # [d, kv_rank + rope]
+        (r"attn/kv_b/kernel$", (None, "tp", None)),  # [kv_rank, H, nope + v]
+        (r"attn/o/kernel$", ("tp", None, "fsdp")),  # [H, v, d]
+    )
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids=None):
+        cfg, dt = self.cfg, self.cfg.compute_dtype
+        heads, nope, rope, v_dim = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        scaling = dict(cfg.rope_scaling)
+        with jax.named_scope("tos.mla"):
+            c_q = _norm(cfg, "q_norm")(nn.Dense(cfg.q_lora_rank, use_bias=False, dtype=dt, name="q_a")(x))
+            q = nn.DenseGeneral((heads, nope + rope), use_bias=False, dtype=dt, name="q_b")(c_q)  # [B, L, H, 192]
+            kv = nn.Dense(cfg.kv_lora_rank + rope, use_bias=False, dtype=dt, name="kv_a")(x)
+            c_kv = _norm(cfg, "kv_norm")(kv[..., :cfg.kv_lora_rank])
+            k_rope = kv[..., cfg.kv_lora_rank:]  # [B, L, rope]: one for all heads
+            up = nn.DenseGeneral((heads, nope + v_dim), use_bias=False, dtype=dt, name="kv_b")(c_kv)
+            k_nope, v = up[..., :nope], up[..., nope:]
+
+            inv_freq = yarn_inv_freq(rope, cfg.rope_theta, scaling)
+            factor = scaling.get("factor", 1.0)
+            rope_scale = yarn_mscale(factor, scaling.get("mscale", 1.0)) / yarn_mscale(
+                factor, scaling.get("mscale_all_dim", 0.0))
+            q_rope = _rope_interleaved(q[..., nope:], positions, inv_freq, rope_scale)
+            k_rope = _rope_interleaved(k_rope, positions, inv_freq, rope_scale)
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], k_nope.shape[:-1] + (rope,))], axis=-1)
+            softmax_scale = (nope + rope) ** -0.5 * yarn_mscale(factor, scaling.get("mscale_all_dim", 0.0)) ** 2
+
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, H, L, ·]
+            out = transformer._dispatch_attention(
+                q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids, scale=softmax_scale)
+            out = out.transpose(0, 2, 1, 3)  # [B, L, H, v]
+            return nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out)
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate x) * up x)``: the dense MLP, and a shared expert."""
+
+    cfg: DecoderConfig
+    width: int
+
+    PARAM_RULES = (
+        (r"(mlp|shared)/(gate|up)/kernel$", ("fsdp", "tp")),  # [d, width]
+        (r"(mlp|shared)/down/kernel$", ("tp", "fsdp")),  # [width, d]
+    )
+
+    @nn.compact
+    def __call__(self, x):
+        dt = self.cfg.compute_dtype
+        gate = nn.Dense(self.width, use_bias=False, dtype=dt, name="gate")(x)
+        up = nn.Dense(self.width, use_bias=False, dtype=dt, name="up")(x)
+        return nn.Dense(self.cfg.hidden_size, use_bias=False, dtype=dt, name="down")(nn.silu(gate) * up)
+
+
+class RoutedExperts(nn.Module):
+    """Sigmoid-scored top-k routing over all the model's experts, the
+    experts held here computed over the slots routed to them, beside the
+    shared experts. Returns ``(y, counts)``.
+
+    Scores ``s = sigmoid(x W_r)`` in float32; chosen: the top-k of ``s + b``
+    (``b`` the selection bias: it picks, it does not weigh, and no gradient
+    reaches it); weights: ``s`` at the chosen, over their sum, times
+    ``routed_scaling_factor``. Every slot whose expert is held here is
+    computed — no capacity, nothing dropped; a slot whose expert lives on
+    another chip adds nothing here (nor is anything put in its place)."""
+
+    cfg: DecoderConfig
+
+    PARAM_RULES = (
+        (r"moe/router$", (None, None)),  # [d, E]: whole on every chip
+        (r"moe/experts_(gate|up)$", ("ep", "fsdp", "tp")),  # [held, d, width]
+        (r"moe/experts_down$", ("ep", "tp", "fsdp")),  # [held, width, d]
+    ) + SwiGLU.PARAM_RULES
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.compute_dtype
+        batch, length, d = x.shape
+        tokens, k = batch * length, cfg.num_experts_per_tok
+        first, held = cfg.held
+        width = cfg.moe_intermediate_size
+        flat = x.reshape(tokens, d)
+
+        with jax.named_scope("tos.moe_route"):
+            router = self.param("router", _kernel_init(), (d, cfg.n_routed_experts), jnp.float32)
+            bias = self.param("router_bias", nn.initializers.normal(0.02), (cfg.n_routed_experts,), jnp.float32)
+            scores = jax.nn.sigmoid(jnp.dot(
+                flat.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST))  # [T, E]
+            _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)  # [T, k]
+            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+            order, group_sizes = gm.sort_slots(chosen.reshape(-1), first, held)
+            place, rows_used = gm.slot_places(order), jnp.sum(group_sizes)
+            sorted_in = gm.rows_to_slots(flat, order, place, k)  # [T * k, d]
+            sorted_weights = weights.reshape(-1)[order]
+
+        with jax.named_scope("tos.moe_experts"):
+            init = _kernel_init(batch_axis=(0,))
+            gate = self.param("experts_gate", init, (held, d, width), jnp.float32)
+            up = self.param("experts_up", init, (held, d, width), jnp.float32)
+            down = self.param("experts_down", init, (held, width, d), jnp.float32)
+            hidden = nn.silu(gm.grouped_matmul(sorted_in, gate.astype(dt), group_sizes)) * gm.grouped_matmul(
+                sorted_in, up.astype(dt), group_sizes)
+            sorted_out = gm.grouped_matmul(hidden, down.astype(dt), group_sizes)  # [T * k, d]
+
+        with jax.named_scope("tos.moe_route"):
+            # weighted where it lies, back to slot order (a slot not held
+            # here finds a zero row), then each token's k slots summed
+            weighted = (sorted_out.astype(jnp.float32) * sorted_weights[:, None]).astype(dt)
+            per_slot = gm.slots_to_order(weighted, order, place).reshape(tokens, k, d)
+            routed = jnp.sum(per_slot, axis=1, dtype=jnp.float32).astype(dt)
+
+        with jax.named_scope("tos.moe_shared"):
+            shared = SwiGLU(cfg, width * cfg.n_shared_experts, name="shared")(flat) if cfg.n_shared_experts else 0
+        counts = {
+            "slots_routed": jnp.float32(tokens * k), "slots_held": rows_used.astype(jnp.float32),
+            "load_max_over_mean": jnp.max(group_sizes) * held / jnp.maximum(rows_used, 1).astype(jnp.float32),
+        }
+        return (routed + shared).reshape(batch, length, d), counts
+
+
+def _kernel_init(batch_axis=()):
+    return nn.initializers.variance_scaling(1.0, "fan_in", "truncated_normal", batch_axis=batch_axis)
+
+
+def sinkhorn(logits, iters, eps):
+    """``exp(logits)`` ``[…, n, n]`` scaled ``iters`` times, rows to sum 1
+    and then columns: a doubly stochastic matrix in the limit. One loop body
+    (``lax.scan``), not ``iters`` copies of it: unrolled, the 40 small
+    reductions of every sub-layer, forward, recomputed and backward, made
+    the compiled step five times its size (PERF.md §6, PR 26)."""
+
+    def round_(m, _):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        return m / (jnp.sum(m, axis=-2, keepdims=True) + eps), None
+
+    return jax.lax.scan(round_, jnp.exp(logits), None, length=iters)[0]
+
+
+class HyperConnection(nn.Module):
+    """The residual path of one sub-layer ``F`` over ``n = hc_mult`` streams
+    ``X`` ``[B, L, n, d]``: ``h = sum_i H_pre,i X_i``, ``y = F(h)``,
+    ``X'_i = sum_j H_res,ij X_j + H_post,i y``, the three maps computed from
+    the streams themselves (``x~ = vec(X) / rms(vec(X))``):
+    ``H_pre = sigmoid(a_pre x~ phi_pre + b_pre)``, ``H_post = 2 sigmoid(…)``,
+    ``H_res = sinkhorn(clamp(a_res mat(x~ phi_res) + b_res))``. The maps'
+    products take the streams as they are (``dtype``) and accumulate in
+    float32; everything after them is float32.
+
+    The module computes the maps and ``h`` (``__call__`` returns ``(h,
+    maps)``); :meth:`merge` writes ``y`` back."""
+
+    cfg: DecoderConfig
+
+    PARAM_RULES = ()  # the maps are small: whole on every chip
+
+    @nn.compact
+    def __call__(self, streams):
+        cfg = self.cfg
+        n, d = streams.shape[-2:]
+        with jax.named_scope("tos.mhc"):
+            phi_init = nn.initializers.normal((n * d) ** -0.5)
+            phi = jnp.concatenate([
+                self.param("phi_pre", phi_init, (n, d, n), jnp.float32),
+                self.param("phi_post", phi_init, (n, d, n), jnp.float32),
+                self.param("phi_res", phi_init, (n, d, n * n), jnp.float32),
+            ], axis=-1)
+            alpha = [self.param("alpha_" + name, nn.initializers.constant(0.01), (), jnp.float32)
+                     for name in ("pre", "post", "res")]
+            b_pre = self.param("b_pre", nn.initializers.zeros, (n,), jnp.float32)
+            b_post = self.param("b_post", nn.initializers.zeros, (n,), jnp.float32)
+            b_res = self.param("b_res", lambda key, shape, dtype: 4.0 * jnp.eye(n, dtype=dtype), (n, n), jnp.float32)
+
+            inv_rms = jax.lax.rsqrt(jnp.mean(jnp.square(streams.astype(jnp.float32)), axis=(-2, -1)))  # [B, L]
+            z = jnp.einsum("blnd,ndk->blk", streams, phi.astype(streams.dtype),
+                           preferred_element_type=jnp.float32) * inv_rms[..., None]
+            h_pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + b_pre)
+            h_post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] + b_post)
+            h_res = sinkhorn(
+                jnp.clip(alpha[2] * z[..., 2 * n:].reshape(z.shape[:-1] + (n, n)) + b_res,
+                         cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
+                cfg.hc_sinkhorn_iters, cfg.hc_eps)
+            h = jnp.sum(h_pre[..., None] * streams.astype(jnp.float32), axis=-2).astype(streams.dtype)
+        return h, (h_res, h_post)
+
+    @staticmethod
+    def merge(streams, maps, y):
+        h_res, h_post = maps
+        with jax.named_scope("tos.mhc"):
+            # written out stream by stream: n x n is far too small a matrix
+            # for the MXU, and as multiply-adds XLA fuses it into one pass
+            wide = streams.astype(jnp.float32)
+            mixed = sum(h_res[..., :, j, None] * wide[..., None, j, :] for j in range(wide.shape[-2]))
+            return (mixed + h_post[..., None] * y.astype(jnp.float32)[..., None, :]).astype(streams.dtype)
+
+
+class AddResidual(nn.Module):
+    """``x + F(x)`` on the one stream ``[B, L, 1, d]``."""
+
+    cfg: DecoderConfig
+
+    PARAM_RULES = ()
+
+    def __call__(self, streams):
+        return streams[..., 0, :], None
+
+    @staticmethod
+    def merge(streams, maps, y):
+        return streams + y[..., None, :]
+
+
+_RESIDUALS = {"add": AddResidual, "mhc": HyperConnection}
+
+
+class DecoderLayer(nn.Module):
+    """One layer of the plan: pre-norm attention, then pre-norm feed-forward,
+    each inside the layer's residual path. Returns ``(streams, counts)``,
+    ``counts`` what a routed feed-forward counted (else empty)."""
+
+    cfg: DecoderConfig
+    kinds: tuple
+    mesh: object = None
+
+    @nn.compact
+    def __call__(self, streams, positions, segment_ids=None):
+        cfg = self.cfg
+        _attention, feed_forward, residual = self.kinds
+        path = _RESIDUALS[residual]
+
+        h, maps = path(cfg, name="res_attn")(streams)
+        y = LatentAttention(cfg, self.mesh, name="attn")(_norm(cfg, "ln1")(h), positions, segment_ids)
+        streams = path.merge(streams, maps, y)
+
+        h, maps = path(cfg, name="res_mlp")(streams)
+        h, counts = _norm(cfg, "ln2")(h), {}
+        if feed_forward == "moe":
+            y, counts = RoutedExperts(cfg, name="moe")(h)
+        else:
+            with jax.named_scope("tos.dense_mlp"):
+                y = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
+        return path.merge(streams, maps, y), counts
+
+
+class Decoder(nn.Module):
+    cfg: DecoderConfig
+    mesh: object = None
+
+    def _constrain(self, x):
+        if self.mesh is None or self.mesh.size == 1:
+            return x
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        batch = transformer._batch_axes(self.mesh, x.shape[0])
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(self.mesh, P(batch, *([None] * (x.ndim - 1)))))
+
+    @nn.compact
+    def __call__(self, tokens, positions=None, segment_ids=None):
+        cfg = self.cfg
+        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.compute_dtype, name="embed")(tokens)
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None, :], tokens.shape)
+        streams = self._constrain(jnp.broadcast_to(x[:, :, None, :], x.shape[:2] + (cfg.hc_mult, x.shape[-1])))
+        layer = nn.remat(DecoderLayer, static_argnums=()) if cfg.remat else DecoderLayer
+        counted = []
+        for i, kinds in enumerate(cfg.plan):
+            streams, counts = layer(cfg, kinds, self.mesh, name="layer_{}".format(i))(
+                streams, positions, segment_ids)
+            streams = self._constrain(streams)
+            if counts:
+                counted.append(counts)
+        if counted:
+            # registered here, by name and with their help; the step carries the
+            # values out and TrainStep books them (obs.book_carried)
+            obs.counter(
+                "moe_slots_routed_total", help="token slots the routed-expert layers routed (tokens x experts per token)")
+            obs.counter("moe_slots_held_total", help="routed slots whose expert this chip holds (and so computed)")
+            obs.gauge(
+                "moe_expert_load_max_over_mean",
+                help="fullest held expert's slots over the held experts' mean, mean over the routed layers, "
+                "last booked step")
+            self.sow("counters", "moe_slots_routed", sum(c["slots_routed"] for c in counted))
+            self.sow("counters", "moe_slots_held", sum(c["slots_held"] for c in counted))
+            self.sow("gauges", "moe_expert_load_max_over_mean",
+                     sum(c["load_max_over_mean"] for c in counted) / len(counted))
+        x = _norm(cfg, "ln_f")(jnp.sum(streams.astype(jnp.float32), axis=-2).astype(cfg.compute_dtype))
+        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.compute_dtype, name="lm_head")(x)
+        return logits.astype(jnp.float32)
+
+
+_SHARED_RULES = (
+    (r"embed/embedding$", ("fsdp", None)),  # [vocab, d]
+    (r"lm_head/kernel$", ("fsdp", "tp")),  # [d, vocab]
+)
+
+
+def param_rules(cfg):
+    """The placement rules of the kinds ``cfg``'s plan uses."""
+    rules = []
+    for _attention, feed_forward, residual in cfg.plan:
+        for module in (LatentAttention, RoutedExperts if feed_forward == "moe" else SwiGLU, _RESIDUALS[residual]):
+            rules += [rule for rule in module.PARAM_RULES if rule not in rules]
+    return tuple(rules) + _SHARED_RULES
+
+
+def make_param_specs(model):
+    """``param_spec_fn`` for ``SyncDataParallel``: the rules of this model's
+    layer kinds through ``transformer.param_specs``' resolution (axes the
+    mesh lacks, or that do not divide, are dropped)."""
+    rules = param_rules(model.cfg)
+    return lambda params, mesh, tp_axis="tp": transformer.param_specs(params, mesh, tp_axis=tp_axis, rules=rules)
+
+
+@register("decoder")
+def create_model(mesh=None, **cfg):
+    return Decoder(DecoderConfig.from_dict(cfg), mesh=mesh)

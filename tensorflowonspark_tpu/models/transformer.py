@@ -104,9 +104,10 @@ def _batch_axes(mesh, batch):
     return axes[0] if len(axes) == 1 else tuple(axes)
 
 
-def _flash(q, k, v, segment_ids, mesh, interpret):
+def _flash(q, k, v, segment_ids, mesh, interpret, scale=None):
     """The pallas flash kernel on ``[B, H, L, D]``, padded to the kernel's
-    128-row granule and run per shard.
+    128-row granule and run per shard. ``scale`` is the softmax scale where
+    it is not ``D ** -0.5``.
 
     A Mosaic custom call has no partitioning rule, so under pjit XLA would
     gather q/k/v and run the whole global batch's attention on every chip.
@@ -128,7 +129,7 @@ def _flash(q, k, v, segment_ids, mesh, interpret):
             segment_ids = jnp.pad(segment_ids, ((0, 0), (0, pad)))
 
     def local(q, k, v, seg=None):
-        return flash_attention(q, k, v, causal=True, segment_ids=seg, interpret=interpret)
+        return flash_attention(q, k, v, causal=True, scale=scale, segment_ids=seg, interpret=interpret)
 
     if mesh is None or mesh.size == 1:
         out = local(q, k, v, segment_ids)
@@ -151,7 +152,7 @@ def _flash(q, k, v, segment_ids, mesh, interpret):
     return out[:, :, :seq] if pad else out
 
 
-def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None):
+def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None):
     """Pick the attention path. ``auto``: ring over ``sp`` when the mesh
     shards the sequence, else the pallas flash kernel on TPU (plain below
     ``TOS_FLASH_MIN_SEQ``), else plain XLA attention. Forcing
@@ -164,19 +165,21 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None):
 
     ``segment_ids`` (``int32 [B, L]``, 0 = padding) is the text plane's
     packed-sequence fence — every path turns it into the same
-    block-diagonal mask, so packed neighbours never cross-attend.
+    block-diagonal mask, so packed neighbours never cross-attend. ``scale``
+    is the softmax scale where it is not the key width's inverse root; ``v``
+    may be narrower than ``q`` and ``k`` (latent attention).
     """
     if impl not in _ATTENTION_IMPLS:
         raise ValueError(
             "unknown attention impl {!r}; expected one of {}".format(impl, _ATTENTION_IMPLS)
         )
     if impl == "plain":
-        return plain_attention(q, k, v, causal=True, segment_ids=segment_ids)
+        return plain_attention(q, k, v, causal=True, scale=scale, segment_ids=segment_ids)
     has_sp = mesh is not None and "sp" in mesh.axis_names
     if impl == "ring" or (impl == "auto" and has_sp):
-        return ring_attention_sharded(q, k, v, mesh, causal=True, segment_ids=segment_ids)
+        return ring_attention_sharded(q, k, v, mesh, causal=True, scale=scale, segment_ids=segment_ids)
     if impl == "flash_interpret":
-        return _flash(q, k, v, segment_ids, mesh, interpret=True)
+        return _flash(q, k, v, segment_ids, mesh, interpret=True, scale=scale)
     on_tpu = jax.default_backend() == "tpu"
     if impl == "flash" and not on_tpu:
         raise RuntimeError(
@@ -185,8 +188,8 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None):
             "interpreter, or 'auto'/'plain'".format(jax.default_backend())
         )
     if on_tpu and (impl == "flash" or q.shape[2] >= _FLASH_MIN_SEQ):
-        return _flash(q, k, v, segment_ids, mesh, interpret=False)
-    return plain_attention(q, k, v, causal=True, segment_ids=segment_ids)
+        return _flash(q, k, v, segment_ids, mesh, interpret=False, scale=scale)
+    return plain_attention(q, k, v, causal=True, scale=scale, segment_ids=segment_ids)
 
 
 class Attention(nn.Module):
@@ -376,9 +379,11 @@ _TP_RULES = (
 )
 
 
-def param_specs(params, mesh, tp_axis="tp"):
+def param_specs(params, mesh, tp_axis="tp", rules=_TP_RULES):
     """PartitionSpecs for the transformer's params over ``mesh``: tp rules
-    above, fsdp for what they leave unnamed, replication for the rest. Axes
+    above (or another model's ``rules`` of the same form,
+    :mod:`~tensorflowonspark_tpu.models.decoder`'s), fsdp for what they
+    leave unnamed, replication for the rest. Axes
     not present in the mesh are dropped from the specs, so the same rules
     serve dp-only, dp×tp, fsdp×sp, etc. ``tp_axis`` renames the mesh axis
     the tensor-parallel dims land on (hybrid meshes sometimes spell it
@@ -398,7 +403,7 @@ def param_specs(params, mesh, tp_axis="tp"):
             p.key if hasattr(p, "key") else str(p) for p in path
         )
         spec = None
-        for pattern, template in _TP_RULES:
+        for pattern, template in rules:
             if re.search(pattern, key):
                 axes = [tp_axis if a == "tp" else a for a in template]
                 spec = P(*(
@@ -453,7 +458,7 @@ def make_loss_fn(model):
             {"params": params}, tokens[:, :-1],
             positions=None if pos is None else pos[:, :-1],
             segment_ids=None if seg is None else seg[:, :-1],
-            mutable=["losses"],
+            mutable=["losses", "counters", "gauges"],
         )
         targets = tokens[:, 1:]
         losses = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
@@ -477,6 +482,12 @@ def make_loss_fn(model):
             moe_aux = sum(jnp.asarray(a).mean() for a in aux) / len(aux)
             metrics["moe_aux"] = moe_aux
             loss = loss + model.cfg.moe_aux_weight * moe_aux
+        # what the model counted in this step: carried out in the step's
+        # metrics, where TrainStep books it by name once the host has found
+        # the step finished
+        for kind in ("counter", "gauge"):
+            for name, sown in mods.get(kind + "s", {}).items():
+                metrics[kind + "/" + name] = sown[-1]
         return loss, metrics
 
     return loss_fn

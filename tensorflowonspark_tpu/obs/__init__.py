@@ -37,7 +37,9 @@ docs/architecture.md "Observability"). The global registry honors
 """
 
 from tensorflowonspark_tpu.obs.registry import (  # noqa: F401
+    CARRIED,
     Registry,
+    book_carried,
     counter,
     enabled,
     gauge,

@@ -309,3 +309,22 @@ def histogram(name, help="", buckets=DEFAULT_BUCKETS):
 
 def snapshot():
     return _global.snapshot()
+
+
+#: prefixes of the names under which a compiled step carries out what its
+#: model counted on the device
+CARRIED = ("counter/", "gauge/")
+
+
+def book_carried(carried):
+    """Book what a compiled step carried out of the device: ``counter/<name>``
+    is added to the counter ``<name>_total`` and ``gauge/<name>`` sets the
+    gauge ``<name>``. The model that sows them registers both under those
+    names, with their help, where it sows; this is the one place that books
+    by a name it is handed."""
+    for key, value in carried.items():
+        kind, name = key.split("/", 1)
+        if kind == "counter":
+            _global.counter(name + "_total").inc(float(value))
+        else:
+            _global.gauge(name).set(float(value))

@@ -342,6 +342,7 @@ def _call(kernel, which, tabs, grid, in_specs, out_specs, out_shape, scratch_sha
 
 def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
     bh, l_q, d = q.shape
+    d_v = v.shape[2]  # v and o may be narrower than q and k (latent attention: 192 / 128)
     block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
     segmented = seg is not None
     kv_range, _ = _block_map(seg, n_q, n_k, block_q, block_k, causal)
@@ -350,20 +351,20 @@ def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
         block_q=block_q, block_k=block_k, heads=heads, n_outer=n_q,
     )
     at = _Specs(heads, n_q)
-    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d, inner=True)]
+    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d_v, inner=True)]
     operands = [q, k, v]
     if segmented:
         in_specs += [at.rows(block_q, _STAT_W, ids=True), at.seg_k(block_k, inner=True)]
         operands += _seg_inputs(seg)
     o, lse = _call(
         kernel, "fwd", kv_range, (bh, n_q, n_k), in_specs,
-        out_specs=[at.rows(block_q, d), at.rows(block_q, _STAT_W)],
+        out_specs=[at.rows(block_q, d_v), at.rows(block_q, _STAT_W)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, l_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, l_q, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, l_q, _STAT_W), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -374,7 +375,7 @@ def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
 
 def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interpret):
     bh, l_q, d = q.shape
-    l_k = k.shape[1]
+    l_k, d_v = k.shape[1], v.shape[2]
     block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
     segmented = seg is not None
     kv_range, q_range = _block_map(seg, n_q, n_k, block_q, block_k, causal)
@@ -386,10 +387,10 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
     seg_operands = _seg_inputs(seg) if segmented else ()
 
     at = _Specs(heads, n_q)  # q outer, kv walked
-    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d, inner=True)]
+    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d_v, inner=True)]
     if segmented:
         in_specs += [at.rows(block_q, _STAT_W, ids=True), at.seg_k(block_k, inner=True)]
-    in_specs += [at.rows(block_q, d), at.rows(block_q, _STAT_W), at.rows(block_q, _STAT_W)]
+    in_specs += [at.rows(block_q, d_v), at.rows(block_q, _STAT_W), at.rows(block_q, _STAT_W)]
     dq = _call(
         functools.partial(_bwd_dq_kernel, n_outer=n_q, **static), "bwd_dq", kv_range,
         (bh, n_q, n_k), in_specs,
@@ -400,25 +401,25 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
     )
 
     at = _Specs(heads, n_k)  # kv outer, q walked
-    in_specs = [at.rows(block_q, d, inner=True), at.rows(block_k, d), at.rows(block_k, d)]
+    in_specs = [at.rows(block_q, d, inner=True), at.rows(block_k, d), at.rows(block_k, d_v)]
     if segmented:
         in_specs += [at.rows(block_q, _STAT_W, inner=True, ids=True), at.seg_k(block_k)]
     in_specs += [
-        at.rows(block_q, d, inner=True),
+        at.rows(block_q, d_v, inner=True),
         at.rows(block_q, _STAT_W, inner=True),
         at.rows(block_q, _STAT_W, inner=True),
     ]
     dk, dv = _call(
         functools.partial(_bwd_dkv_kernel, n_outer=n_k, **static), "bwd_dkv", q_range,
         (bh, n_k, n_q), in_specs,
-        out_specs=[at.rows(block_k, d), at.rows(block_k, d)],
+        out_specs=[at.rows(block_k, d), at.rows(block_k, d_v)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, l_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, l_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, l_k, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         operands=[q, k, v, *seg_operands, do, lse, delta], segmented=segmented, interpret=interpret,
     )
@@ -452,7 +453,9 @@ def flash_attention(
     q, k, v, causal=False, scale=None, segment_ids=None,
     block_q=None, block_k=None, interpret=False,
 ):
-    """Flash attention over ``[batch, heads, seq, head_dim]`` arrays.
+    """Flash attention over ``[batch, heads, seq, head_dim]`` arrays. ``v``
+    (and the output) may have a head size of its own: latent attention's
+    queries and keys are 192 wide (128 + the rotary 64) against values of 128.
 
     Drop-in replacement for
     :func:`tensorflowonspark_tpu.parallel.ring_attention.plain_attention`
@@ -473,7 +476,7 @@ def flash_attention(
     b, h, l_q, d = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    merge = lambda t: t.reshape(b * h, t.shape[2], d)  # noqa: E731
+    merge = lambda t: t.reshape(b * h, t.shape[2], t.shape[3])  # noqa: E731
     segmented = segment_ids is not None
     if block_q is None:
         block_q = flash_blocks.SEGMENTED_BLOCK_Q if segmented else DEFAULT_BLOCK_Q
@@ -484,4 +487,4 @@ def flash_attention(
         merge(q), merge(k), merge(v), seg, float(scale), bool(causal),
         int(block_q), int(block_k), bool(interpret),
     )
-    return o.reshape(b, h, l_q, d)
+    return o.reshape(b, h, l_q, v.shape[3])

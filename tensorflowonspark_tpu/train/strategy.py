@@ -120,6 +120,17 @@ class TrainStep:
     with the device only once JAX's own queue is full: until then its short
     intervals fill the history.) With collection off (``TOS_OBS=0``) a call
     is the jitted function's.
+
+    What a model counted inside a step leaves it in the step's metrics
+    under ``counter/<name>`` and ``gauge/<name>`` (``make_loss_fn`` carries
+    out what the model sowed into its ``counters`` and ``gauges``
+    collections) and is booked by name (``obs.book_carried``: counter
+    ``<name>_total``, gauge ``<name>``, which the model registered where it
+    sows) at a later call that finds that step finished: its scalars
+    were sent towards the host when it was dispatched, so booking waits for
+    nothing and fences nothing. :meth:`drain` books what the last steps
+    carried, once the loop is over. A step that carries nothing books
+    nothing.
     """
 
     def __init__(self, jitted):
@@ -129,6 +140,7 @@ class TrainStep:
         self._last_at = None
         self._last_loss = None
         self._found_running = False
+        self._unbooked = collections.deque()  # what steps not yet seen finished carried out
         # registered here, not at the first stall: a reader has to tell a
         # clean window (0) from a program that does not count (absent)
         self._steps = obs.counter(
@@ -169,7 +181,21 @@ class TrainStep:
         with obs.span("step_dispatch", seconds_total=self._seconds, step_num=self._dispatched):
             out = self._jitted(state, batch)
         self._last_loss = out[1]["loss"]
+        carried = {k: v for k, v in out[1].items() if k.startswith(obs.CARRIED)}
+        if carried:
+            for value in carried.values():
+                value.copy_to_host_async()
+            self._unbooked.append(carried)
+            self._book(lambda step: all(v.is_ready() for v in step.values()))
         return out
+
+    def _book(self, finished):
+        while self._unbooked and finished(self._unbooked[0]):
+            obs.book_carried(self._unbooked.popleft())
+
+    def drain(self):
+        """Book what the steps still in flight carried out (waits for them)."""
+        self._book(lambda step: True)
 
 
 class SyncDataParallel:

@@ -58,9 +58,11 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _compiled_text(one_chip, segmented, **sizes):
-    qkv = jax.ShapeDtypeStruct((ROWS, HEADS, SEQ, HEAD_DIM), jnp.bfloat16, sharding=one_chip)
-    ids = jax.ShapeDtypeStruct((ROWS, SEQ), jnp.int32, sharding=one_chip)
+def _compiled_text(one_chip, segmented, shape=(ROWS, HEADS, SEQ, HEAD_DIM), value_dim=None, **sizes):
+    """``shape`` is q's and k's; ``value_dim`` v's head size where it differs."""
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    values = qkv if value_dim is None else jax.ShapeDtypeStruct(shape[:3] + (value_dim,), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((shape[0], shape[2]), jnp.int32, sharding=one_chip)
 
     def loss(q, k, v, seg=None):
         o = flash_attention(q, k, v, causal=True, segment_ids=seg, **sizes)
@@ -70,7 +72,7 @@ def _compiled_text(one_chip, segmented, **sizes):
     # under plain jax.grad the call's last scope reads jvp(flash_fwd_seg),
     # the parent's kernels as well, and the reduction looks for the bare name
     grad = jax.jit(jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2)))
-    lowered = grad.lower(qkv, qkv, qkv, ids) if segmented else grad.lower(qkv, qkv, qkv)
+    lowered = grad.lower(qkv, qkv, values, ids) if segmented else grad.lower(qkv, qkv, values)
     return lowered.compile().as_text()
 
 
@@ -92,6 +94,14 @@ def test_segmented_kernels_compile_at_the_cells_widths(one_chip, no_compile_cach
     assert "bf16[{},{},{}]".format(ROWS * HEADS, SEQ, HEAD_DIM) in text
     assert "s32[{},{},8]".format(ROWS * HEADS, SEQ) not in text
     assert "s32[{},8,{}]".format(ROWS * HEADS, SEQ) not in text
+
+
+def test_segmented_kernels_compile_at_latent_attention_widths(one_chip, no_compile_cache):
+    """``xing4-a4b.packed8k``: one row of 8192, 32 heads, queries and keys 192
+    wide (not a multiple of the 128 lanes) against values of 128."""
+    text = _compiled_text(one_chip, True, shape=(1, 32, 8192, 192), value_dim=128)
+    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_bwd_dq_seg", "flash_fwd_seg"]
+    assert "bf16[32,8192,192]" in text and "bf16[32,8192,128]" in text
 
 
 def test_unsegmented_kernels_compile_and_keep_their_names(one_chip, no_compile_cache):

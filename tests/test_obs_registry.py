@@ -218,3 +218,15 @@ def test_snapshot_publisher_disabled_registry_spins_nothing():
     assert pub._thread is None
     pub.stop()
     assert mgr.kv == {}
+
+
+def test_book_carried_adds_counters_and_sets_gauges_by_name():
+    """What a compiled step carries out (``TrainStep``): ``counter/<name>``
+    adds to ``<name>_total``, ``gauge/<name>`` sets ``<name>``."""
+    before = registry.counter("carried_probe_total").value
+    for held in (3.0, 4.0):
+        registry.book_carried({"counter/carried_probe": held, "gauge/carried_probe_load": held / 2})
+    assert registry.counter("carried_probe_total").value - before == 7.0
+    assert registry.gauge("carried_probe_load").value == 2.0
+    assert all(key.startswith(registry.CARRIED) for key in ("counter/x", "gauge/x"))
+    assert not "loss".startswith(registry.CARRIED)
