@@ -56,7 +56,7 @@ DEADLINE_SECS = 1100
 _HIT = "/jax/compilation_cache/cache_hits"
 _MISS = "/jax/compilation_cache/cache_misses"
 _COMPILE = "/jax/core/compile/backend_compile_duration"
-_KERNELS = ("flash_fwd_seg", "flash_bwd_dq_seg", "flash_bwd_dkv_seg")
+_KERNELS = ("flash_fwd_seg", "flash_bwd_dkv_seg")  # one forward, one backward (dq, dk and dv)
 
 
 # -- inside the jax child -----------------------------------------------------

@@ -4,8 +4,25 @@ Blockwise attention that never materializes the [L, L] score matrix: the
 forward streams K/V blocks through VMEM accumulating an online softmax
 (running max ``m``, denominator ``l``, weighted values ``acc``); the backward
 recomputes probabilities per block from the saved log-sum-exp and accumulates
-dq / dk / dv — three matmul-dominated kernels that keep the MXU busy while
-HBM traffic stays O(L·D).
+dq / dk / dv. Two kernels a call, one forward and one backward, while HBM
+traffic stays O(L·D).
+
+**One backward kernel.** A block's scores, masks, exponentials, ``dp`` and
+``ds`` are computed once and feed all three gradients (PR 27; two kernels,
+q-major for dq and kv-major for dk/dv, each recomputed them). The kernel is
+kv-major: dk and dv of the kv block accumulate in block-sized scratch over
+the q blocks its range walks, and dq accumulates in a float32 scratch that
+holds the **whole row** ``[L_q, d]`` of one (batch, head) in VMEM across both
+inner grid dims, zeroed at the row's first step and written out at its last.
+For a fixed q block the kv blocks arrive in increasing order, so the float32
+sum is the q-major kernel's and dq, dk, dv are bit-identical to the two
+kernels' (read on the chip at 64/64 and 192/128, PERF.md §6). The call asks
+Mosaic for the row's VMEM on top of the default 16 MiB
+(:func:`_bwd_vmem_limit`: 4 MiB at 4096 x 64, 16 MiB at 8192 x 192), up to 96 of a
+v5e's 128 MiB: **the largest row is 81,920 positions at a head size up to
+128 and 40,960 up to 256**; a longer one is refused at trace time by a
+``ValueError`` that names the limit (a row that long is sharded over chips:
+:mod:`~tensorflowonspark_tpu.parallel.ring_attention`).
 
 **The block map.** The kernels visit only the blocks the job needs. From the
 segment ids (in the jitted step, a few thousand integers in XLA) comes, per
@@ -58,9 +75,9 @@ def _walk(lo_ref, hi_ref, at, j):
     (False once parked, and for an empty range, ``hi < lo``).
 
     Scalar code here and in :func:`_here` is written in ``lax`` primitives:
-    it is traced into every index map of every call (96 a step in a 24-layer
-    model), and a ``jnp`` wrapper or a floor division costs milliseconds of
-    lowering each time (PERF.md §6, PR 25)."""
+    it is traced into every index map of every call (72 a step in a 24-layer
+    model that recomputes), and a ``jnp`` wrapper or a floor division costs
+    milliseconds of lowering each time (PERF.md §6, PR 25)."""
     lo, hi = lo_ref[at], hi_ref[at]
     return jax.lax.max(jax.lax.min(lo + j, hi), 0), lo + j <= hi
 
@@ -148,52 +165,29 @@ def _fwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_oute
         lse_ref[0] = jnp.broadcast_to(m[:] + jnp.log(denom), (l.shape[0], _STAT_W))
 
 
-def _bwd_dq_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_outer):
-    tabs, refs = refs[:2], refs[2:]
-    if segmented:
-        q_ref, k_ref, v_ref, sq_ref, sk_ref, do_ref, lse_ref, delta_ref, dq_ref, acc = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc = refs
-        sq_ref = sk_ref = None
-    iq, ik, needed = _here(tabs, heads, n_outer)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-
-    @pl.when(needed)
-    def _block():
-        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        acc[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        dq_ref[0] = acc[:].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_outer):
+def _bwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_outer):
+    """The whole backward of one (kv block, q block) pair: kv-major (the kv
+    block is grid dim 1, the q blocks that need it are walked), so dk and dv
+    accumulate in block-sized scratch and leave when the walk ends, while dq
+    accumulates, float32, in the scratch ``dq_acc`` that holds the whole
+    ``[L_q, d]`` row of this (batch, head) across both inner grid dims. For
+    a fixed q block the contributions arrive in increasing kv block, the
+    order a q-major pass would sum them in."""
     tabs, refs = refs[:2], refs[2:]
     if segmented:
         (q_ref, k_ref, v_ref, sq_ref, sk_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
         sq_ref = sk_ref = None
     ik, iq, needed = _here(tabs, heads, n_outer)  # note: kv outer, q inner
     j = pl.program_id(2)
+    last = j == pl.num_programs(2) - 1
+
+    @pl.when((ik == 0) & (j == 0))
+    def _init_row():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
     @pl.when(j == 0)
     def _init():
@@ -214,17 +208,27 @@ def _bwd_dkv_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta_ref[0][:, :1]) * scale  # [bq, bk]
+        ds = (p * (dp - delta_ref[0][:, :1]) * scale).astype(q_ref.dtype)  # [bq, bk]
         dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0],
+            ds, q_ref[0],
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+        dq_acc[rows, :] += jax.lax.dot_general(
+            ds, k_ref[0],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(last & (ik == pl.num_programs(1) - 1))
+    def _finish_row():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _ranges(needed, axis):
@@ -284,6 +288,12 @@ class _Specs:
         """Blocks of the transposed [rows, _STAT_W, L] key-segment layout."""
         return pl.BlockSpec((1, _STAT_W, block_k), self._index(inner, True, True))
 
+    @staticmethod
+    def whole_row(length, width):
+        """All ``length`` rows of one (batch, head): the block stays where it
+        is across both inner grid dims and moves once per ``b``."""
+        return pl.BlockSpec((1, length, width), lambda b, o, j, lo, hi: (b, 0, 0))
+
 
 def _seg_inputs(seg):
     """Segment-id operands for the kernels, one set per batch row: query ids
@@ -307,26 +317,51 @@ def _geometry(q, k, seg, block_q, block_k):
 
 
 def _kernel_name(which, segmented):
-    """Stable kernel names (``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``;
-    ``_seg`` when the segment fence is compiled in): the Mosaic custom call
-    carries the name into the compiled HLO and the profiler trace, where
-    ``chip_smoke.py`` and trace reductions look for it."""
+    """Stable kernel names (``flash_fwd``, ``flash_bwd_dkv``; ``_seg`` when
+    the segment fence is compiled in): the Mosaic custom call carries the
+    name into the compiled HLO and the profiler trace, where ``chip_smoke.py``
+    and trace reductions look for it. The one backward kernel is the
+    kv-major pass that always bore ``flash_bwd_dkv``, now emitting dq too,
+    and keeps that name: the benchmark's readers sum the flash kernels they
+    find by name, and a new name would drop the whole backward from them."""
     return "flash_{}{}".format(which, "_seg" if segmented else "")
 
 
-def _compiler_params(interpret):
-    """batch/outer-block grid dims run in any order; only the walked dim
-    carries the accumulator, so mark it 'arbitrary' and the rest 'parallel'
-    for pipelining."""
+#: what a kernel may use of VMEM unless it says otherwise (Mosaic's scoped
+#: default on a v5e), and what the backward may ask for of the chip's 128 MiB
+_VMEM_DEFAULT = 16 * 2 ** 20
+_VMEM_MOST = 96 * 2 ** 20
+
+
+def _bwd_vmem_limit(length, width, dtype):
+    """The VMEM limit the backward sets: the default plus its dq row (the
+    float32 accumulator and the output block's two buffers, lanes padded to
+    128). A row that would take it past :data:`_VMEM_MOST` is refused."""
+    lanes = -(-width // 128) * 128
+    row = length * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+    if _VMEM_DEFAULT + row > _VMEM_MOST:
+        raise ValueError(
+            "flash attention's backward keeps the dq of a whole row in VMEM: {} x {} takes {:.1f} MiB "
+            "of the {:.1f} it may; shard a row this long over chips (parallel.ring_attention)".format(
+                length, width, row / 2 ** 20, (_VMEM_MOST - _VMEM_DEFAULT) / 2 ** 20))
+    return _VMEM_DEFAULT + row
+
+
+def _compiler_params(interpret, row_limit=None):
+    """The batch·heads grid dim runs in any order; the walked dim carries
+    the block accumulators, so it is 'arbitrary'. ``row_limit`` is the
+    backward's: its dq row is carried across the outer block dim too, under
+    this VMEM limit."""
     if interpret:
         return None
+    outer = "parallel" if row_limit is None else "arbitrary"
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
+        dimension_semantics=("parallel", outer, "arbitrary"), vmem_limit_bytes=row_limit,
     )
 
 
 def _call(kernel, which, tabs, grid, in_specs, out_specs, out_shape, scratch_shapes,
-          operands, segmented, interpret):
+          operands, segmented, interpret, row_limit=None):
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -334,7 +369,7 @@ def _call(kernel, which, tabs, grid, in_specs, out_specs, out_shape, scratch_sha
             out_specs=out_specs, scratch_shapes=scratch_shapes,
         ),
         out_shape=out_shape,
-        compiler_params=_compiler_params(interpret),
+        compiler_params=_compiler_params(interpret, row_limit),
         interpret=interpret,
         name=_kernel_name(which, segmented),
     )(*tabs, *operands)
@@ -378,50 +413,40 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
     l_k, d_v = k.shape[1], v.shape[2]
     block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
     segmented = seg is not None
-    kv_range, q_range = _block_map(seg, n_q, n_k, block_q, block_k, causal)
+    row_limit = _bwd_vmem_limit(l_q, d, q.dtype)
+    _, q_range = _block_map(seg, n_q, n_k, block_q, block_k, causal)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[:, :, None], (bh, l_q, _STAT_W))
-    static = dict(
-        scale=scale, causal=causal, segmented=segmented, block_q=block_q, block_k=block_k, heads=heads,
+    kernel = functools.partial(
+        _bwd_kernel, scale=scale, causal=causal, segmented=segmented,
+        block_q=block_q, block_k=block_k, heads=heads, n_outer=n_k,
     )
-    seg_operands = _seg_inputs(seg) if segmented else ()
-
-    at = _Specs(heads, n_q)  # q outer, kv walked
-    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d_v, inner=True)]
-    if segmented:
-        in_specs += [at.rows(block_q, _STAT_W, ids=True), at.seg_k(block_k, inner=True)]
-    in_specs += [at.rows(block_q, d_v), at.rows(block_q, _STAT_W), at.rows(block_q, _STAT_W)]
-    dq = _call(
-        functools.partial(_bwd_dq_kernel, n_outer=n_q, **static), "bwd_dq", kv_range,
-        (bh, n_q, n_k), in_specs,
-        out_specs=at.rows(block_q, d),
-        out_shape=jax.ShapeDtypeStruct((bh, l_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        operands=[q, k, v, *seg_operands, do, lse, delta], segmented=segmented, interpret=interpret,
-    )
-
     at = _Specs(heads, n_k)  # kv outer, q walked
     in_specs = [at.rows(block_q, d, inner=True), at.rows(block_k, d), at.rows(block_k, d_v)]
+    operands = [q, k, v]
     if segmented:
         in_specs += [at.rows(block_q, _STAT_W, inner=True, ids=True), at.seg_k(block_k)]
+        operands += _seg_inputs(seg)
     in_specs += [
         at.rows(block_q, d_v, inner=True),
         at.rows(block_q, _STAT_W, inner=True),
         at.rows(block_q, _STAT_W, inner=True),
     ]
-    dk, dv = _call(
-        functools.partial(_bwd_dkv_kernel, n_outer=n_k, **static), "bwd_dkv", q_range,
-        (bh, n_k, n_q), in_specs,
-        out_specs=[at.rows(block_k, d), at.rows(block_k, d_v)],
+    dq, dk, dv = _call(
+        kernel, "bwd_dkv", q_range, (bh, n_k, n_q), in_specs,
+        out_specs=[at.whole_row(l_q, d), at.rows(block_k, d), at.rows(block_k, d_v)],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, l_q, d), q.dtype),
             jax.ShapeDtypeStruct((bh, l_k, d), k.dtype),
             jax.ShapeDtypeStruct((bh, l_k, d_v), v.dtype),
         ],
         scratch_shapes=[
+            pltpu.VMEM((l_q, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
-        operands=[q, k, v, *seg_operands, do, lse, delta], segmented=segmented, interpret=interpret,
+        operands=operands + [do, lse, delta], segmented=segmented, interpret=interpret,
+        row_limit=row_limit,
     )
     return dq, dk, dv
 

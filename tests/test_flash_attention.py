@@ -162,6 +162,40 @@ def test_segmented_matches_plain_at_unequal_blocks(blocks):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-4, err_msg=name)
 
 
+def _case(l_q, l_k, d, d_v, seed, b=1, h=2):
+    """q, k, v and a cotangent at lengths and widths of their own."""
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
+    return draw(b, h, l_q, d), draw(b, h, l_k, d), draw(b, h, l_k, d_v), draw(b, h, l_q, d_v)
+
+
+#: (mask, l_q, l_k): ids ride one table for queries and keys, so the fence
+#: wants equal lengths; 128 x 256 causal leaves kv blocks 2 and 3 to no q block
+_BACKWARD_CASES = [
+    ("segmented_causal", 256, 256),
+    ("causal", 256, 256), ("causal", 128, 256), ("causal", 256, 128),
+    ("neither", 256, 256), ("neither", 128, 256), ("neither", 256, 128),
+]
+
+
+@pytest.mark.parametrize("mask,l_q,l_k", _BACKWARD_CASES, ids=["{}-{}x{}".format(*c) for c in _BACKWARD_CASES])
+@pytest.mark.parametrize("widths", [(64, 64), (192, 128)], ids=["64/64", "192/128"])
+def test_one_backward_kernel_matches_plain(widths, mask, l_q, l_k):
+    """dq, dk and dv of the one kv-major kernel against plain attention:
+    equal and unequal q/k and v widths, every mask, ``l_q != l_k``."""
+    q, k, v, do = _case(l_q, l_k, *widths, seed=l_q + l_k + widths[0])
+    seg = jnp.asarray(_ids([100, 60, 70])[None]) if mask == "segmented_causal" else None
+    causal = mask != "neither"
+    got = _flash_fn(causal)(q, k, v, seg, do)
+    want = _plain_fn(causal)(q, k, v, seg, do)
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-3, err_msg=name)
+    if causal and l_q < l_k:
+        # kv blocks above every query: walked by no q block, written as zeros
+        assert not np.asarray(got[2])[:, :, l_q:].any() and not np.asarray(got[3])[:, :, l_q:].any()
+
+
 @pytest.fixture
 def patch_rule(monkeypatch):
     """Replace ``flash_blocks.blocks_needed`` for one test. The device map is
@@ -186,16 +220,23 @@ def _block_rows(block, size=_BLOCK):
 @pytest.mark.parametrize("kind", ["long_document", "short_documents", "padded_tail", "arbitrary_order"])
 def test_skipped_blocks_are_not_read(kind):
     """Poison: NaN in the K/V blocks a q block's map skips leaves that q
-    block's output and dq as they were; NaN in the Q/dO blocks a kv block's
-    map skips leaves its dk and dv. (A kernel that multiplied a skipped
-    block by p = 0 would spread the NaN.)"""
+    block's output as it was, and its dq, which the kv-major backward kernel
+    sums; NaN in the Q/dO blocks a kv block's map skips leaves its dk and
+    dv. (A kernel that multiplied a skipped block by p = 0 would spread the
+    NaN.)"""
     row = ROWS[kind]
     needed = flash_blocks.needed_blocks(row[None], _BLOCK, _BLOCK)[0]
     assert not needed.all() and needed.any(1).all()
-    # the kernels walk from the first needed block to the last: for ids in
-    # no order that range may hold a block between two needed ones
-    needed = (np.maximum.accumulate(needed, 1) & np.maximum.accumulate(needed[:, ::-1], 1)[:, ::-1]
-              if kind == "arbitrary_order" else needed)
+
+    def walked(needed, axis):
+        # a kernel walks from the first needed block to the last: for ids in
+        # no order that range may hold a block between two needed ones
+        ahead = np.maximum.accumulate(needed, axis)
+        behind = np.flip(np.maximum.accumulate(np.flip(needed, axis), axis), axis)
+        return ahead & behind
+
+    forward = walked(needed, 1)  # q-major: per q block a range of kv blocks
+    backward = walked(needed, 0)  # kv-major, dq as well: per kv block a range of q blocks
     q, k, v, do = _operands(row[None], heads=1, seed=3)
     seg = jnp.asarray(row[None])
     run = _flash_fn(True)
@@ -209,12 +250,15 @@ def test_skipped_blocks_are_not_read(kind):
 
     n = _L // _BLOCK
     for iq in range(n):
-        skipped = [ik for ik in range(n) if not needed[iq, ik]]
-        o_p, dq_p, _, _ = run(q, poisoned(k, skipped), poisoned(v, skipped), seg, do)
+        skipped = [ik for ik in range(n) if not forward[iq, ik]]
+        o_p = run(q, poisoned(k, skipped), poisoned(v, skipped), seg, do)[0]
         np.testing.assert_array_equal(np.asarray(o_p)[:, :, _block_rows(iq)], o[:, :, _block_rows(iq)])
+        # dq of this q block reads its own o and lse too: poison what neither pass walks
+        skipped = [ik for ik in range(n) if not (forward[iq, ik] or backward[iq, ik])]
+        dq_p = run(q, poisoned(k, skipped), poisoned(v, skipped), seg, do)[1]
         np.testing.assert_array_equal(np.asarray(dq_p)[:, :, _block_rows(iq)], dq[:, :, _block_rows(iq)])
     for ik in range(n):
-        skipped = [iq for iq in range(n) if not needed[iq, ik]]
+        skipped = [iq for iq in range(n) if not backward[iq, ik]]
         _, _, dk_p, dv_p = run(poisoned(q, skipped), k, v, seg, poisoned(do, skipped))
         np.testing.assert_array_equal(np.asarray(dk_p)[:, :, _block_rows(ik)], dk[:, :, _block_rows(ik)])
         np.testing.assert_array_equal(np.asarray(dv_p)[:, :, _block_rows(ik)], dv[:, :, _block_rows(ik)])
@@ -341,25 +385,66 @@ def test_map_is_bit_identical_to_the_dense_grid(causal, patch_rule):
             np.asarray(m, np.float32), np.asarray(d, np.float32), err_msg=name)
 
 
-def test_q_block_with_no_kv_block_writes_finite_rows(patch_rule):
-    """No ids reach this through the rule (a block always needs itself);
-    a map that leaves q block 1 nothing must still give finite o and zero
-    gradients there, forward and backward skipping alike."""
+def _starved_of(axis):
+    """The rule with block 1 along ``axis`` (1: a q block, 2: a kv block)
+    needed by nothing."""
     real = flash_blocks.blocks_needed
 
     def starved(bounds, block_q, block_k, causal=True, xp=np):
         needed = real(bounds, block_q, block_k, causal, xp)
-        return needed & (xp.arange(needed.shape[1]) != 1)[None, :, None]
+        keep = xp.arange(needed.shape[axis]) != 1
+        return needed & (keep[None, :, None] if axis == 1 else keep[None, None, :])
 
-    patch_rule(starved)
+    return starved
+
+
+def _starved_run(widths, seed):
     rows = ROWS["short_documents"][None]
-    q, k, v, do = _operands(rows, seed=2)
+    q, k, v, do = _case(_L, _L, *widths, seed=seed)
+    # traced here and now: a cached trace (``_flash_fn``) would keep the real rule
     o, vjp = jax.vjp(
         lambda q, k, v: flash_attention(
             q, k, v, causal=True, segment_ids=jnp.asarray(rows), block_q=_BLOCK, block_k=_BLOCK, interpret=True),
         q, k, v)
-    grads = vjp(do)
-    for t in (o,) + grads:
+    return (o,) + vjp(do)
+
+
+@pytest.mark.parametrize("widths", [(32, 32), (192, 128)], ids=["32/32", "192/128"])
+def test_q_block_with_no_kv_block_writes_finite_rows(patch_rule, widths):
+    """No ids reach this through the rule (a block always needs itself);
+    a map that leaves q block 1 nothing must still give finite o and zero
+    gradients there, forward and backward skipping alike: the backward
+    kernel never visits the block, and its rows of the dq accumulator leave
+    as the zeros they were set to."""
+    patch_rule(_starved_of(1))
+    o, dq, dk, dv = _starved_run(widths, seed=2)
+    for t in (o, dq, dk, dv):
         assert np.isfinite(np.asarray(t)).all()
     assert not np.asarray(o)[:, :, _block_rows(1)].any()
-    assert not np.asarray(grads[0])[:, :, _block_rows(1)].any()
+    assert not np.asarray(dq)[:, :, _block_rows(1)].any()
+    assert np.asarray(dq)[:, :, _block_rows(0)].any() and np.asarray(dq)[:, :, _block_rows(2)].any()
+
+
+@pytest.mark.parametrize("widths", [(32, 32), (192, 128)], ids=["32/32", "192/128"])
+def test_kv_block_that_no_q_block_needs_gets_zero_gradients(patch_rule, widths):
+    """The other way round: kv block 1 is needed by no q block, so its walk
+    is empty and its dk and dv are exactly 0; dq stays finite everywhere."""
+    patch_rule(_starved_of(2))
+    o, dq, dk, dv = _starved_run(widths, seed=5)
+    for t in (o, dq, dk, dv):
+        assert np.isfinite(np.asarray(t)).all()
+    assert not np.asarray(dk)[:, :, _block_rows(1)].any()
+    assert not np.asarray(dv)[:, :, _block_rows(1)].any()
+    assert np.asarray(dk)[:, :, _block_rows(0)].any() and np.asarray(dv)[:, :, _block_rows(2)].any()
+
+
+def test_a_row_too_long_for_the_accumulator_is_refused_by_name():
+    """The backward keeps a whole row's dq in VMEM; past what the call may
+    ask of the chip it says so at trace time (rows that long are
+    ring attention's)."""
+    q = jax.ShapeDtypeStruct((1, 1, 82432, 64), jnp.bfloat16)
+    loss = lambda q, k, v: flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()  # noqa: E731
+    with pytest.raises(ValueError, match="82432 x 64.*ring_attention"):
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    fits = jax.ShapeDtypeStruct((1, 1, 81920, 64), jnp.bfloat16)
+    assert jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), fits, fits, fits)[0].shape == fits.shape

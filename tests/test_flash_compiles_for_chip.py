@@ -2,7 +2,8 @@
 described v5e (no chip attached; nothing runs, so this says nothing about
 results or times). It guards what the Pallas interpreter cannot see: the
 scalar-prefetch block map, its index maps and the SMEM reads lowering through
-Mosaic, the blocks fitting the chip's fast memory, and the three kernel names
+Mosaic, the blocks and the backward's whole-row dq accumulator fitting the
+chip's fast memory under the limit the call sets, and the two kernel names
 the trace reductions look for.
 
 The topology is described inside a fixture, never while a module is
@@ -88,8 +89,8 @@ def _kernels(text):
 ], ids=["defaults", "512x512", "512x256", "256x256"])
 def test_segmented_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, blocks):
     text = _compiled_text(one_chip, True, **blocks)
-    # exactly the three, one call each: a fourth kernel would be undercounted
-    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_bwd_dq_seg", "flash_fwd_seg"]
+    # exactly the two, one call each: a third kernel would be undercounted
+    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
     # per head the operands are the benchmark's; the ids ride per batch row
     assert "bf16[{},{},{}]".format(ROWS * HEADS, SEQ, HEAD_DIM) in text
     assert "s32[{},{},8]".format(ROWS * HEADS, SEQ) not in text
@@ -98,15 +99,24 @@ def test_segmented_kernels_compile_at_the_cells_widths(one_chip, no_compile_cach
 
 def test_segmented_kernels_compile_at_latent_attention_widths(one_chip, no_compile_cache):
     """``xing4-a4b.packed8k``: one row of 8192, 32 heads, queries and keys 192
-    wide (not a multiple of the 128 lanes) against values of 128."""
+    wide (not a multiple of the 128 lanes) against values of 128. The dq row
+    alone (8 MiB float32 + the output block's buffers) is over Mosaic's
+    default 16 MiB: this is where the call's own VMEM limit is proved."""
     text = _compiled_text(one_chip, True, shape=(1, 32, 8192, 192), value_dim=128)
-    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_bwd_dq_seg", "flash_fwd_seg"]
+    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
     assert "bf16[32,8192,192]" in text and "bf16[32,8192,128]" in text
+
+
+def test_segmented_kernels_compile_at_one_row_of_16k(one_chip, no_compile_cache):
+    """PERF.md §7's ``lm1024.packed16k``: one packed row of 16,384."""
+    text = _compiled_text(one_chip, True, shape=(1, 16, 16384, 64))
+    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
+    assert "bf16[16,16384,64]" in text
 
 
 def test_unsegmented_kernels_compile_and_keep_their_names(one_chip, no_compile_cache):
     text = _compiled_text(one_chip, False)
-    assert _kernels(text) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert _kernels(text) == ["flash_bwd_dkv", "flash_fwd"]
 
 
 def test_defaults_are_the_segmented_constants():

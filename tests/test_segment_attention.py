@@ -73,8 +73,10 @@ def test_flash_segment_mask_matches_unpacked(causal):
     np.testing.assert_allclose(out[:, :, real[0]], ref[:, :, real[0]], atol=2e-5)
 
 
-def test_flash_segment_gradients_match_masked_plain():
+@pytest.mark.parametrize("value_dim", [16, 8], ids=["16/16", "16/8"])
+def test_flash_segment_gradients_match_masked_plain(value_dim):
     q, k, v, seg, spans = _packed_case(seed=2)
+    v = v[..., :value_dim]  # the one backward kernel at equal and unequal q/k and v widths
 
     def loss_flash(q, k, v):
         o = flash_attention(
