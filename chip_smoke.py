@@ -38,7 +38,7 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "examples", "transformer")]
 OUT = os.path.join(ROOT, "chip_smoke_out")
 
-#: the repo's full LM width (bench.py's non-tiny ``lm`` config), 4 per chip
+#: the repo's full LM width, 4 per chip
 FULL = (
     "--vocab_size 32000 --d_model 1024 --n_heads 16 --d_ff 4096 --n_layers 4 "
     "--seq_len 4096 --batch_size 4 --dtype bfloat16"

@@ -57,7 +57,6 @@ MODULES = [
     "tensorflowonspark_tpu.parallel.mesh",
     "tensorflowonspark_tpu.parallel.sharding",
     "tensorflowonspark_tpu.parallel.collectives",
-    "tensorflowonspark_tpu.parallel.hostreduce",
     "tensorflowonspark_tpu.parallel.ring_attention",
     "tensorflowonspark_tpu.parallel.pipeline_parallel",
     "tensorflowonspark_tpu.train.strategy",

@@ -19,10 +19,10 @@
 #                 merge the shards and validate the Chrome trace schema
 #                 (required keys, monotone ts per track, matched B/E pairs)
 #                 and that the fault force-dumped a flight ring
-#   --multichip   run only the multi-process gloo legs: 2-rank host all-reduce
-#                 determinism + bucketed-overlap smoke (always), and the 4-rank
-#                 weak-scaling smoke (skips cleanly on hosts under 4 cores
-#                 where four lockstep jax processes just timeshare one core)
+#   --multichip   run only the mesh legs: hybrid-mesh placement, the dp×tp /
+#                 fsdp / ring numeric-parity gates on forced cpu devices, and
+#                 the 2-rank dp×tp gloo world (the jitted step across two
+#                 processes)
 #   --perf-smoke  run only the perf_smoke marker leg: structural pipelining
 #                 assertions (sleep-staged IO/parse overlap — proves the
 #                 read-ahead actually overlaps, no absolute-throughput flake)
@@ -117,13 +117,10 @@ else
 fi
 
 if [[ "$MULTICHIP" == "1" ]]; then
-  # multi-process gloo legs (tests/test_multichip.py): 2-rank host
-  # all-reduce determinism + bucketed-overlap bit-identity smoke runs
-  # everywhere; the 4-rank weak-scaling smoke marks itself skipped below
-  # 4 cores (four lockstep jax worlds on one core prove nothing). The
-  # model-axis legs (tests/test_model_axes.py) ride along: fast dp×tp and
-  # 1F1B-pipeline numeric-parity gates on forced cpu devices, plus the
-  # 2-rank dp×tp gloo world
+  # mesh legs: hybrid-mesh placement and the 2-rank dp×tp gloo world
+  # (tests/test_multichip.py), and the model-axis legs
+  # (tests/test_model_axes.py): dp×tp, fsdp overlay and ring numeric-parity
+  # gates on forced cpu devices
   exec python -m pytest tests/test_multichip.py tests/test_model_axes.py -q \
     -m "not chaos" ${EXTRA[@]+"${EXTRA[@]}"}
 fi
@@ -131,14 +128,10 @@ fi
 if [[ "$PERF_SMOKE" == "1" ]]; then
   # covers the IO/parse overlap proof, the autotune adaptation leg
   # (tests/test_autotune.py::TestChaosDeviceLink) — both sleep-staged, no
-  # real accelerator or absolute-throughput assertion involved — the
+  # real accelerator or absolute-throughput assertion involved — and the
   # decode-plane GIL-release leg (tests/test_decode_plane.py::TestGilRelease:
-  # process workers must beat one thread on a CPU-bound parse; skips
-  # cleanly on hosts with fewer than 4 cores where the race is meaningless),
-  # and the lm leg (tests/test_text_pipeline.py::TestPerfSmokeLM: a tiny
-  # transformer fine-tunes through the packed TextPipeline and the
-  # train-vs-input-only pair methodology must yield a valid, non-discarded
-  # pair — the BENCH_MODE=lm shape in miniature)
+  # the parse runs in the workers' own pids and fills the thread pool's
+  # stream byte for byte; no clock)
   exec python -m pytest tests/ -q -m perf_smoke ${EXTRA[@]+"${EXTRA[@]}"}
 fi
 
@@ -202,12 +195,6 @@ if [[ "$CHAOS" == "1" ]]; then
   # replicas_active gauge dips and recovers, and the dead lease expires.
   echo "chaos leg: serving.replica_kill mesh-failover run"
   python -m pytest tests/test_chaos_mesh.py -q -m "chaos and slow"
-  # comm-plane leg (self-installed plan): comm.link_delay makes one rank's
-  # host all-reduces straggle — the 2-rank world must degrade gracefully
-  # (bit-identical losses, steps complete) and the straggler must be
-  # visible in the per-rank step-time spread bucketed overlap reports.
-  echo "chaos leg: comm.link_delay straggler run"
-  python -m pytest tests/test_multichip.py -q -m "chaos and slow"
   # text-plane leg (self-installed plans): data.tokenize_error swaps records
   # for invalid UTF-8 on a live cluster — the skips must be charged against
   # max_bad_records and surface as chaos_fault_data_tokenize_error_total /
@@ -243,7 +230,6 @@ if [[ "$CHAOS" == "1" ]]; then
     "serving.latency":      {"probability": 0.05, "max_count": null, "delay_s": 0.01},
     "reservation.slow_accept": {"probability": 0.05, "max_count": null, "delay_s": 0.01},
     "control.lease_delay":  {"probability": 0.05, "max_count": null, "delay_s": 0.005},
-    "comm.link_delay":      {"probability": 0.05, "max_count": null, "delay_s": 0.005, "victim": 0},
     "ckpt.snapshot_stall":  {"probability": 0.05, "max_count": null, "delay_s": 0.01},
     "ckpt.write_slow":      {"probability": 0.05, "max_count": null, "delay_s": 0.01}
   }}'

@@ -26,8 +26,8 @@ with minimal changes, while every implementation is TPU-first:
 * :mod:`~tensorflowonspark_tpu.backends` — Spark and local multi-process execution backends.
 
 Importing this package configures NO logging: applications opt in with
-:func:`tensorflowonspark_tpu.util.setup_logging` (examples and bench.py call
-it; the jax child process calls it on entry). The format carries
+:func:`tensorflowonspark_tpu.util.setup_logging` (examples call it; the jax child
+process calls it on entry). The format carries
 process/thread like the reference (/root/reference/tensorflowonspark/__init__.py:3)
 because the runtime spans a driver, N executor processes and N jax child
 processes.

@@ -296,15 +296,21 @@ class LocalStreamingContext:
                 # drain, or it feeds after the end-of-feed markers
                 with self._busy:
                     try:
-                        rdd = self._queue.get(timeout=self.batch_interval)
+                        rdd = self._queue.get_nowait()
                     except queue.Empty:
-                        continue
-                    for stream in self._streams:
-                        for handler in stream._handlers:
-                            try:
-                                handler(rdd)
-                            except Exception:
-                                logger.exception("streaming micro-batch handler failed")
+                        rdd = None
+                    else:
+                        for stream in self._streams:
+                            for handler in stream._handlers:
+                                try:
+                                    handler(rdd)
+                                except Exception:
+                                    logger.exception("streaming micro-batch handler failed")
+                if rdd is None:
+                    # idle: wait for the next tick with the lock free. A lock
+                    # is not fair: held across this wait and re-taken at once,
+                    # it starved stop()'s acquire for minutes
+                    self._stop_ev.wait(self.batch_interval)
 
         self._thread = threading.Thread(target=_run, name="tos-streaming", daemon=True)
         self._thread.start()

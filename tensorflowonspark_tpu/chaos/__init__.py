@@ -139,12 +139,6 @@ site                            effect at the injection point
                                 manifest; replicas must reject the swap via
                                 ``manifest.verify()`` and keep serving the
                                 old bundle
-``comm.link_delay``             host gradient all-reduce sleeps ``delay_s``
-                                before the exchange on rank ``victim`` only
-                                (a straggling DCN link); peers must absorb
-                                it — bucketed overlap hides the wait behind
-                                backprop and the straggler stays visible in
-                                the MULTICHIP per-rank step-time spread
 ``native_io.read_fail``         TFRecord read raises ``IOError``
 ``store.read_error``            one remote store HTTP request raises
                                 ``IOError`` — absorbed by the store's retry

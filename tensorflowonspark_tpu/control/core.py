@@ -20,9 +20,8 @@ def classify_stalls(read_s, parse_s, emit_s, wait_s):
     starved means the consumer (device) is the gate (``device_bound``);
     otherwise the input path is, split by which producer stage dominated —
     ``decode_bound`` when parse time beats shard IO, ``io_bound`` when
-    reads do. Shared by ``bench.py`` (the BENCH JSON's ``classification``
-    field), the per-process autotuners' rationale, and the cluster scaler's
-    regrow gate."""
+    reads do. Shared by the per-process autotuners' rationale and the
+    cluster scaler's regrow gate."""
     if emit_s >= wait_s:
         return "device_bound"
     return "decode_bound" if parse_s >= read_s else "io_bound"

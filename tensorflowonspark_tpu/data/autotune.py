@@ -181,7 +181,7 @@ class FeedAutotuner:
     Decision rule: the smallest bucket whose predicted fixed-cost share
     ``fixed / (fixed + K·batch_bytes/bw)`` is ≤ ``overhead_target``
     (default 0.1 — at the measured ~250 ms fixed cost and ~20 MB/s this
-    lands on K=8, the value BENCH_FUSED converged to by hand). Upward
+    lands on K=8, the value once set by hand). Upward
     moves apply immediately; downward moves need ``down_patience``
     consecutive lower recommendations. Every ``reprobe_every``-th window a
     fenced micro-probe refreshes the fixed-cost estimate, so a mood change
@@ -303,9 +303,8 @@ class FeedAutotuner:
     @staticmethod
     def _fence(tree):
         """One-element readback proving the transfer landed (slicing on
-        device first, so the fence never ships the array back — the same
-        fencing bench.py uses; a readback cannot return before the data
-        is on the device)."""
+        device first, so the fence never ships the array back; a readback
+        cannot return before the data is on the device)."""
         import jax
         import numpy as np
 

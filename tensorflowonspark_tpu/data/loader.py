@@ -1135,9 +1135,7 @@ def loop_prefetch(batches, strategy, num_steps, depth=None):
 def packed_place(window, strategy):
     """Stack a list of host batches into ONE ``[K, B, ...]`` pytree and ship
     it as a single sharded host→device transfer — the placement used by
-    :func:`packed_prefetch` and mirrored by bench.py's packed link probe
-    (kept here so the probe can never measure a different shape than the
-    training path)."""
+    :func:`packed_prefetch`."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -28,7 +28,7 @@ Layers (data flows left to right):
   registry snapshots over the existing TFManager channel; the driver merges
   them into one cluster view (``TFCluster.metrics()``).
 * :mod:`~tensorflowonspark_tpu.obs.exporter` — Prometheus text format over a
-  tiny stdlib HTTP endpoint, plus a JSON dump for tests and ``bench.py``.
+  tiny stdlib HTTP endpoint, plus a JSON dump for tests.
 
 Metric naming follows Prometheus conventions: ``<area>_<what>_<unit>``,
 counters end in ``_total``, histograms in ``_seconds`` (see

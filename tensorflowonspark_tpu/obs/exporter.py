@@ -9,7 +9,7 @@ view (``TFCluster.metrics()``) as easily as a single process's registry.
 Endpoints (:class:`MetricsHTTPServer`):
 
 * ``GET /metrics``         → Prometheus text format, ``text/plain; version=0.0.4``
-* ``GET /metrics.json``    → the raw snapshot dict as JSON (tests, bench.py)
+* ``GET /metrics.json``    → the raw snapshot dict as JSON (tests)
 * ``GET /trace``           → this process's flight-recorder shard as JSON
   (``{"records": [...], "torn": N, "shard": path}``) — the raw span/event
   stream :mod:`~tensorflowonspark_tpu.obs.tracemerge` stitches cluster-wide,

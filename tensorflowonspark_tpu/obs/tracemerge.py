@@ -16,11 +16,9 @@ same-host children via ``TOS_TRACE_CLOCK_OFF``), else 0.
 Track layout.  Each shard becomes one Chrome *process* (``M``
 ``process_name`` metadata from its ``meta`` header).  Context-manager spans
 are emitted as matched ``B``/``E`` pairs on their recording thread's track;
-retroactive spans carrying a ``track`` label (the ``BucketedOverlap`` comm
-spans) land on a dedicated named track as ``X`` complete events, so the
-comm/compute overlap the ``comm_overlap_fraction`` gauge reports is directly
-visible — and :func:`overlap_fraction` recomputes it from the drawn spans
-alone so the two can be cross-checked.
+retroactive spans carrying a ``track`` label
+(:func:`tensorflowonspark_tpu.obs.tracing.record_span`) land on a track of
+that name as ``X`` complete events.
 
 Nesting repair.  Span starts are wall-clock but durations are monotonic
 (NTP steps must not corrupt durations — see ``obs/trace.py``), so a child's
@@ -36,16 +34,9 @@ import sys
 
 from tensorflowonspark_tpu.obs import flight
 
-#: synthetic Chrome tid for retro comm-track spans (real thread ids are
-#: os-assigned and never this large on Linux, whose pid space caps at 2^22)
-COMM_TID = 9_000_000
-WINDOW_TID = 9_000_001
-
-_TRACK_TIDS = {"comm": COMM_TID, "comm_window": WINDOW_TID}
-_TRACK_NAMES = {
-    COMM_TID: "comm (bucketed all-reduce)",
-    WINDOW_TID: "comm overlap windows",
-}
+#: first synthetic Chrome tid of a shard's labelled tracks (real thread ids
+#: are os-assigned and never this large on Linux, whose pid space caps at 2^22)
+TRACK_TID_BASE = 9_000_000
 
 
 def resolve_offset(records):
@@ -90,7 +81,7 @@ def _shard_events(records, pid, offset):
         {"ph": "M", "name": "process_name", "pid": pid, "tid": 0,
          "args": {"name": label}},
     ]
-    named_tids = set()
+    track_tids = {}
     by_tid = {}
     for rec in records:
         kind = rec.get("kind")
@@ -98,12 +89,12 @@ def _shard_events(records, pid, offset):
         if kind == "span":
             track = rec.get("track")
             if track:
-                tid = _TRACK_TIDS.get(track, COMM_TID)
-                if tid not in named_tids:
-                    named_tids.add(tid)
+                tid = track_tids.get(track)
+                if tid is None:
+                    tid = track_tids[track] = TRACK_TID_BASE + len(track_tids)
                     events.append({
                         "ph": "M", "name": "thread_name", "pid": pid, "tid": tid,
-                        "args": {"name": _TRACK_NAMES.get(tid, track)},
+                        "args": {"name": track},
                     })
                 events.append({
                     "ph": "X", "name": rec.get("name", "?"), "cat": track,
@@ -194,45 +185,8 @@ def merge_directory(root):
         "shards": shards,
         "events": len(metas) + len(rest),
         "trace_ids": sorted(trace_ids),
-        "overlap_fraction": overlap_fraction(trace["traceEvents"]),
     }
     return trace, summary
-
-
-def overlap_fraction(events):
-    """Recompute comm/compute overlap from the drawn comm-track spans: the
-    fraction of ``comm_allreduce`` busy time lying inside some
-    ``comm_window`` interval — the same estimate ``BucketedOverlap`` folds
-    into the ``comm_overlap_fraction`` gauge, but derived purely from the
-    merged timeline so the gauge can be corroborated visually AND
-    numerically.  None when no comm spans were recorded."""
-    comm, windows = [], []
-    for e in events:
-        if e.get("ph") != "X":
-            continue
-        iv = (e["ts"], e["ts"] + e.get("dur", 0.0))
-        if e.get("name") == "comm_allreduce":
-            comm.append(iv)
-        elif e.get("name") == "comm_window":
-            windows.append(iv)
-    if not comm:
-        return None
-    # merge the window set, then intersect
-    windows.sort()
-    merged = []
-    for b, e in windows:
-        if merged and b <= merged[-1][1]:
-            merged[-1][1] = max(merged[-1][1], e)
-        else:
-            merged.append([b, e])
-    busy = sum(e - b for b, e in comm)
-    hidden = 0.0
-    for b, e in comm:
-        for wb, we in merged:
-            lo, hi = max(b, wb), min(e, we)
-            if hi > lo:
-                hidden += hi - lo
-    return (hidden / busy) if busy > 0 else None
 
 
 def validate_chrome_trace(trace):

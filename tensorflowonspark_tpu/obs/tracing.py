@@ -45,10 +45,6 @@ tracing analogue of chaos-obs-coverage):
 ``h2d_transfer``           host→device transfer of a feed window
 ``step_compute``           one optimizer step (jit dispatch + wait)
 ``ckpt_snapshot``          checkpoint snapshot handoff to the async engine
-``comm_allreduce``         one bucketed all-reduce on the comm thread (retro)
-``comm_window``            backprop window a bucket may hide under (retro)
-``pipeline_stage``         one 1F1B stage op (fwd/bwd/fused loss) (retro)
-``pipeline_transfer``      stage-boundary activation/cotangent hop (retro)
 ``serving_route``          serving-mesh router handling one client request
 ``elastic_relaunch``       recovery-ladder relaunch attempt
 ``elastic_regrow``         scaler-initiated regrow restart (drain → relaunch)
@@ -68,13 +64,6 @@ the registry keeps of them — no event, no histogram — and they take a span
 id only while a flight shard is open. Every span, of either kind, is also a
 ``tos.<name>`` ``TraceAnnotation`` in a ``jax.profiler`` trace when the
 process has jax imported (:mod:`~tensorflowonspark_tpu.obs.trace`).
-
-``comm_allreduce``/``comm_window`` and ``pipeline_stage``/
-``pipeline_transfer`` are *retroactive* spans (:func:`record_span`): the
-bucketed-overlap comm thread and the 1F1B stage/comm threads record
-perf-counter intervals while overlapping compute, and the step publishes
-them afterwards with explicit timestamps so the merger can draw the comm
-and pipeline tracks without the tracer ever being on the hot path.
 """
 
 import os
@@ -262,9 +251,8 @@ def event(name, **attrs):
 def record_span(name, ts, dur_s, ok=True, track=None, **attrs):
     """Retroactively record a completed span with explicit timestamps.
 
-    Used for intervals measured off-thread (the bucketed-overlap comm
-    thread) where a context manager cannot wrap the work.  ``track`` labels
-    a dedicated merge-time lane (the comm track)."""
+    For intervals measured off-thread, where a context manager cannot wrap
+    the work.  ``track`` names the lane the merger draws the span on."""
     rec = {
         "kind": "span",
         "name": name,

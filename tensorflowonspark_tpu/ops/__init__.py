@@ -8,7 +8,6 @@ numerics are testable on CPU.
 
 _EXPORTS = {
     "flash_attention": "flash_attention",
-    "flash_attention_kernel": "flash_attention",
     "fused_batch_norm": "fused_bn",
     "FusedBatchNorm": "fused_bn",
 }

@@ -40,12 +40,9 @@ _EXPORTS = {
     "shard_batch": "sharding",
     "shard_params": "sharding",
     "collectives": None,
-    "HostAllReduceGroup": "hostreduce",
     "ring_attention": "ring_attention",
     "ring_attention_sharded": "ring_attention",
     "pipeline_apply": "pipeline_parallel",
-    "Pipeline1F1B": "pipeline_parallel",
-    "schedule_1f1b": "pipeline_parallel",
     "stack_stage_params": "pipeline_parallel",
     "split_microbatches": "pipeline_parallel",
     "merge_microbatches": "pipeline_parallel",

@@ -1,42 +1,25 @@
-"""Pipeline parallelism: GPipe and 1F1B schedules over pipeline stages.
+"""Pipeline parallelism: a GPipe schedule over pipeline stages.
 
 Absent from the reference (SURVEY.md §2.7: model parallelism was
 "claimed-but-user-managed" TF1 device scopes; no pipeline support) — this is
-a beyond-parity capability, built the TPU way in two tiers:
+a beyond-parity capability, built the TPU way:
 
-* :func:`pipeline_apply` — GPipe-style forward pipeline over the ``pp`` mesh
-  axis: microbatch activations rotate between neighbours with
-  ``lax.ppermute`` (ICI neighbour links) and the whole schedule is a
-  ``lax.scan`` inside ``shard_map`` — one compiled program, no host round
-  trips, fully differentiable (gradients flow back through the permutes in
-  reverse schedule order, which is exactly GPipe's backward). Schedule is
-  the classic bubble pipeline: with P stages and M microbatches, step t has
-  stage i working on microbatch t-i; M + P - 1 steps, bubble fraction
-  (P-1)/(M+P-1).
-
-* :class:`Pipeline1F1B` — a host-driven one-forward-one-backward schedule
-  (Narayanan et al. 2019/Megatron's interleaved baseline): each stage owns
-  a device and a worker thread, stage-boundary activation/cotangent hops
-  run through the same dedicated comm-thread pattern as
-  :class:`~tensorflowonspark_tpu.train.strategy.BucketedOverlap`, and the
-  bubble is *measured* from per-op compute spans rather than assumed from
-  the closed form (the ``pipeline_bubble_fraction`` gauge, with the same
-  spans published as retroactive trace tracks for corroboration in the
-  merged Perfetto timeline).
+:func:`pipeline_apply` — GPipe-style forward pipeline over the ``pp`` mesh
+axis: microbatch activations rotate between neighbours with
+``lax.ppermute`` (ICI neighbour links) and the whole schedule is a
+``lax.scan`` inside ``shard_map`` — one compiled program, no host round
+trips, fully differentiable (gradients flow back through the permutes in
+reverse schedule order, which is exactly GPipe's backward). Schedule is
+the classic bubble pipeline: with P stages and M microbatches, step t has
+stage i working on microbatch t-i; M + P - 1 steps, bubble fraction
+(P-1)/(M+P-1).
 """
-
-import logging
-import queue as queue_mod
-import threading
-import time
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from tensorflowonspark_tpu.parallel.mesh import mesh_shape
-
-logger = logging.getLogger(__name__)
 
 
 def stack_stage_params(params_list):
@@ -121,363 +104,3 @@ def split_microbatches(x, n_micro):
 def merge_microbatches(y):
     """Inverse of :func:`split_microbatches`."""
     return y.reshape((-1,) + y.shape[2:])
-
-
-def schedule_1f1b(stage, n_stages, n_micro):
-    """The 1F1B op order for one stage: ``[("F", m) | ("B", m), ...]``.
-
-    ``n_stages - 1 - stage`` warmup forwards, then alternating F/B in
-    steady state, then cooldown backwards — each stage holds at most
-    ``n_stages - stage`` activation stashes in flight, which is the whole
-    point of 1F1B over GPipe's all-forwards-then-all-backwards. The last
-    stage's pairs are fused by :class:`Pipeline1F1B` into single loss+vjp
-    ops, but the order here is the canonical schedule for every stage.
-    """
-    warmup = min(n_stages - 1 - stage, n_micro)
-    ops = [("F", m) for m in range(warmup)]
-    f = warmup
-    b = 0
-    while f < n_micro:
-        ops.append(("F", f))
-        f += 1
-        ops.append(("B", b))
-        b += 1
-    while b < n_micro:
-        ops.append(("B", b))
-        b += 1
-    return ops
-
-
-class Pipeline1F1B:
-    """Host-driven 1F1B microbatch pipeline with measured bubble accounting.
-
-    ``stage_fn(stage_params, x) -> y`` is one stage's computation (same
-    homogeneous contract as :func:`pipeline_apply`); ``params_list`` holds P
-    per-stage param pytrees, each pinned to its own device; ``loss_fn(y,
-    target) -> scalar`` closes the last stage. One optimizer step::
-
-        sched = Pipeline1F1B(stage_fn, params_list, loss_fn)
-        loss, grads = sched.step(split_microbatches(x, M),
-                                 split_microbatches(t, M))
-        # grads[i] lives on stage i's device, scaled to d(mean loss)/dparams
-
-    Execution: one worker thread per stage runs :func:`schedule_1f1b`;
-    backward ops re-derive the stage forward through ``jax.vjp`` (activation
-    rematerialization — only the stage *inputs* are stashed, at most
-    ``P - stage`` of them, which is 1F1B's memory contract). Stage-boundary
-    activation/cotangent hops go through a dedicated comm thread — the
-    :class:`~tensorflowonspark_tpu.train.strategy.BucketedOverlap` pattern:
-    the comm thread waits on the producing device stream *beside* the next
-    op's compute, then lands the buffer on the neighbour device.
-    ``overlap=False`` runs the identical transfers inline on the stage
-    threads (same buffers, same order, host-side fencing only), which is
-    the measured-off leg the bench compares against.
-
-    Measurement: every op's dispatch-to-ready interval is recorded per
-    stage. ``pipeline_bubble_fraction`` = 1 - busy/(P × window) over the
-    step's wall window — the *measured* counterpart of GPipe's closed-form
-    (P-1)/(M+P-1), visible per step in :attr:`last_stats` and the gauge.
-    Transfer seconds that land inside some stage's compute span count as
-    hidden; the ``pipeline_comm_overlap_fraction`` gauge reports the
-    fraction. With tracing active both land as retroactive spans
-    (``pipeline_stage`` / ``pipeline_transfer`` tracks) so the merged
-    Perfetto timeline corroborates the gauges.
-
-    Donation contract: no program donates anything — params feed every
-    microbatch, stashed inputs feed the backward, and grads accumulate
-    functionally on each stage's device.
-    """
-
-    def __init__(self, stage_fn, params_list, loss_fn, devices=None, overlap=True):
-        if not params_list:
-            raise ValueError("need at least one pipeline stage")
-        self.stage_fn = stage_fn
-        self.loss_fn = loss_fn
-        self.overlap = overlap
-        self.n_stages = len(params_list)
-        if devices is None:
-            devices = jax.local_devices()
-        if len(devices) < self.n_stages:
-            raise ValueError(
-                "{} pipeline stages need {} devices; have {}".format(
-                    self.n_stages, self.n_stages, len(devices)
-                )
-            )
-        self.devices = list(devices[: self.n_stages])
-        self.params = [
-            jax.device_put(p, d) for p, d in zip(params_list, self.devices)
-        ]
-        self.last_stats = {}
-        self._fwd = [None] * self.n_stages
-        self._bwd = [None] * self.n_stages
-        self._last_prog = None
-        # bounded: a wedged comm thread should exert backpressure on the
-        # stage workers instead of accumulating device buffers in the queue
-        self._jobs = queue_mod.Queue(maxsize=max(8, 4 * self.n_stages))
-        self._comm_worker = None
-        self._comm_err = None
-
-    # -- compiled programs -------------------------------------------------
-
-    def _fwd_prog(self, i):
-        if self._fwd[i] is None:
-            self._fwd[i] = jax.jit(self.stage_fn, donate_argnums=())
-        return self._fwd[i]
-
-    def _bwd_prog(self, i):
-        if self._bwd[i] is None:
-
-            def bwd(params, x, g):
-                _y, vjp = jax.vjp(self.stage_fn, params, x)
-                return vjp(g)  # (dparams, dx)
-
-            self._bwd[i] = jax.jit(bwd, donate_argnums=())
-        return self._bwd[i]
-
-    def _last(self):
-        """Fused loss+vjp program for the final stage (its F/B pair)."""
-        if self._last_prog is None:
-
-            def last(params, x, target):
-                def f(p, xx):
-                    return self.loss_fn(self.stage_fn(p, xx), target)
-
-                loss, (dp, dx) = jax.value_and_grad(f, argnums=(0, 1))(params, x)
-                return loss, dp, dx
-
-            self._last_prog = jax.jit(last, donate_argnums=())
-        return self._last_prog
-
-    # -- comm thread (BucketedOverlap pattern) -----------------------------
-
-    def _transfer(self, payload, dest, out_q, tag, spans):
-        t0 = time.perf_counter()
-        jax.block_until_ready(payload)  # producing device stream, not comm
-        t1 = time.perf_counter()
-        moved = jax.device_put(payload, dest)
-        jax.block_until_ready(moved)
-        t2 = time.perf_counter()
-        spans.append((t1, t2, tag))
-        out_q.put((tag[1], moved))
-
-    def _comm_loop(self):
-        while True:
-            job = self._jobs.get()
-            if job is None:
-                return
-            try:
-                self._transfer(*job)
-            except BaseException as e:  # surfaces at the step join
-                self._comm_err = e
-                job[2].put((job[3][1], e))
-
-    def _ensure_comm_worker(self):
-        if self._comm_worker is None or not self._comm_worker.is_alive():
-            self._comm_worker = threading.Thread(
-                target=self._comm_loop, name="pipeline-comm", daemon=True
-            )
-            self._comm_worker.start()
-
-    # -- the step ----------------------------------------------------------
-
-    def step(self, microbatches, targets):
-        """One step over ``microbatches`` (``[M, b, ...]`` from
-        :func:`split_microbatches`) and matching per-microbatch ``targets``.
-        Returns ``(loss, grads)``: the microbatch-mean loss and per-stage
-        grad pytrees scaled to match ``grad(mean loss)``."""
-        P = self.n_stages
-        M = int(microbatches.shape[0])
-        if M < 1:
-            raise ValueError("step needs at least one microbatch")
-        if self.overlap:
-            self._ensure_comm_worker()
-        # ingest: land inputs on the edge devices before the measured window
-        mbs = [jax.device_put(microbatches[m], self.devices[0]) for m in range(M)]
-        tgts = [jax.device_put(targets[m], self.devices[-1]) for m in range(M)]
-        jax.block_until_ready((mbs, tgts))
-
-        acts = [queue_mod.Queue() for _ in range(P)]
-        grads_q = [queue_mod.Queue() for _ in range(P)]
-        compute_spans = [[] for _ in range(P)]  # (t0, t1, op, m) per stage
-        comm_spans = []  # (t0, t1, (kind, m, src)) — comm thread + inline
-        losses = [None] * M
-        grad_acc = [None] * P
-        errs = [None] * P
-
-        def _send(payload, dest_stage, out_q, tag):
-            if self.overlap:
-                self._jobs.put(
-                    (payload, self.devices[dest_stage], out_q, tag, comm_spans)
-                )
-            else:
-                self._transfer(
-                    payload, self.devices[dest_stage], out_q, tag, comm_spans
-                )
-
-        def _recv(q, m):
-            got_m, payload = q.get()
-            if isinstance(payload, BaseException):
-                raise RuntimeError("pipeline transfer failed") from payload
-            if got_m != m:
-                raise RuntimeError(
-                    "pipeline schedule out of order: wanted microbatch "
-                    "{}, got {}".format(m, got_m)
-                )
-            return payload
-
-        def _run_stage(i):
-            try:
-                stash = {}
-                for op, m in schedule_1f1b(i, P, M):
-                    if i == P - 1:
-                        if op == "B":
-                            continue  # fused into the F slot's loss+vjp op
-                        x = mbs[m] if P == 1 else _recv(acts[i], m)
-                        t0 = time.perf_counter()
-                        loss, dp, dx = self._last()(self.params[i], x, tgts[m])
-                        grad_acc[i] = (
-                            dp
-                            if grad_acc[i] is None
-                            else jax.tree.map(jnp.add, grad_acc[i], dp)
-                        )
-                        jax.block_until_ready((loss, grad_acc[i], dx))
-                        t1 = time.perf_counter()
-                        compute_spans[i].append((t0, t1, "fb", m))
-                        losses[m] = loss
-                        if P > 1:
-                            _send(dx, i - 1, grads_q[i - 1], ("grad", m, i))
-                    elif op == "F":
-                        x = mbs[m] if i == 0 else _recv(acts[i], m)
-                        stash[m] = x
-                        t0 = time.perf_counter()
-                        y = self._fwd_prog(i)(self.params[i], x)
-                        jax.block_until_ready(y)
-                        t1 = time.perf_counter()
-                        compute_spans[i].append((t0, t1, "fwd", m))
-                        _send(y, i + 1, acts[i + 1], ("act", m, i))
-                    else:  # backward: vjp against the stashed input
-                        g = _recv(grads_q[i], m)
-                        x = stash.pop(m)
-                        t0 = time.perf_counter()
-                        dp, dx = self._bwd_prog(i)(self.params[i], x, g)
-                        grad_acc[i] = (
-                            dp
-                            if grad_acc[i] is None
-                            else jax.tree.map(jnp.add, grad_acc[i], dp)
-                        )
-                        jax.block_until_ready(grad_acc[i] if i == 0 else (grad_acc[i], dx))
-                        t1 = time.perf_counter()
-                        compute_spans[i].append((t0, t1, "bwd", m))
-                        if i > 0:
-                            _send(dx, i - 1, grads_q[i - 1], ("grad", m, i))
-            except BaseException as e:
-                errs[i] = e
-                # unblock neighbours waiting on this stage's sends
-                if i + 1 < P:
-                    acts[i + 1].put((-1, e))
-                if i > 0:
-                    grads_q[i - 1].put((-1, e))
-
-        workers = [
-            threading.Thread(
-                target=_run_stage, args=(i,), name="pipeline-stage-{}".format(i),
-                daemon=True,  # a wedged XLA call must not pin interpreter exit
-            )
-            for i in range(P)
-        ]
-        for w in workers:
-            w.start()
-        for w in workers:
-            # the error path unblocks neighbours, so every stage terminates;
-            # bounded join slices keep a wedged device call diagnosable
-            while w.is_alive():
-                w.join(timeout=60.0)
-        for i, e in enumerate(errs):
-            if e is not None:
-                raise RuntimeError("pipeline stage {} failed".format(i)) from e
-        if self._comm_err is not None:
-            err, self._comm_err = self._comm_err, None
-            raise RuntimeError("pipeline comm thread failed") from err
-
-        scale = jnp.float32(1.0 / M)
-        grads = [
-            jax.tree.map(lambda g: g * scale, acc) for acc in grad_acc
-        ]
-        loss = jnp.mean(jnp.stack([jax.device_put(l, self.devices[-1]) for l in losses]))
-        self._publish(compute_spans, comm_spans, M)
-        return loss, grads
-
-    # -- measurement -------------------------------------------------------
-
-    def _publish(self, compute_spans, comm_spans, n_micro):
-        """Span accounting → last_stats + gauges + retroactive trace spans."""
-        from tensorflowonspark_tpu import obs
-        from tensorflowonspark_tpu.obs import tracing as obs_tracing
-
-        P = self.n_stages
-        all_spans = [s for spans in compute_spans for s in spans]
-        t_first = min(s[0] for s in all_spans)
-        t_last = max(s[1] for s in all_spans)
-        window = max(t_last - t_first, 1e-9)
-        busy = sum(t1 - t0 for t0, t1, _op, _m in all_spans)
-        bubble = max(0.0, 1.0 - busy / (P * window))
-
-        # merge compute spans into a busy-interval union; transfer seconds
-        # inside it ran beside some stage's compute — hidden comm
-        union = []
-        for t0, t1, _op, _m in sorted(all_spans):
-            if union and t0 <= union[-1][1]:
-                union[-1] = (union[-1][0], max(union[-1][1], t1))
-            else:
-                union.append((t0, t1))
-        comm_busy = sum(t1 - t0 for t0, t1, _tag in comm_spans)
-        hidden = 0.0
-        for t0, t1, _tag in comm_spans:
-            for u0, u1 in union:
-                hidden += max(0.0, min(t1, u1) - max(t0, u0))
-        overlap_fraction = min(1.0, hidden / comm_busy) if comm_busy > 0 else 0.0
-
-        self.last_stats = {
-            "n_stages": P,
-            "n_microbatches": n_micro,
-            "window_s": window,
-            "busy_s": busy,
-            "bubble_fraction": bubble,
-            "bubble_fraction_theory": (P - 1.0) / (2.0 * n_micro + P - 1.0),
-            "comm_busy_s": comm_busy,
-            "hidden_comm_s": hidden,
-            "overlap_fraction": overlap_fraction,
-        }
-        obs.gauge(
-            "pipeline_bubble_fraction",
-            help="measured idle fraction of the 1F1B pipeline window "
-            "(1 - stage busy seconds / (stages x window))",
-        ).set(bubble)
-        obs.gauge(
-            "pipeline_comm_overlap_fraction",
-            help="fraction of stage-boundary transfer time hidden behind "
-            "pipeline stage compute",
-        ).set(overlap_fraction)
-        if obs_tracing.active():
-            # publish the measured intervals as retroactive spans (one track
-            # per plane, like BucketedOverlap's comm tracks) so tracemerge's
-            # timeline corroborates the bubble/overlap gauges
-            anchor = time.time() - time.perf_counter()
-            for i, spans in enumerate(compute_spans):
-                for t0, t1, op, m in spans:
-                    obs_tracing.record_span(
-                        "pipeline_stage", ts=anchor + t0, dur_s=t1 - t0,
-                        track="pipeline", stage=i, op=op, microbatch=m,
-                    )
-            for t0, t1, (kind, m, src) in comm_spans:
-                obs_tracing.record_span(
-                    "pipeline_transfer", ts=anchor + t0, dur_s=t1 - t0,
-                    track="pipeline_comm", kind=kind, microbatch=m, stage=src,
-                )
-
-    def close(self):
-        """Stop the comm thread (idempotent)."""
-        if self._comm_worker is not None and self._comm_worker.is_alive():
-            self._jobs.put(None)
-            self._comm_worker.join(timeout=10)
-        self._comm_worker = None

@@ -1,9 +1,8 @@
 """Shared harness utilities for multi-process test worlds.
 
-One place for the CPU-world bootstrap used by the test suite and
-``bench.py``'s multichip members, so fixes to world wiring (platform
-forcing, gloo selection, coordinator addressing) cannot drift between
-copies.
+One place for the CPU-world bootstrap used by the test suite, so fixes to
+world wiring (platform forcing, gloo selection, coordinator addressing)
+cannot drift between copies.
 """
 
 import os

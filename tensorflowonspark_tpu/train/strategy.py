@@ -15,7 +15,6 @@ hand and no PS; sync DP over ICI serves both of the reference's modes
 import collections
 import logging
 import statistics
-import threading
 import time
 
 import jax
@@ -538,325 +537,6 @@ class SyncDataParallel:
         """Compile ``apply_fn(params, batch) -> predictions``; outputs gather
         to fully-addressable arrays for host-side result queues."""
         return jax.jit(apply_fn, out_shardings=replicated(self.mesh))
-
-
-class BucketedOverlap:
-    """Bucketed gradient sync overlapping collectives with backprop.
-
-    The serial path (:meth:`SyncDataParallel.compile_train_step`) lets XLA
-    insert the gradient all-reduce inside the step program, which the CPU
-    PJRT client executes strictly in order — a straggler peer stalls the
-    whole stream (measured; see :mod:`tensorflowonspark_tpu.parallel.hostreduce`).
-    This scheduler splits the step into microbatches and moves gradient
-    synchronization onto a dedicated comm thread: as each microbatch's
-    backprop program is dispatched, the comm thread fetches its gradients
-    (waiting on the device stream *beside* the next microbatch's compute),
-    partitions them into byte-bounded buckets, and runs one deterministic
-    host all-reduce per bucket through a
-    :class:`~tensorflowonspark_tpu.parallel.hostreduce.HostAllReduceGroup`.
-    The optimizer applies the accumulated mean once per step.
-
-    ``overlap=False`` runs the *identical* dispatch sequence but joins the
-    comm thread after every microbatch — the same programs, fetches, sums
-    and reductions in the same order, differing only in host-side fencing,
-    so loss trajectories are bit-identical with overlap on or off (the
-    packed-window double-buffer fencing discipline, applied to grads).
-
-    Compiled-program budget mirrors :class:`PackedLoopCache`: one grad
-    program per microbatch shape and one apply program total, cached
-    forever; the per-bucket work is host numpy and never recompiles.
-
-    Donation contract: the grad program donates **nothing** — its outputs
-    are referenced by the comm thread until each bucket is fetched, and its
-    ``params`` input is shared by every microbatch. Only the apply program
-    donates (params, opt_state), which no in-flight collective can
-    reference because :meth:`step` drains the comm thread first.
-
-    Scope: data parallelism over params that are replicated across the
-    *processes* of the host group — pure dp (each process steps its own
-    replica, like the reference's ``MultiWorkerMirroredStrategy``) and dp×tp
-    (params sharded along an in-process ``tp`` mesh axis: the grad fetch
-    gathers each leaf to host, the dp all-reduce averages the full arrays,
-    and the apply program re-shards through pinned output shardings).
-    FSDP params are NOT supported — their leaves are partitions of the
-    per-process replica, so a host-side dp all-reduce of gathered shards
-    would double-count the reduce-scatter XLA already derives from the
-    shardings; the constructor rejects that composition by axis name.
-
-    Per-step stats land in :attr:`last_stats` and the
-    ``comm_overlap_fraction`` gauge::
-
-        group = HostAllReduceGroup(rank, world)
-        sched = BucketedOverlap(strategy, loss_fn, optimizer, group=group)
-        state, metrics = sched.step(state, microbatches)
-    """
-
-    def __init__(self, strategy, loss_fn, optimizer, group=None,
-                 bucket_bytes=1 << 22, overlap=True, has_aux=False):
-        import queue
-
-        if getattr(strategy, "fsdp", False):
-            raise ValueError(
-                "BucketedOverlap cannot sync params sharded along mesh "
-                "axes ('fsdp',): each process holds only a partition of "
-                "its replica, and FSDP params already sync through XLA's "
-                "sharding-derived reduce-scatter/all-gather. Supported "
-                "compositions: replicated params (pure dp) and tp-sharded "
-                "params (dp x tp) — only the replicated dp axis is "
-                "all-reduced host-side."
-            )
-        self.strategy = strategy
-        self.loss_fn = loss_fn
-        self.optimizer = optimizer
-        self.group = group
-        self.bucket_bytes = int(bucket_bytes)
-        self.overlap = overlap
-        self.has_aux = has_aux
-        self.last_stats = {}
-        self._grad_fns = {}
-        self._apply_fn = None
-        self._buckets = None  # list of (dtype, [leaf indices]) once shapes known
-        self._treedef = None
-        # bounded: a stalled all-reduce worker should backpressure the
-        # dispatch loop, not let gradient buckets pile up unboundedly
-        self._jobs = queue.Queue(maxsize=32)
-        self._worker = None
-        self._worker_err = None
-
-    # -- compiled programs -----------------------------------------------------
-
-    def _grad_fn(self, batch):
-        key = tuple(
-            (getattr(x, "shape", ()), str(getattr(x, "dtype", "")))
-            for x in jax.tree.leaves(batch)
-        )
-        fn = self._grad_fns.get(key)
-        if fn is None:
-            # donate nothing: params feed every microbatch, grads are read by
-            # the comm thread after dispatch (donation-safety rule fixture:
-            # tests/test_tosa_dataflow.py::TestDonationSafety)
-            fn = jax.jit(
-                jax.value_and_grad(self.loss_fn, has_aux=self.has_aux),
-                donate_argnums=(),
-            )
-            self._grad_fns[key] = fn
-        return fn
-
-    def _apply(self, params, opt_state, step):
-        if self._apply_fn is None:
-            import optax
-
-            def apply(params, opt_state, step, grads, scale):
-                grads = jax.tree.map(lambda g: g * scale, grads)
-                updates, opt_state = self.optimizer.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-                return params, opt_state, step + 1
-
-            # pin output shardings to the inputs': the accumulated grads
-            # arrive as host arrays, and without the pin a tp-sharded params
-            # tree would come back with whatever placement jit infers from
-            # the unsharded operands — the next microbatch's grad program
-            # would then recompile against moved params
-            kw = {}
-            try:
-                kw["out_shardings"] = (
-                    jax.tree.map(lambda x: x.sharding, params),
-                    jax.tree.map(lambda x: x.sharding, opt_state),
-                    step.sharding,
-                )
-            except AttributeError:
-                pass  # host-numpy state (unit tests): let jit place outputs
-            self._apply_fn = jax.jit(apply, donate_argnums=(0, 1), **kw)
-        return self._apply_fn
-
-    # -- bucket partition ------------------------------------------------------
-
-    def _partition(self, grad_leaves):
-        """Partition flat grad-leaf indices into byte-bounded buckets, one
-        dtype per bucket (payloads concatenate raw)."""
-        buckets = []
-        cur, cur_bytes, cur_dtype = [], 0, None
-        order = sorted(
-            range(len(grad_leaves)), key=lambda i: str(grad_leaves[i].dtype)
-        )
-        for i in order:
-            leaf = grad_leaves[i]
-            dt = str(leaf.dtype)
-            if cur and (dt != cur_dtype or cur_bytes + leaf.nbytes > self.bucket_bytes):
-                buckets.append((cur_dtype, cur))
-                cur, cur_bytes = [], 0
-            cur.append(i)
-            cur_bytes += leaf.nbytes
-            cur_dtype = dt
-        if cur:
-            buckets.append((cur_dtype, cur))
-        return buckets
-
-    # -- comm thread -----------------------------------------------------------
-
-    def _comm_loop(self):
-        import numpy as np
-
-        while True:
-            job = self._jobs.get()
-            if job is None:
-                return
-            grad_leaves, acc, done, stats, record = job
-            try:
-                for _dtype, idxs in self._buckets:
-                    leaves = [grad_leaves[i] for i in idxs]
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(leaves)  # device stream, not comm
-                    t1 = time.perf_counter()
-                    stats["device_wait_s"] += t1 - t0
-                    record["dw_end"] = t1
-                    flat = np.concatenate([np.asarray(x).ravel() for x in leaves])
-                    if self.group is not None:
-                        flat = self.group.allreduce_mean(flat)
-                    off = 0
-                    for i in idxs:
-                        n = int(np.prod(grad_leaves[i].shape, dtype=np.int64))
-                        part = flat[off:off + n].reshape(grad_leaves[i].shape)
-                        acc[i] = part if acc[i] is None else acc[i] + part
-                        off += n
-                    t2 = time.perf_counter()
-                    record["comm_spans"].append((t1, t2))
-                    stats["comm_busy_s"] += t2 - t1
-            except BaseException as e:  # surfaces at the next drain
-                self._worker_err = e
-            finally:
-                done.set()
-
-    def _ensure_worker(self):
-        if self._worker is None or not self._worker.is_alive():
-            self._worker = threading.Thread(
-                target=self._comm_loop, name="grad-comm", daemon=True
-            )
-            self._worker.start()
-
-    def _check_err(self):
-        if self._worker_err is not None:
-            err, self._worker_err = self._worker_err, None
-            raise RuntimeError("gradient comm thread failed") from err
-
-    # -- the step --------------------------------------------------------------
-
-    def step(self, state, microbatches):
-        """One optimizer step over ``microbatches`` (a non-empty list of
-        batch pytrees, each already device-resident via
-        ``strategy.shard_batch``). Returns ``(state, metrics)`` with the
-        loss averaged over microbatches and ranks."""
-        import numpy as np
-
-        if not microbatches:
-            raise ValueError("step needs at least one microbatch")
-        self._ensure_worker()
-        self._check_err()
-        stats = {"comm_busy_s": 0.0, "device_wait_s": 0.0, "blocked_s": 0.0}
-        losses, dones, records = [], [], []
-        acc = None
-        t_step0 = time.perf_counter()
-        for batch in microbatches:
-            dispatch_ts = time.perf_counter()
-            out = self._grad_fn(batch)(state.params, batch)
-            (loss, _aux), grads = out if self.has_aux else ((out[0], None), out[1])
-            grad_leaves, treedef = jax.tree.flatten(grads)
-            if self._buckets is None:
-                self._buckets = self._partition(grad_leaves)
-                self._treedef = treedef
-                logger.info(
-                    "bucketed overlap: %d grad arrays -> %d bucket(s) <= %d bytes",
-                    len(grad_leaves), len(self._buckets), self.bucket_bytes,
-                )
-            if acc is None:
-                acc = [None] * len(grad_leaves)
-            losses.append(loss)
-            done = threading.Event()
-            dones.append(done)
-            record = {"dispatch_ts": dispatch_ts, "comm_spans": [], "dw_end": 0.0}
-            records.append(record)
-            self._jobs.put((grad_leaves, acc, done, stats, record))
-            if not self.overlap:
-                t0 = time.perf_counter()
-                done.wait()
-                stats["blocked_s"] += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        for done in dones:
-            done.wait()
-        stats["blocked_s"] += time.perf_counter() - t0
-        self._check_err()
-
-        grads = jax.tree.unflatten(self._treedef, acc)
-        scale = jnp.asarray(1.0 / len(microbatches), dtype=jnp.float32)
-        params, opt_state, step = self._apply(
-            state.params, state.opt_state, state.step
-        )(state.params, state.opt_state, state.step, grads, scale)
-        new_state = TrainState(step, params, opt_state, state.model_state)
-        loss = jnp.mean(jnp.stack(losses))
-        if self.group is not None and self.group.world > 1:
-            loss_mean = self.group.allreduce_mean(
-                np.asarray(loss, dtype=np.float32).reshape(1)
-            )[0]
-        else:
-            loss_mean = loss
-        stats["step_s"] = time.perf_counter() - t_step0
-        # measured overlap: comm seconds that ran while backprop work from a
-        # later microbatch was resident on the device stream. Job i's comm is
-        # hidden where its spans fall inside [dispatch of job i+1, last
-        # grad-ready time]; before that window nothing later is enqueued,
-        # after it the device is idle. overlap=False joins the comm thread
-        # before dispatching the next microbatch, so the window is empty and
-        # the fraction is exactly 0 — same programs, same order, only fencing.
-        window_end = max((r["dw_end"] for r in records), default=0.0)
-        hidden = 0.0
-        for i, rec in enumerate(records):
-            if i + 1 >= len(records):
-                break  # last job's comm has nothing behind it to hide under
-            window_start = records[i + 1]["dispatch_ts"]
-            for s, e in rec["comm_spans"]:
-                hidden += max(0.0, min(e, window_end) - max(s, window_start))
-        stats["hidden_comm_s"] = hidden
-        stats["overlap_fraction"] = (
-            min(1.0, hidden / stats["comm_busy_s"])
-            if stats["comm_busy_s"] > 0
-            else 0.0
-        )
-        self.last_stats = stats
-        from tensorflowonspark_tpu import obs
-        from tensorflowonspark_tpu.obs import tracing as obs_tracing
-
-        obs.gauge(
-            "comm_overlap_fraction",
-            help="fraction of host all-reduce time hidden behind device backprop",
-        ).set(stats["overlap_fraction"])
-        if obs_tracing.active():
-            # publish the comm thread's measured intervals as retroactive
-            # spans on the dedicated comm track: perf_counter -> wall via a
-            # single anchor, comm_window marking where later backprop could
-            # hide each bucket — tracemerge recomputes the overlap fraction
-            # from exactly these drawn spans to corroborate the gauge
-            anchor = time.time() - time.perf_counter()
-            for i, rec in enumerate(records):
-                for s, e in rec["comm_spans"]:
-                    obs_tracing.record_span(
-                        "comm_allreduce", ts=anchor + s, dur_s=e - s,
-                        track="comm", microbatch=i,
-                    )
-                if i + 1 < len(records) and window_end > records[i + 1]["dispatch_ts"]:
-                    win0 = records[i + 1]["dispatch_ts"]
-                    obs_tracing.record_span(
-                        "comm_window", ts=anchor + win0, dur_s=window_end - win0,
-                        track="comm_window", microbatch=i,
-                    )
-        metrics = {"loss": loss_mean, "step": new_state.step}
-        return new_state, metrics
-
-    def close(self):
-        """Stop the comm thread (the group is the caller's to close)."""
-        if self._worker is not None and self._worker.is_alive():
-            self._jobs.put(None)
-            self._worker.join(timeout=10)
-        self._worker = None
 
 
 class PackedLoopCache:

@@ -31,7 +31,7 @@ LOG_FORMAT = "%(asctime)s %(levelname)s (%(processName)s %(threadName)s) %(name)
 
 def setup_logging(level=logging.INFO):
     """Configure root logging for an APPLICATION entry point (examples,
-    bench.py, the jax child process). Libraries must never do this at import
+    the jax child process). Libraries must never do this at import
     time — importing :mod:`tensorflowonspark_tpu` leaves the root logger's
     handlers untouched so embedding applications keep control of their own
     logging (enforced by the ``import-hygiene`` rule of ``python -m tosa``
@@ -166,7 +166,7 @@ def place_compile_cache():
     (None when it gets none).
 
     Called at the top of every process that compiles (the jax child, a
-    TFParallel instance, the serving CLI, ``bench.py``).
+    TFParallel instance, the serving CLI).
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and
     nothing is set in code. Otherwise the cache goes to ``.jax_cache`` beside
     the package — a fixed path, because the path is what lets the next

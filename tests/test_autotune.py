@@ -406,7 +406,7 @@ class TestChaosDeviceLink:
 
 class TestReadaheadAutotuner:
     """The third controller: shard read-ahead depth steered by the same
-    stall accounting ``bench.classify_stalls`` reads — deepen only when the
+    stall accounting ``classify_stalls`` reads — deepen only when the
     interval was io_bound, never when decode is the bottleneck."""
 
     def _tuner(self, **kw):

@@ -625,3 +625,34 @@ class TestChaosCacheAndReadahead:
         # the stall is charged to shard-read time, where the readahead
         # autotuner and classify_stalls can see it
         assert _counter("data_producer_read_seconds_total") - read_before >= 0.03
+
+
+class _CountingStrategy:
+    """Stands in for the strategy: placing a batch only counts it."""
+
+    def __init__(self):
+        self.placed = 0
+
+    def shard_batch(self, batch):
+        self.placed += 1
+        return ("placed", batch)
+
+
+@pytest.mark.parametrize(
+    "n_batches,depth", [(6, 2), (3, 3), (1, 4)],
+    ids=["steady", "source-as-long-as-depth", "source-shorter-than-depth"],
+)
+def test_device_prefetch_places_ahead_in_order(n_batches, depth):
+    """The image cell's feed (``device_prefetch``): every batch comes out
+    once, in the source's order, placed by the strategy; when the consumer
+    holds batch i the next ``depth`` are already placed (as far as the source
+    reaches), and a source shorter than ``depth`` drains without error."""
+    from tensorflowonspark_tpu.data import device_prefetch
+
+    strategy = _CountingStrategy()
+    got = []
+    for i, out in enumerate(device_prefetch(iter(range(n_batches)), strategy, depth=depth)):
+        got.append(out)
+        assert strategy.placed == min(i + 1 + depth, n_batches)
+    assert got == [("placed", i) for i in range(n_batches)]
+    assert strategy.placed == n_batches

@@ -1,9 +1,8 @@
 """Model-axis parallelism gates: every model-sharding path — dp×tp,
-dp×fsdp×tp, 1F1B pipeline, ring attention on real TextPipeline slabs — must
-be a pure placement/scheduling change, never a numerics change. Each path is
-held to a numeric-parity gate against its single-axis reference, and the
-measured accounting (bubble fraction, overlap fraction, sharded-param
-gauges) must be live and in range."""
+dp×fsdp×tp, ring attention on real TextPipeline slabs — must be a pure
+placement change, never a numerics change. Each path is held to a
+numeric-parity gate against its single-axis reference, and the
+sharded-param gauges must be live and in range."""
 
 import os
 
@@ -18,11 +17,6 @@ import jax.numpy as jnp
 from tensorflowonspark_tpu import obs, parallel, tfrecord
 from tensorflowonspark_tpu.data import TextPipeline, Tokenizer
 from tensorflowonspark_tpu.models import transformer
-from tensorflowonspark_tpu.parallel.pipeline_parallel import (
-    Pipeline1F1B,
-    schedule_1f1b,
-    split_microbatches,
-)
 from tensorflowonspark_tpu.train.strategy import SyncDataParallel
 
 CFG = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=64,
@@ -148,108 +142,6 @@ class TestTensorParallel:
                 assert spec[1] is None  # H=2 % 4 != 0 → replicated
             if "mlp/wi/kernel" in key:
                 assert "tp" in spec  # d_ff=64 still shards
-
-
-class TestPipeline1F1B:
-    """The 1F1B schedule and host-driven pipeline: exact loss/grad parity
-    with the sequential (single-device) reference, measured bubble and
-    overlap accounting live and in range."""
-
-    def test_schedule_shape_and_memory_bound(self):
-        P, M = 4, 6
-        for s in range(P):
-            ops = schedule_1f1b(s, P, M)
-            assert [m for op, m in ops if op == "F"] == list(range(M))
-            assert [m for op, m in ops if op == "B"] == list(range(M))
-            # every F precedes its own B
-            for m in range(M):
-                assert ops.index(("F", m)) < ops.index(("B", m))
-            # ≤ P - s activation stashes in flight (the 1F1B contract)
-            depth = peak = 0
-            for op, _m in ops:
-                depth += 1 if op == "F" else -1
-                peak = max(peak, depth)
-            assert peak == min(P - s, M)
-
-    def _stages(self, n_stages=4, width=16, seed=0):
-        rng = np.random.default_rng(seed)
-        params = [
-            {"w": jnp.asarray(rng.standard_normal((width, width)) / 4.0,
-                              jnp.float32)}
-            for _ in range(n_stages)
-        ]
-
-        def stage_fn(p, x):
-            return jnp.tanh(x @ p["w"])
-
-        def loss_fn(y, target):
-            return jnp.mean((y - target) ** 2)
-
-        return stage_fn, params, loss_fn
-
-    @pytest.mark.parametrize("overlap", [True, False])
-    def test_loss_and_grads_match_sequential(self, overlap):
-        if jax.device_count() < 4:
-            pytest.skip("needs 4 cpu devices")
-        stage_fn, params, loss_fn = self._stages()
-        rng = np.random.default_rng(1)
-        x = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
-        t = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
-
-        def sequential(params_list, x, t):
-            y = x
-            for p in params_list:
-                y = stage_fn(p, y)
-            return loss_fn(y, t)
-
-        ref_loss, ref_grads = jax.value_and_grad(sequential)(params, x, t)
-
-        pipe = Pipeline1F1B(stage_fn, params, loss_fn, overlap=overlap)
-        try:
-            loss, grads = pipe.step(
-                split_microbatches(x, 8), split_microbatches(t, 8)
-            )
-            assert abs(float(loss) - float(ref_loss)) <= 1e-6
-            for ref_g, got_g in zip(ref_grads, grads):
-                np.testing.assert_allclose(
-                    np.asarray(got_g["w"]), np.asarray(ref_g["w"]), atol=1e-5
-                )
-            stats = pipe.last_stats
-            assert stats["n_stages"] == 4 and stats["n_microbatches"] == 8
-            assert 0.0 <= stats["bubble_fraction"] <= 1.0
-            assert 0.0 <= stats["overlap_fraction"] <= 1.0
-            assert stats["comm_busy_s"] > 0.0
-            assert obs.gauge("pipeline_bubble_fraction").value == pytest.approx(
-                stats["bubble_fraction"]
-            )
-        finally:
-            pipe.close()
-
-    def test_grad_accumulation_weights_microbatches_equally(self):
-        # 1 stage, M microbatches: grads must equal grad(mean-of-means loss)
-        stage_fn, params, loss_fn = self._stages(n_stages=1)
-        rng = np.random.default_rng(2)
-        x = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
-        t = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
-
-        def mean_of_micro(p, x, t):
-            xs, ts = split_microbatches(x, 4), split_microbatches(t, 4)
-            return jnp.mean(
-                jnp.stack([loss_fn(stage_fn(p, xs[m]), ts[m]) for m in range(4)])
-            )
-
-        ref_loss, ref_grad = jax.value_and_grad(mean_of_micro)(params[0], x, t)
-        pipe = Pipeline1F1B(stage_fn, params, loss_fn, overlap=False)
-        try:
-            loss, grads = pipe.step(
-                split_microbatches(x, 4), split_microbatches(t, 4)
-            )
-            assert abs(float(loss) - float(ref_loss)) <= 1e-6
-            np.testing.assert_allclose(
-                np.asarray(grads[0]["w"]), np.asarray(ref_grad["w"]), atol=1e-5
-            )
-        finally:
-            pipe.close()
 
 
 class TestRingOnTextSlabs:

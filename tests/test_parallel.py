@@ -223,6 +223,72 @@ class TestPipelineParallel:
             np.asarray(g_pp["w"]), np.asarray(g_seq["w"]), atol=1e-5
         )
 
+    @staticmethod
+    def _mean_loss(y, target):
+        return jnp.mean((y - target) ** 2)
+
+    @pytest.mark.parametrize("n_micro", [2, 4])
+    def test_loss_and_grads_match_sequential(self, n_micro):
+        """Fewer microbatches than stages, and as many: every stage idles
+        for part of the schedule and stage 0 re-reads a clipped microbatch;
+        the loss against a target and every stage's gradient are still the
+        sequential stages'."""
+        parallel, mesh, weights, stacked, x = self._setup()
+        t = jnp.asarray(np.random.default_rng(1).standard_normal(x.shape), jnp.float32)
+
+        def loss_pp(stacked_params):
+            out = parallel.pipeline_apply(
+                self._stage_fn, stacked_params,
+                parallel.split_microbatches(x, n_micro), mesh,
+            )
+            return self._mean_loss(parallel.merge_microbatches(out), t)
+
+        def loss_seq(stacked_params):
+            y = x
+            for i in range(4):
+                y = self._stage_fn(jax.tree.map(lambda a: a[i], stacked_params), y)
+            return self._mean_loss(y, t)
+
+        loss, grads = jax.value_and_grad(loss_pp)(stacked)
+        ref_loss, ref_grads = jax.value_and_grad(loss_seq)(stacked)
+        assert abs(float(loss) - float(ref_loss)) <= 1e-6
+        np.testing.assert_allclose(
+            np.asarray(grads["w"]), np.asarray(ref_grads["w"]), atol=1e-5
+        )
+
+    def test_microbatches_weigh_equally_in_the_gradient(self):
+        """One stage, four microbatches, each against its own target: the
+        gradient through the pipeline is that of the mean of the four
+        microbatch losses, so no microbatch is dropped, doubled or handed
+        another's slot."""
+        mesh = parallel.build_mesh({"pp": 1}, devices=jax.devices()[:1])
+        rng = np.random.default_rng(2)
+        w = {"w": jnp.asarray(rng.standard_normal((8, 8)) / 4.0, jnp.float32)}
+        x = jnp.asarray(rng.standard_normal((8, 8)), jnp.float32)
+        t = jnp.asarray(rng.standard_normal((8, 8)), jnp.float32)
+        xs, ts = parallel.split_microbatches(x, 4), parallel.split_microbatches(t, 4)
+
+        def loss_pp(p):
+            out = parallel.pipeline_apply(
+                self._stage_fn, parallel.stack_stage_params([p]), xs, mesh
+            )
+            return jnp.mean(jnp.stack([self._mean_loss(out[m], ts[m]) for m in range(4)]))
+
+        def mean_of_micro(p):
+            return jnp.mean(jnp.stack(
+                [self._mean_loss(self._stage_fn(p, xs[m]), ts[m]) for m in range(4)]
+            ))
+
+        loss, grad = jax.value_and_grad(loss_pp)(w)
+        ref_loss, ref_grad = jax.value_and_grad(mean_of_micro)(w)
+        assert abs(float(loss) - float(ref_loss)) <= 1e-6
+        np.testing.assert_allclose(np.asarray(grad["w"]), np.asarray(ref_grad["w"]), atol=1e-5)
+
+    def test_split_refuses_a_batch_the_count_does_not_divide(self):
+        with pytest.raises(ValueError, match="not divisible into 3"):
+            parallel.split_microbatches(jnp.zeros((16, 8)), 3)
+        assert parallel.split_microbatches(jnp.zeros((16, 8)), 4).shape == (4, 4, 8)
+
     def test_jit_with_sharded_stage_params(self):
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P

@@ -1,5 +1,8 @@
 """train/strategy tests: sync DP and FSDP training on the 8-device CPU mesh."""
 
+import functools
+import re
+
 import numpy as np
 import pytest
 
@@ -9,6 +12,7 @@ import optax
 from jax.sharding import PartitionSpec as P
 
 from tensorflowonspark_tpu import parallel
+from tensorflowonspark_tpu.parallel.sharding import _spec_axes
 from tensorflowonspark_tpu.train import SyncDataParallel, TrainState, steps_per_worker
 
 
@@ -183,9 +187,9 @@ def test_loop_prefetch_windows_and_drops_remainder():
 
 
 def test_packed_prefetch_stacks_and_shards_windows():
-    """packed_place (shared by packed_prefetch and bench.py's packed link
-    probe): K host batches -> ONE [K, B, ...] device tree, batch dim sharded
-    over the data axes; short final windows are dropped."""
+    """packed_place (packed_prefetch's placement): K host batches -> ONE
+    [K, B, ...] device tree, batch dim sharded over the data axes; short
+    final windows are dropped."""
     from tensorflowonspark_tpu.data import packed_prefetch
 
     mesh = parallel.build_mesh({"dp": 8})
@@ -251,6 +255,176 @@ def test_prune_checkpoints_keeps_newest(tmp_path):
     assert checkpoint.latest_checkpoint(str(tmp_path)).endswith("ckpt_10")
     assert checkpoint.latest_checkpoint(str(tmp_path), prefix="").endswith("run_99")
     assert checkpoint.prune_checkpoints(str(tmp_path), keep=0) == 0  # disabled
+
+
+# -- one step, many layouts -----------------------------------------------------
+# What the jitted step owes under every placement: the layout is where the
+# arrays live, never what the step computes.
+
+_LM_CFG = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=64, dtype="float32")
+
+
+def _mlp_job(mesh):
+    def init(rng):
+        k1, k2 = jax.random.split(rng)
+        return {
+            "w1": jax.random.normal(k1, (64, 64)) * 0.1,
+            "w2": jax.random.normal(k2, (64, 8)) * 0.1,
+        }
+
+    def loss(params, batch):
+        h = jnp.tanh(batch["x"] @ params["w1"])
+        return jnp.mean((h @ params["w2"] - batch["y"]) ** 2)
+
+    rng = np.random.default_rng(7)
+    batch = {
+        "x": rng.normal(size=(16, 64)).astype(np.float32),
+        "y": rng.normal(size=(16, 8)).astype(np.float32),
+    }
+    return init, loss, False, None, batch
+
+
+def _lm_job(mesh):
+    from tensorflowonspark_tpu.models import transformer
+
+    model = transformer.create_model(mesh=mesh, attention="plain", **_LM_CFG)
+    rng = np.random.default_rng(7)
+    rows, l = 8, 25
+    # two documents and a padded tail a row, as the text plane packs them
+    seg = np.zeros((rows, l), np.int32)
+    pos = np.zeros((rows, l), np.int32)
+    seg[:, :13], seg[:, 13:21] = 1, 2
+    pos[:, :13], pos[:, 13:21] = np.arange(13), np.arange(8)
+    tokens = np.where(seg > 0, rng.integers(3, 64, (rows, l)), 0).astype(np.int32)
+    batch = {"tokens": tokens, "segment_ids": seg, "positions": pos}
+    return (
+        transformer.make_init_fn(model, sample_len=8),
+        transformer.make_loss_fn(model),
+        True,
+        transformer.param_specs,
+        batch,
+    )
+
+
+def _job_on(job, axes):
+    """``job`` placed on a mesh of ``axes`` over the first devices: the
+    strategy, its seeded state, the compiled step and the placed batch."""
+    n = int(np.prod(list(axes.values())))
+    mesh = parallel.build_mesh(axes, devices=jax.devices()[:n])
+    init, loss, has_aux, spec_fn, batch = job(mesh)
+    strategy = SyncDataParallel(
+        mesh,
+        fsdp="fsdp" in axes,
+        min_weight_size=1,
+        tp=spec_fn if "tp" in axes else False,
+    )
+    optimizer = optax.sgd(0.1)
+    state = strategy.create_state(init, optimizer, jax.random.PRNGKey(0))
+    step = strategy.compile_train_step(loss, optimizer, has_aux=has_aux)
+    return strategy, state, step, strategy.shard_batch(batch)
+
+
+def _one_step(job, axes):
+    """Loss of the first step, the seeded parameters and every leaf's
+    update, on the host."""
+    _, state, step, batch = _job_on(job, axes)
+    before = jax.tree.map(np.array, state.params)  # the step donates its state
+    state, metrics = step(state, batch)
+    jax.block_until_ready(metrics["loss"])
+    update = jax.tree.map(lambda a, b: np.array(a) - b, state.params, before)
+    return float(metrics["loss"]), before, update
+
+
+@functools.cache
+def _one_device(job):
+    return _one_step(job, {"dp": 1})
+
+
+@pytest.mark.parametrize(
+    "job,axes",
+    [
+        (_mlp_job, {"dp": 8}),
+        (_mlp_job, {"fsdp": 8}),
+        (_lm_job, {"dp": 8}),
+        (_lm_job, {"dp": 4, "tp": 2}),
+        (_lm_job, {"dp": 2, "fsdp": 2, "tp": 2}),
+        (_lm_job, {"fsdp": 8}),
+    ],
+    ids=["mlp-dp8", "mlp-fsdp8", "lm-dp8", "lm-dp4.tp2", "lm-dp2.fsdp2.tp2", "lm-fsdp8"],
+)
+def test_step_is_layout_invariant(job, axes):
+    """Same seed, same global batch: under every layout the loss and every
+    leaf's update are one device's."""
+    ref_loss, ref_params, ref_update = _one_device(job)
+    loss, params, update = _one_step(job, axes)
+    assert abs(loss - ref_loss) <= 1e-5
+    names = [
+        jax.tree_util.keystr(path)
+        for path, _ in jax.tree_util.tree_flatten_with_path(ref_params)[0]
+    ]
+    for name, got, want in zip(names, jax.tree.leaves(params), jax.tree.leaves(ref_params)):
+        np.testing.assert_array_equal(got, want, err_msg="seeded " + name)
+    for name, got, want in zip(names, jax.tree.leaves(update), jax.tree.leaves(ref_update)):
+        assert np.abs(want).max() > 0, name  # a leaf that never moved compares nothing
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=0, err_msg=name)
+
+
+@pytest.mark.parametrize(
+    "axes",
+    [{"dp": 2, "fsdp": 4}, {"dp": 4, "tp": 2}, {"dp": 2, "fsdp": 2, "tp": 2}],
+    ids=["fsdp", "tp", "fsdp.tp"],
+)
+def test_params_keep_their_placement_through_steps(axes):
+    """After donated steps every parameter (and the step's own outputs, not
+    only the seeded state) sits where ``param_shardings`` put it: a leaf that
+    came back replicated would compile a second program and hold a whole
+    copy a chip."""
+    strategy, state, step, batch = _job_on(_lm_job, axes)
+    want = strategy.param_shardings(jax.eval_shape(lambda p: p, state.params))
+    sharded = set().union(*(_spec_axes(s.spec) for s in jax.tree.leaves(want)))
+    assert sharded == set(axes) - {"dp"}
+    for _ in range(2):
+        state, metrics = step(state, batch)
+        jax.block_until_ready(metrics["loss"])
+    for (path, leaf), sharding in zip(
+        jax.tree_util.tree_flatten_with_path(state.params)[0], jax.tree.leaves(want)
+    ):
+        assert leaf.sharding.is_equivalent_to(sharding, leaf.ndim), (
+            jax.tree_util.keystr(path), leaf.sharding, sharding)
+
+
+def _collectives(compiled_text):
+    """kind -> the distinct replica groups its operations run over."""
+    found = {}
+    for kind, groups in re.findall(
+        r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+        r"(?:-start)?\(.*?replica_groups=(\S+?), ",
+        compiled_text,
+    ):
+        found.setdefault(kind, set()).add(groups)
+    return found
+
+
+@pytest.mark.parametrize(
+    "axes", [{"dp": 8}, {"fsdp": 8}, {"dp": 4, "tp": 2}], ids=["dp", "fsdp", "dp.tp"]
+)
+def test_compiled_step_collectives(axes):
+    """The kinds of collective XLA derives from the shardings, read in the
+    compiled step's text (kinds present or absent, never counts, which its
+    combiner may change): dp reduces gradients and gathers nothing (what the
+    four-chip smoke run read, PERF.md PR 21); fsdp gathers parameters; tp
+    reduces activations over its own axis beside dp's gradients."""
+    _, state, step, batch = _job_on(_lm_job, axes)
+    found = _collectives(step.lower(state, batch).compile().as_text())
+    if "fsdp" in axes:
+        assert "all-gather" in found
+        assert "all-reduce" in found or "reduce-scatter" in found
+    else:
+        assert "all-reduce" in found
+        assert "all-gather" not in found and "reduce-scatter" not in found
+        # dp alone reduces over one grouping of the devices; tp adds its own
+        assert (len(found["all-reduce"]) > 1) == ("tp" in axes), found
+    assert "all-to-all" not in found
 
 
 class _FakeDevice:

@@ -62,6 +62,23 @@ def test_waves_then_clean_shutdown(sc, tmp_path):
     assert _totals(str(tmp_path), 2) == 3 * 64
 
 
+def test_idle_ticker_leaves_the_drain_lock_free():
+    """``stop()`` takes the ticker's lock to wait out a micro-batch that is
+    feeding. A lock is not fair, so a ticker that held it across its idle
+    wait and took it again at once could starve ``stop()`` for minutes
+    (``cluster.shutdown(ssc=...)`` hung so in a sandbox): while nothing is
+    queued the lock is free."""
+    ssc = LocalStreamingContext(None, batch_interval=0.2)
+    ssc.queueStream()
+    ssc.start()
+    try:
+        for _ in range(5):
+            time.sleep(0.05)
+            assert not ssc._busy.locked()
+    finally:
+        ssc.stop()
+
+
 def test_generator_of_rdds(sc, tmp_path):
     """cluster.train also accepts a plain iterable of RDDs."""
     cluster = TFCluster.run(

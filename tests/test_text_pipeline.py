@@ -3,13 +3,12 @@ determinism contract (byte-identical [B, L] streams across pack modes,
 knobs, and cache states), the text chaos sites, the TFEstimator LM
 fine-tune wiring, and the perf-smoke lm leg."""
 
-import importlib.util
-import os
 
 import numpy as np
 import pytest
 
 from tensorflowonspark_tpu import chaos, obs, tfrecord
+from tensorflowonspark_tpu.control import classify_stalls
 from tensorflowonspark_tpu.data import TextPipeline, TokenizeError, Tokenizer, pack_bins
 from tensorflowonspark_tpu.data.tokenizer import BOS_ID, EOS_ID, PAD_ID, RESERVED_IDS
 
@@ -284,11 +283,10 @@ class TestChaosSites:
         assert _collect(pipe)
         stall = _d("text_pack_stall_seconds_total")
         assert stall > 0, "pack_stall delay was not charged"
-        bench = _load_bench()
         # the injected delay lands in parse time: the classifier must call
         # the run input-bound (decode_bound), not io/device bound
         assert (
-            bench.classify_stalls(
+            classify_stalls(
                 _d("data_producer_read_seconds_total"),
                 _d("data_producer_parse_seconds_total"),
                 0.0,  # producer never blocked on the queue in this drain
@@ -297,14 +295,6 @@ class TestChaosSites:
             == "decode_bound"
         )
         assert _d("chaos_fault_data_pack_stall_total") > 0
-
-
-def _load_bench():
-    path = os.path.join(os.path.dirname(__file__), "..", "bench.py")
-    spec = importlib.util.spec_from_file_location("bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 class TestEstimatorLMFinetune:
@@ -400,77 +390,3 @@ def _lm_finetune_fn(args, ctx):
     assert all(np.isfinite(losses)), losses
     while not feed.should_stop():
         feed.next_batch(16)
-
-
-@pytest.mark.perf_smoke
-class TestPerfSmokeLM:
-    """The BENCH_MODE=lm shape in miniature: a tiny transformer fine-tunes
-    through the packed loader and the train-vs-input-only pair must
-    validate under the regime-aware band (train can never beat its own
-    input path)."""
-
-    def test_pair_validates(self, tmp_path):
-        import time
-
-        import jax
-        import optax
-
-        from tensorflowonspark_tpu import parallel
-        from tensorflowonspark_tpu.models import transformer
-        from tensorflowonspark_tpu.train import SyncDataParallel
-
-        bench = _load_bench()
-        batch = jax.device_count()  # dp=-1 mesh: batch divides the mesh
-        files = _write_corpus(tmp_path, _sample_texts(400, seed=21), shards=4)
-        pipe = TextPipeline(
-            files, Tokenizer(kind="word", vocab_size=256), seq_len=33,
-            batch_size=batch, seed=0, epochs=None, prefetch_batches=4,
-        )
-        stream = iter(pipe)
-        mesh = parallel.local_mesh({"dp": -1})
-        strategy = SyncDataParallel(mesh)
-        model = transformer.create_model(
-            mesh=mesh, vocab_size=256, d_model=32, n_layers=2, n_heads=2,
-            d_ff=64, dtype="float32",
-        )
-        optimizer = optax.adamw(1e-3)
-        state = strategy.create_state(
-            transformer.make_init_fn(model, sample_len=8), optimizer,
-            jax.random.PRNGKey(0),
-        )
-        step = strategy.compile_train_step(
-            transformer.make_loss_fn(model), optimizer, has_aux=True
-        )
-        batches = (strategy.shard_batch(b) for b in stream)
-        state, metrics = step(state, next(batches))  # compile
-        float(np.asarray(jax.device_get(metrics["loss"])))
-        d = 6
-
-        def no_compute():
-            jax.block_until_ready(next(batches)["tokens"])
-            t0 = time.perf_counter()
-            buf = None
-            for _ in range(d):
-                buf = next(batches)
-            jax.block_until_ready(buf["tokens"])
-            return d / (time.perf_counter() - t0)
-
-        def train():
-            nonlocal state, metrics
-            state, metrics = step(state, next(batches))
-            float(np.asarray(jax.device_get(metrics["loss"])))
-            t0 = time.perf_counter()
-            for _ in range(d):
-                state, metrics = step(state, next(batches))
-            float(np.asarray(jax.device_get(metrics["loss"])))
-            return d / (time.perf_counter() - t0)
-
-        no_compute(), train()  # warm-up pair, discarded
-        nc, tr = no_compute(), train()
-        stream.close()
-        # regime-aware validity: train <= 1.10 * input-path always holds
-        valid, _invalid = bench.partition_pairs(
-            [nc], [tr], min_ratio=0.0
-        )
-        assert valid, "train block ({:.1f}/s) beat its own input path ({:.1f}/s)".format(tr, nc)
-        assert np.isfinite(float(np.asarray(jax.device_get(metrics["loss"]))))

@@ -215,15 +215,14 @@ class TestDonationSafety(unittest.TestCase):
         self.assertEqual(findings, [])
 
 
-class TestBucketedOverlapDonation(unittest.TestCase):
-    """Pins the BucketedOverlap donation contract: a grad program that
-    donated its params would invalidate the buffers every later microbatch
-    (and the comm thread's in-flight bucket fetches) still reference."""
+class TestDonatedBufferHandedOutAgain(unittest.TestCase):
+    """A program that donates a buffer it hands out again: a grad program
+    that donated its params would invalidate the buffers every later
+    microbatch (and whatever still holds the first's gradients) reads."""
 
     def test_donating_grad_fn_fires(self):
-        # the shape BucketedOverlap must never take: donate params to the
-        # grad program, then keep handing them out for the next microbatch
-        # while the first's grads sit on the comm queue
+        # donate params to the grad program, then keep handing them out for
+        # the next microbatch while the first's grads sit on a queue
         findings = run_project_rule("donation-safety", {LIB_PATH: _src(
             """
             import jax
@@ -238,9 +237,9 @@ class TestBucketedOverlapDonation(unittest.TestCase):
         self.assertEqual(len(findings), 1)
         self.assertIn("read after being donated", findings[0].message)
 
-    def test_overlap_shape_stays_clean(self):
-        # the in-tree shape: grad program donates nothing; only the apply
-        # program donates, after the comm drain, and its result is rebound
+    def test_donating_only_the_rebound_state_stays_clean(self):
+        # the grad program donates nothing; only the apply program donates,
+        # after the last read of what it takes, and its result is rebound
         findings = run_project_rule("donation-safety", {LIB_PATH: _src(
             """
             import jax

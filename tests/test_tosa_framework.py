@@ -280,7 +280,7 @@ class TestIndexCache:
 class TestSelfRun:
     def test_repo_is_clean_under_all_rules(self):
         """The hard gate: the analyzer over its default targets (library,
-        bench.py, scripts) finds nothing to report — every invariant the
+        scripts) finds nothing to report — every invariant the
         thirteen rules encode holds in this repo, with an empty baseline."""
         proc = _run_cli([])
         assert proc.returncode == 0, "\n" + proc.stdout + proc.stderr

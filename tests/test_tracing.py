@@ -194,11 +194,11 @@ class TestTraceContext:
 
     def test_record_span_lands_on_named_track(self, trace_root):
         tracing.mint(proc="driver")
-        tracing.record_span("comm_allreduce", ts=10.0, dur_s=0.5, track="comm")
+        tracing.record_span("feed_wave", ts=10.0, dur_s=0.5, track="feeder")
         flight.current().close()
         ((_, records, _),) = _shard_records(trace_root)
         (span,) = [r for r in records if r.get("kind") == "span"]
-        assert span["track"] == "comm"
+        assert span["track"] == "feeder"
         assert span["ts"] == 10.0 and span["dur_s"] == 0.5
 
     def test_chaos_record_dumps_flight_ring(self, trace_root):
@@ -264,6 +264,38 @@ class TestTraceMerge:
         assert rc == 1
         assert "never_happened" in capsys.readouterr().err
 
+    def test_labelled_spans_land_on_tracks_of_their_own(self, tmp_path):
+        """``record_span(track=...)`` spans are drawn as complete events on
+        one synthetic thread a label, named by the label, apart from the
+        recording thread's B/E pairs."""
+        root = str(tmp_path)
+        drv = flight.FlightRecorder(root, "driver", trace_id="t" * 32)
+        for name, ts, track in (
+            ("feed_wave", 1000.0, "feeder"),
+            ("inference_wave", 1000.2, "scorer"),
+            ("feed_wave", 1000.5, "feeder"),
+        ):
+            drv.append({"kind": "span", "name": name, "trace": "t" * 32,
+                        "span": name + str(ts), "parent": None, "ts": ts,
+                        "dur_s": 0.25, "ok": True, "tid": 7, "track": track})
+        drv.close()
+        trace, _ = tracemerge.merge_directory(root)
+        assert tracemerge.validate_chrome_trace(trace) == []
+        names = {e["args"]["name"]: e["tid"] for e in trace["traceEvents"]
+                 if e.get("ph") == "M" and e["name"] == "thread_name"}
+        assert set(names) == {"feeder", "scorer"}
+        assert names["feeder"] != names["scorer"]
+        assert min(names.values()) >= tracemerge.TRACK_TID_BASE
+        drawn = sorted(
+            (e["tid"], e["name"], round(e["ts"]), round(e["dur"]))
+            for e in trace["traceEvents"] if e.get("ph") == "X"
+        )
+        assert drawn == sorted([
+            (names["feeder"], "feed_wave", 1_000_000_000, 250_000),
+            (names["scorer"], "inference_wave", 1_000_200_000, 250_000),
+            (names["feeder"], "feed_wave", 1_000_500_000, 250_000),
+        ])
+
     def test_validate_rejects_unmatched_pairs(self):
         bad = {"traceEvents": [
             {"ph": "B", "name": "a", "pid": 1, "tid": 1, "ts": 1.0},
@@ -278,16 +310,6 @@ class TestTraceMerge:
             "unclosed B" in p
             for p in tracemerge.validate_chrome_trace(dangling)
         )
-
-    def test_overlap_fraction_from_drawn_geometry(self):
-        events = [
-            {"ph": "X", "name": "comm_allreduce", "ts": 0.0, "dur": 10.0},
-            {"ph": "X", "name": "comm_window", "ts": 2.0, "dur": 4.0},
-            {"ph": "X", "name": "comm_window", "ts": 4.0, "dur": 4.0},
-        ]
-        # windows [2,6] and [4,8] merge to [2,8]: 6 of 10 units hidden
-        assert tracemerge.overlap_fraction(events) == pytest.approx(0.6)
-        assert tracemerge.overlap_fraction([]) is None
 
 
 class TestRegistryAndExporter:

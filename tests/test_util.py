@@ -3,6 +3,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from tensorflowonspark_tpu import util
 
 
@@ -124,3 +126,17 @@ def test_compile_cache_default_is_one_fixed_in_checkout_path():
 def test_compile_cache_not_placed_for_cpu_pinned_processes():
     """The CPU test worlds get no cache unless the variable names one."""
     assert _cache_probe(JAX_PLATFORMS="cpu") == [None, None]
+
+
+@pytest.mark.parametrize("package", ["train", "parallel", "ops", "ckpt"])
+def test_lazy_exports_resolve(package):
+    """Every name a lazily exporting package offers is there to be had: the
+    table outlives a deleted module or function in silence until someone
+    asks for the name."""
+    import importlib
+
+    pkg = importlib.import_module("tensorflowonspark_tpu." + package)
+    assert pkg._EXPORTS
+    for name in pkg._EXPORTS:
+        assert getattr(pkg, name) is not None, name
+    assert dir(pkg) == sorted(pkg._EXPORTS)

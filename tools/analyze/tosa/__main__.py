@@ -21,7 +21,7 @@ from . import __version__, core, sarif
 from .checkers import ALL_CHECKERS, make_checkers
 
 #: what a bare ``python -m tosa`` analyzes, relative to the repo root
-DEFAULT_TARGETS = ("tensorflowonspark_tpu", "bench.py", "scripts")
+DEFAULT_TARGETS = ("tensorflowonspark_tpu", "scripts")
 
 BASELINE_RELPATH = os.path.join("tools", "analyze", "baseline.json")
 
