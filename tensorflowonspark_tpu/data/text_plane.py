@@ -260,6 +260,11 @@ class TextPipeline(ImagePipeline):
             help="blocks of the causal triangle over the emitted rows: what the kernels "
             "computed before they skipped by the packing",
         )
+        grid_steps_c = obs.counter(
+            "flash_grid_steps_total",
+            help="grid steps the segmented flash kernels take for the emitted rows, per head "
+            "and pass: every row of a batch walks as many as the batch's longest list of needed blocks",
+        )
 
         # the pack plane forks its workers HERE, before any pipeline thread
         # exists (fork-with-threads is the one mp lifecycle hazard)
@@ -444,9 +449,10 @@ class TextPipeline(ImagePipeline):
                 eff_g.set(eff)
                 pad_g.set(1.0 - eff)
                 # the columns the LM attends (make_loss_fn feeds [:, :-1])
-                needed, dense = flash_blocks.attended_blocks(buf[:rows, 1, :-1])
+                needed, dense, steps = flash_blocks.attended_blocks(buf[:rows, 1, :-1])
                 blocks_needed_c.inc(needed)
                 blocks_dense_c.inc(dense)
+                grid_steps_c.inc(steps)
                 if plane is not None:
                     # slab views are copied out and the slab returns to the
                     # pool at once (yielded batches are retainable)

@@ -11,9 +11,9 @@ traffic stays O(L·D).
 ``ds`` are computed once and feed all three gradients (PR 27; two kernels,
 q-major for dq and kv-major for dk/dv, each recomputed them). The kernel is
 kv-major: dk and dv of the kv block accumulate in block-sized scratch over
-the q blocks its range walks, and dq accumulates in a float32 scratch that
-holds the **whole row** ``[L_q, d]`` of one (batch, head) in VMEM across both
-inner grid dims, zeroed at the row's first step and written out at its last.
+the q blocks that need it, and dq accumulates in a float32 scratch that
+holds the **whole row** ``[L_q, d]`` of one (batch, head) in VMEM across the
+row's grid steps, zeroed at the first and written out at the last.
 For a fixed q block the kv blocks arrive in increasing order, so the float32
 sum is the q-major kernel's and dq, dk, dv are bit-identical to the two
 kernels' (read on the chip at 64/64 and 192/128, PERF.md §6). The call asks
@@ -24,24 +24,40 @@ v5e's 128 MiB: **the largest row is 81,920 positions at a head size up to
 ``ValueError`` that names the limit (a row that long is sharded over chips:
 :mod:`~tensorflowonspark_tpu.parallel.ring_attention`).
 
-**The block map.** The kernels visit only the blocks the job needs. From the
-segment ids (in the jitted step, a few thousand integers in XLA) comes, per
-batch row, each block's [smallest, largest] id
-(:mod:`~tensorflowonspark_tpu.ops.flash_blocks` holds the rule) and from
-those the range of kv blocks every q block needs and the range of q blocks
-every kv block needs. The two range tables ride into the kernels as
-scalar-prefetch operands: the accumulating grid axis walks the range from
-its first block and then parks on its last, so a step with nothing to do
-names the block already resident and Pallas copies nothing, and only a new
-block of the range is computed (for the text plane's ids, which do not
-decrease along a row, the range holds needed blocks only). A skipped block
-would have contributed exactly 0 (``exp(_NEG_BIG - m)``), so outputs and
-gradients are bit-identical to the dense grid's. Without segment ids the map
-is the causal triangle (or everything). A computed block is masked as
-before, causal and fence both: choosing a body by what a block needs (no
-mask below the diagonal inside one document) was built and read on the chip
-in PR 25, where it ran 2% slower than masking always and cost three times
-the tracing (PERF.md §6).
+**The block map and the work list.** The kernels visit only the blocks the
+job needs. From the segment ids (in the jitted step, a few thousand integers
+in XLA) comes, per batch row, each block's [smallest, largest] id
+(:mod:`~tensorflowonspark_tpu.ops.flash_blocks` holds the rule), from those
+the needed (q block, kv block) pairs, and from those, per batch row and per
+kernel, **one flat list of the needed blocks** in outer-major order with the
+inner blocks ascending (q-major for the forward, kv-major for the backward;
+:func:`flash_blocks.work_list`): one packed int32 an item, naming its two
+blocks and whether it is the first of its outer block (zero the block
+accumulators), the last (write them out) and whether to compute at all (an
+outer block that needs nothing keeps one item that computes nothing, so it
+is still written). The list rides into the kernel as its scalar-prefetch
+operand and the grid is ``(batch·heads, steps)``: every index map reads the
+item of ``(b // heads, t)``. ``steps`` is the batch's longest list (a traced
+grid bound; the interpreter is given the shape's bound,
+:func:`flash_blocks.work_bound`: the causal triangle, or the square without
+``causal``), and a row with a shorter list parks on its last item: a parked
+step names the blocks already resident, so Pallas copies nothing, and
+computes nothing. A skipped block would have contributed exactly 0
+(``exp(_NEG_BIG - m)``), so outputs and gradients are bit-identical to the
+dense grid's. Without segment ids the list is the causal triangle (or
+everything). A computed block is masked as before, causal and fence both:
+choosing a body by what a block needs (no mask below the diagonal inside
+one document) was built and read on the chip in PR 25, where it ran 2%
+slower than masking always and cost three times the tracing (PERF.md §6).
+
+**What the lists cost.** The lists of all batch rows of a call sit in SMEM
+(1 MiB on a v5e), four bytes an item, the shape's bound a row: 36 items at
+4096 with 512 x 512 blocks, 136 at 8192, 528 at 16,384 and 12,880 (50 KiB)
+at the 81,920 positions the backward's VMEM limit allows, of which 19 batch
+rows fit; without ``causal`` the bound is the square (25,600 there, 9 rows).
+A call whose lists would take more than 960 KiB, or with more than 16,384
+blocks along an axis, is refused at trace time by a ``ValueError`` that
+names the limit (:func:`_work`).
 
 This is the single-device analogue of
 :mod:`tensorflowonspark_tpu.parallel.ring_attention` (same math, blocks
@@ -68,33 +84,39 @@ _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
 _STAT_W = 8
 
 
-def _walk(lo_ref, hi_ref, at, j):
-    """Step ``j`` of a kernel's accumulating grid axis: the block it visits,
-    walking the outer block's needed range ``[lo, hi]`` (entry ``at`` of the
-    map) from ``lo`` and parking on ``hi``, and whether that block is new
-    (False once parked, and for an empty range, ``hi < lo``).
-
-    Scalar code here and in :func:`_here` is written in ``lax`` primitives:
-    it is traced into every index map of every call (72 a step in a 24-layer
-    model that recomputes), and a ``jnp`` wrapper or a floor division costs
-    milliseconds of lowering each time (PERF.md §6, PR 25)."""
-    lo, hi = lo_ref[at], hi_ref[at]
-    return jax.lax.max(jax.lax.min(lo + j, hi), 0), lo + j <= hi
-
-
 def _row(b, heads):
     """The batch row of grid index ``b`` over batch·heads (``b`` ≥ 0, so the
-    truncating division is the floor)."""
+    truncating division is the floor).
+
+    Scalar code here and in :func:`_outer`, :func:`_inner` and :func:`_flag`
+    is written in ``lax`` primitives: it is traced into every index map of
+    every call (72 a step in a 24-layer model that recomputes), and a ``jnp``
+    wrapper or a floor division costs milliseconds of lowering each time
+    (PERF.md §6, PR 25)."""
     return jax.lax.div(b, jnp.int32(heads))
 
 
-def _here(tabs, heads, n_outer):
-    """This grid step's place: ``(outer, inner, needed)`` — the block of
-    grid dim 1, the block that :func:`_walk` visits along the accumulating
-    dim, and whether to compute it."""
-    outer = pl.program_id(1)
-    at = _row(pl.program_id(0), heads) * n_outer + outer
-    return (outer,) + _walk(*tabs, at, pl.program_id(2))
+def _outer(item):
+    """The outer block of a work-list item."""
+    return jax.lax.shift_right_logical(item, jnp.int32(flash_blocks.ITEM_OUTER_SHIFT))
+
+
+def _inner(item):
+    """The inner block of a work-list item."""
+    return jax.lax.bitwise_and(
+        jax.lax.shift_right_logical(item, jnp.int32(flash_blocks.ITEM_INNER_SHIFT)),
+        jnp.int32(flash_blocks.ITEM_BLOCKS_MOST - 1))
+
+
+def _flag(item, bit):
+    return jax.lax.bitwise_and(item, jnp.int32(bit)) != 0
+
+
+def _here(items_ref, heads, steps):
+    """This grid step's place: ``(outer, inner, item)``, from entry ``t`` of
+    the work list of ``b``'s batch row (``steps`` entries a row)."""
+    item = items_ref[_row(pl.program_id(0), heads) * steps + pl.program_id(1)]
+    return _outer(item), _inner(item), item
 
 
 def _causal_mask(s, iq, ik, block_q, block_k):
@@ -127,23 +149,21 @@ def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_
     return s
 
 
-def _fwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_outer):
-    tabs, refs = refs[:2], refs[2:]
+def _fwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps):
     if segmented:
         q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref, acc, m, l = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l = refs
         sq_ref = sk_ref = None
-    iq, ik, needed = _here(tabs, heads, n_outer)
-    j = pl.program_id(2)
+    iq, ik, item = _here(items_ref, heads, steps)
 
-    @pl.when(j == 0)
+    @pl.when(_flag(item, flash_blocks.ITEM_FIRST))
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m[:] = jnp.full_like(m, _NEG_BIG)
         l[:] = jnp.zeros_like(l)
 
-    @pl.when(needed)
+    @pl.when(_flag(item, flash_blocks.ITEM_COMPUTE))
     def _block():
         s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k)
         m_new = jnp.maximum(m[:], jnp.max(s, axis=1, keepdims=True))
@@ -157,7 +177,7 @@ def _fwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_oute
         )
         m[:] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(_flag(item, flash_blocks.ITEM_LAST))
     def _finish():
         # a q block that no kv block served still writes finite rows
         denom = jnp.maximum(l[:], 1e-30)
@@ -165,15 +185,15 @@ def _fwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_oute
         lse_ref[0] = jnp.broadcast_to(m[:] + jnp.log(denom), (l.shape[0], _STAT_W))
 
 
-def _bwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_outer):
-    """The whole backward of one (kv block, q block) pair: kv-major (the kv
-    block is grid dim 1, the q blocks that need it are walked), so dk and dv
-    accumulate in block-sized scratch and leave when the walk ends, while dq
-    accumulates, float32, in the scratch ``dq_acc`` that holds the whole
-    ``[L_q, d]`` row of this (batch, head) across both inner grid dims. For
-    a fixed q block the contributions arrive in increasing kv block, the
-    order a q-major pass would sum them in."""
-    tabs, refs = refs[:2], refs[2:]
+def _bwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps):
+    """The whole backward of one (kv block, q block) pair: kv-major (the
+    work list's outer block is the kv block, the q blocks that need it
+    follow one another), so dk and dv accumulate in block-sized scratch and
+    leave with the kv block's last item, while dq accumulates, float32, in
+    the scratch ``dq_acc`` that holds the whole ``[L_q, d]`` row of this
+    (batch, head) across the list. For a fixed q block the contributions
+    arrive in increasing kv block, the order a q-major pass would sum them
+    in."""
     if segmented:
         (q_ref, k_ref, v_ref, sq_ref, sk_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
@@ -181,20 +201,19 @@ def _bwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_oute
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
         sq_ref = sk_ref = None
-    ik, iq, needed = _here(tabs, heads, n_outer)  # note: kv outer, q inner
-    j = pl.program_id(2)
-    last = j == pl.num_programs(2) - 1
+    ik, iq, item = _here(items_ref, heads, steps)  # note: kv outer, q inner
+    t = pl.program_id(1)
 
-    @pl.when((ik == 0) & (j == 0))
+    @pl.when(t == 0)
     def _init_row():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(j == 0)
+    @pl.when(_flag(item, flash_blocks.ITEM_FIRST))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(needed)
+    @pl.when(_flag(item, flash_blocks.ITEM_COMPUTE))
     def _block():
         s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k)
         p = jnp.exp(s - lse_ref[0][:, :1])  # [bq, bk]
@@ -221,32 +240,24 @@ def _bwd_kernel(*refs, scale, causal, segmented, block_q, block_k, heads, n_oute
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(last)
+    @pl.when(_flag(item, flash_blocks.ITEM_LAST))
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
-    @pl.when(last & (ik == pl.num_programs(1) - 1))
+    @pl.when(t == pl.num_programs(1) - 1)
     def _finish_row():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _ranges(needed, axis):
-    """First and last True along ``axis`` of ``needed`` ``[rows, n_q, n_k]``
-    as flat int32 tables; (0, -1) where there is none."""
-    n = needed.shape[axis]
-    some = needed.any(axis)
-    lo = jnp.where(some, jnp.argmax(needed, axis), 0)
-    hi = jnp.where(some, n - 1 - jnp.argmax(jnp.flip(needed, axis), axis), -1)
-    return lo.reshape(-1).astype(jnp.int32), hi.reshape(-1).astype(jnp.int32)
-
-
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
 def _block_map(seg, n_q, n_k, block_q, block_k, causal):
-    """The block map of ``seg`` (``int32 [rows, L]``, or None: one row of one
-    segment): ``(kv_range, q_range)`` — per q block the (first, last) kv
-    block it needs, per kv block the (first, last) q block that needs it;
-    flat int32 tables, entry ``row * n + block``.
+    """The work lists of ``seg`` (``int32 [rows, L]``, or None: one row of
+    one segment): ``(forward, backward)``, each ``(items, longest)``: a flat
+    int32 table of :func:`flash_blocks.work_list` items, :func:`_steps`
+    entries a row, q-major for the forward kernel and kv-major for the
+    backward, and the length of the batch's longest list, the kernel's
+    traced grid bound.
 
     Jitted on its own so that a model's layers, which all call it on the same
     shapes, trace and lower its few dozen integer operations once and not
@@ -257,24 +268,57 @@ def _block_map(seg, n_q, n_k, block_q, block_k, causal):
     else:
         bounds = flash_blocks.block_bounds(seg, block_q, block_k, xp=jnp)
     needed = flash_blocks.blocks_needed(bounds, block_q, block_k, causal, xp=jnp)
-    return _ranges(needed, 2), _ranges(needed, 1)
+    fwd_steps, bwd_steps = _steps(n_q, n_k, block_q, block_k, causal)
+    forward, fwd_lengths = flash_blocks.work_list(needed, fwd_steps, xp=jnp)
+    backward, bwd_lengths = flash_blocks.work_list(needed.swapaxes(1, 2), bwd_steps, xp=jnp)
+    return (forward.reshape(-1), fwd_lengths.max()), (backward.reshape(-1), bwd_lengths.max())
+
+
+#: what the work lists of a call (every batch row's) may take of SMEM, where
+#: scalar-prefetch operands live
+_SMEM_MOST = 960 * 2 ** 10
+
+
+def _steps(n_q, n_k, block_q, block_k, causal):
+    """``(forward, backward)`` lengths of the accumulating grid axis: the
+    longest work list the shape allows (:func:`flash_blocks.work_bound`: the
+    causal triangle, or the square), Python integers."""
+    dense = flash_blocks.dense_blocks(n_q, n_k, block_q, block_k, causal)
+    return flash_blocks.work_bound(dense), flash_blocks.work_bound(dense.T)
+
+
+def _work(seg, rows, n_q, n_k, block_q, block_k, causal, backward):
+    """``(steps, items, longest)`` of one kernel's call: the row stride of
+    its table of work lists (the shape's bound), the table, and the batch's
+    longest list. Refuses a call whose lists would not fit: the lists of all
+    batch rows ride in SMEM, and an item names a block in 14 bits."""
+    steps = _steps(n_q, n_k, block_q, block_k, causal)[backward]
+    if max(n_q, n_k) > flash_blocks.ITEM_BLOCKS_MOST or 4 * rows * steps > _SMEM_MOST:
+        raise ValueError(
+            "flash attention walks a list of the blocks it needs, kept in SMEM: {} rows x {} blocks ({} x {} "
+            "a row) take {:.0f} KiB of the {:.0f} it may (and an axis at most {} blocks); use larger blocks, "
+            "or shard a row this long over chips (parallel.ring_attention)".format(
+                rows, steps, n_q, n_k, 4 * rows * steps / 2 ** 10, _SMEM_MOST / 2 ** 10,
+                flash_blocks.ITEM_BLOCKS_MOST))
+    return (steps,) + _block_map(seg, n_q, n_k, block_q, block_k, causal)[backward]
 
 
 class _Specs:
-    """BlockSpecs of one kernel's operands. ``outer`` blocks follow grid dim
-    1; ``inner`` blocks follow :func:`_walk` over the outer block's range
-    (the kernel's two scalar-prefetch tables). Segment ids are per batch
-    row, not per head: ``ids=True`` indexes them by ``b // heads``."""
+    """BlockSpecs of one kernel's operands on the grid ``(batch·heads,
+    steps)``: ``outer`` blocks follow the outer block of the step's
+    work-list item, ``inner`` blocks its inner block (the kernel's
+    scalar-prefetch table). Segment ids are per batch row, not per head:
+    ``ids=True`` indexes them by ``b // heads``."""
 
-    def __init__(self, heads, n_outer):
-        self.heads, self.n_outer = heads, n_outer
+    def __init__(self, heads, steps):
+        self.heads, self.steps = heads, steps
 
     def _index(self, inner, ids, transposed):
-        heads, n_outer = self.heads, self.n_outer
+        heads, steps = self.heads, self.steps
 
-        def index_map(b, o, j, lo, hi):
-            row = _row(b, heads) if ids or inner else None
-            at = _walk(lo, hi, row * n_outer + o, j)[0] if inner else o
+        def index_map(b, t, items):
+            row = _row(b, heads)
+            at = (_inner if inner else _outer)(items[row * steps + t])
             first = row if ids else b
             return (first, 0, at) if transposed else (first, at, 0)
 
@@ -291,8 +335,8 @@ class _Specs:
     @staticmethod
     def whole_row(length, width):
         """All ``length`` rows of one (batch, head): the block stays where it
-        is across both inner grid dims and moves once per ``b``."""
-        return pl.BlockSpec((1, length, width), lambda b, o, j, lo, hi: (b, 0, 0))
+        is along the list and moves once per ``b``."""
+        return pl.BlockSpec((1, length, width), lambda b, t, items: (b, 0, 0))
 
 
 def _seg_inputs(seg):
@@ -348,31 +392,32 @@ def _bwd_vmem_limit(length, width, dtype):
 
 
 def _compiler_params(interpret, row_limit=None):
-    """The batch·heads grid dim runs in any order; the walked dim carries
-    the block accumulators, so it is 'arbitrary'. ``row_limit`` is the
-    backward's: its dq row is carried across the outer block dim too, under
-    this VMEM limit."""
+    """The batch·heads grid dim runs in any order; the list's dim carries
+    the block accumulators (and the backward's dq row), so it is
+    'arbitrary'. ``row_limit`` is the backward's VMEM limit."""
     if interpret:
         return None
-    outer = "parallel" if row_limit is None else "arbitrary"
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", outer, "arbitrary"), vmem_limit_bytes=row_limit,
-    )
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=row_limit)
 
 
-def _call(kernel, which, tabs, grid, in_specs, out_specs, out_shape, scratch_shapes,
+def _call(kernel, which, work, bh, in_specs, out_specs, out_shape, scratch_shapes,
           operands, segmented, interpret, row_limit=None):
+    """One kernel over the grid ``(bh, the batch's longest list)``. The
+    interpreter is given the shape's bound instead (a static grid; every row
+    then parks for the rest), and nothing else differs."""
+    steps, items, longest = work
+    grid = (bh, steps if interpret else longest)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(tabs), grid=grid, in_specs=in_specs,
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch_shapes,
         ),
         out_shape=out_shape,
         compiler_params=_compiler_params(interpret, row_limit),
         interpret=interpret,
         name=_kernel_name(which, segmented),
-    )(*tabs, *operands)
+    )(items, *operands)
 
 
 def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
@@ -380,19 +425,19 @@ def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
     d_v = v.shape[2]  # v and o may be narrower than q and k (latent attention: 192 / 128)
     block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
     segmented = seg is not None
-    kv_range, _ = _block_map(seg, n_q, n_k, block_q, block_k, causal)
+    work = _work(seg, bh // heads, n_q, n_k, block_q, block_k, causal, backward=False)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, segmented=segmented,
-        block_q=block_q, block_k=block_k, heads=heads, n_outer=n_q,
+        block_q=block_q, block_k=block_k, heads=heads, steps=work[0],
     )
-    at = _Specs(heads, n_q)
+    at = _Specs(heads, work[0])
     in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d_v, inner=True)]
     operands = [q, k, v]
     if segmented:
         in_specs += [at.rows(block_q, _STAT_W, ids=True), at.seg_k(block_k, inner=True)]
         operands += _seg_inputs(seg)
     o, lse = _call(
-        kernel, "fwd", kv_range, (bh, n_q, n_k), in_specs,
+        kernel, "fwd", work, bh, in_specs,
         out_specs=[at.rows(block_q, d_v), at.rows(block_q, _STAT_W)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, l_q, d_v), q.dtype),
@@ -414,14 +459,14 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
     block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
     segmented = seg is not None
     row_limit = _bwd_vmem_limit(l_q, d, q.dtype)
-    _, q_range = _block_map(seg, n_q, n_k, block_q, block_k, causal)
+    work = _work(seg, bh // heads, n_q, n_k, block_q, block_k, causal, backward=True)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[:, :, None], (bh, l_q, _STAT_W))
     kernel = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, segmented=segmented,
-        block_q=block_q, block_k=block_k, heads=heads, n_outer=n_k,
+        block_q=block_q, block_k=block_k, heads=heads, steps=work[0],
     )
-    at = _Specs(heads, n_k)  # kv outer, q walked
+    at = _Specs(heads, work[0])  # kv outer, q inner
     in_specs = [at.rows(block_q, d, inner=True), at.rows(block_k, d), at.rows(block_k, d_v)]
     operands = [q, k, v]
     if segmented:
@@ -433,7 +478,7 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
         at.rows(block_q, _STAT_W, inner=True),
     ]
     dq, dk, dv = _call(
-        kernel, "bwd_dkv", q_range, (bh, n_k, n_q), in_specs,
+        kernel, "bwd_dkv", work, bh, in_specs,
         out_specs=[at.whole_row(l_q, d), at.rows(block_k, d), at.rows(block_k, d_v)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, l_q, d), q.dtype),

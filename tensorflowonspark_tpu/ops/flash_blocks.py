@@ -13,13 +13,21 @@ overlapping intervals, so for any int32 ids a needed block is never dropped;
 for ids that do not decrease along the row (the text plane's: 1, 2, …, n,
 then 0 for padding) overlapping intervals do share an id, so nothing
 skippable is kept, and the needed kv blocks of a q block (and the needed q
-blocks of a kv block) are one unbroken range. The kernels walk from the
-first needed block to the last; for ids in no order a block between two
-needed ones is computed too, under the full masks, which is exact.
+blocks of a kv block) are one unbroken range. The kernels visit
+the needed blocks alone; for ids in no order a block whose interval overlaps
+and shares no id is computed too, under the full masks, which is exact.
 
 Padding is ordered *after* every real id (:func:`_order`): a row's padded
 tail then overlaps only itself, where ordering 0 first would make the last
 block need every block before it.
+
+**The work list.** A kernel's grid does not walk the square of blocks: its
+accumulating axis walks, per batch row, one flat list of the blocks the map
+needs (:func:`work_list`), outer block by outer block with the inner blocks
+ascending, one packed int32 an item. The axis is as long as the batch's
+longest list, at most what the shape allows (:func:`work_bound`: the causal
+triangle, or the square without ``causal``; the tables' row stride), and
+steps past a row's own list park on its last item.
 """
 
 import numpy as np
@@ -105,18 +113,80 @@ def needed_blocks(segment_ids, block_q, block_k, causal=True):
     return blocks_needed(bounds, block_q, block_k, causal)
 
 
+#: an item of a work list, one int32: the outer block from bit 17, the inner
+#: block in bits 3-16, then three flags
+ITEM_INNER_SHIFT, ITEM_OUTER_SHIFT = 3, 17
+ITEM_COMPUTE, ITEM_FIRST, ITEM_LAST = 1, 2, 4
+#: the most blocks along one axis that an item can name
+ITEM_BLOCKS_MOST = 1 << (ITEM_OUTER_SHIFT - ITEM_INNER_SHIFT)
+
+_INT32_MAX = np.int32(2 ** 31 - 1)
+
+
+def dense_blocks(n_q, n_k, block_q, block_k, causal=True):
+    """``bool [n_q, n_k]`` (numpy): every block a call of that shape can need,
+    whatever its ids: the causal triangle, or the square."""
+    return causal_blocks(n_q, n_k, block_q, block_k) if causal else np.ones((n_q, n_k), bool)
+
+
+def work_bound(dense):
+    """The longest :func:`work_list` a row can have when its needed blocks lie
+    inside ``dense`` (``bool [n_outer, n_inner]``): every block of ``dense``,
+    and one item for an outer block that ``dense`` leaves none (kv blocks
+    above every query when keys outnumber queries). A Python integer: it is
+    the length of the kernels' accumulating grid axis."""
+    return int(np.maximum(np.asarray(dense).sum(1), 1).sum())
+
+
+def work_list(needed, steps, xp=np):
+    """The kernels' work lists: ``(items int32 [rows, steps], lengths int32
+    [rows])`` from ``needed`` ``bool [rows, n_outer, n_inner]``.
+
+    A row's list holds its needed blocks in outer-major order, the inner
+    blocks ascending, and for an outer block that needs none one item
+    (inner block 0) that computes nothing, so that every outer block is
+    visited and written. An item is ``outer << 17 | inner << 3 | flags``:
+    :data:`ITEM_COMPUTE` (a needed block), :data:`ITEM_FIRST` and
+    :data:`ITEM_LAST` of its outer block (zero the block accumulators; write
+    them out). Entries past a row's length repeat its last item without
+    flags: a grid step there names the blocks already resident and does
+    nothing (a parked step). ``steps`` must be at least the longest list
+    (:func:`work_bound` of the shape is)."""
+    rows, n_outer, n_inner = needed.shape
+    inner = xp.arange(n_inner, dtype=xp.int32)[None, None, :]
+    outer = xp.arange(n_outer, dtype=xp.int32)[None, :, None]
+    emitted = needed | (~needed.any(2, keepdims=True) & (inner == 0))
+    nth = xp.cumsum(emitted.astype(xp.int32), axis=2)
+    flags = (needed * xp.int32(ITEM_COMPUTE) + (nth == 1) * xp.int32(ITEM_FIRST)
+             + (nth == nth[:, :, -1:]) * xp.int32(ITEM_LAST))
+    item = (outer << ITEM_OUTER_SHIFT) | (inner << ITEM_INNER_SHIFT) | flags
+    # an item's value orders it: sorting the emitted ones to the front is the compaction
+    items = xp.sort(xp.where(emitted, item, _INT32_MAX).reshape(rows, n_outer * n_inner), axis=1)[:, :steps]
+    if steps > items.shape[1]:
+        items = xp.concatenate([items, xp.full((rows, steps - items.shape[1]), _INT32_MAX, xp.int32)], axis=1)
+    lengths = emitted.sum((1, 2)).astype(xp.int32)
+    at = xp.arange(steps, dtype=xp.int32)[None, :]
+    parked = xp.take_along_axis(items, lengths[:, None] - 1, axis=1) & ~xp.int32(ITEM_COMPUTE | ITEM_FIRST | ITEM_LAST)
+    return xp.where(at < lengths[:, None], items, parked), lengths
+
+
 def attended_blocks(segment_ids):
-    """``(needed, dense)`` block counts of one packed batch as the segmented
+    """``(needed, dense, steps)`` counts of one packed batch as the segmented
     kernels see it: rows padded to :data:`GRANULE`, the block sizes the
-    kernels pick for that length, causal. ``dense`` is the causal triangle."""
+    kernels pick for that length, causal. ``needed`` blocks are computed;
+    ``dense`` is the causal triangle, a row's :func:`work_bound`; ``steps``
+    are the grid steps a kernel takes a head: every row walks as many as the
+    batch's longest :func:`work_list` has items, and a row with fewer parks
+    for the rest."""
     seg = np.asarray(segment_ids)
     if not seg.size:
-        return 0, 0
+        return 0, 0, 0
     pad = (-seg.shape[1]) % GRANULE
     if pad:
         seg = np.pad(seg, ((0, 0), (0, pad)))
     block_q = pick_block(seg.shape[1], SEGMENTED_BLOCK_Q)
     block_k = pick_block(seg.shape[1], SEGMENTED_BLOCK_K)
     needed = needed_blocks(seg, block_q, block_k)
-    dense = causal_blocks(needed.shape[1], needed.shape[2], block_q, block_k)
-    return int(needed.sum()), int(dense.sum()) * seg.shape[0]
+    dense = work_bound(causal_blocks(needed.shape[1], needed.shape[2], block_q, block_k))
+    _, lengths = work_list(needed, dense)
+    return int(needed.sum()), dense * seg.shape[0], int(lengths.max()) * seg.shape[0]

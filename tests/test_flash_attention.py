@@ -228,15 +228,9 @@ def test_skipped_blocks_are_not_read(kind):
     needed = flash_blocks.needed_blocks(row[None], _BLOCK, _BLOCK)[0]
     assert not needed.all() and needed.any(1).all()
 
-    def walked(needed, axis):
-        # a kernel walks from the first needed block to the last: for ids in
-        # no order that range may hold a block between two needed ones
-        ahead = np.maximum.accumulate(needed, axis)
-        behind = np.flip(np.maximum.accumulate(np.flip(needed, axis), axis), axis)
-        return ahead & behind
-
-    forward = walked(needed, 1)  # q-major: per q block a range of kv blocks
-    backward = walked(needed, 0)  # kv-major, dq as well: per kv block a range of q blocks
+    # the kernels' lists hold the needed blocks alone, q-major and kv-major
+    # (dq as well) the same pairs: for ids in no order not even a block
+    # between two needed ones is read
     q, k, v, do = _operands(row[None], heads=1, seed=3)
     seg = jnp.asarray(row[None])
     run = _flash_fn(True)
@@ -250,15 +244,12 @@ def test_skipped_blocks_are_not_read(kind):
 
     n = _L // _BLOCK
     for iq in range(n):
-        skipped = [ik for ik in range(n) if not forward[iq, ik]]
-        o_p = run(q, poisoned(k, skipped), poisoned(v, skipped), seg, do)[0]
+        skipped = [ik for ik in range(n) if not needed[iq, ik]]
+        o_p, dq_p, _, _ = run(q, poisoned(k, skipped), poisoned(v, skipped), seg, do)
         np.testing.assert_array_equal(np.asarray(o_p)[:, :, _block_rows(iq)], o[:, :, _block_rows(iq)])
-        # dq of this q block reads its own o and lse too: poison what neither pass walks
-        skipped = [ik for ik in range(n) if not (forward[iq, ik] or backward[iq, ik])]
-        dq_p = run(q, poisoned(k, skipped), poisoned(v, skipped), seg, do)[1]
         np.testing.assert_array_equal(np.asarray(dq_p)[:, :, _block_rows(iq)], dq[:, :, _block_rows(iq)])
     for ik in range(n):
-        skipped = [iq for iq in range(n) if not backward[iq, ik]]
+        skipped = [iq for iq in range(n) if not needed[iq, ik]]
         _, _, dk_p, dv_p = run(poisoned(q, skipped), k, v, seg, poisoned(do, skipped))
         np.testing.assert_array_equal(np.asarray(dk_p)[:, :, _block_rows(ik)], dk[:, :, _block_rows(ik)])
         np.testing.assert_array_equal(np.asarray(dv_p)[:, :, _block_rows(ik)], dv[:, :, _block_rows(ik)])
@@ -314,38 +305,171 @@ def test_padding_sorts_after_every_real_id():
     np.testing.assert_array_equal(needed, np.eye(4, dtype=bool))
 
 
+def _decoded(items):
+    """``(outer, inner, compute, first, last)`` arrays of work-list items."""
+    items = np.asarray(items)
+    inner = (items >> flash_blocks.ITEM_INNER_SHIFT) & (flash_blocks.ITEM_BLOCKS_MOST - 1)
+    flag = lambda bit: (items & bit) != 0  # noqa: E731
+    return (items >> flash_blocks.ITEM_OUTER_SHIFT, inner, flag(flash_blocks.ITEM_COMPUTE),
+            flag(flash_blocks.ITEM_FIRST), flag(flash_blocks.ITEM_LAST))
+
+
+def _check_work_list(needed, steps):
+    """One orientation of ``needed`` ``[rows, n_outer, n_inner]`` against the
+    list's contract, row by row, by brute force."""
+    items, lengths = flash_blocks.work_list(needed, steps)
+    assert items.dtype == np.int32 and items.shape == (len(needed), steps)
+    n_outer, n_inner = needed.shape[1:]
+    for row, row_items, length in zip(needed, items, lengths):
+        assert n_outer <= length <= steps
+        outer, inner, compute, first, last = (t[:length] for t in _decoded(row_items))
+        # outer-major, inner ascending, no item twice
+        assert (np.diff(outer * n_inner + inner) > 0).all()
+        # every needed block once, and nothing else computed
+        visited = np.zeros_like(row)
+        visited[outer[compute], inner[compute]] = True
+        np.testing.assert_array_equal(visited, row)
+        assert compute.sum() == row.sum()
+        # every outer block at least once; one that needs nothing has one item that computes nothing
+        np.testing.assert_array_equal(np.unique(outer), np.arange(n_outer))
+        idle = ~compute
+        np.testing.assert_array_equal(np.sort(outer[idle]), np.flatnonzero(~row.any(1)))
+        assert (first[idle] & last[idle]).all()
+        # first / last of an outer block are where the outer block changes
+        np.testing.assert_array_equal(first, np.r_[True, np.diff(outer) != 0])
+        np.testing.assert_array_equal(last, np.r_[np.diff(outer) != 0, True])
+        # past the list: the last item again, without flags (a parked step)
+        parked = row_items[length:]
+        assert (parked == (row_items[length - 1] & ~np.int32(7))).all()
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(64, 64), (64, 32), (32, 64), (16, 16)])
+def test_work_list_against_brute_force(blocks, causal):
+    """Both kernels' lists (q-major, kv-major) over packed rows, ids in no
+    order and maps with starved blocks: every needed block once, outer-major
+    with the inner blocks ascending, every outer block at least once, the
+    three bits, never longer than the shape's bound."""
+    rng = np.random.default_rng(sum(blocks) + 2 * causal)
+    rows = np.stack([_random_packing(rng, 512) for _ in range(12)] + [_ids([], 512), _ids([512], 512)]
+                    + [_arbitrary_ids(seed, 512) for seed in range(12)])
+    needed = flash_blocks.needed_blocks(rows, *blocks, causal=causal)
+    dense = flash_blocks.dense_blocks(*needed.shape[1:], *blocks, causal)
+    holes = needed & (rng.random(needed.shape) < 0.5)  # no ids give these: blocks and whole rows of the map starved
+    holes[0, 1], holes[1, :, 2] = False, False
+    for some in (needed, holes):
+        _check_work_list(some, flash_blocks.work_bound(dense))
+        _check_work_list(some.swapaxes(1, 2), flash_blocks.work_bound(dense.T))
+        _check_work_list(some, some.shape[1] * some.shape[2] + 5)  # a bound longer than the square
+
+
+_BOUNDS = [
+    # n_q, n_k, causal: forward steps, backward steps
+    ((8, 8, True), (36, 36)), ((16, 16, True), (136, 136)), ((32, 32, True), (528, 528)),
+    ((160, 160, True), (12880, 12880)), ((8, 8, False), (64, 64)), ((2, 4, False), (8, 8)),
+    ((2, 4, True), (3, 5)),  # kv blocks 2 and 3 lie above every query: one idle item each in the backward
+    ((4, 2, True), (7, 7)),
+]
+
+
+@pytest.mark.parametrize("shape,steps", _BOUNDS, ids=["{}x{}-{}".format(*s) for s, _ in _BOUNDS])
+def test_grid_axis_is_as_long_as_the_triangle(shape, steps):
+    """The accumulating grid axis: the blocks ``causal_blocks`` marks (and
+    one step for an outer block it leaves none), the square without
+    ``causal``: what ``flash_blocks_dense_total`` counts a row."""
+    n_q, n_k, causal = shape
+    assert fa._steps(n_q, n_k, 512, 512, causal) == steps
+    if causal and n_q == n_k:
+        assert steps[0] == int(flash_blocks.causal_blocks(n_q, n_k, 512, 512).sum()) == n_q * (n_q + 1) // 2
+
+
+@pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("blocks", [(64, 64), (64, 32)])
-def test_device_map_is_the_host_rule(blocks):
-    """The jitted step's map and the host's counters state one rule: equal
-    block for block, and for packed ids the walked ranges hold exactly the
-    needed blocks."""
+def test_device_map_is_the_host_rule(blocks, causal):
+    """The jitted step's map and the host's counters state one rule: the
+    needed blocks equal block for block, and the two work lists the kernels
+    walk equal the numpy rule's item for item."""
     rng = np.random.default_rng(9)
-    rows = np.stack([_random_packing(rng, 512) for _ in range(8)] + [_ids([], 512), _ids([512], 512)])
+    rows = np.stack([_random_packing(rng, 512) for _ in range(8)] + [_ids([], 512), _ids([512], 512)]
+                    + [_arbitrary_ids(3, 512)])
     n_q, n_k = 512 // blocks[0], 512 // blocks[1]
-    host = flash_blocks.needed_blocks(rows, *blocks)
+    host = flash_blocks.needed_blocks(rows, *blocks, causal=causal)
+    fwd_steps, bwd_steps = fa._steps(n_q, n_k, *blocks, causal)
 
     @jax.jit
     def device(seg):
         bounds = flash_blocks.block_bounds(seg, *blocks, xp=jnp)
-        return flash_blocks.blocks_needed(bounds, *blocks, xp=jnp), fa._block_map(seg, n_q, n_k, *blocks, True)
+        return flash_blocks.blocks_needed(bounds, *blocks, causal, xp=jnp), fa._block_map(seg, n_q, n_k, *blocks, causal)
 
-    needed, ((kv_lo, kv_hi), (q_lo, q_hi)) = device(jnp.asarray(rows))
+    needed, (forward, backward) = device(jnp.asarray(rows))
     np.testing.assert_array_equal(np.asarray(needed), host)
-    assert int((np.asarray(kv_hi) - np.asarray(kv_lo) + 1).sum()) == int(host.sum())
-    assert int((np.asarray(q_hi) - np.asarray(q_lo) + 1).sum()) == int(host.sum())
-    np.testing.assert_array_equal(np.asarray(kv_lo).reshape(len(rows), n_q), host.argmax(2))
-    np.testing.assert_array_equal(np.asarray(q_lo).reshape(len(rows), n_k), host.argmax(1))
+    for (got, longest), some, steps in ((forward, host, fwd_steps), (backward, host.swapaxes(1, 2), bwd_steps)):
+        items, lengths = flash_blocks.work_list(some, steps)
+        assert np.asarray(got).dtype == np.int32
+        np.testing.assert_array_equal(np.asarray(got).reshape(len(rows), steps), items)
+        assert int(longest) == lengths.max()  # the traced grid bound: the batch's longest list
+        # for packed ids the lists hold the needed blocks and nothing else
+        assert int(lengths.sum()) == int(host.sum())
+
+
+def test_unsegmented_map_is_the_triangle():
+    (forward, fwd_longest), (backward, bwd_longest) = fa._block_map(None, 4, 4, 64, 64, True)
+    outer, inner, compute, _, _ = _decoded(forward)
+    assert compute.all() and len(forward) == 10 == int(fwd_longest) == int(bwd_longest)
+    np.testing.assert_array_equal(np.stack([outer, inner], 1), [(q, k) for q in range(4) for k in range(q + 1)])
+    outer, inner, compute, _, _ = _decoded(backward)
+    np.testing.assert_array_equal(np.stack([outer, inner], 1), [(k, q) for k in range(4) for q in range(k, 4)])
+
+
+@pytest.mark.parametrize("surplus", [1, 7])
+@pytest.mark.parametrize("causal", [True, False])
+def test_surplus_grid_steps_park(monkeypatch, causal, surplus):
+    """The body is right under any grid axis no shorter than the list: with
+    ``surplus`` more steps than the shape's bound every row parks longer, and
+    no bit of o, dq, dk or dv changes."""
+    rows = np.stack([ROWS["long_document"], ROWS["short_documents"], ROWS["padded_tail"], ROWS["all_padding"],
+                     ROWS["single_document"], ROWS["arbitrary_order"]])
+    q, k, v, do = _operands(rows, seed=12, dtype=jnp.bfloat16)
+
+    def run():
+        o, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, segment_ids=jnp.asarray(rows), block_q=_BLOCK, block_k=_BLOCK, interpret=True),
+            q, k, v)
+        return (o,) + vjp(do)
+
+    fa._block_map.clear_cache()
+    exact = run()
+    bound = flash_blocks.work_bound
+    monkeypatch.setattr(flash_blocks, "work_bound", lambda dense: bound(dense) + surplus)
+    fa._block_map.clear_cache()
+    try:
+        assert fa._steps(4, 4, _BLOCK, _BLOCK, causal)[0] == (10 if causal else 16) + surplus
+        longer = run()
+    finally:
+        monkeypatch.undo()
+        fa._block_map.clear_cache()
+    for a, b, name in zip(exact, longer, ("o", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name)
 
 
 def test_attended_blocks_counts_what_the_kernels_run():
     rows = np.stack([_ids([4097], 4097), _ids([600, 3000, 400], 4097)])[:, :-1]  # the columns the LM attends
-    needed, dense = flash_blocks.attended_blocks(rows)
+    needed, dense, steps = flash_blocks.attended_blocks(rows)
     block_q, block_k = flash_blocks.SEGMENTED_BLOCK_Q, flash_blocks.SEGMENTED_BLOCK_K
     triangle = int(flash_blocks.causal_blocks(4096 // block_q, 4096 // block_k, block_q, block_k).sum())
     assert dense == 2 * triangle
     assert needed == triangle + int(flash_blocks.needed_blocks(rows[1:], block_q, block_k).sum()) < dense
+    # the one-document row's list is the triangle, and the other row walks as many steps
+    assert steps == 2 * triangle
+    # without it every row's list is shorter than the triangle: the grid is the longest of them
+    short = np.stack([_ids([600, 3000, 400], 4097), _ids([2000, 2000], 4097)])[:, :-1]
+    needed, dense, steps = flash_blocks.attended_blocks(short)
+    per_row = flash_blocks.needed_blocks(short, block_q, block_k).sum((1, 2))
+    assert needed == per_row.sum() < steps == 2 * per_row.max() < dense
     # a length off the granule is padded as the model pads it
-    assert flash_blocks.attended_blocks(np.ones((3, 100), np.int32)) == (3, 3)
+    assert flash_blocks.attended_blocks(np.ones((3, 100), np.int32)) == (3, 3, 3)
+    assert flash_blocks.attended_blocks(np.zeros((0, 128), np.int32)) == (0, 0, 0)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -427,8 +551,8 @@ def test_q_block_with_no_kv_block_writes_finite_rows(patch_rule, widths):
 
 @pytest.mark.parametrize("widths", [(32, 32), (192, 128)], ids=["32/32", "192/128"])
 def test_kv_block_that_no_q_block_needs_gets_zero_gradients(patch_rule, widths):
-    """The other way round: kv block 1 is needed by no q block, so its walk
-    is empty and its dk and dv are exactly 0; dq stays finite everywhere."""
+    """The other way round: kv block 1 is needed by no q block, so its one
+    item computes nothing and its dk and dv are exactly 0; dq stays finite everywhere."""
     patch_rule(_starved_of(2))
     o, dq, dk, dv = _starved_run(widths, seed=5)
     for t in (o, dq, dk, dv):
@@ -448,3 +572,20 @@ def test_a_row_too_long_for_the_accumulator_is_refused_by_name():
         jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
     fits = jax.ShapeDtypeStruct((1, 1, 81920, 64), jnp.bfloat16)
     assert jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), fits, fits, fits)[0].shape == fits.shape
+
+
+def test_lists_too_long_for_smem_are_refused_by_name():
+    """The work lists of all batch rows ride in SMEM (1 MiB on a v5e): a
+    call whose lists would not fit says so at trace time. The longest row
+    the backward's VMEM limit allows fits, 19 batch rows of it."""
+    loss = lambda q, k, v: flash_attention(q, k, v, causal=False).astype(jnp.float32).sum()  # noqa: E731
+    q = jax.ShapeDtypeStruct((1, 1, 262144, 64), jnp.bfloat16)  # 512 x 512 blocks of the square
+    with pytest.raises(ValueError, match="SMEM: 1 rows x 262144 blocks"):
+        jax.eval_shape(loss, q, q, q)
+    ids = jax.ShapeDtypeStruct((20, 81920), jnp.int32)
+    packed = lambda q, k, v, seg: flash_attention(q, k, v, causal=True, segment_ids=seg).astype(jnp.float32).sum()  # noqa: E731
+    q = jax.ShapeDtypeStruct((20, 1, 81920, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="20 rows x 12880 blocks"):
+        jax.eval_shape(packed, q, q, q, ids)
+    q, ids = jax.ShapeDtypeStruct((19, 1, 81920, 64), jnp.bfloat16), jax.ShapeDtypeStruct((19, 81920), jnp.int32)
+    assert jax.eval_shape(jax.grad(packed, argnums=(0, 1, 2)), q, q, q, ids)[0].shape == q.shape

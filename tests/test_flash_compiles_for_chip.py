@@ -1,10 +1,11 @@
 """The flash kernels at the benchmark's real widths, compiled here for a
 described v5e (no chip attached; nothing runs, so this says nothing about
 results or times). It guards what the Pallas interpreter cannot see: the
-scalar-prefetch block map, its index maps and the SMEM reads lowering through
-Mosaic, the blocks and the backward's whole-row dq accumulator fitting the
-chip's fast memory under the limit the call sets, and the two kernel names
-the trace reductions look for.
+scalar-prefetch work list, its index maps and the SMEM reads lowering through
+Mosaic, **the traced grid bound** (the batch's longest list: the interpreter
+is given the static bound instead), the blocks and the backward's whole-row
+dq accumulator fitting the chip's fast memory under the limit the call sets,
+and the two kernel names the trace reductions look for.
 
 The topology is described inside a fixture, never while a module is
 imported, and only in this file: one process at a time may hold the TPU
@@ -83,6 +84,15 @@ def _kernels(text):
     return sorted(trace_reduce.kernel_names(text).values())
 
 
+def _walks_a_list_of(text, items):
+    """Both kernels' custom calls take a traced scalar (the grid bound: the
+    batch's longest list) and then one table of ``items`` int32 (every batch
+    row's work list, the shape's bound a row), and no second table."""
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    opening = "operand_layout_constraints={{s32[], s32[{}]{{0}}, bf16[".format(items)
+    return len(calls) == 2 and all(opening in line for line in calls)
+
+
 @pytest.mark.parametrize("blocks", [
     {},  # the constants the program runs with
     {"block_q": 512, "block_k": 512}, {"block_q": 512, "block_k": 256}, {"block_q": 256, "block_k": 256},
@@ -91,6 +101,10 @@ def test_segmented_kernels_compile_at_the_cells_widths(one_chip, no_compile_cach
     text = _compiled_text(one_chip, True, **blocks)
     # exactly the two, one call each: a third kernel would be undercounted
     assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
+    # the grid walks a list as long as the causal triangle of the blocks, not their square
+    n_q, n_k = SEQ // blocks.get("block_q", 512), SEQ // blocks.get("block_k", 512)
+    triangle = int(flash_blocks.causal_blocks(n_q, n_k, SEQ // n_q, SEQ // n_k).sum())
+    assert triangle < n_q * n_k and _walks_a_list_of(text, ROWS * triangle)
     # per head the operands are the benchmark's; the ids ride per batch row
     assert "bf16[{},{},{}]".format(ROWS * HEADS, SEQ, HEAD_DIM) in text
     assert "s32[{},{},8]".format(ROWS * HEADS, SEQ) not in text
@@ -105,6 +119,7 @@ def test_segmented_kernels_compile_at_latent_attention_widths(one_chip, no_compi
     text = _compiled_text(one_chip, True, shape=(1, 32, 8192, 192), value_dim=128)
     assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
     assert "bf16[32,8192,192]" in text and "bf16[32,8192,128]" in text
+    assert _walks_a_list_of(text, 136)  # 16 x 17 / 2 of 256 blocks
 
 
 def test_segmented_kernels_compile_at_one_row_of_16k(one_chip, no_compile_cache):
@@ -112,11 +127,21 @@ def test_segmented_kernels_compile_at_one_row_of_16k(one_chip, no_compile_cache)
     text = _compiled_text(one_chip, True, shape=(1, 16, 16384, 64))
     assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
     assert "bf16[16,16384,64]" in text
+    assert _walks_a_list_of(text, 528)
 
 
 def test_unsegmented_kernels_compile_and_keep_their_names(one_chip, no_compile_cache):
     text = _compiled_text(one_chip, False)
     assert _kernels(text) == ["flash_bwd_dkv", "flash_fwd"]
+    assert _walks_a_list_of(text, 36)  # one list for all rows and heads: the triangle
+
+
+def test_longest_row_the_lists_allow_compiles(one_chip, no_compile_cache):
+    """One row of 81,920 positions, the longest the backward's VMEM limit
+    takes: its work list is 12,880 items, 50 KiB of SMEM."""
+    text = _compiled_text(one_chip, True, shape=(1, 1, 81920, 64))
+    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
+    assert _walks_a_list_of(text, 12880)
 
 
 def test_defaults_are_the_segmented_constants():

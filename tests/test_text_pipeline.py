@@ -182,15 +182,16 @@ class TestDeterminism:
 
     @pytest.mark.parametrize("pack_workers", [0, 2])
     def test_flash_block_counters_follow_the_emitted_rows(self, tmp_path, pack_workers):
-        """flash_blocks_needed_total / _dense_total grow by what the
-        segmented kernels compute, and would have computed densely, for the
-        columns the LM attends of every emitted batch."""
+        """flash_blocks_needed_total / _dense_total / flash_grid_steps_total
+        grow by what the segmented kernels compute, would have computed
+        densely, and walk (the batch's longest list a row), for the columns
+        the LM attends of every emitted batch."""
         from tensorflowonspark_tpu.ops import flash_blocks
 
         def counts():
             found = obs.snapshot()["counters"]
             return [found.get(name, {"value": 0})["value"]
-                    for name in ("flash_blocks_needed_total", "flash_blocks_dense_total")]
+                    for name in ("flash_blocks_needed_total", "flash_blocks_dense_total", "flash_grid_steps_total")]
 
         # documents of 100-400 tokens in rows of 4096+1, the benchmark's rows
         rng = np.random.default_rng(1)
@@ -202,7 +203,7 @@ class TestDeterminism:
         want = np.sum([flash_blocks.attended_blocks(b["segment_ids"][:, :-1]) for b in batches], axis=0)
         got = np.subtract(counts(), before)
         assert list(got) == list(want)
-        assert 0 < got[0] < got[1]
+        assert 0 < got[0] <= got[2] < got[1]
 
 
 class TestBadRecords:
