@@ -84,6 +84,7 @@ MODULES = [
     "tensorflowonspark_tpu.ops.flash_blocks",
     "tensorflowonspark_tpu.ops.fused_bn",
     "tensorflowonspark_tpu.ops.grouped_matmul",
+    "tensorflowonspark_tpu.ops.hyper_connection",
     "tensorflowonspark_tpu.backends",
     "tensorflowonspark_tpu.backends.local",
     "tosa",

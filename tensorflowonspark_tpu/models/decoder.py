@@ -21,7 +21,9 @@ residual     ``mhc``     :class:`HyperConnection`: ``hc_mult`` residual
                          streams, three learned maps per sub-layer, the
                          stream-mixing map projected onto doubly stochastic
                          matrices by Sinkhorn iterations (manifold-constrained
-                         hyper-connections, arXiv:2512.24880)
+                         hyper-connections, arXiv:2512.24880); the streams are
+                         carried as ``[B, L, hc_mult * d]`` and read twice a
+                         sub-layer each way (``ops/hyper_connection.py``)
 ===========  ==========  ======================================================
 
 The configuration is a dict with the published ``config.json``'s keys
@@ -51,6 +53,7 @@ sown into the ``counters`` collection (``moe_slots_routed``,
 """
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -60,6 +63,7 @@ from flax import linen as nn
 from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.models import register, transformer
 from tensorflowonspark_tpu.ops import grouped_matmul as gm
+from tensorflowonspark_tpu.ops import hyper_connection
 
 ATTENTION_KINDS = ("mla",)
 FEED_FORWARD_KINDS = ("swiglu", "moe")
@@ -362,6 +366,30 @@ def sinkhorn(logits, iters, eps):
     return jax.lax.scan(round_, jnp.exp(logits), None, length=iters)[0]
 
 
+def _per_shard(fn, mesh, sharded, whole=()):
+    """``fn(*sharded, *whole)`` of :mod:`~tensorflowonspark_tpu.ops.hyper_connection`,
+    its kernels interpreted anywhere but on a TPU (the flash kernels'
+    convention). A Mosaic call has no partitioning rule (see
+    :func:`transformer._flash`): on a mesh it runs per shard of the batch,
+    ``sharded`` and every result (all ``[B, L, width]``) split over the data
+    axes as :meth:`Decoder._constrain` leaves the streams, ``whole`` (the
+    maps' small parameters) on every chip. Tokens are independent of one
+    another, so nothing is exchanged inside; the parameters' gradients are
+    summed over the shards by ``shard_map``'s own transpose."""
+    run = functools.partial(fn, interpret=jax.default_backend() != "tpu")
+    if mesh is None or mesh.size == 1:
+        return run(*sharded, *whole)
+    from jax.sharding import PartitionSpec as P
+
+    from tensorflowonspark_tpu.parallel.collectives import shard_map
+
+    rows = P(transformer._batch_axes(mesh, sharded[0].shape[0]), None, None)
+    # check_vma off: pallas_call outputs carry no varying-axes type
+    return shard_map(
+        run, mesh=mesh, in_specs=(rows,) * len(sharded) + (P(),) * len(whole), out_specs=rows,
+        check_vma=False)(*sharded, *whole)
+
+
 class HyperConnection(nn.Module):
     """The residual path of one sub-layer ``F`` over ``n = hc_mult`` streams
     ``X`` ``[B, L, n, d]``: ``h = sum_i H_pre,i X_i``, ``y = F(h)``,
@@ -372,17 +400,29 @@ class HyperConnection(nn.Module):
     products take the streams as they are (``dtype``) and accumulate in
     float32; everything after them is float32.
 
-    The module computes the maps and ``h`` (``__call__`` returns ``(h,
-    maps)``); :meth:`merge` writes ``y`` back."""
+    The streams are 235 MB at the benchmark's shape and everything else here
+    is small, so the path is two passes over them forward and two backward
+    (``ops/hyper_connection.py``): ``__call__`` is the first (``1 / rms``,
+    ``z = x~ phi``, ``H_pre`` and ``h`` in one reading) and finishes the
+    other two maps from ``z``; it returns ``(h, maps)``, and :meth:`merge`,
+    the second pass, writes ``y`` back. The passes take ``vec(X)``, ``[B, L,
+    n * d]``, and that is how :class:`Decoder` carries the streams (on a chip
+    ``[B, L, n, d]`` is another tiling, and every reshape between the two a
+    copy of the streams); given ``[B, L, n, d]``, the path answers in kind.
+    ``maps`` carries the streams as the first pass handed them on: their two
+    cotangents then meet inside its backward pass and not in an addition of
+    XLA's."""
 
     cfg: DecoderConfig
+    mesh: object = None
 
     PARAM_RULES = ()  # the maps are small: whole on every chip
 
     @nn.compact
     def __call__(self, streams):
         cfg = self.cfg
-        n, d = streams.shape[-2:]
+        streams = streams.reshape(streams.shape[:2] + (-1,))
+        n, d = cfg.hc_mult, streams.shape[-1] // cfg.hc_mult
         with jax.named_scope("tos.mhc"):
             phi_init = nn.initializers.normal((n * d) ** -0.5)
             phi = jnp.concatenate([
@@ -396,42 +436,37 @@ class HyperConnection(nn.Module):
             b_post = self.param("b_post", nn.initializers.zeros, (n,), jnp.float32)
             b_res = self.param("b_res", lambda key, shape, dtype: 4.0 * jnp.eye(n, dtype=dtype), (n, n), jnp.float32)
 
-            inv_rms = jax.lax.rsqrt(jnp.mean(jnp.square(streams.astype(jnp.float32)), axis=(-2, -1)))  # [B, L]
-            z = jnp.einsum("blnd,ndk->blk", streams, phi.astype(streams.dtype),
-                           preferred_element_type=jnp.float32) * inv_rms[..., None]
-            h_pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + b_pre)
+            h, z, streams = _per_shard(hyper_connection.read, self.mesh, (streams,), (phi, alpha[0], b_pre))
             h_post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] + b_post)
             h_res = sinkhorn(
                 jnp.clip(alpha[2] * z[..., 2 * n:].reshape(z.shape[:-1] + (n, n)) + b_res,
                          cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
                 cfg.hc_sinkhorn_iters, cfg.hc_eps)
-            h = jnp.sum(h_pre[..., None] * streams.astype(jnp.float32), axis=-2).astype(streams.dtype)
-        return h, (h_res, h_post)
+            maps = jnp.concatenate([h_res.reshape(z.shape[:-1] + (n * n,)), h_post], axis=-1)
+        return h, (streams, maps)
 
     @staticmethod
-    def merge(streams, maps, y):
-        h_res, h_post = maps
+    def merge(streams, maps, y, mesh=None):
+        shape, (streams, maps) = streams.shape, maps
         with jax.named_scope("tos.mhc"):
-            # written out stream by stream: n x n is far too small a matrix
-            # for the MXU, and as multiply-adds XLA fuses it into one pass
-            wide = streams.astype(jnp.float32)
-            mixed = sum(h_res[..., :, j, None] * wide[..., None, j, :] for j in range(wide.shape[-2]))
-            return (mixed + h_post[..., None] * y.astype(jnp.float32)[..., None, :]).astype(streams.dtype)
+            return _per_shard(hyper_connection.merge, mesh, (streams, y, maps)).reshape(shape)
 
 
 class AddResidual(nn.Module):
-    """``x + F(x)`` on the one stream ``[B, L, 1, d]``."""
+    """``x + F(x)`` on the one stream, ``[B, L, d]`` as :class:`Decoder`
+    carries it or ``[B, L, 1, d]``."""
 
     cfg: DecoderConfig
+    mesh: object = None
 
     PARAM_RULES = ()
 
     def __call__(self, streams):
-        return streams[..., 0, :], None
+        return streams.reshape(streams.shape[:2] + (-1,)), None
 
     @staticmethod
-    def merge(streams, maps, y):
-        return streams + y[..., None, :]
+    def merge(streams, maps, y, mesh=None):
+        return streams + y.reshape(streams.shape)
 
 
 _RESIDUALS = {"add": AddResidual, "mhc": HyperConnection}
@@ -452,18 +487,18 @@ class DecoderLayer(nn.Module):
         _attention, feed_forward, residual = self.kinds
         path = _RESIDUALS[residual]
 
-        h, maps = path(cfg, name="res_attn")(streams)
+        h, maps = path(cfg, self.mesh, name="res_attn")(streams)
         y = LatentAttention(cfg, self.mesh, name="attn")(_norm(cfg, "ln1")(h), positions, segment_ids)
-        streams = path.merge(streams, maps, y)
+        streams = path.merge(streams, maps, y, self.mesh)
 
-        h, maps = path(cfg, name="res_mlp")(streams)
+        h, maps = path(cfg, self.mesh, name="res_mlp")(streams)
         h, counts = _norm(cfg, "ln2")(h), {}
         if feed_forward == "moe":
             y, counts = RoutedExperts(cfg, name="moe")(h)
         else:
             with jax.named_scope("tos.dense_mlp"):
                 y = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
-        return path.merge(streams, maps, y), counts
+        return path.merge(streams, maps, y, self.mesh), counts
 
 
 class Decoder(nn.Module):
@@ -485,7 +520,7 @@ class Decoder(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.compute_dtype, name="embed")(tokens)
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None, :], tokens.shape)
-        streams = self._constrain(jnp.broadcast_to(x[:, :, None, :], x.shape[:2] + (cfg.hc_mult, x.shape[-1])))
+        streams = self._constrain(jnp.tile(x, (1, 1, cfg.hc_mult)))  # vec(X): the embedding copied to every stream
         layer = nn.remat(DecoderLayer, static_argnums=()) if cfg.remat else DecoderLayer
         counted = []
         for i, kinds in enumerate(cfg.plan):
@@ -508,7 +543,8 @@ class Decoder(nn.Module):
             self.sow("counters", "moe_slots_held", sum(c["slots_held"] for c in counted))
             self.sow("gauges", "moe_expert_load_max_over_mean",
                      sum(c["load_max_over_mean"] for c in counted) / len(counted))
-        x = _norm(cfg, "ln_f")(jnp.sum(streams.astype(jnp.float32), axis=-2).astype(cfg.compute_dtype))
+        summed = sum(jnp.split(streams.astype(jnp.float32), cfg.hc_mult, axis=-1))
+        x = _norm(cfg, "ln_f")(summed.astype(cfg.compute_dtype))
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.compute_dtype, name="lm_head")(x)
         return logits.astype(jnp.float32)
 

@@ -1,5 +1,5 @@
-"""The flash kernels at the benchmark's real widths, compiled here for a
-described v5e (no chip attached; nothing runs, so this says nothing about
+"""The flash kernels (and, last, the hyper-connections' four) at the
+benchmark's real widths, compiled here for a described v5e (no chip attached; nothing runs, so this says nothing about
 results or times). It guards what the Pallas interpreter cannot see: the
 scalar-prefetch work list, its index maps and the SMEM reads lowering through
 Mosaic, **the traced grid bound** (the batch's longest list: the interpreter
@@ -142,6 +142,32 @@ def test_longest_row_the_lists_allow_compiles(one_chip, no_compile_cache):
     text = _compiled_text(one_chip, True, shape=(1, 1, 81920, 64))
     assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
     assert _walks_a_list_of(text, 12880)
+
+
+def test_hyper_connection_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache):
+    """``xing4-a4b.packed8k``'s residual path (kept in this file: one process
+    may hold the TPU library): one row of 8192 tokens, four streams of 3584
+    in bfloat16, forward and backward of both passes. Tiles of 256 whole
+    rows (7 MiB a block of the streams, up to three of them double-buffered
+    and a float32 scratch of the same rows in the maps' backward) have to fit
+    the VMEM limit the calls set, the chunk loops' dynamic lane slices have
+    to lower, and the four kernels keep the names the trace shows."""
+    from tensorflowonspark_tpu.ops import hyper_connection
+
+    tokens, n, d = 8192, 4, 3584
+    shape = lambda width, dtype=jnp.bfloat16: jax.ShapeDtypeStruct((1, tokens, width), dtype, sharding=one_chip)  # noqa: E731
+    whole = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)  # noqa: E731
+
+    def loss(streams, y, maps, phi, alpha_pre, b_pre):
+        h, z, streams = hyper_connection.read(streams, phi, alpha_pre, b_pre)
+        out = hyper_connection.merge(streams, y + h, maps + z[..., :n * n + n])  # every input and result differentiated
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        shape(n * d), shape(d), shape(n * n + n, jnp.float32), whole(n, d, 2 * n + n * n), whole(), whole(n)
+    ).compile().as_text()
+    assert _kernels(text) == ["mhc_merge", "mhc_merge_bwd", "mhc_read", "mhc_read_bwd"]
+    assert "bf16[{},{}]".format(tokens, n * d) in text
 
 
 def test_defaults_are_the_segmented_constants():
