@@ -39,6 +39,12 @@ router's scores, the hyper-connection maps, the Sinkhorn iterations and the
 softmax statistics are float32. The model plugs into
 ``transformer.make_init_fn`` / ``make_loss_fn`` and ``SyncDataParallel`` as
 the dense LM does (``models.get_model("decoder", **config)``).
+``remat=True`` recomputes each layer in the backward pass; a layer keeps its
+input (the streams, 2 · hc_mult · hidden_size bytes a token), the
+attention's output (2 · heads · v_head_dim bytes a token) and one float32 a
+position and head, so the flash forward kernel runs once a layer
+(:data:`~tensorflowonspark_tpu.ops.flash_attention.REMAT_POLICY`); the
+hyper-connections' reading is computed again.
 
 Device scopes (``jax.named_scope``, in every operation's ``op_name``):
 ``tos.mla``, ``tos.moe_route`` (router, top-k, sort, gather, combine),
@@ -64,6 +70,7 @@ from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.models import register, transformer
 from tensorflowonspark_tpu.ops import grouped_matmul as gm
 from tensorflowonspark_tpu.ops import hyper_connection
+from tensorflowonspark_tpu.ops.flash_attention import REMAT_POLICY
 
 ATTENTION_KINDS = ("mla",)
 FEED_FORWARD_KINDS = ("swiglu", "moe")
@@ -117,6 +124,8 @@ class DecoderConfig:
     #: ((attention, feed-forward, residual), …), one entry per layer
     layer_plan: tuple = None
     dtype: str = "float32"  # compute dtype; params stay float32
+    #: recompute each layer in the backward pass: a layer keeps its input (the
+    #: streams), the attention's output and one float32 a position and head
     remat: bool = False
     attention: str = "auto"  # transformer._dispatch_attention's choices
 
@@ -521,7 +530,7 @@ class Decoder(nn.Module):
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None, :], tokens.shape)
         streams = self._constrain(jnp.tile(x, (1, 1, cfg.hc_mult)))  # vec(X): the embedding copied to every stream
-        layer = nn.remat(DecoderLayer, static_argnums=()) if cfg.remat else DecoderLayer
+        layer = nn.remat(DecoderLayer, static_argnums=(), policy=REMAT_POLICY) if cfg.remat else DecoderLayer
         counted = []
         for i, kinds in enumerate(cfg.plan):
             streams, counts = layer(cfg, kinds, self.mesh, name="layer_{}".format(i))(

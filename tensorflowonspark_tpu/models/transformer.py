@@ -12,6 +12,16 @@ parallelism (``tp``: attention heads / MLP hidden sharded) with FSDP
 (``fsdp``: remaining large dims), and the model inserts activation sharding
 constraints so XLA keeps activations distributed across dp/sp/tp instead of
 gathering them.
+
+``remat=True`` recomputes each block in the backward pass (``nn.remat``). A
+block then keeps its input (2 · d_model bytes a token in bfloat16) and, on the
+flash path, what the attention kernel hands its backward
+(:data:`~tensorflowonspark_tpu.ops.flash_attention.REMAT_POLICY`): the
+attention's output with its heads merged, another 2 · d_model bytes a token,
+and one float32 a position and head. The forward kernel therefore runs once a
+layer, not twice; a job that fitted the chip by less than that fails at compile
+time with XLA's out-of-memory message. The ``plain`` and ``ring`` paths keep
+the block's input alone.
 """
 
 import dataclasses
@@ -24,6 +34,8 @@ import optax
 from flax import linen as nn
 
 from tensorflowonspark_tpu.models import register
+from tensorflowonspark_tpu.ops import flash_blocks
+from tensorflowonspark_tpu.ops.flash_attention import REMAT_POLICY, flash_attention
 from tensorflowonspark_tpu.parallel.ring_attention import (
     plain_attention,
     ring_attention_sharded,
@@ -39,7 +51,10 @@ class TransformerConfig:
     d_ff: int = 2048
     max_seq_len: int = 2048
     dtype: str = "float32"  # compute dtype; params stay float32
-    remat: bool = False  # jax.checkpoint each block: FLOPs for HBM
+    #: recompute each block in the backward pass, FLOPs for HBM: a block keeps
+    #: its input and, on the flash path, the attention's output and one float32
+    #: a position and head (the module's text has the bytes)
+    remat: bool = False
     #: "auto" — ring over sp when the mesh has it, else the pallas flash
     #: kernel on TPU, else plain XLA attention; or force "flash" (TPU only),
     #: "flash_interpret" (the kernel in the Pallas interpreter), "plain", "ring"
@@ -114,11 +129,8 @@ def _flash(q, k, v, segment_ids, mesh, interpret, scale=None):
     On a multi-device mesh the call therefore goes through ``shard_map``:
     batch over the data axes, heads over ``tp`` — attention is independent
     across both, so no collective is needed inside."""
-    from tensorflowonspark_tpu.ops.flash_attention import flash_attention
-    from tensorflowonspark_tpu.ops.flash_blocks import GRANULE
-
     seq = q.shape[2]
-    pad = (-seq) % GRANULE
+    pad = (-seq) % flash_blocks.GRANULE
     if pad:
         # causal masking means queries < seq never attend to the zero
         # padding appended after them, so pad-run-slice is exact; with
@@ -347,7 +359,7 @@ class Transformer(nn.Module):
             )
         block = Block
         if cfg.remat:
-            block = nn.remat(Block, static_argnums=())
+            block = nn.remat(Block, static_argnums=(), policy=REMAT_POLICY)
         for i in range(cfg.n_layers):
             x = block(cfg, self.mesh, name="layer_{}".format(i))(
                 x, positions, segment_ids

@@ -59,6 +59,22 @@ A call whose lists would take more than 960 KiB, or with more than 16,384
 blocks along an axis, is refused at trace time by a ``ValueError`` that
 names the limit (:func:`_work`).
 
+**What a recomputed layer keeps.** The call hands its backward its operands,
+its output ``o`` and the log-sum-exp, one float32 a position and head (the
+kernels read and write the statistic ``_STAT_W`` lanes wide, which pad to
+128 in HBM: it is narrowed where it leaves the forward and widened again where
+the backward, as ``delta``, is fed). Both are passed through
+``jax.ad_checkpoint.checkpoint_name`` (:data:`KEPT_O`, :data:`KEPT_LSE`) and
+are the call's only results, so a model that recomputes its layers under
+:data:`REMAT_POLICY` rebuilds q, k and v in the recomputed pass and does not
+run the forward kernel a second time; without such a policy the names are
+identities. ``o`` is named with its heads merged, ``[batch, L, heads·d_v]``,
+as a model's output projection reads it: 2 · heads · d_v bytes a token in
+bfloat16 (the kernel's own ``[batch·heads, L, d_v]`` pads 64 lanes to 128,
+twice the bytes, and read 1.3 ms a layer slower on the chip, ``PERF.md`` §6
+PR 32). The way back into the kernel's layout folds against the model's own
+merge in the forward pass and is one transposition in the recomputed one.
+
 This is the single-device analogue of
 :mod:`tensorflowonspark_tpu.parallel.ring_attention` (same math, blocks
 streamed from local HBM instead of rotated over ICI). ``interpret=True`` runs
@@ -70,6 +86,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -77,6 +94,17 @@ from tensorflowonspark_tpu.ops import flash_blocks
 from tensorflowonspark_tpu.ops.flash_blocks import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q
 
 _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+#: the names (``jax.ad_checkpoint.checkpoint_name``) of what a call hands its
+#: backward besides its operands: the output and the log-sum-exp, one float32
+#: a position and head. They are identities until a policy asks for them
+KEPT_O = "tos.flash_o"
+KEPT_LSE = "tos.flash_lse"
+
+#: the policy of a model that recomputes its layers (``nn.remat``): a layer
+#: keeps its input, as always, and these two, so the recomputed pass rebuilds
+#: q, k and v for the backward kernel and does not run the forward kernel again
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(KEPT_O, KEPT_LSE)
 
 #: row-statistics (lse/delta) are stored [BH, L, _STAT_W]: TPU block shapes
 #: need a tileable trailing dim, and a trailing dim equal to the full array
@@ -496,19 +524,39 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_attention_bhld(q, k, v, seg, scale, causal, block_q, block_k, interpret):
+def _heads_last(o, heads):
+    """``[batch·heads, L, d_v]`` → ``[batch, L, heads·d_v]``, the layout in
+    which a model merges its heads."""
+    batch, length, d_v = o.shape[0] // heads, o.shape[1], o.shape[2]
+    return o.reshape(batch, heads, length, d_v).transpose(0, 2, 1, 3).reshape(batch, length, heads * d_v)
+
+
+def _heads_first(o, heads):
+    """:func:`_heads_last`'s inverse."""
+    batch, length, d_v = o.shape[0], o.shape[1], o.shape[2] // heads
+    return o.reshape(batch, length, heads, d_v).transpose(0, 2, 1, 3).reshape(batch * heads, length, d_v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_attention_bhld(q, k, v, seg, heads, scale, causal, block_q, block_k, interpret):
     o, _ = _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret)
     return o
 
 
-def _flash_attention_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
+def _flash_attention_fwd(q, k, v, seg, heads, scale, causal, block_q, block_k, interpret):
     o, lse = _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret)
+    # the named values are the call's only results, out and residuals both, so
+    # a recomputed pass that was told to keep them (REMAT_POLICY) has no use
+    # for the call. o with its heads merged: d_v 64 pads to 128 lanes in the
+    # kernel's layout; lse one float32 a position: _STAT_W lanes pad to 128
+    o = _heads_first(checkpoint_name(_heads_last(o, heads), KEPT_O), heads)
+    lse = checkpoint_name(lse[:, :, 0], KEPT_LSE)
     return o, (q, k, v, seg, o, lse)
 
 
-def _flash_attention_bwd(scale, causal, block_q, block_k, interpret, res, do):
+def _flash_attention_bwd(heads, scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, seg, o, lse = res
+    lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (_STAT_W,))
     dq, dk, dv = _flash_bwd(
         q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interpret
     )
@@ -554,7 +602,7 @@ def flash_attention(
         block_k = flash_blocks.SEGMENTED_BLOCK_K if segmented else DEFAULT_BLOCK_K
     seg = segment_ids.astype(jnp.int32) if segmented else None
     o = _flash_attention_bhld(
-        merge(q), merge(k), merge(v), seg, float(scale), bool(causal),
+        merge(q), merge(k), merge(v), seg, h, float(scale), bool(causal),
         int(block_q), int(block_k), bool(interpret),
     )
     return o.reshape(b, h, l_q, v.shape[3])

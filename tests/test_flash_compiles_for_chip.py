@@ -13,6 +13,7 @@ library (the ``on-chip-measurement`` guide, section 2).
 """
 
 import os
+import re
 import sys
 
 import pytest
@@ -25,6 +26,7 @@ from tensorflowonspark_tpu.ops.flash_attention import flash_attention
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks import trace_reduce  # noqa: E402  (the reduction that has to find the kernels)
+from benchmarks.layer_metrics import _program  # noqa: E402  (and the reader that books them to a phase)
 
 ROWS, HEADS, SEQ, HEAD_DIM = 4, 16, 4096, 64  # lm1024.packed4k, one chip
 
@@ -70,9 +72,10 @@ def _compiled_text(one_chip, segmented, shape=(ROWS, HEADS, SEQ, HEAD_DIM), valu
         o = flash_attention(q, k, v, causal=True, segment_ids=seg, **sizes)
         return (o.astype(jnp.float32) ** 2).sum()
 
-    # recomputed in the backward pass, as the cell's blocks are (remat: true):
-    # under plain jax.grad the call's last scope reads jvp(flash_fwd_seg),
-    # the parent's kernels as well, and the reduction looks for the bare name
+    # the reduction looks for the bare kernel name as the call's last scope.
+    # Differentiated bare, the custom_vjp is the outermost scope and its call
+    # reads jvp(flash_fwd_seg); jax.checkpoint, or any module round the call
+    # (recomputed or not: the models' tests below), takes the transform's name
     grad = jax.jit(jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2)))
     lowered = grad.lower(qkv, qkv, values, ids) if segmented else grad.lower(qkv, qkv, values)
     return lowered.compile().as_text()
@@ -142,6 +145,91 @@ def test_longest_row_the_lists_allow_compiles(one_chip, no_compile_cache):
     text = _compiled_text(one_chip, True, shape=(1, 1, 81920, 64))
     assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
     assert _walks_a_list_of(text, 12880)
+
+
+def _model_step(one_chip, model, rows, seq):
+    """``(compiled loss-and-gradient of ``model`` on packed rows, its compiler's text)``,
+    under the scope the train step gives it."""
+    from tensorflowonspark_tpu.models import transformer
+
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)  # noqa: E731
+    ids = jax.ShapeDtypeStruct((rows, seq + 1), jnp.int32, sharding=one_chip)
+    batch = {"tokens": ids, "segment_ids": ids, "positions": ids}
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"]))
+    loss_fn = transformer.make_loss_fn(model)
+
+    def step(params, batch):
+        with jax.named_scope("tos.loss_and_grad"):
+            return jax.value_and_grad(lambda p: loss_fn(p, batch)[0])(params)
+
+    compiled = jax.jit(step).lower(params, batch).compile()
+    return compiled, compiled.as_text()
+
+
+def _kept_a_layer(one_chip, monkeypatch, build, layers, rows, seq):
+    """What the recomputation's policy costs a layer, in bytes of the step's
+    temporaries, and the text of the program with the policy."""
+    from tensorflowonspark_tpu.models import decoder, transformer
+
+    # the models refuse attention="flash" off the chip; this compiles for one
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, text = _model_step(one_chip, build(), rows, seq)
+    for module in (transformer, decoder):
+        monkeypatch.setattr(module, "REMAT_POLICY", None)
+    plain, plain_text = _model_step(one_chip, build(), rows, seq)
+    assert _kernels(plain_text).count("flash_fwd_seg") == 2 * layers
+    grown = compiled.memory_analysis().temp_size_in_bytes - plain.memory_analysis().temp_size_in_bytes
+    return grown / layers, text
+
+
+def _forward_calls_are_bare_and_not_recomputed(text, layers):
+    assert _kernels(text) == ["flash_bwd_dkv_seg"] * layers + ["flash_fwd_seg"] * layers
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    forward = [name for name in names if "flash_fwd_seg" in name]
+    assert len(forward) == layers and all(name.endswith("/flash_fwd_seg/pallas_call") for name in forward)
+    assert not any("rematted_computation" in name for name in names)
+    # the phases the benchmark's readers book them to
+    assert [_program.phase_of(name) for name in forward] == ["fwd"] * layers
+    assert [_program.phase_of(name) for name in names if name not in forward] == ["bwd"] * layers
+
+
+def test_recomputed_transformer_runs_the_forward_kernel_once_a_layer(one_chip, no_compile_cache, monkeypatch):
+    """``lm1024.packed4k``'s blocks, two of them: the recomputed pass keeps
+    ``o`` and the log-sum-exp, so each layer's forward kernel is called once,
+    under the bare name the trace reductions look for and in the forward
+    phase. ``o`` is kept with its heads merged, ``[rows, L, 1024]``: 33.5 MB a
+    layer and 1 MB of log-sum-exp (the kernel's ``[batch·heads, L, 64]`` pads
+    its 64 lanes to 128, 67 MB)."""
+    from tensorflowonspark_tpu.models import transformer
+
+    layers = 2
+    build = lambda: transformer.create_model(  # noqa: E731
+        vocab_size=50304, d_model=1024, n_layers=layers, n_heads=HEADS, d_ff=4096, max_seq_len=SEQ,
+        dtype="bfloat16", remat=True, attention="flash")
+    kept, text = _kept_a_layer(one_chip, monkeypatch, build, layers, ROWS, SEQ)
+    _forward_calls_are_bare_and_not_recomputed(text, layers)
+    assert 0 < kept < 40e6
+
+
+def test_recomputed_decoder_runs_the_forward_kernel_once_a_layer(one_chip, no_compile_cache, monkeypatch):
+    """``xing4-a4b.packed8k``'s latent attention (192/128, one row of 8192) in
+    two layers of ``mla`` + ``swiglu`` on one stream: values of 128 pad nothing,
+    so a layer keeps ``o``'s 67 MB and 1 MB of log-sum-exp."""
+    import json
+
+    from benchmarks.families import moe_lm
+    from tensorflowonspark_tpu.models import get_model
+
+    layers = 2
+    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", "xing4-a4b.json")) as f:
+        cfg = moe_lm.model_config(json.load(f), remat=True)
+    cfg.update(num_hidden_layers=layers, first_k_dense_replace=layers, hc_mult=1, attention="flash")
+    kept, text = _kept_a_layer(one_chip, monkeypatch, lambda: get_model("decoder", **cfg), layers, 1, 8192)
+    _forward_calls_are_bare_and_not_recomputed(text, layers)
+    assert "bf16[32,8192,192]" in text and "bf16[32,8192,128]" in text
+    assert 0 < kept < 75e6
 
 
 def test_hyper_connection_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache):
