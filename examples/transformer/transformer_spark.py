@@ -18,7 +18,12 @@ TPU-native capabilities the framework adds on top of reference parity:
   (``models/decoder.py``: latent attention, routed + shared experts, of
   which a chip may hold a share, hyper-connected residual streams); its
   file holds the published ``config.json``'s keys, and
-  ``examples/transformer/decoder_toy.json`` is one at toy widths.
+  ``examples/transformer/decoder_toy.json`` is one at toy widths. A file
+  whose ``objective`` is ``block_diffusion``
+  (``examples/transformer/sdar_toy.json``: grouped-query attention, softmax
+  top-k experts) trains by diffusion over blocks: the text plane noises
+  every packed row and the model reads the clean copy beside the noised one
+  under the block-diffusion mask.
 
 Data is real: TFRecord text shards stream through the sequence-packing
 :class:`~tensorflowonspark_tpu.data.TextPipeline` (per-worker file shards,
@@ -34,6 +39,10 @@ Usage (single host):
     # the plan-built decoder at toy widths:
     python examples/transformer/transformer_spark.py --model decoder \
         --model_config examples/transformer/decoder_toy.json --seq_len 128 \
+        --tokenizer word --platform cpu
+    # block diffusion (the objective is the configuration's):
+    python examples/transformer/transformer_spark.py --model decoder \
+        --model_config examples/transformer/sdar_toy.json --seq_len 128 \
         --tokenizer word --platform cpu
 """
 
@@ -160,9 +169,11 @@ def main_fun(args, ctx, observer=None):
                 ctx.executor_id, len(all_files), args.data_dir, ctx.num_workers
             )
         )
+    # a block-diffusion model's mask id is noise, not a token: the tokenizer draws from the ids below it
+    diffusion = getattr(model.cfg, "objective", "next_token") == "block_diffusion"
     tokenizer = Tokenizer(
         kind=args.tokenizer,
-        vocab_size=args.vocab_size if args.tokenizer == "word" else None,
+        vocab_size=(model.cfg.mask_id if diffusion else args.vocab_size) if args.tokenizer == "word" else None,
     )
     if tokenizer.vocab_size > args.vocab_size:
         raise ValueError(
@@ -171,9 +182,11 @@ def main_fun(args, ctx, observer=None):
             )
         )
     pipe = TextPipeline(
-        files, tokenizer, seq_len=args.seq_len + 1, batch_size=args.batch_size,
+        # next-token rows carry one more column than the model reads (the shift); block diffusion has no shift
+        files, tokenizer, seq_len=args.seq_len + (0 if diffusion else 1), batch_size=args.batch_size,
         seed=ctx.executor_id, epochs=None, max_bad_records=args.max_bad_records,
         pack_workers=args.pack_workers, slab_cache_dir=args.slab_cache_dir,
+        block_diffusion={"block_length": model.cfg.block_length, "mask_id": model.cfg.mask_id} if diffusion else None,
     )
     stream = iter(pipe)
 

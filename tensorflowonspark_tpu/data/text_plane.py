@@ -39,6 +39,24 @@ record crc32 under the tokenizer-config fingerprint (kind, vocab, field,
 effective token count, and epoch >= 2 (or a warm relaunch) serves token
 ids from a memory map instead of re-tokenizing.
 
+**Noising for diffusion over blocks** (``block_diffusion={"block_length",
+"mask_id", "t_min"}``). A further producer stage, after the pack: a
+document's positions fall in blocks of ``block_length``, counted from its
+start; every block of a real document draws a rate ``t ~ U(t_min, 1)`` and
+each of its tokens becomes ``mask_id`` with probability ``t``. The batch then
+also carries ``noised_tokens`` (``int32 [B, L]``) and ``loss_weights``
+(``float32 [B, L]``: ``1 / t`` at a masked position, else 0), which
+``models.transformer.make_block_diffusion_loss_fn`` consumes. The draws come
+from the pipeline's ``seed`` and the batch's index in the stream, in the
+producer thread, so the noise is as reproducible as the packing. Documents
+then start on multiples of ``block_length`` in their row (up to
+``block_length - 1`` slots of padding each), so that no block of the
+diffusion straddles a block of the attention kernels, and the ``flash_*``
+counters follow the rows as that model reads them (both copies, the
+block-diffusion rule). Timed by the span ``producer_noise``
+(``data_producer_noise_seconds_total``) and counted in
+``bd_positions_masked_total`` / ``bd_tokens_real_total``.
+
 Chaos sites native to this stage: ``data.tokenize_error`` poisons a
 record's bytes producer-side so the tokenizer rejects it (charged against
 ``max_bad_records``, identically in every pack mode) and
@@ -62,7 +80,7 @@ from tensorflowonspark_tpu.ops import flash_blocks
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["TextPipeline", "pack_bins"]
+__all__ = ["TextPipeline", "pack_bins", "noise_blocks"]
 
 #: invalid UTF-8 the ``data.tokenize_error`` site swaps in for a record
 _CHAOS_BAD_RECORD = b"\xff\xfe chaos-malformed-text-record"
@@ -92,6 +110,23 @@ def pack_bins(lengths, capacity):
     return [b[1] for b in bins]
 
 
+def noise_blocks(tokens, segment_ids, positions, block_length, mask_id, t_min, rng):
+    """Block-diffusion noise for packed rows (``int32 [B, L]`` each):
+    ``(noised_tokens int32 [B, L], loss_weights float32 [B, L])``. One rate
+    ``t ~ U(t_min, 1)`` a diffusion block (``block_length`` positions of one
+    document, counted from its start), every real token masked with its
+    block's probability; a masked position weighs ``1 / t``. The draws are
+    two arrays of the rows' shape whatever the packing, so a seeded ``rng``
+    gives the same noise for the same rows."""
+    rate_at = rng.uniform(t_min, 1.0, size=tokens.shape)
+    coin = rng.random(size=tokens.shape)
+    # a block's rate is the one drawn at its first position
+    rate = np.take_along_axis(rate_at, np.arange(tokens.shape[1])[None, :] - positions % block_length, axis=1)
+    masked = (coin < rate) & (segment_ids > 0)
+    return (np.where(masked, np.int32(mask_id), tokens).astype(np.int32),
+            np.where(masked, 1.0 / rate, 0.0).astype(np.float32))
+
+
 class TextPipeline(ImagePipeline):
     """files -> shuffled, tokenized, sequence-packed batches of
     ``{"tokens", "segment_ids", "positions"}`` (all ``int32 [B, L]``).
@@ -109,6 +144,9 @@ class TextPipeline(ImagePipeline):
       is FFD-packed — deeper windows pack tighter, at more producer
       buffering (leftover part-full bins carry their sequences into the
       next window, so nothing is dropped mid-stream);
+    - ``block_diffusion`` (a dict: ``block_length``, ``mask_id``, ``t_min``
+      default 1e-3) adds the noising stage and its batch keys (the module's
+      text);
     - ``cache="decoded"`` and ``recycle_buffers`` are not supported (the
       decoded-pair cache is image-geometry machinery; packed rows already
       have the packed-slab cache).
@@ -145,6 +183,7 @@ class TextPipeline(ImagePipeline):
         slab_cache_dir=None,
         store=None,
         prefetch=None,
+        block_diffusion=None,
     ):
         if cache == "decoded":
             raise ValueError(
@@ -178,6 +217,11 @@ class TextPipeline(ImagePipeline):
         self.tokenizer = tokenizer
         self.seq_len = seq_len
         self.pack_ahead = float(pack_ahead)
+        self.block_diffusion = None
+        if block_diffusion is not None:
+            self.block_diffusion = dict({"t_min": 1e-3}, **block_diffusion)
+            if seq_len % int(self.block_diffusion["block_length"]):
+                raise ValueError("seq_len must be a multiple of the diffusion's block_length")
 
     # -- stage 3: pack assembly ---------------------------------------------
 
@@ -260,6 +304,19 @@ class TextPipeline(ImagePipeline):
             help="blocks of the causal triangle over the emitted rows: what the kernels "
             "computed before they skipped by the packing",
         )
+        noise = self.block_diffusion
+        #: documents start on multiples of this in their row
+        align = int(noise["block_length"]) if noise else 1
+        if noise:
+            noise_c = obs.counter(
+                "data_producer_noise_seconds_total",
+                help="seconds the producer spent drawing block-diffusion noise for packed batches",
+            )
+            masked_c = obs.counter(
+                "bd_positions_masked_total", help="positions the block-diffusion noise masked (the loss-bearing ones)")
+            real_c = obs.counter(
+                "bd_tokens_real_total", help="real (non-pad) tokens of the batches the block-diffusion noise was drawn for")
+        emitted_batches = [0]
         grid_steps_c = obs.counter(
             "flash_grid_steps_total",
             help="grid steps the segmented flash kernels take for the emitted rows, per head "
@@ -414,7 +471,7 @@ class TextPipeline(ImagePipeline):
                                 plan.append((offset, seg_id, eff_len, rec))
                                 if crc is not None:
                                     puts.append((crc, slot, offset, eff_len))
-                            offset += eff_len
+                            offset += -(-eff_len // align) * align
                         labels[slot] = len(entries)
                         if plan:
                             plans.append((slot, tuple(plan)))
@@ -448,8 +505,24 @@ class TextPipeline(ImagePipeline):
                 eff = emitted_tokens[0] / emitted_slots[0]
                 eff_g.set(eff)
                 pad_g.set(1.0 - eff)
-                # the columns the LM attends (make_loss_fn feeds [:, :-1])
-                needed, dense, steps = flash_blocks.attended_blocks(buf[:rows, 1, :-1])
+                extra = {}
+                if noise:
+                    with obs.span("producer_noise", seconds_total=noise_c):
+                        rng = np.random.default_rng([self.seed, emitted_batches[0]])
+                        noised, weights = noise_blocks(
+                            buf[:rows, 0], buf[:rows, 1], buf[:rows, 2], align, noise["mask_id"], noise["t_min"], rng)
+                        extra = {"noised_tokens": noised, "loss_weights": weights}
+                    masked_c.inc(int((weights > 0).sum()))
+                    real_c.inc(n_tokens)
+                    # the row as that model reads it: the clean copy, then the noised one
+                    twice = np.concatenate([buf[:rows, 1], buf[:rows, 1]], axis=1)
+                    block = buf[:rows, 2] // align
+                    needed, dense, steps = flash_blocks.attended_blocks(
+                        twice, np.concatenate([2 * block, 2 * block + 1], axis=1))
+                else:
+                    # the columns the LM attends (make_loss_fn feeds [:, :-1])
+                    needed, dense, steps = flash_blocks.attended_blocks(buf[:rows, 1, :-1])
+                emitted_batches[0] += 1
                 blocks_needed_c.inc(needed)
                 blocks_dense_c.inc(dense)
                 grid_steps_c.inc(steps)
@@ -460,13 +533,7 @@ class TextPipeline(ImagePipeline):
                     free_q.put((buf, labels))
                 else:
                     out = buf[:rows]
-                _emit(
-                    {
-                        "tokens": out[:, 0],
-                        "segment_ids": out[:, 1],
-                        "positions": out[:, 2],
-                    }
-                )
+                _emit(dict({"tokens": out[:, 0], "segment_ids": out[:, 1], "positions": out[:, 2]}, **extra))
 
             def _flush(final):
                 """FFD-pack the window and emit whole batches of B bins.
@@ -475,7 +542,7 @@ class TextPipeline(ImagePipeline):
                 the leftovers become one short batch unless
                 ``drop_remainder``."""
                 nonlocal window, window_tokens
-                bins = pack_bins([n for _, n in window], L)
+                bins = pack_bins([-(-n // align) * align for _, n in window], L)
                 full = (len(bins) // B) * B
                 for g in range(0, full, B):
                     _fill_and_emit([[window[i] for i in b] for b in bins[g : g + B]])

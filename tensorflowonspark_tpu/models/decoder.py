@@ -11,11 +11,19 @@ part         kind        module
 attention    ``mla``     :class:`LatentAttention`: low-rank query and key/value
                          paths, one rotary key shared by all heads (YaRN
                          frequencies), queries and keys wider than values
+attention    ``gqa``     :class:`GroupedQueryAttention`: q/k/v/o projections,
+                         ``num_key_value_heads`` key/value heads each read by
+                         a group of query heads, an RMSNorm with a learned
+                         weight on every head of q and k, rotary positions
+                         over the whole head (halves rotated together)
 feed-forward ``swiglu``  :class:`SwiGLU`: the dense gated MLP
-feed-forward ``moe``     :class:`RoutedExperts`: sigmoid scores, top-k of the
-                         biased scores, the experts *held here* applied to the
-                         slots routed to them (nothing dropped), beside shared
-                         experts that see every token
+feed-forward ``moe``     :class:`RoutedExperts`: scores (``scoring_func``:
+                         ``sigmoid`` with a selection bias, ``noaux_tc``; or
+                         ``softmax`` over all experts, no bias), top-k, the
+                         chosen scores renormalised, the experts *held here*
+                         applied to the slots routed to them (nothing
+                         dropped), beside shared experts, if any, that see
+                         every token
 residual     ``add``     ``x + F(norm(x))``
 residual     ``mhc``     :class:`HyperConnection`: ``hc_mult`` residual
                          streams, three learned maps per sub-layer, the
@@ -27,8 +35,22 @@ residual     ``mhc``     :class:`HyperConnection`: ``hc_mult`` residual
 ===========  ==========  ======================================================
 
 The configuration is a dict with the published ``config.json``'s keys
-(:class:`DecoderConfig`); ``layer_plan`` lists the layers' kinds and defaults
-to ``first_k_dense_replace`` dense layers followed by routed ones. A chip
+(:class:`DecoderConfig`), in either of two dialects: the one that says
+``n_routed_experts`` (latent attention where ``kv_lora_rank`` is given,
+sigmoid ``noaux_tc`` routing) and the one that says ``num_experts`` (``gqa``,
+softmax routing without a bias; ``decoder_sparse_step`` 1, no
+``mlp_only_layers``, no sliding window). ``layer_plan`` lists the layers'
+kinds and defaults to ``first_k_dense_replace`` dense layers followed by
+routed ones, with ``mla`` where the configuration has a ``kv_lora_rank`` and
+``gqa`` where it has none.
+
+``objective`` is ``next_token`` or ``block_diffusion`` (with ``block_length``
+and ``mask_token_id``: a published configuration gives neither, so the job
+does): ``transformer.make_loss_fn`` then builds that loss, and the model is
+called with ``labels`` (the attention's second rule:
+:mod:`~tensorflowonspark_tpu.ops.flash_blocks`) and ``head_from`` (the first
+position the head is run on: the noised half of a row that holds a clean
+and a noised copy). A chip
 that holds a share of a layer's experts says which (``experts_held``:
 first, count): the router stays as wide as the model's, and the layer adds
 only its own experts' terms — what expert parallelism asks of a layer, here
@@ -47,7 +69,7 @@ position and head, so the flash forward kernel runs once a layer
 hyper-connections' reading is computed again.
 
 Device scopes (``jax.named_scope``, in every operation's ``op_name``):
-``tos.mla``, ``tos.moe_route`` (router, top-k, sort, gather, combine),
+``tos.mla``, ``tos.gqa``, ``tos.moe_route`` (router, top-k, sort, gather, combine),
 ``tos.moe_experts`` (the grouped products), ``tos.moe_shared``,
 ``tos.dense_mlp``, ``tos.mhc``. What the routed layers count in a step is
 sown into the ``counters`` collection (``moe_slots_routed``,
@@ -72,20 +94,23 @@ from tensorflowonspark_tpu.ops import grouped_matmul as gm
 from tensorflowonspark_tpu.ops import hyper_connection
 from tensorflowonspark_tpu.ops.flash_attention import REMAT_POLICY
 
-ATTENTION_KINDS = ("mla",)
+ATTENTION_KINDS = ("mla", "gqa")
 FEED_FORWARD_KINDS = ("swiglu", "moe")
 RESIDUAL_KINDS = ("add", "mhc")
 
 #: keys of a published ``config.json`` that say nothing this module computes
 #: from, and the values the ones it does not implement must have
 _IGNORED_KEYS = (
-    "model_type", "ep_size", "moe_layer_freq", "num_key_value_heads", "max_position_embeddings",
-    "tie_word_embeddings", "num_nextn_predict_layers",
+    "model_type", "ep_size", "moe_layer_freq", "max_position_embeddings", "tie_word_embeddings",
+    "num_nextn_predict_layers", "max_window_layers",
 )
 _REQUIRED_VALUES = {
-    "attention_bias": False, "hidden_act": "silu", "scoring_func": "sigmoid", "topk_method": "noaux_tc",
-    "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+    "attention_bias": False, "hidden_act": "silu", "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+    "decoder_sparse_step": 1, "mlp_only_layers": [], "use_sliding_window": False, "sliding_window": None,
 }
+#: the routing each dialect's ``scoring_func`` goes with
+_TOPK_METHODS = {"sigmoid": "noaux_tc", "softmax": "greedy"}
+OBJECTIVES = ("next_token", "block_diffusion")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,12 +119,15 @@ class DecoderConfig:
     hidden_size: int
     num_hidden_layers: int
     num_attention_heads: int
-    # latent attention
-    q_lora_rank: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
+    # latent attention (``mla``)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # grouped-query attention (``gqa``); ``mla`` reads neither
+    num_key_value_heads: int = 0
+    head_dim: int = 0
     rope_theta: float = 10000.0
     #: the ``rope_scaling`` dict (``type: yarn``) as sorted items, or ()
     rope_scaling: tuple = ()
@@ -111,6 +139,8 @@ class DecoderConfig:
     #: (first, count) of the routed experts this chip holds; None = all
     experts_held: tuple = None
     num_experts_per_tok: int = 0
+    #: ``sigmoid`` (top-k of the biased scores) or ``softmax`` (no bias)
+    scoring_func: str = "sigmoid"
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     first_k_dense_replace: int = 0
@@ -128,6 +158,12 @@ class DecoderConfig:
     #: streams), the attention's output and one float32 a position and head
     remat: bool = False
     attention: str = "auto"  # transformer._dispatch_attention's choices
+    #: what ``transformer.make_loss_fn`` trains: the next token, or diffusion
+    #: over blocks of ``block_length`` positions with ``mask_token_id`` the
+    #: noise (None: the vocabulary's last id)
+    objective: str = "next_token"
+    block_length: int = 4
+    mask_token_id: int = None
 
     @classmethod
     def from_dict(cls, cfg):
@@ -137,6 +173,14 @@ class DecoderConfig:
         for key, want in _REQUIRED_VALUES.items():
             if cfg.pop(key, want) != want:
                 raise ValueError("decoder: {} must be {!r}".format(key, want))
+        if "num_experts" in cfg:  # the dialect of softmax routers: no scoring_func key, no bias
+            cfg["n_routed_experts"] = cfg.pop("num_experts")
+            cfg.setdefault("scoring_func", "softmax")
+        scoring = cfg.setdefault("scoring_func", "sigmoid")
+        if scoring not in _TOPK_METHODS or cfg.pop("topk_method", _TOPK_METHODS[scoring]) != _TOPK_METHODS[scoring]:
+            raise ValueError("decoder: scoring_func/topk_method must be one of {}".format(sorted(_TOPK_METHODS.items())))
+        if cfg.get("objective", "next_token") not in OBJECTIVES:
+            raise ValueError("decoder: objective must be one of {}".format(OBJECTIVES))
         scaling = cfg.pop("rope_scaling", None) or {}
         if scaling and scaling.get("type") != "yarn":
             raise ValueError("decoder: rope_scaling type {!r} is not implemented".format(scaling.get("type")))
@@ -162,8 +206,9 @@ class DecoderConfig:
             plan = self.layer_plan
         else:
             residual = "mhc" if self.hc_mult > 1 else "add"
+            attention = "mla" if self.kv_lora_rank else "gqa"
             plan = tuple(
-                ("mla", "swiglu" if i < self.first_k_dense_replace or not self.n_routed_experts else "moe", residual)
+                (attention, "swiglu" if i < self.first_k_dense_replace or not self.n_routed_experts else "moe", residual)
                 for i in range(self.num_hidden_layers))
         if len(plan) != self.num_hidden_layers:
             raise ValueError("decoder: layer_plan has {} layers, num_hidden_layers is {}".format(
@@ -175,6 +220,10 @@ class DecoderConfig:
             if residual == "add" and self.hc_mult != 1:
                 raise ValueError("decoder: an 'add' residual carries one stream (hc_mult 1)")
         return plan
+
+    @property
+    def mask_id(self):
+        return self.vocab_size - 1 if self.mask_token_id is None else self.mask_token_id
 
     @property
     def held(self):
@@ -241,7 +290,7 @@ class LatentAttention(nn.Module):
     )
 
     @nn.compact
-    def __call__(self, x, positions, segment_ids=None):
+    def __call__(self, x, positions, segment_ids=None, labels=None):
         cfg, dt = self.cfg, self.cfg.compute_dtype
         heads, nope, rope, v_dim = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         scaling = dict(cfg.rope_scaling)
@@ -266,8 +315,47 @@ class LatentAttention(nn.Module):
 
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, H, L, ·]
             out = transformer._dispatch_attention(
-                q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids, scale=softmax_scale)
+                q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids, scale=softmax_scale, **_rule(labels))
             out = out.transpose(0, 2, 1, 3)  # [B, L, H, v]
+            return nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out)
+
+
+def _rule(labels):
+    """The attention's rule and labels: the second rule where the model was
+    given labels, else the call as it was."""
+    return {} if labels is None else {"rule": "block_diffusion", "labels": labels}
+
+
+class GroupedQueryAttention(nn.Module):
+    """``num_attention_heads`` query heads over ``num_key_value_heads``
+    key/value heads of ``head_dim``: query head ``h`` reads key/value head
+    ``h // (heads / kv heads)`` (the flash kernels read it in place). Every
+    head of q and of k goes through an RMSNorm over its ``head_dim`` with one
+    learned weight for all heads, then rotary positions over the whole head,
+    its halves rotated together; scores times ``head_dim ** -0.5``."""
+
+    cfg: DecoderConfig
+    mesh: object = None
+
+    PARAM_RULES = (
+        (r"attn/(q|k|v)/kernel$", ("fsdp", "tp", None)),  # [d, heads, head_dim]
+        (r"attn/o/kernel$", ("tp", None, "fsdp")),  # [H, head_dim, d]
+    )
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids=None, labels=None):
+        cfg, dt = self.cfg, self.cfg.compute_dtype
+        heads, width = cfg.num_attention_heads, cfg.head_dim or cfg.hidden_size // cfg.num_attention_heads
+        kv_heads = cfg.num_key_value_heads or heads
+        with jax.named_scope("tos.gqa"):
+            dense = lambda n, name: nn.DenseGeneral((n, width), use_bias=False, dtype=dt, name=name)  # noqa: E731
+            q, k, v = dense(heads, "q")(x), dense(kv_heads, "k")(x), dense(kv_heads, "v")(x)  # [B, L, ·, width]
+            q = transformer._rope(_norm(cfg, "q_norm")(q), positions, cfg.rope_theta)
+            k = transformer._rope(_norm(cfg, "k_norm")(k), positions, cfg.rope_theta)
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, ·, L, width]
+            out = transformer._dispatch_attention(
+                q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids, **_rule(labels))
+            out = out.transpose(0, 2, 1, 3)  # [B, L, H, width]
             return nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out)
 
 
@@ -291,14 +379,16 @@ class SwiGLU(nn.Module):
 
 
 class RoutedExperts(nn.Module):
-    """Sigmoid-scored top-k routing over all the model's experts, the
-    experts held here computed over the slots routed to them, beside the
-    shared experts. Returns ``(y, counts)``.
+    """Top-k routing over all the model's experts, the experts held here
+    computed over the slots routed to them, beside the shared experts, if
+    any. Returns ``(y, counts)``.
 
-    Scores ``s = sigmoid(x W_r)`` in float32; chosen: the top-k of ``s + b``
-    (``b`` the selection bias: it picks, it does not weigh, and no gradient
-    reaches it); weights: ``s`` at the chosen, over their sum, times
-    ``routed_scaling_factor``. Every slot whose expert is held here is
+    ``scoring_func: sigmoid``: scores ``s = sigmoid(x W_r)`` in float32;
+    chosen: the top-k of ``s + b`` (``b`` the selection bias: it picks, it
+    does not weigh, and no gradient reaches it). ``softmax``: ``s =
+    softmax(x W_r)`` over all the experts, the k largest, and no bias (the
+    layer has no such parameter). Weights: ``s`` at the chosen, over their
+    sum, times ``routed_scaling_factor``. Every slot whose expert is held here is
     computed — no capacity, nothing dropped; a slot whose expert lives on
     another chip adds nothing here (nor is anything put in its place)."""
 
@@ -321,10 +411,14 @@ class RoutedExperts(nn.Module):
 
         with jax.named_scope("tos.moe_route"):
             router = self.param("router", _kernel_init(), (d, cfg.n_routed_experts), jnp.float32)
-            bias = self.param("router_bias", nn.initializers.normal(0.02), (cfg.n_routed_experts,), jnp.float32)
-            scores = jax.nn.sigmoid(jnp.dot(
-                flat.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST))  # [T, E]
-            _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)  # [T, k]
+            logits = jnp.dot(flat.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST)  # [T, E]
+            if cfg.scoring_func == "softmax":
+                scores = jax.nn.softmax(logits, axis=-1)
+                _, chosen = jax.lax.top_k(scores, k)  # [T, k]
+            else:
+                bias = self.param("router_bias", nn.initializers.normal(0.02), (cfg.n_routed_experts,), jnp.float32)
+                scores = jax.nn.sigmoid(logits)
+                _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
             picked = jnp.take_along_axis(scores, chosen, axis=-1)
             weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
             order, group_sizes = gm.sort_slots(chosen.reshape(-1), first, held)
@@ -479,6 +573,7 @@ class AddResidual(nn.Module):
 
 
 _RESIDUALS = {"add": AddResidual, "mhc": HyperConnection}
+_ATTENTIONS = {"mla": LatentAttention, "gqa": GroupedQueryAttention}
 
 
 class DecoderLayer(nn.Module):
@@ -491,13 +586,13 @@ class DecoderLayer(nn.Module):
     mesh: object = None
 
     @nn.compact
-    def __call__(self, streams, positions, segment_ids=None):
+    def __call__(self, streams, positions, segment_ids=None, labels=None):
         cfg = self.cfg
-        _attention, feed_forward, residual = self.kinds
+        attention, feed_forward, residual = self.kinds
         path = _RESIDUALS[residual]
 
         h, maps = path(cfg, self.mesh, name="res_attn")(streams)
-        y = LatentAttention(cfg, self.mesh, name="attn")(_norm(cfg, "ln1")(h), positions, segment_ids)
+        y = _ATTENTIONS[attention](cfg, self.mesh, name="attn")(_norm(cfg, "ln1")(h), positions, segment_ids, labels)
         streams = path.merge(streams, maps, y, self.mesh)
 
         h, maps = path(cfg, self.mesh, name="res_mlp")(streams)
@@ -524,7 +619,10 @@ class Decoder(nn.Module):
             x, NamedSharding(self.mesh, P(batch, *([None] * (x.ndim - 1)))))
 
     @nn.compact
-    def __call__(self, tokens, positions=None, segment_ids=None):
+    def __call__(self, tokens, positions=None, segment_ids=None, labels=None, head_from=0):
+        """``labels`` (``int32 [B, L]``) asks the attention for its second
+        rule; ``head_from`` (static) is the first position whose logits are
+        wanted (the final norm and the head run from there on)."""
         cfg = self.cfg
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.compute_dtype, name="embed")(tokens)
         if positions is None:
@@ -534,7 +632,7 @@ class Decoder(nn.Module):
         counted = []
         for i, kinds in enumerate(cfg.plan):
             streams, counts = layer(cfg, kinds, self.mesh, name="layer_{}".format(i))(
-                streams, positions, segment_ids)
+                streams, positions, segment_ids, labels)
             streams = self._constrain(streams)
             if counts:
                 counted.append(counts)
@@ -552,7 +650,7 @@ class Decoder(nn.Module):
             self.sow("counters", "moe_slots_held", sum(c["slots_held"] for c in counted))
             self.sow("gauges", "moe_expert_load_max_over_mean",
                      sum(c["load_max_over_mean"] for c in counted) / len(counted))
-        summed = sum(jnp.split(streams.astype(jnp.float32), cfg.hc_mult, axis=-1))
+        summed = sum(jnp.split(streams[:, head_from:].astype(jnp.float32), cfg.hc_mult, axis=-1))
         x = _norm(cfg, "ln_f")(summed.astype(cfg.compute_dtype))
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.compute_dtype, name="lm_head")(x)
         return logits.astype(jnp.float32)
@@ -567,8 +665,8 @@ _SHARED_RULES = (
 def param_rules(cfg):
     """The placement rules of the kinds ``cfg``'s plan uses."""
     rules = []
-    for _attention, feed_forward, residual in cfg.plan:
-        for module in (LatentAttention, RoutedExperts if feed_forward == "moe" else SwiGLU, _RESIDUALS[residual]):
+    for attention, feed_forward, residual in cfg.plan:
+        for module in (_ATTENTIONS[attention], RoutedExperts if feed_forward == "moe" else SwiGLU, _RESIDUALS[residual]):
             rules += [rule for rule in module.PARAM_RULES if rule not in rules]
     return tuple(rules) + _SHARED_RULES
 

@@ -81,7 +81,8 @@ class TransformerConfig:
 
 
 def _rope(x, positions, base=10000.0):
-    """Rotary position embedding over the last (head) dim; x: [B, L, H, D]."""
+    """Rotary position embedding over the last (head) dim, its two halves
+    rotated together (``rotate_half``); x: [B, L, H, D]."""
     d = x.shape[-1]
     half = d // 2
     freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -119,10 +120,11 @@ def _batch_axes(mesh, batch):
     return axes[0] if len(axes) == 1 else tuple(axes)
 
 
-def _flash(q, k, v, segment_ids, mesh, interpret, scale=None):
-    """The pallas flash kernel on ``[B, H, L, D]``, padded to the kernel's
-    128-row granule and run per shard. ``scale`` is the softmax scale where
-    it is not ``D ** -0.5``.
+def _flash(q, k, v, segment_ids, mesh, interpret, scale=None, rule="causal", labels=None):
+    """The pallas flash kernel on ``[B, H, L, D]`` (``k`` and ``v`` may have
+    fewer heads: key/value groups), padded to the kernel's 128-row granule
+    and run per shard. ``scale`` is the softmax scale where it is not ``D **
+    -0.5``; ``rule`` and ``labels`` are the kernel's (block diffusion).
 
     A Mosaic custom call has no partitioning rule, so under pjit XLA would
     gather q/k/v and run the whole global batch's attention on every chip.
@@ -139,12 +141,16 @@ def _flash(q, k, v, segment_ids, mesh, interpret, scale=None):
         q, k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) for t in (q, k, v))
         if segment_ids is not None:
             segment_ids = jnp.pad(segment_ids, ((0, 0), (0, pad)))
+        if labels is not None:  # beside id 0, which the rule shows nothing
+            labels = jnp.pad(labels, ((0, 0), (0, pad)))
 
-    def local(q, k, v, seg=None):
-        return flash_attention(q, k, v, causal=True, scale=scale, segment_ids=seg, interpret=interpret)
+    def local(q, k, v, seg=None, labels=None):
+        # positions play no part in the second rule: its mask is the labels'
+        return flash_attention(
+            q, k, v, causal=rule == "causal", scale=scale, segment_ids=seg, interpret=interpret, rule=rule, labels=labels)
 
     if mesh is None or mesh.size == 1:
-        out = local(q, k, v, segment_ids)
+        out = local(q, k, v, segment_ids, labels)
     else:
         from jax.sharding import PartitionSpec as P
 
@@ -152,11 +158,14 @@ def _flash(q, k, v, segment_ids, mesh, interpret, scale=None):
 
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         batch = _batch_axes(mesh, q.shape[0])
-        heads = "tp" if "tp" in sizes and q.shape[1] % sizes["tp"] == 0 else None
+        # key/value heads fewer than the tp axis stay whole, and the query heads with them
+        heads = "tp" if "tp" in sizes and q.shape[1] % sizes["tp"] == 0 and k.shape[1] % sizes["tp"] == 0 else None
         spec = P(batch, heads, None, None)
         operands, in_specs = (q, k, v), (spec, spec, spec)
         if segment_ids is not None:
             operands, in_specs = operands + (segment_ids,), in_specs + (P(batch, None),)
+        if labels is not None:
+            operands, in_specs = operands + (labels,), in_specs + (P(batch, None),)
         # check_vma off: pallas_call outputs carry no varying-axes type
         out = shard_map(
             local, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False
@@ -164,7 +173,20 @@ def _flash(q, k, v, segment_ids, mesh, interpret, scale=None):
     return out[:, :, :seq] if pad else out
 
 
-def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None):
+def _masked_attention(q, k, v, mask, scale=None):
+    """Attention under a mask written out, ``bool [B, L, L]`` (query, key),
+    float32 inside; ``k`` and ``v`` may have fewer heads than ``q``. For the
+    rows the ``plain`` path is for: the mask is the row's length squared."""
+    batch, heads, length, width = q.shape
+    group = heads // k.shape[1]
+    grouped = q.astype(jnp.float32).reshape(batch, k.shape[1], group, length, width)
+    scores = jnp.einsum("bngqd,bnkd->bngqk", grouped, k.astype(jnp.float32)) * (width ** -0.5 if scale is None else scale)
+    probs = jax.nn.softmax(jnp.where(mask[:, None, None], scores, -0.7 * jnp.finfo(jnp.float32).max), axis=-1)
+    out = jnp.einsum("bngqk,bnkd->bngqd", probs, v.astype(jnp.float32))
+    return out.reshape(batch, heads, length, v.shape[-1]).astype(q.dtype)
+
+
+def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None, rule="causal", labels=None):
     """Pick the attention path. ``auto``: ring over ``sp`` when the mesh
     shards the sequence, else the pallas flash kernel on TPU (plain below
     ``TOS_FLASH_MIN_SEQ``), else plain XLA attention. Forcing
@@ -179,19 +201,39 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None):
     packed-sequence fence — every path turns it into the same
     block-diagonal mask, so packed neighbours never cross-attend. ``scale``
     is the softmax scale where it is not the key width's inverse root; ``v``
-    may be narrower than ``q`` and ``k`` (latent attention).
+    may be narrower than ``q`` and ``k`` (latent attention), and ``k`` and
+    ``v`` may have fewer heads than ``q`` (key/value groups: the flash
+    kernels read a group's head in place, the other paths repeat it).
+
+    ``rule="block_diffusion"`` with ``labels`` (``int32 [B, L]``) is the
+    second mask (:mod:`~tensorflowonspark_tpu.ops.flash_blocks`): the flash
+    paths hand both to the kernels, ``plain`` writes the mask out, and the
+    ring path, whose blocks know the causal rule alone, refuses it.
     """
     if impl not in _ATTENTION_IMPLS:
         raise ValueError(
             "unknown attention impl {!r}; expected one of {}".format(impl, _ATTENTION_IMPLS)
         )
-    if impl == "plain":
+    if rule not in flash_blocks.RULES:
+        raise ValueError("unknown attention rule {!r}; expected one of {}".format(rule, flash_blocks.RULES))
+
+    def plain(q, k, v):
+        if rule == "block_diffusion":
+            return _masked_attention(q, k, v, flash_blocks.bd_mask(segment_ids, labels, xp=jnp), scale)
+        group = q.shape[1] // k.shape[1]
+        if group > 1:
+            k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
         return plain_attention(q, k, v, causal=True, scale=scale, segment_ids=segment_ids)
+
+    if impl == "plain":
+        return plain(q, k, v)
     has_sp = mesh is not None and "sp" in mesh.axis_names
     if impl == "ring" or (impl == "auto" and has_sp):
+        if rule != "causal" or k.shape[1] != q.shape[1]:
+            raise ValueError("ring attention knows the causal rule and one key/value head a query head")
         return ring_attention_sharded(q, k, v, mesh, causal=True, scale=scale, segment_ids=segment_ids)
     if impl == "flash_interpret":
-        return _flash(q, k, v, segment_ids, mesh, interpret=True, scale=scale)
+        return _flash(q, k, v, segment_ids, mesh, interpret=True, scale=scale, rule=rule, labels=labels)
     on_tpu = jax.default_backend() == "tpu"
     if impl == "flash" and not on_tpu:
         raise RuntimeError(
@@ -200,8 +242,8 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None):
             "interpreter, or 'auto'/'plain'".format(jax.default_backend())
         )
     if on_tpu and (impl == "flash" or q.shape[2] >= _FLASH_MIN_SEQ):
-        return _flash(q, k, v, segment_ids, mesh, interpret=False, scale=scale)
-    return plain_attention(q, k, v, causal=True, scale=scale, segment_ids=segment_ids)
+        return _flash(q, k, v, segment_ids, mesh, interpret=False, scale=scale, rule=rule, labels=labels)
+    return plain(q, k, v)
 
 
 class Attention(nn.Module):
@@ -450,8 +492,55 @@ def make_init_fn(model, sample_len=16):
     return init
 
 
+def _carried(metrics, mods):
+    """What the model counted in this step, into the step's ``metrics``,
+    where TrainStep books it by name once the host has found the step
+    finished."""
+    for kind in ("counter", "gauge"):
+        for name, sown in mods.get(kind + "s", {}).items():
+            metrics[kind + "/" + name] = sown[-1]
+    return metrics
+
+
+def make_block_diffusion_loss_fn(model):
+    """The block-diffusion objective (a model whose configuration says
+    ``objective: block_diffusion``; arXiv:2503.09573, as SDAR trains it):
+    batch = the text plane's noised batch, ``{"tokens", "noised_tokens",
+    "segment_ids", "positions"}`` ``int32 [B, L]`` and ``"loss_weights"``
+    ``float32 [B, L]`` (``1 / t`` of its block at a masked position, else 0).
+
+    The model reads the row ``[x_0 ; x_t]``, 2 L positions, both copies at the
+    same rotary positions and ids, labelled ``2 * block + half`` (a document's
+    blocks are ``block_length`` positions, counted from its start) for the
+    attention's second rule; the head runs on the noised half alone. Loss:
+    the weighted cross-entropy of a masked position's logits against its own
+    clean token (no shift), summed and divided by the batch's real tokens."""
+    block_length = model.cfg.block_length
+
+    def loss_fn(params, batch):
+        tokens, seg, pos = batch["tokens"], batch["segment_ids"], batch["positions"]
+        length = tokens.shape[1]
+        block = pos // block_length
+        twice = lambda x: jnp.concatenate([x, x], axis=1)  # noqa: E731
+        logits, mods = model.apply(
+            {"params": params}, jnp.concatenate([tokens, batch["noised_tokens"]], axis=1),
+            positions=twice(pos), segment_ids=twice(seg),
+            labels=jnp.concatenate([2 * block, 2 * block + 1], axis=1), head_from=length,
+            mutable=["losses", "counters", "gauges"],
+        )
+        losses = optax.softmax_cross_entropy_with_integer_labels(logits, tokens)
+        weights = batch["loss_weights"].astype(losses.dtype)
+        loss = (losses * weights).sum() / jnp.maximum((seg > 0).sum(), 1)
+        return loss, _carried({"masked_positions": (weights > 0).sum()}, mods)
+
+    return loss_fn
+
+
 def make_loss_fn(model):
-    """Next-token LM loss; batch = {"tokens": int32 [B, L]} (optionally with
+    """The model's objective: next-token unless its configuration says
+    ``objective: block_diffusion`` (:func:`make_block_diffusion_loss_fn`).
+
+    Next-token LM loss; batch = {"tokens": int32 [B, L]} (optionally with
     {"mask": [B, L]} to exclude padding). MoE models contribute their sown
     router load-balancing losses, weighted by ``cfg.moe_aux_weight``.
 
@@ -461,6 +550,9 @@ def make_loss_fn(model):
     and the loss drops targets that cross a pack boundary (the last token
     of one sequence must not be asked to predict the first of the next) or
     fall in padding."""
+
+    if getattr(model.cfg, "objective", "next_token") == "block_diffusion":
+        return make_block_diffusion_loss_fn(model)
 
     def loss_fn(params, batch):
         tokens = batch["tokens"]
@@ -494,12 +586,6 @@ def make_loss_fn(model):
             moe_aux = sum(jnp.asarray(a).mean() for a in aux) / len(aux)
             metrics["moe_aux"] = moe_aux
             loss = loss + model.cfg.moe_aux_weight * moe_aux
-        # what the model counted in this step: carried out in the step's
-        # metrics, where TrainStep books it by name once the host has found
-        # the step finished
-        for kind in ("counter", "gauge"):
-            for name, sown in mods.get(kind + "s", {}).items():
-                metrics[kind + "/" + name] = sown[-1]
-        return loss, metrics
+        return loss, _carried(metrics, mods)
 
     return loss_fn

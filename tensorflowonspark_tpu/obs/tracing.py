@@ -56,9 +56,10 @@ tracing analogue of chaos-obs-coverage):
 ``producer_read``          the input pipeline's reader opening a shard or reading a chunk
 ``producer_parse``         the input pipeline's producer decoding / packing one batch
 ``producer_emit``          the input pipeline's producer waiting on a full prefetch queue
+``producer_noise``         the text pipeline's producer drawing block-diffusion noise for one batch
 ``step_dispatch``          one call of a compiled train step (per step; the trace's Steps line)
 
-The last six are opened every step (or every chunk): they pass their own
+The last seven are opened every step (or every chunk): they pass their own
 ``*_seconds_total`` counter to ``span(..., seconds_total=)``, which is all
 the registry keeps of them — no event, no histogram — and they take a span
 id only while a flight shard is open. Every span, of either kind, is also a

@@ -75,6 +75,29 @@ twice the bytes, and read 1.3 ms a layer slower on the chip, ``PERF.md`` §6
 PR 32). The way back into the kernel's layout folds against the model's own
 merge in the forward pass and is one transposition in the recomputed one.
 
+**Key/value groups.** ``k`` and ``v`` may have fewer heads than ``q``: query
+head ``h`` of ``G = heads / kv heads`` a group reads key/value head ``h //
+G`` (grouped-query attention). Nothing is repeated in HBM. The forward
+kernel's grid is the same ``(batch·heads, steps)`` and its key and value
+index maps read block ``b // G``. The backward kernel's grid becomes
+``(batch·kv heads, G, steps)``: a key/value head's ``dk`` and ``dv``
+accumulate, float32, over its ``G`` query heads as well as over the q blocks,
+so they too are kept as whole rows ``[L_k, d]`` in VMEM (zeroed at the
+group's first step, written at its last), beside the dq row of the query
+head at hand (:func:`_bwd_vmem_limit`: 40 MiB in all at 8192 x 128). With one
+head a group the programs are the ones described above, operation for
+operation.
+
+**A second rule.** ``rule="block_diffusion"`` masks and skips by the
+block-diffusion rule (:mod:`~tensorflowonspark_tpu.ops.flash_blocks`: a row
+holds a clean and a noised copy of every document; ``labels`` beside the
+segment ids say which block and which copy a position is). The marks of a
+position (four int32: :func:`flash_blocks.bd_marks`) ride where the segment
+ids ride, the mask is two comparisons and an equality on a block's marks,
+never an ``[L, L]`` array, and the work lists hold the blocks the rule needs,
+on either side of the diagonal (their stride is the square). The kernels are
+named ``flash_fwd_bd`` and ``flash_bwd_dkv_bd``.
+
 This is the single-device analogue of
 :mod:`tensorflowonspark_tpu.parallel.ring_attention` (same math, blocks
 streamed from local HBM instead of rotated over ICI). ``interpret=True`` runs
@@ -140,10 +163,12 @@ def _flag(item, bit):
     return jax.lax.bitwise_and(item, jnp.int32(bit)) != 0
 
 
-def _here(items_ref, heads, steps):
+def _here(items_ref, heads, steps, axis=1):
     """This grid step's place: ``(outer, inner, item)``, from entry ``t`` of
-    the work list of ``b``'s batch row (``steps`` entries a row)."""
-    item = items_ref[_row(pl.program_id(0), heads) * steps + pl.program_id(1)]
+    the work list of ``b``'s batch row (``steps`` entries a row; ``heads``
+    is how many values of ``b`` a batch row has, ``axis`` the grid axis that
+    walks the list)."""
+    item = items_ref[_row(pl.program_id(0), heads) * steps + pl.program_id(axis)]
     return _outer(item), _inner(item), item
 
 
@@ -164,12 +189,24 @@ def _segment_mask(s, sq_ref, sk_ref):
     return jnp.where(seg_q == seg_k, s, _NEG_BIG)
 
 
-def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k):
+def _block_diffusion_mask(s, sq_ref, sk_ref):
+    """The block-diffusion rule on a block's marks
+    (:func:`flash_blocks.bd_marks`): lanes 0-2 of the query side hold ``lo``,
+    ``hi`` and ``own``, the key side holds ``key``."""
+    marks = sq_ref[0]
+    lo, hi, own = marks[:, 0:1], marks[:, 1:2], marks[:, 2:3]  # [bq, 1]
+    key = sk_ref[0][:1, :]  # [1, bk]
+    return jnp.where(((key >= lo) & (key <= hi)) | (key == own), s, _NEG_BIG)
+
+
+def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k, rule="causal"):
     s = jax.lax.dot_general(
         q_ref[0], k_ref[0],
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
+    if rule == "block_diffusion":
+        return _block_diffusion_mask(s, sq_ref, sk_ref)
     if causal:
         s = _causal_mask(s, iq, ik, block_q, block_k)
     if sq_ref is not None:
@@ -177,7 +214,7 @@ def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_
     return s
 
 
-def _fwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps):
+def _fwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps, rule="causal"):
     if segmented:
         q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref, acc, m, l = refs
     else:
@@ -193,7 +230,7 @@ def _fwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, he
 
     @pl.when(_flag(item, flash_blocks.ITEM_COMPUTE))
     def _block():
-        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k)
+        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k, rule)
         m_new = jnp.maximum(m[:], jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m[:] - m_new)
         p = jnp.exp(s - m_new)
@@ -213,7 +250,48 @@ def _fwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, he
         lse_ref[0] = jnp.broadcast_to(m[:] + jnp.log(denom), (l.shape[0], _STAT_W))
 
 
-def _bwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps):
+def _bwd_refs(refs, segmented):
+    """A backward kernel's references, ``(inputs, outputs, scratch)``, the
+    ids' two as None where the call has none."""
+    if segmented:
+        return refs[:8], refs[8:11], refs[11:]
+    return refs[:3] + (None, None) + refs[3:6], refs[6:9], refs[9:]
+
+
+def _bwd_pair(inputs, scratch, iq, ik, at_k, scale, causal, block_q, block_k, rule):
+    """The whole backward of one (kv block, q block) pair, added into the
+    three accumulators: dq's rows of q block ``iq`` of the whole-row scratch,
+    dk's and dv's at ``at_k`` of theirs (all of a block-sized scratch, or the
+    kv block's rows of a whole-row one)."""
+    q_ref, k_ref, v_ref, sq_ref, sk_ref, do_ref, lse_ref, delta_ref = inputs
+    dq_acc, dk_acc, dv_acc = scratch
+    s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k, rule)
+    p = jnp.exp(s - lse_ref[0][:, :1])  # [bq, bk]
+    dv_acc[at_k] += jax.lax.dot_general(
+        p.astype(do_ref.dtype), do_ref[0],
+        dimension_numbers=(((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dp = jax.lax.dot_general(
+        do_ref[0], v_ref[0],
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    ds = (p * (dp - delta_ref[0][:, :1]) * scale).astype(q_ref.dtype)  # [bq, bk]
+    dk_acc[at_k] += jax.lax.dot_general(
+        ds, q_ref[0],
+        dimension_numbers=(((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+    dq_acc[rows, :] += jax.lax.dot_general(
+        ds, k_ref[0],
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _bwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps, rule="causal"):
     """The whole backward of one (kv block, q block) pair: kv-major (the
     work list's outer block is the kv block, the q blocks that need it
     follow one another), so dk and dv accumulate in block-sized scratch and
@@ -222,13 +300,8 @@ def _bwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, he
     (batch, head) across the list. For a fixed q block the contributions
     arrive in increasing kv block, the order a q-major pass would sum them
     in."""
-    if segmented:
-        (q_ref, k_ref, v_ref, sq_ref, sk_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
-        sq_ref = sk_ref = None
+    inputs, (dq_ref, dk_ref, dv_ref), scratch = _bwd_refs(refs, segmented)
+    dq_acc, dk_acc, dv_acc = scratch
     ik, iq, item = _here(items_ref, heads, steps)  # note: kv outer, q inner
     t = pl.program_id(1)
 
@@ -243,30 +316,7 @@ def _bwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, he
 
     @pl.when(_flag(item, flash_blocks.ITEM_COMPUTE))
     def _block():
-        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k)
-        p = jnp.exp(s - lse_ref[0][:, :1])  # [bq, bk]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[0],
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta_ref[0][:, :1]) * scale).astype(q_ref.dtype)  # [bq, bk]
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q_ref[0],
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
-        dq_acc[rows, :] += jax.lax.dot_general(
-            ds, k_ref[0],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _bwd_pair(inputs, scratch, iq, ik, slice(None), scale, causal, block_q, block_k, rule)
 
     @pl.when(_flag(item, flash_blocks.ITEM_LAST))
     def _finish():
@@ -278,8 +328,44 @@ def _bwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, he
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
-def _block_map(seg, n_q, n_k, block_q, block_k, causal):
+def _bwd_kernel_grouped(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps, rule="causal"):
+    """:func:`_bwd_kernel` where ``G`` query heads share a key/value head, on
+    the grid ``(batch·kv heads, G, steps)`` (``heads`` counts the kv heads of
+    a batch row): the same pair's work, with dk and dv summed into whole-row
+    scratch ``[L_k, d]`` over the group's query heads and their q blocks, and
+    the dq row of the query head at hand zeroed and written once a head."""
+    inputs, (dq_ref, dk_ref, dv_ref), scratch = _bwd_refs(refs, segmented)
+    dq_acc, dk_acc, dv_acc = scratch
+    ik, iq, item = _here(items_ref, heads, steps, axis=2)  # kv outer, q inner
+    g, t = pl.program_id(1), pl.program_id(2)
+    last = t == pl.num_programs(2) - 1
+
+    @pl.when(t == 0)
+    def _init_row():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when((t == 0) & (g == 0))
+    def _init_group():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_flag(item, flash_blocks.ITEM_COMPUTE))
+    def _block():
+        at_k = (pl.ds(pl.multiple_of(ik * block_k, block_k), block_k), slice(None))
+        _bwd_pair(inputs, scratch, iq, ik, at_k, scale, causal, block_q, block_k, rule)
+
+    @pl.when(last)
+    def _finish_row():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when(last & (g == pl.num_programs(1) - 1))
+    def _finish_group():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
+def _block_map(seg, n_q, n_k, block_q, block_k, causal, rule="causal"):
     """The work lists of ``seg`` (``int32 [rows, L]``, or None: one row of
     one segment): ``(forward, backward)``, each ``(items, longest)``: a flat
     int32 table of :func:`flash_blocks.work_list` items, :func:`_steps`
@@ -290,12 +376,16 @@ def _block_map(seg, n_q, n_k, block_q, block_k, causal):
     Jitted on its own so that a model's layers, which all call it on the same
     shapes, trace and lower its few dozen integer operations once and not
     three times a layer."""
-    if seg is None:
-        zq, zk = jnp.zeros((1, n_q), jnp.int32), jnp.zeros((1, n_k), jnp.int32)
-        bounds = (zq, zq, zk, zk)
+    if rule == "block_diffusion":  # seg holds the four marks of every position, [rows, 4, L]
+        needed = flash_blocks.bd_blocks_needed(
+            flash_blocks.bd_bounds(tuple(seg[:, i] for i in range(4)), block_q, block_k, xp=jnp))
     else:
-        bounds = flash_blocks.block_bounds(seg, block_q, block_k, xp=jnp)
-    needed = flash_blocks.blocks_needed(bounds, block_q, block_k, causal, xp=jnp)
+        if seg is None:
+            zq, zk = jnp.zeros((1, n_q), jnp.int32), jnp.zeros((1, n_k), jnp.int32)
+            bounds = (zq, zq, zk, zk)
+        else:
+            bounds = flash_blocks.block_bounds(seg, block_q, block_k, xp=jnp)
+        needed = flash_blocks.blocks_needed(bounds, block_q, block_k, causal, xp=jnp)
     fwd_steps, bwd_steps = _steps(n_q, n_k, block_q, block_k, causal)
     forward, fwd_lengths = flash_blocks.work_list(needed, fwd_steps, xp=jnp)
     backward, bwd_lengths = flash_blocks.work_list(needed.swapaxes(1, 2), bwd_steps, xp=jnp)
@@ -315,7 +405,7 @@ def _steps(n_q, n_k, block_q, block_k, causal):
     return flash_blocks.work_bound(dense), flash_blocks.work_bound(dense.T)
 
 
-def _work(seg, rows, n_q, n_k, block_q, block_k, causal, backward):
+def _work(seg, rows, n_q, n_k, block_q, block_k, causal, backward, rule="causal"):
     """``(steps, items, longest)`` of one kernel's call: the row stride of
     its table of work lists (the shape's bound), the table, and the batch's
     longest list. Refuses a call whose lists would not fit: the lists of all
@@ -328,7 +418,7 @@ def _work(seg, rows, n_q, n_k, block_q, block_k, causal, backward):
             "or shard a row this long over chips (parallel.ring_attention)".format(
                 rows, steps, n_q, n_k, 4 * rows * steps / 2 ** 10, _SMEM_MOST / 2 ** 10,
                 flash_blocks.ITEM_BLOCKS_MOST))
-    return (steps,) + _block_map(seg, n_q, n_k, block_q, block_k, causal)[backward]
+    return (steps,) + _block_map(seg, n_q, n_k, block_q, block_k, causal, rule)[backward]
 
 
 class _Specs:
@@ -338,23 +428,26 @@ class _Specs:
     scalar-prefetch table). Segment ids are per batch row, not per head:
     ``ids=True`` indexes them by ``b // heads``."""
 
-    def __init__(self, heads, steps):
-        self.heads, self.steps = heads, steps
+    def __init__(self, heads, steps, group=1):
+        self.heads, self.steps, self.group = heads, steps, group
 
-    def _index(self, inner, ids, transposed):
-        heads, steps = self.heads, self.steps
+    def _index(self, inner, ids, transposed, shared=False):
+        heads, steps, group = self.heads, self.steps, self.group
 
         def index_map(b, t, items):
             row = _row(b, heads)
             at = (_inner if inner else _outer)(items[row * steps + t])
             first = row if ids else b
+            if shared and group > 1:  # the key/value head of query head b
+                first = jax.lax.div(b, jnp.int32(group))
             return (first, 0, at) if transposed else (first, at, 0)
 
         return index_map
 
-    def rows(self, block_rows, width, inner=False, ids=False):
-        """Blocks of ``block_rows`` rows of a [·, L, width] operand."""
-        return pl.BlockSpec((1, block_rows, width), self._index(inner, ids, False))
+    def rows(self, block_rows, width, inner=False, ids=False, shared=False):
+        """Blocks of ``block_rows`` rows of a [·, L, width] operand;
+        ``shared``: a key/value operand, one head for ``group`` query heads."""
+        return pl.BlockSpec((1, block_rows, width), self._index(inner, ids, False, shared))
 
     def seg_k(self, block_k, inner=False):
         """Blocks of the transposed [rows, _STAT_W, L] key-segment layout."""
@@ -367,11 +460,57 @@ class _Specs:
         return pl.BlockSpec((1, length, width), lambda b, t, items: (b, 0, 0))
 
 
-def _seg_inputs(seg):
+class _GroupSpecs:
+    """:class:`_Specs` for the grouped backward's grid ``(batch·kv heads, G,
+    steps)``, kv-major: ``kv_heads`` key/value heads a batch row, ``group``
+    query heads each. Query-side operands (``[batch·heads, L, ·]``) follow the
+    item's inner block of query head ``b * G + g``; a key/value head's
+    operands its outer block; dk and dv are whole rows that move once a
+    key/value head, dq a whole row that moves once a query head."""
+
+    def __init__(self, kv_heads, steps, group):
+        self.kv_heads, self.steps, self.group = kv_heads, steps, group
+
+    def _item(self, b, t, items):
+        return items[_row(b, self.kv_heads) * self.steps + t]
+
+    def q_rows(self, block_q, width):
+        return pl.BlockSpec(
+            (1, block_q, width),
+            lambda b, g, t, items: (b * jnp.int32(self.group) + g, _inner(self._item(b, t, items)), 0))
+
+    def kv_rows(self, block_k, width):
+        return pl.BlockSpec((1, block_k, width), lambda b, g, t, items: (b, _outer(self._item(b, t, items)), 0))
+
+    def ids_q(self, block_q):
+        return pl.BlockSpec(
+            (1, block_q, _STAT_W),
+            lambda b, g, t, items: (_row(b, self.kv_heads), _inner(self._item(b, t, items)), 0))
+
+    def ids_k(self, block_k):
+        return pl.BlockSpec(
+            (1, _STAT_W, block_k),
+            lambda b, g, t, items: (_row(b, self.kv_heads), 0, _outer(self._item(b, t, items))))
+
+    def q_whole_row(self, length, width):
+        return pl.BlockSpec((1, length, width), lambda b, g, t, items: (b * jnp.int32(self.group) + g, 0, 0))
+
+    @staticmethod
+    def kv_whole_row(length, width):
+        return pl.BlockSpec((1, length, width), lambda b, g, t, items: (b, 0, 0))
+
+
+def _seg_inputs(seg, rule="causal"):
     """Segment-id operands for the kernels, one set per batch row: query ids
     broadcast onto the [rows, L, _STAT_W] row-statistics layout, key ids
     pre-transposed to [rows, _STAT_W, L] so a kv block is a
-    directly-loadable row vector."""
+    directly-loadable row vector. Under the block-diffusion rule ``seg`` is
+    the marks ``[rows, 4, L]``: ``lo``, ``hi``, ``own`` in the query side's
+    first three lanes, ``key`` on the key side."""
+    if rule == "block_diffusion":
+        rows, _, seq = seg.shape
+        lanes = jnp.concatenate([seg[:, :3], jnp.zeros((rows, _STAT_W - 3, seq), jnp.int32)], axis=1)
+        return lanes.transpose(0, 2, 1), jnp.broadcast_to(seg[:, 3:4], (rows, _STAT_W, seq))
     rows, seq = seg.shape
     seg = seg.astype(jnp.int32)
     seg_q = jnp.broadcast_to(seg[:, :, None], (rows, seq, _STAT_W))
@@ -388,7 +527,15 @@ def _geometry(q, k, seg, block_q, block_k):
     return block_q, block_k, q.shape[1] // block_q, k.shape[1] // block_k, heads
 
 
-def _kernel_name(which, segmented):
+def _group(q, k):
+    """Query heads a key/value head: 1 unless ``k`` has fewer heads than ``q``."""
+    if q.shape[0] % k.shape[0]:
+        raise ValueError("flash attention: {} query heads do not divide into {} key/value heads".format(
+            q.shape[0], k.shape[0]))
+    return q.shape[0] // k.shape[0]
+
+
+def _kernel_name(which, segmented, rule="causal"):
     """Stable kernel names (``flash_fwd``, ``flash_bwd_dkv``; ``_seg`` when
     the segment fence is compiled in): the Mosaic custom call carries the
     name into the compiled HLO and the profiler trace, where ``chip_smoke.py``
@@ -396,6 +543,8 @@ def _kernel_name(which, segmented):
     kv-major pass that always bore ``flash_bwd_dkv``, now emitting dq too,
     and keeps that name: the benchmark's readers sum the flash kernels they
     find by name, and a new name would drop the whole backward from them."""
+    if rule == "block_diffusion":
+        return "flash_{}_bd".format(which)
     return "flash_{}{}".format(which, "_seg" if segmented else "")
 
 
@@ -405,12 +554,15 @@ _VMEM_DEFAULT = 16 * 2 ** 20
 _VMEM_MOST = 96 * 2 ** 20
 
 
-def _bwd_vmem_limit(length, width, dtype):
+def _bwd_vmem_limit(length, width, dtype, kv_rows=()):
     """The VMEM limit the backward sets: the default plus its dq row (the
     float32 accumulator and the output block's two buffers, lanes padded to
-    128). A row that would take it past :data:`_VMEM_MOST` is refused."""
-    lanes = -(-width // 128) * 128
-    row = length * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+    128) and, where query heads share key/value heads, the dk and dv rows
+    likewise (``kv_rows``: their ``(length, width)``). A row that would take
+    it past :data:`_VMEM_MOST` is refused."""
+    row = 0
+    for length, width in ((length, width),) + tuple(kv_rows):
+        row += length * (-(-width // 128) * 128) * (4 + 2 * jnp.dtype(dtype).itemsize)
     if _VMEM_DEFAULT + row > _VMEM_MOST:
         raise ValueError(
             "flash attention's backward keeps the dq of a whole row in VMEM: {} x {} takes {:.1f} MiB "
@@ -419,22 +571,25 @@ def _bwd_vmem_limit(length, width, dtype):
     return _VMEM_DEFAULT + row
 
 
-def _compiler_params(interpret, row_limit=None):
+def _compiler_params(interpret, row_limit=None, dims=2):
     """The batch·heads grid dim runs in any order; the list's dim carries
     the block accumulators (and the backward's dq row), so it is
-    'arbitrary'. ``row_limit`` is the backward's VMEM limit."""
+    'arbitrary', as is the grouped backward's dim over a group's query heads
+    (``dims`` 3). ``row_limit`` is the backward's VMEM limit."""
     if interpret:
         return None
-    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=row_limit)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) + ("arbitrary",) * (dims - 1), vmem_limit_bytes=row_limit)
 
 
 def _call(kernel, which, work, bh, in_specs, out_specs, out_shape, scratch_shapes,
-          operands, segmented, interpret, row_limit=None):
-    """One kernel over the grid ``(bh, the batch's longest list)``. The
-    interpreter is given the shape's bound instead (a static grid; every row
-    then parks for the rest), and nothing else differs."""
+          operands, segmented, interpret, row_limit=None, rule="causal", group=None):
+    """One kernel over the grid ``(bh, the batch's longest list)``, or
+    ``(bh, group, list)`` for the grouped backward. The interpreter is given
+    the shape's bound instead (a static grid; every row then parks for the
+    rest), and nothing else differs."""
     steps, items, longest = work
-    grid = (bh, steps if interpret else longest)
+    grid = (bh,) + (() if group is None else (group,)) + (steps if interpret else longest,)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -442,28 +597,29 @@ def _call(kernel, which, work, bh, in_specs, out_specs, out_shape, scratch_shape
             out_specs=out_specs, scratch_shapes=scratch_shapes,
         ),
         out_shape=out_shape,
-        compiler_params=_compiler_params(interpret, row_limit),
+        compiler_params=_compiler_params(interpret, row_limit, len(grid)),
         interpret=interpret,
-        name=_kernel_name(which, segmented),
+        name=_kernel_name(which, segmented, rule),
     )(items, *operands)
 
 
-def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret, rule="causal"):
     bh, l_q, d = q.shape
     d_v = v.shape[2]  # v and o may be narrower than q and k (latent attention: 192 / 128)
     block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
-    segmented = seg is not None
-    work = _work(seg, bh // heads, n_q, n_k, block_q, block_k, causal, backward=False)
+    segmented, group = seg is not None, _group(q, k)
+    work = _work(seg, bh // heads, n_q, n_k, block_q, block_k, causal, False, rule)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, segmented=segmented,
-        block_q=block_q, block_k=block_k, heads=heads, steps=work[0],
+        block_q=block_q, block_k=block_k, heads=heads, steps=work[0], rule=rule,
     )
-    at = _Specs(heads, work[0])
-    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True), at.rows(block_k, d_v, inner=True)]
+    at = _Specs(heads, work[0], group)
+    in_specs = [at.rows(block_q, d), at.rows(block_k, d, inner=True, shared=True),
+                at.rows(block_k, d_v, inner=True, shared=True)]
     operands = [q, k, v]
     if segmented:
         in_specs += [at.rows(block_q, _STAT_W, ids=True), at.seg_k(block_k, inner=True)]
-        operands += _seg_inputs(seg)
+        operands += _seg_inputs(seg, rule)
     o, lse = _call(
         kernel, "fwd", work, bh, in_specs,
         out_specs=[at.rows(block_q, d_v), at.rows(block_q, _STAT_W)],
@@ -476,30 +632,33 @@ def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        operands=operands, segmented=segmented, interpret=interpret,
+        operands=operands, segmented=segmented, interpret=interpret, rule=rule,
     )
     return o, lse
 
 
-def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interpret):
+def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interpret, rule="causal"):
     bh, l_q, d = q.shape
     l_k, d_v = k.shape[1], v.shape[2]
     block_q, block_k, n_q, n_k, heads = _geometry(q, k, seg, block_q, block_k)
-    segmented = seg is not None
-    row_limit = _bwd_vmem_limit(l_q, d, q.dtype)
-    work = _work(seg, bh // heads, n_q, n_k, block_q, block_k, causal, backward=True)
+    segmented, group = seg is not None, _group(q, k)
+    work = _work(seg, bh // heads, n_q, n_k, block_q, block_k, causal, True, rule)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[:, :, None], (bh, l_q, _STAT_W))
+    if group > 1:
+        return _flash_bwd_grouped(
+            q, k, v, seg, do, lse, delta, work, group, heads // group, scale, causal, block_q, block_k, interpret, rule)
+    row_limit = _bwd_vmem_limit(l_q, d, q.dtype)
     kernel = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, segmented=segmented,
-        block_q=block_q, block_k=block_k, heads=heads, steps=work[0],
+        block_q=block_q, block_k=block_k, heads=heads, steps=work[0], rule=rule,
     )
     at = _Specs(heads, work[0])  # kv outer, q inner
     in_specs = [at.rows(block_q, d, inner=True), at.rows(block_k, d), at.rows(block_k, d_v)]
     operands = [q, k, v]
     if segmented:
         in_specs += [at.rows(block_q, _STAT_W, inner=True, ids=True), at.seg_k(block_k)]
-        operands += _seg_inputs(seg)
+        operands += _seg_inputs(seg, rule)
     in_specs += [
         at.rows(block_q, d_v, inner=True),
         at.rows(block_q, _STAT_W, inner=True),
@@ -519,9 +678,45 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
             pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         operands=operands + [do, lse, delta], segmented=segmented, interpret=interpret,
-        row_limit=row_limit,
+        row_limit=row_limit, rule=rule,
     )
     return dq, dk, dv
+
+
+def _flash_bwd_grouped(q, k, v, seg, do, lse, delta, work, group, kv_heads, scale, causal, block_q, block_k,
+                       interpret, rule):
+    """The backward where ``group`` query heads share each of a batch row's
+    ``kv_heads`` key/value heads (the module's text)."""
+    (bh, l_q, d), (bkv, l_k, _), d_v = q.shape, k.shape, v.shape[2]
+    segmented = seg is not None
+    row_limit = _bwd_vmem_limit(l_q, d, q.dtype, kv_rows=((l_k, d), (l_k, d_v)))
+    kernel = functools.partial(
+        _bwd_kernel_grouped, scale=scale, causal=causal, segmented=segmented,
+        block_q=block_q, block_k=block_k, heads=kv_heads, steps=work[0], rule=rule,
+    )
+    at = _GroupSpecs(kv_heads, work[0], group)
+    in_specs = [at.q_rows(block_q, d), at.kv_rows(block_k, d), at.kv_rows(block_k, d_v)]
+    operands = [q, k, v]
+    if segmented:
+        in_specs += [at.ids_q(block_q), at.ids_k(block_k)]
+        operands += _seg_inputs(seg, rule)
+    in_specs += [at.q_rows(block_q, d_v), at.q_rows(block_q, _STAT_W), at.q_rows(block_q, _STAT_W)]
+    return _call(
+        kernel, "bwd_dkv", work, bkv, in_specs,
+        out_specs=[at.q_whole_row(l_q, d), at.kv_whole_row(l_k, d), at.kv_whole_row(l_k, d_v)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, l_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bkv, l_k, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, l_k, d_v), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((l_q, d), jnp.float32),
+            pltpu.VMEM((l_k, d), jnp.float32),
+            pltpu.VMEM((l_k, d_v), jnp.float32),
+        ],
+        operands=operands + [do, lse, delta], segmented=segmented, interpret=interpret,
+        row_limit=row_limit, rule=rule, group=group,
+    )
 
 
 def _heads_last(o, heads):
@@ -537,14 +732,14 @@ def _heads_first(o, heads):
     return o.reshape(batch, length, heads, d_v).transpose(0, 2, 1, 3).reshape(batch * heads, length, d_v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_attention_bhld(q, k, v, seg, heads, scale, causal, block_q, block_k, interpret):
-    o, _ = _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_attention_bhld(q, k, v, seg, heads, scale, causal, block_q, block_k, interpret, rule="causal"):
+    o, _ = _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret, rule)
     return o
 
 
-def _flash_attention_fwd(q, k, v, seg, heads, scale, causal, block_q, block_k, interpret):
-    o, lse = _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret)
+def _flash_attention_fwd(q, k, v, seg, heads, scale, causal, block_q, block_k, interpret, rule="causal"):
+    o, lse = _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret, rule)
     # the named values are the call's only results, out and residuals both, so
     # a recomputed pass that was told to keep them (REMAT_POLICY) has no use
     # for the call. o with its heads merged: d_v 64 pads to 128 lanes in the
@@ -554,11 +749,11 @@ def _flash_attention_fwd(q, k, v, seg, heads, scale, causal, block_q, block_k, i
     return o, (q, k, v, seg, o, lse)
 
 
-def _flash_attention_bwd(heads, scale, causal, block_q, block_k, interpret, res, do):
+def _flash_attention_bwd(heads, scale, causal, block_q, block_k, interpret, rule, res, do):
     q, k, v, seg, o, lse = res
     lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (_STAT_W,))
     dq, dk, dv = _flash_bwd(
-        q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interpret
+        q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interpret, rule
     )
     # integer segment ids carry no gradient (None = zero cotangent)
     return dq, dk, dv, None
@@ -569,11 +764,19 @@ _flash_attention_bhld.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 def flash_attention(
     q, k, v, causal=False, scale=None, segment_ids=None,
-    block_q=None, block_k=None, interpret=False,
+    block_q=None, block_k=None, interpret=False, rule="causal", labels=None,
 ):
     """Flash attention over ``[batch, heads, seq, head_dim]`` arrays. ``v``
     (and the output) may have a head size of its own: latent attention's
     queries and keys are 192 wide (128 + the rotary 64) against values of 128.
+    ``k`` and ``v`` may have fewer heads than ``q``, a divisor of its count:
+    query head ``h`` reads key/value head ``h // (heads / kv heads)``.
+
+    ``rule="block_diffusion"`` (static) masks by the block-diffusion rule
+    and wants ``segment_ids`` and ``labels`` (``int32 [batch, seq]``: ``2 *
+    block + half``, the block counted from the document's start, half 0 the
+    clean copy and 1 the noised; :mod:`~tensorflowonspark_tpu.ops.flash_blocks`
+    states the rule). Positions play no part in it, so ``causal`` must be off.
 
     Drop-in replacement for
     :func:`tensorflowonspark_tpu.parallel.ring_attention.plain_attention`
@@ -594,15 +797,21 @@ def flash_attention(
     b, h, l_q, d = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    merge = lambda t: t.reshape(b * h, t.shape[2], t.shape[3])  # noqa: E731
+    merge = lambda t: t.reshape(b * t.shape[1], t.shape[2], t.shape[3])  # noqa: E731
     segmented = segment_ids is not None
+    if rule not in flash_blocks.RULES:
+        raise ValueError("flash attention: unknown rule {!r}; expected one of {}".format(rule, flash_blocks.RULES))
+    if rule == "block_diffusion" and (causal or not segmented or labels is None):
+        raise ValueError("flash attention: rule 'block_diffusion' takes segment_ids and labels, and causal=False")
     if block_q is None:
         block_q = flash_blocks.SEGMENTED_BLOCK_Q if segmented else DEFAULT_BLOCK_Q
     if block_k is None:
         block_k = flash_blocks.SEGMENTED_BLOCK_K if segmented else DEFAULT_BLOCK_K
     seg = segment_ids.astype(jnp.int32) if segmented else None
+    if rule == "block_diffusion":
+        seg = jnp.stack(flash_blocks.bd_marks(seg, labels, xp=jnp), axis=1)  # [batch, 4, seq]
     o = _flash_attention_bhld(
         merge(q), merge(k), merge(v), seg, h, float(scale), bool(causal),
-        int(block_q), int(block_k), bool(interpret),
+        int(block_q), int(block_k), bool(interpret), rule,
     )
     return o.reshape(b, h, l_q, v.shape[3])

@@ -28,6 +28,27 @@ ascending, one packed int32 an item. The axis is as long as the batch's
 longest list, at most what the shape allows (:func:`work_bound`: the causal
 triangle, or the square without ``causal``; the tables' row stride), and
 steps past a row's own list park on its last item.
+
+**A second rule: block diffusion.** A row trained by diffusion over blocks
+holds every document twice, a clean copy and a noised one, and its mask is
+neither causal nor a window. A position carries its document's id and a label
+``2 * block + half`` (``block``: its diffusion block, counted from the
+document's start; ``half``: 0 clean, 1 noised). A query sees a key of its own
+document when both are noised and of one block, or when the key is clean and
+of a block before the query's (a noised query) or not after it (a clean one);
+no clean query sees a noised key, and padding sees and is seen by nothing.
+:func:`bd_marks` turns ids and labels into four int32 marks a position, under
+which that is two comparisons and an equality whatever the row's layout: a
+key's ``key`` (its document and block in one number, the noised copies moved
+up by :data:`BD_NOISED`), and a query's ``[lo, hi]`` (the clean keys it sees:
+from its document's first block to its own, less one where it is noised) and
+``own`` (the noised keys it sees). :func:`bd_blocks_needed` is the rule on
+block bounds, from each block's smallest and largest marks over its real
+positions; on rows whose two halves each hold the documents in order and
+fill whole blocks (the text plane's ``[clean ; noised]`` rows) it keeps
+exactly the blocks with a visible pair, and on any other marks never drops
+one. The lists' stride is then the square: which side of the diagonal a
+needed block lies on is the layout's business, not the rule's.
 """
 
 import numpy as np
@@ -106,11 +127,87 @@ def blocks_needed(bounds, block_q, block_k, causal=True, xp=np):
     return needed
 
 
-def needed_blocks(segment_ids, block_q, block_k, causal=True):
+def needed_blocks(segment_ids, block_q, block_k, causal=True, labels=None):
     """``bool [rows, L // block_q, L // block_k]``: True where the kernels
-    compute the block for ``segment_ids`` ``[rows, L]`` (numpy, on the host)."""
+    compute the block for ``segment_ids`` ``[rows, L]`` (numpy, on the host);
+    with ``labels``, by the block-diffusion rule."""
+    if labels is not None:
+        return bd_blocks_needed(bd_bounds(bd_marks(np.asarray(segment_ids), np.asarray(labels)), block_q, block_k))
     bounds = block_bounds(np.asarray(segment_ids), block_q, block_k)
     return blocks_needed(bounds, block_q, block_k, causal)
+
+
+#: the rules a call may mask and skip by: ``causal`` is the module's first
+#: (with or without ids, with or without the triangle), ``block_diffusion``
+#: the second
+RULES = ("causal", "block_diffusion")
+
+#: what a noised key's mark lies above its clean copy's, and how a mark packs
+#: document and block: ``id << BD_BLOCK_BITS | block``
+BD_BLOCK_BITS = 16
+BD_NOISED = np.int32(1 << 30)
+_INT32_MAX = np.int32(2 ** 31 - 1)
+
+
+def bd_marks(segment_ids, labels, xp=np):
+    """``(lo, hi, own, key)``, int32 ``[rows, L]`` each, of a block-diffusion
+    row: query ``i`` sees key ``j`` when ``lo[i] <= key[j] <= hi[i]`` or
+    ``key[j] == own[i]``. ``labels`` is ``2 * block + half``; a document has
+    at most ``2 ** 16`` blocks and a row at most ``2 ** 14 - 1`` documents.
+    Padding (id 0) gets an empty range, an ``own`` no key has and a ``key``
+    no query asks for."""
+    seg, labels = segment_ids.astype(xp.int32), labels.astype(xp.int32)
+    noised = labels & 1
+    first = seg << BD_BLOCK_BITS
+    at = first | (labels >> 1)
+    real = seg > 0
+    lo = xp.where(real, first, xp.int32(0))
+    hi = xp.where(real, at - noised, xp.int32(-1))
+    own = xp.where(real, at + noised * BD_NOISED, xp.int32(-2))
+    return lo, hi, own, xp.where(real, own, xp.int32(-3))
+
+
+def bd_mask(segment_ids, labels, xp=np):
+    """The rule written out, ``bool [rows, L, L]`` (query, key): what the
+    marks and the kernels are held to, and the mask of the paths that
+    materialise one (``plain`` attention, small rows only)."""
+    seg, labels = segment_ids.astype(xp.int32), labels.astype(xp.int32)
+    block, noised = labels >> 1, labels & 1
+    q = lambda t: t[:, :, None]  # noqa: E731
+    k = lambda t: t[:, None, :]  # noqa: E731
+    same = (q(seg) == k(seg)) & (q(seg) > 0)
+    among_noised = (q(noised) == 1) & (k(noised) == 1) & (q(block) == k(block))
+    clean_before = (k(noised) == 0) & (k(block) <= q(block) - q(noised))
+    return same & (among_noised | clean_before)
+
+
+def bd_bounds(marks, block_q, block_k, xp=np):
+    """The six tables :func:`bd_blocks_needed` compares: per q block the
+    smallest ``lo``, largest ``hi`` and the ``[smallest, largest]`` ``own``,
+    per kv block the ``[smallest, largest]`` ``key``, over real positions
+    alone (a block of padding gets an interval nothing overlaps)."""
+    lo, hi, own, key = marks
+    rows, seq = key.shape
+    real_q = (own >= 0).reshape(rows, seq // block_q, block_q)
+    real_k = (key >= 0).reshape(rows, seq // block_k, block_k)
+
+    def least(x, real, block):
+        return xp.where(real, x.reshape(rows, seq // block, block), _INT32_MAX).min(-1)
+
+    def most(x, real, block):
+        return xp.where(real, x.reshape(rows, seq // block, block), xp.int32(-4)).max(-1)
+
+    return (least(lo, real_q, block_q), most(hi, real_q, block_q), least(own, real_q, block_q),
+            most(own, real_q, block_q), least(key, real_k, block_k), most(key, real_k, block_k))
+
+
+def bd_blocks_needed(bounds):
+    """The block-diffusion rule on :func:`bd_bounds`' tables: ``bool [rows,
+    n_q, n_k]``, True where a kv block's keys overlap the clean keys some
+    query of the q block sees, or the noised ones."""
+    lo, hi, own_min, own_max = (t[:, :, None] for t in bounds[:4])
+    key_min, key_max = (t[:, None, :] for t in bounds[4:])
+    return ((key_min <= hi) & (key_max >= lo)) | ((key_min <= own_max) & (key_max >= own_min))
 
 
 #: an item of a work list, one int32: the outer block from bit 17, the inner
@@ -119,8 +216,6 @@ ITEM_INNER_SHIFT, ITEM_OUTER_SHIFT = 3, 17
 ITEM_COMPUTE, ITEM_FIRST, ITEM_LAST = 1, 2, 4
 #: the most blocks along one axis that an item can name
 ITEM_BLOCKS_MOST = 1 << (ITEM_OUTER_SHIFT - ITEM_INNER_SHIFT)
-
-_INT32_MAX = np.int32(2 ** 31 - 1)
 
 
 def dense_blocks(n_q, n_k, block_q, block_k, causal=True):
@@ -170,23 +265,27 @@ def work_list(needed, steps, xp=np):
     return xp.where(at < lengths[:, None], items, parked), lengths
 
 
-def attended_blocks(segment_ids):
+def attended_blocks(segment_ids, labels=None):
     """``(needed, dense, steps)`` counts of one packed batch as the segmented
     kernels see it: rows padded to :data:`GRANULE`, the block sizes the
-    kernels pick for that length, causal. ``needed`` blocks are computed;
-    ``dense`` is the causal triangle, a row's :func:`work_bound`; ``steps``
-    are the grid steps a kernel takes a head: every row walks as many as the
-    batch's longest :func:`work_list` has items, and a row with fewer parks
-    for the rest."""
+    kernels pick for that length, causal; with ``labels``, the rows as a
+    block-diffusion model reads them (both copies) under that rule.
+    ``needed`` blocks are computed; ``dense`` is the row's causal triangle
+    (under either rule: what a kernel that knew only the triangle would
+    walk); ``steps`` are the grid steps a kernel takes a head: every row
+    walks as many as the batch's longest :func:`work_list` has items, and a
+    row with fewer parks for the rest."""
     seg = np.asarray(segment_ids)
     if not seg.size:
         return 0, 0, 0
     pad = (-seg.shape[1]) % GRANULE
     if pad:
         seg = np.pad(seg, ((0, 0), (0, pad)))
+        labels = None if labels is None else np.pad(np.asarray(labels), ((0, 0), (0, pad)))
     block_q = pick_block(seg.shape[1], SEGMENTED_BLOCK_Q)
     block_k = pick_block(seg.shape[1], SEGMENTED_BLOCK_K)
-    needed = needed_blocks(seg, block_q, block_k)
-    dense = work_bound(causal_blocks(needed.shape[1], needed.shape[2], block_q, block_k))
-    _, lengths = work_list(needed, dense)
+    needed = needed_blocks(seg, block_q, block_k, labels=labels)
+    n_q, n_k = needed.shape[1:]
+    dense = work_bound(causal_blocks(n_q, n_k, block_q, block_k))
+    _, lengths = work_list(needed, work_bound(dense_blocks(n_q, n_k, block_q, block_k, causal=labels is None)))
     return int(needed.sum()), dense * seg.shape[0], int(lengths.max()) * seg.shape[0]

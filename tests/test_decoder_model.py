@@ -160,7 +160,9 @@ def test_default_plan_and_its_checks():
     with pytest.raises(ValueError, match="unknown configuration keys"):
         decoder.DecoderConfig.from_dict(program_config(REF, capacity_factor=1.25))
     with pytest.raises(ValueError, match="scoring_func"):
-        decoder.DecoderConfig.from_dict(program_config(REF, scoring_func="softmax"))
+        decoder.DecoderConfig.from_dict(program_config(REF, scoring_func="tanh"))
+    with pytest.raises(ValueError, match="scoring_func"):  # sigmoid scores go with noaux_tc, softmax with neither
+        decoder.DecoderConfig.from_dict(program_config(REF, scoring_func="softmax", topk_method="noaux_tc"))
     with pytest.raises(ValueError, match="unknown layer kinds"):
         decoder.DecoderConfig.from_dict(program_config(REF, layer_plan=[["mha", "moe", "mhc"]] * 3)).plan
     # one stream, sequential residual, routed from the first layer: a plan of its own

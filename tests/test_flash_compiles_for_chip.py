@@ -147,6 +147,33 @@ def test_longest_row_the_lists_allow_compiles(one_chip, no_compile_cache):
     assert _walks_a_list_of(text, 12880)
 
 
+def test_block_diffusion_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache):
+    """``sdar-30b-a3b.bd4-packed4k``: two rows of 8192 positions (a clean and a
+    noised copy of 4096), 32 query heads over 4 key/value heads of 128, under
+    the block-diffusion rule. What only the chip's compiler can say: the
+    grouped backward's three-dimensional grid, its whole-row dk and dv scratch
+    beside the dq row (40 MiB of VMEM under the limit the call sets), the
+    marks riding where the ids ride, and the key/value operands at 4 heads:
+    nothing repeated in HBM."""
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 4, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, seg, labels):
+        o = flash_attention(q, k, v, segment_ids=seg, labels=labels, rule="block_diffusion")
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2))).lower(q, kv, kv, ids, ids).compile().as_text()
+    assert _kernels(text) == ["flash_bwd_dkv_bd", "flash_fwd_bd"]
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    # one table of 2 rows x the square of 16 blocks, then q at 32 heads and k, v at 4
+    opening = "operand_layout_constraints={s32[], s32[512]{0}, bf16[64,8192,128]{2,1,0}, bf16[8,8192,128]{2,1,0}, bf16[8,8192,128]{2,1,0}, s32[2,8192,8]"
+    assert len(calls) == 2 and all(opening in line for line in calls)
+    backward = next(line for line in calls if "flash_bwd_dkv_bd" in line)
+    # dq at the 32 query heads, dk and dv at the 4 key/value heads: summed over the group inside the kernel
+    assert "(bf16[64,8192,128]{2,1,0:T(8,128)(2,1)}, bf16[8,8192,128]{2,1,0:T(8,128)(2,1)}, bf16[8,8192,128]{2,1,0:T(8,128)(2,1)})" in backward
+
+
 def _model_step(one_chip, model, rows, seq):
     """``(compiled loss-and-gradient of ``model`` on packed rows, its compiler's text)``,
     under the scope the train step gives it."""
