@@ -73,9 +73,12 @@ Device scopes (``jax.named_scope``, in every operation's ``op_name``):
 ``tos.moe_experts`` (the grouped products), ``tos.moe_shared``,
 ``tos.dense_mlp``, ``tos.mhc``. What the routed layers count in a step is
 sown into the ``counters`` collection (``moe_slots_routed``,
-``moe_slots_held``) and the ``gauges`` collection
-(``moe_expert_load_max_over_mean``), and registered where it is sown
-(``moe_slots_routed_total``, ``moe_slots_held_total``, the gauge);
+``moe_slots_held``; where a chip holds under half the experts also
+``moe_layers_compact`` and ``moe_layers_at_bound``: the layers that ran on
+the compact slot buffer and those that fell back to the whole one) and the
+``gauges`` collection (``moe_expert_load_max_over_mean``), and registered
+where it is sown (``moe_slots_routed_total``, ``moe_slots_held_total``,
+``moe_layers_compact_total``, ``moe_layers_at_bound_total``, the gauge);
 ``make_loss_fn`` carries both out in the step's metrics and
 :class:`~tensorflowonspark_tpu.train.TrainStep` books them by name.
 """
@@ -390,7 +393,19 @@ class RoutedExperts(nn.Module):
     layer has no such parameter). Weights: ``s`` at the chosen, over their
     sum, times ``routed_scaling_factor``. Every slot whose expert is held here is
     computed — no capacity, nothing dropped; a slot whose expert lives on
-    another chip adds nothing here (nor is anything put in its place)."""
+    another chip adds nothing here (nor is anything put in its place).
+
+    The slots are sorted by held expert and the experts run over the head of
+    that order (:func:`_experts_on_rows`). A chip that holds all the experts
+    runs it on all ``T * k`` rows. One that holds a share runs it on
+    ``gm.compact_rows`` of them, twice the even share, **when the device's own
+    count of the held slots says they fit, and on all ``T * k`` slots in any
+    step when they do not** (``gm.either``: a ``lax.cond``, both branches
+    compiled; the fallback is this same function on a share of the tokens at
+    a time, all their slots, and gives what one pass over ``T * k`` rows
+    gives): that fallback, not a bound on the routing, is what "nothing
+    dropped" rests on. ``counts`` then also says which of the two ran
+    (``layers_compact`` / ``layers_at_bound``, one of them 1)."""
 
     cfg: DecoderConfig
 
@@ -402,7 +417,7 @@ class RoutedExperts(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        cfg, dt = self.cfg, self.cfg.compute_dtype
+        cfg = self.cfg
         batch, length, d = x.shape
         tokens, k = batch * length, cfg.num_experts_per_tok
         first, held = cfg.held
@@ -423,32 +438,57 @@ class RoutedExperts(nn.Module):
             weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
             order, group_sizes = gm.sort_slots(chosen.reshape(-1), first, held)
             place, rows_used = gm.slot_places(order), jnp.sum(group_sizes)
-            sorted_in = gm.rows_to_slots(flat, order, place, k)  # [T * k, d]
-            sorted_weights = weights.reshape(-1)[order]
 
-        with jax.named_scope("tos.moe_experts"):
-            init = _kernel_init(batch_axis=(0,))
-            gate = self.param("experts_gate", init, (held, d, width), jnp.float32)
-            up = self.param("experts_up", init, (held, d, width), jnp.float32)
-            down = self.param("experts_down", init, (held, width, d), jnp.float32)
-            hidden = nn.silu(gm.grouped_matmul(sorted_in, gate.astype(dt), group_sizes)) * gm.grouped_matmul(
-                sorted_in, up.astype(dt), group_sizes)
-            sorted_out = gm.grouped_matmul(hidden, down.astype(dt), group_sizes)  # [T * k, d]
-
-        with jax.named_scope("tos.moe_route"):
-            # weighted where it lies, back to slot order (a slot not held
-            # here finds a zero row), then each token's k slots summed
-            weighted = (sorted_out.astype(jnp.float32) * sorted_weights[:, None]).astype(dt)
-            per_slot = gm.slots_to_order(weighted, order, place).reshape(tokens, k, d)
-            routed = jnp.sum(per_slot, axis=1, dtype=jnp.float32).astype(dt)
+        init = _kernel_init(batch_axis=(0,))
+        gate = self.param("experts_gate", init, (held, d, width), jnp.float32)
+        up = self.param("experts_up", init, (held, d, width), jnp.float32)
+        down = self.param("experts_down", init, (held, width, d), jnp.float32)
+        per_token, shared, indices = (flat, weights), (gate, up, down), (order, place, group_sizes)
+        slots = tokens * k
+        counts = {
+            "slots_routed": jnp.float32(slots), "slots_held": rows_used.astype(jnp.float32),
+            "load_max_over_mean": jnp.max(group_sizes) * held / jnp.maximum(rows_used, 1).astype(jnp.float32),
+        }
+        compact = gm.compact_rows(slots, held, cfg.n_routed_experts)
+        if compact == slots:
+            routed = _experts_on_rows(slots, *per_token, *shared, *indices)
+        else:
+            fits = rows_used <= compact
+            routed = gm.either(_experts_on_rows, compact, fits, per_token, shared, *indices)
+            counts.update(layers_compact=fits.astype(jnp.float32), layers_at_bound=1.0 - fits)
 
         with jax.named_scope("tos.moe_shared"):
             shared = SwiGLU(cfg, width * cfg.n_shared_experts, name="shared")(flat) if cfg.n_shared_experts else 0
-        counts = {
-            "slots_routed": jnp.float32(tokens * k), "slots_held": rows_used.astype(jnp.float32),
-            "load_max_over_mean": jnp.max(group_sizes) * held / jnp.maximum(rows_used, 1).astype(jnp.float32),
-        }
         return (routed + shared).reshape(batch, length, d), counts
+
+
+@functools.partial(jax.jit, static_argnums=0, inline=True)
+def _experts_on_rows(rows, flat, weights, gate, up, down, order, place, group_sizes):
+    """The held experts over the first ``rows`` of the sorted slots, weighed
+    and summed into their tokens: ``[T, d]``. ``flat`` ``[T, d]``, ``weights``
+    ``float32 [T, k]``, the experts' float32 matrices, and
+    :func:`~tensorflowonspark_tpu.ops.grouped_matmul.sort_slots`' ``order``,
+    its inverse and the group sizes. Right for any ``rows`` that holds every
+    held slot (``sum(group_sizes) <= rows``); its scopes open in here, so
+    that a ``cond`` round it carries none. ``jax.jit(inline=True)``: traced
+    once for all the layers, branches and passes of a step that call it at
+    one length, and that trace spliced in at each (traced anew at each, a
+    warm start of ``sdar-30b-a3b`` took 8 s longer; a call that XLA inlines
+    would hand its name to the grouped products' kernels: PERF.md §6, PR 35)."""
+    dt, k = flat.dtype, weights.shape[1]
+    with jax.named_scope("tos.moe_route"):
+        head = order[:rows]
+        sorted_in = gm.rows_to_slots(flat, head, place, k)  # [rows, d]
+        sorted_weights = weights.reshape(-1)[head]
+    with jax.named_scope("tos.moe_experts"):
+        hidden = nn.silu(gm.grouped_matmul(sorted_in, gate.astype(dt), group_sizes)) * gm.grouped_matmul(
+            sorted_in, up.astype(dt), group_sizes)
+        sorted_out = gm.grouped_matmul(hidden, down.astype(dt), group_sizes)  # [rows, d]
+    with jax.named_scope("tos.moe_route"):
+        # weighted where it lies, then each token's k slots found by their
+        # places and summed (a slot not among the rows finds a zero row)
+        weighted = (sorted_out.astype(jnp.float32) * sorted_weights[:, None]).astype(dt)
+        return gm.slots_to_tokens(weighted, head, place, k)
 
 
 def _kernel_init(batch_axis=()):
@@ -650,6 +690,15 @@ class Decoder(nn.Module):
             self.sow("counters", "moe_slots_held", sum(c["slots_held"] for c in counted))
             self.sow("gauges", "moe_expert_load_max_over_mean",
                      sum(c["load_max_over_mean"] for c in counted) / len(counted))
+        if any("layers_compact" in c for c in counted):
+            obs.counter(
+                "moe_layers_compact_total",
+                help="routed layers of a step whose held slots fitted the compact slot buffer (twice the even share)")
+            obs.counter(
+                "moe_layers_at_bound_total",
+                help="routed layers of a step that fell back to the whole slot buffer (tokens x experts per token rows)")
+            for name in ("layers_compact", "layers_at_bound"):
+                self.sow("counters", "moe_" + name, sum(c.get(name, 0.0) for c in counted))
         summed = sum(jnp.split(streams[:, head_from:].astype(jnp.float32), cfg.hc_mult, axis=-1))
         x = _norm(cfg, "ln_f")(summed.astype(cfg.compute_dtype))
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.compute_dtype, name="lm_head")(x)

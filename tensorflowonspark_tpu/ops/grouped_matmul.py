@@ -1,13 +1,25 @@
 """Grouped matrix products for routed experts: every expert held here applied
 to the token slots routed to it, nothing dropped, static shapes.
 
-A routed layer sends each of ``T`` tokens to ``k`` experts: ``T * k``
+A routed layer sends each of ``T`` tokens to ``k`` experts: ``S = T * k``
 *slots*. Of the experts a chip holds only some; :func:`sort_slots` orders the
 slots so that those of the first held expert come first, then the second's,
-…, and the slots of experts held elsewhere last. The sorted buffer always has
-``T * k`` rows — the bound when every token picks held experts only — but
-the grouped product (:func:`grouped_matmul`) visits only the row tiles its
-groups cover, so its time follows the slots that came, not the bound.
+…, and the slots of experts held elsewhere last. Only the head of that order
+is work: the *slot buffer* is its first ``R`` rows (:func:`rows_to_slots`,
+the products, :func:`slots_to_tokens`), and everything ``d`` or ``width``
+wide between the sort and the sum over ``k`` is ``R`` rows long. ``R = S`` is
+the bound (every token picked held experts only) and always right; a chip
+that holds ``held`` of ``E`` experts gets about ``S * held / E`` slots, so
+the layer runs on :func:`compact_rows` ``= C`` rows, twice the even share,
+and on all ``S`` slots in any step whose held slots do not fit in ``C``
+(:func:`either`: a ``lax.cond`` on the device's own count; the fallback takes
+a share of the tokens at a time, so it too holds ``C`` rows at most, and
+gives what one pass over ``S`` rows gives): no capacity, nothing dropped,
+whatever the routing. What stays ``S`` long: the int32 /
+float32 vectors (the sort, ``place``, the weights). The way back to token
+order (:func:`slots_to_tokens` forward, :func:`rows_to_slots` backward)
+reads the buffer through ``place``, a zero row behind it for the slots that
+are not in it, as ``k`` gathers of ``[T, d]``.
 
 The product is ``jax.lax.ragged_dot``: on a TPU, XLA lowers it to its own
 Mosaic kernels (a metadata pass over the group sizes and a tiled product
@@ -46,6 +58,20 @@ def sort_slots(expert_of_slot, first, held):
     return order, group_sizes
 
 
+#: a compact slot buffer's length is a multiple of this many rows
+ROW_TILE = 512
+#: the device scope of sorting the slots (``models/decoder.py``'s, where the fallback sorts again)
+SORT_SCOPE = "tos.moe_route"
+
+
+def compact_rows(slots, held, experts):
+    """The slot buffer's length ``C`` for a chip that holds ``held`` of the
+    router's ``experts``: twice the even share of the ``slots``, a multiple
+    of :data:`ROW_TILE`, at most ``slots`` (all of them where all the experts
+    are held, or the share is a half or more)."""
+    return min(slots, -(-2 * slots * held // (experts * ROW_TILE)) * ROW_TILE)
+
+
 def slot_places(order):
     """The inverse of :func:`sort_slots`' ``order``: where each slot sits in
     the sorted buffer."""
@@ -57,51 +83,161 @@ def _rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
+def _sum_over_slots(buffer, place, k):
+    """``float32 [T, d]``: each token's ``k`` slots found in ``buffer`` (``[R,
+    d]``: the head of the sorted order) by their ``place`` there and summed
+    in slot order; a slot that is not in the buffer (its place is past the
+    end) adds a zero row. From the
+    whole buffer (``R = T * k``) that is one gather back to slot order and a
+    sum over ``[T, k, d]``; from a shorter one, ``k`` gathers of ``[T, d]``
+    added up, so that nothing is ``T * k`` rows long (read on the chip against
+    one gather of ``[T * k, d]`` and against a scatter-add of the ``R`` rows:
+    PERF.md §6, PR 35)."""
+    rows = buffer.shape[0]
+    if rows == place.shape[0]:
+        per_slot = _rows(buffer, place)
+        return jnp.sum(per_slot.reshape(-1, k, per_slot.shape[-1]), axis=1, dtype=jnp.float32)
+    padded = jnp.concatenate([buffer, jnp.zeros((1,) + buffer.shape[1:], buffer.dtype)])
+    at = jnp.minimum(place, rows).reshape(-1, k)
+    total = _rows(padded, at[:, 0]).astype(jnp.float32)
+    for j in range(1, k):
+        total = total + _rows(padded, at[:, j]).astype(jnp.float32)
+    return total
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def rows_to_slots(rows, order, place, k):
-    """``[T, d]`` token rows to the sorted slot buffer ``[T * k, d]``: sorted
-    slot ``i`` is slot ``order[i]``, which belongs to token ``order[i] //
-    k``. The gradient comes back by the inverse permutation (``place``) and a
-    sum over each token's ``k`` slots — a gather, where the gather's own
+def rows_to_slots(rows, head, place, k):
+    """``[T, d]`` token rows to the head of the sorted slot buffer, ``[R,
+    d]``: sorted slot ``i`` is slot ``head[i]`` (``head = order[:R]``), which
+    belongs to token ``head[i] // k``. The gradient comes back by the inverse
+    permutation (``place``, ``int32 [T * k]``) and a sum over each token's
+    ``k`` slots (:func:`_sum_over_slots`) — gathers, where the gather's own
     transpose would be a scatter-add over repeated rows."""
-    return _rows(rows, order // k)
+    return _rows(rows, head // k)
 
 
-def _rows_to_slots_fwd(rows, order, place, k):
-    return _rows(rows, order // k), (order, place)
+def _rows_to_slots_fwd(rows, head, place, k):
+    return _rows(rows, head // k), (place,)
 
 
 def _rows_to_slots_bwd(k, res, d_sorted):
-    _order, place = res
-    per_slot = _rows(d_sorted, place)
-    d_rows = jnp.sum(per_slot.reshape(-1, k, per_slot.shape[-1]), axis=1, dtype=jnp.float32)
-    return d_rows.astype(d_sorted.dtype), None, None
+    return _sum_over_slots(d_sorted, res[0], k).astype(d_sorted.dtype), None, None
 
 
 rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
 
 
-@jax.custom_vjp
-def slots_to_order(sorted_rows, order, place):
-    """The sorted buffer back in slot order (``[T * k, d]``, slot ``t * k +
-    j`` the ``j``-th choice of token ``t``); the gradient returns by
-    ``order``, again a gather."""
-    return _rows(sorted_rows, place)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def slots_to_tokens(sorted_rows, head, place, k):
+    """The head of the sorted buffer (``[R, d]``) summed into its tokens,
+    ``[T, d]`` (:func:`_sum_over_slots`, then back to the buffer's type). The
+    gradient is ``R`` rows gathered from ``[T, d]`` by ``head // k`` (no ``[T,
+    k, d]`` broadcast)."""
+    return _sum_over_slots(sorted_rows, place, k).astype(sorted_rows.dtype)
 
 
-def _slots_to_order_fwd(sorted_rows, order, place):
-    return _rows(sorted_rows, place), (order,)
+def _slots_to_tokens_fwd(sorted_rows, head, place, k):
+    return slots_to_tokens(sorted_rows, head, place, k), (head,)
 
 
-def _slots_to_order_bwd(res, d_rows):
-    return _rows(d_rows, res[0]), None, None
+def _slots_to_tokens_bwd(k, res, d_tokens):
+    return _rows(d_tokens, res[0] // k), None, None
 
 
-slots_to_order.defvjp(_slots_to_order_fwd, _slots_to_order_bwd)
+slots_to_tokens.defvjp(_slots_to_tokens_fwd, _slots_to_tokens_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def either(fn, compact, fits, per_token, shared, order, place, group_sizes):
+    """``fn(compact, *per_token, *shared, order, place, group_sizes)``, the
+    layer on the first ``compact`` rows of the sorted order, where ``fits`` (a
+    traced bool: every held slot is among them), and else the layer on all
+    the slots (:func:`_on_every_slot`): every held slot computed whatever the
+    routing. One ``jax.lax.cond``, differentiable in ``per_token`` (the
+    arguments with a row a token, ``[T, ...]``) and ``shared`` (the others:
+    the experts' matrices).
+
+    JAX's own rule for a differentiated ``cond`` has every branch write, as
+    zeros, whatever the other branch keeps for its backward pass, and hand
+    what it keeps itself through the ``conditional``'s result, fusions cut
+    there. Here a forward pass keeps nothing but its arguments, and the
+    backward pass is a second ``cond`` whose branches each run forward again
+    and then backward. Inside a recomputed layer that is the layer's
+    recomputed forward pass, which then need not run unless something else
+    reads the result; its operations carry the backward pass's ``op_name``
+    (a ``jax.checkpoint`` round the branch would name them apart, and costs
+    the step's tracing 1 to 1.5 s)."""
+    return _cond(fn, compact, fits, per_token, shared, order, place, group_sizes)
+
+
+def _cond(fn, compact, fits, per_token, shared, order, place, group_sizes, *d_out):
+    """The ``cond``; with ``d_out``, the result's cotangent, the backward
+    pass. Each branch sits under a scope of its name: the device trace says
+    which ran, and ``jax.vjp`` wraps the outermost scope it meets into
+    ``jvp(...)``, which must not be one that a reader looks for."""
+    def compact_rows(per_token, shared):
+        return fn(compact, *per_token, *shared, order, place, group_sizes)
+
+    def every_slot(per_token, shared):
+        return _on_every_slot(fn, compact, per_token, shared, order, place, group_sizes)
+
+    def branch(run):
+        scoped = jax.named_scope(run.__name__)(run)
+        if not d_out:
+            return lambda: scoped(per_token, shared)
+        return lambda: jax.vjp(scoped, per_token, shared)[1](*d_out)
+
+    return jax.lax.cond(fits, branch(compact_rows), branch(every_slot))
+
+
+def _either_fwd(fn, compact, fits, per_token, shared, order, place, group_sizes):
+    kept = (fits, per_token, shared, order, place, group_sizes)
+    return _cond(fn, compact, *kept), kept
+
+
+def _either_bwd(fn, compact, kept, d_out):
+    return (None,) + _cond(fn, compact, *kept, d_out) + (None, None, None)
+
+
+either.defvjp(_either_fwd, _either_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _on_every_slot(fn, compact, per_token, shared, order, place, group_sizes):
+    """The fallback: ``fn`` on all ``S`` slots, there to be right, not fast,
+    and never to set the step's peak memory (a ``cond``'s branches share no
+    temporaries: the peak is the larger branch's). The tokens are independent
+    of each other, so it is ``fn`` on a share of the tokens at a time, as many
+    shares as make a share's ``T / n * k`` slots fit in ``compact`` rows: each
+    share's slots are sorted for themselves (their held experts read back
+    from ``place`` and the group sizes) and every share is the whole layer on
+    its tokens, so the result and the per-token gradients are those of one
+    pass over ``S`` rows, the same sums in the same order, and the experts'
+    gradients are float32 sums over the shares (each share's rounded to the
+    products' type first: on the chip a bfloat16 step from one pass's, PERF.md
+    §6, PR 35). Nothing is kept a share: the backward pass runs each again."""
+    tokens, slots = per_token[0].shape[0], order.shape[0]
+    shares = next(n for n in range(-(-slots // compact), tokens + 1) if tokens % n == 0)
+    held = group_sizes.shape[0]
+    with jax.named_scope(SORT_SCOPE):
+        # a slot's expert among the held ones (``held``: an expert held elsewhere): the groups its place lies behind
+        local = jnp.searchsorted(jnp.cumsum(group_sizes), place, side="right").astype(jnp.int32)
+
+    @jax.checkpoint
+    def one_share(shared, rows):
+        *per_token, local = rows
+        with jax.named_scope(SORT_SCOPE):
+            order, sizes = sort_slots(local, 0, held)
+            place = slot_places(order)
+        return fn(local.shape[0], *per_token, *shared, order, place, sizes)
+
+    split = lambda a: a.reshape((shares, -1) + a.shape[1:])  # noqa: E731
+    _, out = jax.lax.scan(lambda _, rows: (None, one_share(shared, rows)), None, (*map(split, per_token), split(local)))
+    return out.reshape((tokens,) + out.shape[2:])
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
-    """``lhs[rows of group g] @ rhs[g]`` for every group: ``lhs`` ``[S, K]``
+    """``lhs[rows of group g] @ rhs[g]`` for every group: ``lhs`` ``[R, K]``
     sorted by group, ``rhs`` ``[G, K, N]``, ``group_sizes`` ``int32 [G]``.
     Rows past the last group come back as zeros and pass no gradient (the
     kernels neither read nor write them, so both sides are fenced here)."""

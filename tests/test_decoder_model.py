@@ -266,5 +266,5 @@ def test_grouped_matmul_fences_the_rows_past_its_groups():
     rows = jax.random.normal(jax.random.PRNGKey(15), (8, 8), jnp.float32)
     d_rows = jax.grad(lambda r: jnp.sum(gm.rows_to_slots(r, order, place, 2) * lhs))(rows)
     close(d_rows, jax.grad(lambda r: jnp.sum(r[order // 2] * lhs))(rows))
-    d_sorted = jax.grad(lambda s: jnp.sum(gm.slots_to_order(s, order, place) * lhs))(lhs)
-    close(d_sorted, jax.grad(lambda s: jnp.sum(s[place] * lhs))(lhs))
+    d_sorted = jax.grad(lambda s: jnp.sum(gm.slots_to_tokens(s, order, place, 2) * rows))(lhs)
+    close(d_sorted, jax.grad(lambda s: jnp.sum(s[place].reshape(8, 2, 8).sum(1) * rows))(lhs))

@@ -285,6 +285,100 @@ def test_hyper_connection_kernels_compile_at_the_cells_shape(one_chip, no_compil
     assert "bf16[{},{}]".format(tokens, n * d) in text
 
 
+def _computations(text):
+    """``{name: [lines]}`` of a compiled module's computations."""
+    found, lines = {}, None
+    for line in text.splitlines():
+        opened = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->.*\{\s*$", line)
+        if opened:
+            lines = found.setdefault(opened.group(1), [])
+        elif lines is not None:
+            lines.append(line)
+    return found
+
+
+def _reached(computations, name, seen):
+    """The lines of ``name`` and of every computation it calls; what a line
+    inside a fusion defines is never written to memory, so those are left out."""
+    if name in seen or name not in computations or name.startswith("fused_computation"):
+        return []
+    seen.add(name)
+    lines = list(computations[name])
+    for line in computations[name]:
+        for callee in re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line):
+            lines += _reached(computations, callee, seen)
+    return lines
+
+
+def test_routed_layer_compiles_a_compact_branch_at_the_cells_shape(one_chip, no_compile_cache):
+    """One routed layer of ``sdar-30b-a3b.bd4-packed4k`` (2 x 8192 positions,
+    top-8 of 128, 16 held: 131,072 slots, a compact buffer of 32,768),
+    forward and backward under ``jax.checkpoint``. Three conditionals (the
+    forward pass, the recomputed one because this loss reads the layer's
+    result again, the backward one); in each, the branch for the compact
+    buffer holds no array of the bound's length as wide as the model or an
+    expert (the way back to token order is ``k`` gathers of ``[T, d]``), and
+    nor does the other, the fallback, which runs a quarter of the tokens at a
+    time. In both branches the grouped products keep the bare name the
+    readers find them by and every other kernel call sits under one of the
+    mechanisms' scopes (the fallback's second sort too); the operations fall into the phases they fell into
+    (the forward pass that the backward branch runs again is booked with it);
+    and the ``conditional`` instructions carry none of the mechanisms'
+    scopes, so that a reader which sums events by scope counts a branch's
+    operations once."""
+    import json
+
+    from benchmarks.families import bd_lm
+    from benchmarks.layer_metrics import _moe
+    from tensorflowonspark_tpu.models import decoder
+
+    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", "sdar-30b-a3b.json")) as f:
+        cfg = decoder.DecoderConfig.from_dict(bd_lm.model_config(json.load(f), remat=True))
+    rows, seq, d, width = 2, 8192, cfg.hidden_size, cfg.moe_intermediate_size
+    slots = rows * seq * cfg.num_experts_per_tok
+    layer = decoder.RoutedExperts(cfg)
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)  # noqa: E731
+    x = jax.ShapeDtypeStruct((rows, seq, d), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 128, d), jnp.bfloat16))["params"]))
+
+    def loss(p, x):
+        return jnp.sum(layer.apply({"params": p}, x)[0].astype(jnp.float32) ** 2)
+
+    def step(p, x):
+        with jax.named_scope("tos.loss_and_grad"):
+            return jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1))(p, x)
+
+    computations = _computations(jax.jit(step).lower(params, x).compile().as_text())
+    conditionals = [line for lines in computations.values() for line in lines if " conditional(" in line]
+    assert len(conditionals) == 3
+    long_and_wide = re.compile(r"= \(?(?:bf16|f32|pred)\[{},({}|{})\]".format(slots, d, width))
+    phases = []
+    for line in conditionals:
+        scope = re.search(r'op_name="([^"]*)"', line)
+        assert scope is None or "tos.moe_" not in scope.group(1)
+        every_slot, compact = (name.strip().lstrip("%") for name in re.search(
+            r"branch_computations=\{([^}]*)\}", line).group(1).split(","))  # cond(fits, compact, fallback): true is last
+        booked = []
+        for branch, scope in ((compact, "compact_rows"), (every_slot, "every_slot")):
+            inside = [ln for ln in _reached(computations, branch, set()) if " parameter(" not in ln]
+            assert not [ln for ln in inside if long_and_wide.search(ln)]
+            assert any(" while(" in ln for ln in inside) == (branch == every_slot)
+            named = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in inside
+                     if ("custom-call(" in ln or "kind=kCustom" in ln) and "op_name" in ln]
+            products = [name for name in named if _moe.is_grouped_product(name)]
+            assert products and set(products) == {"ragged-dot-none", "ragged-dot-metadata"}
+            scoped = [name for name in named if _moe.in_scope(name, "tos.moe_route") or _moe.in_scope(name, "tos.moe_experts")]
+            # every kernel call is a product's or under a scope (the fallback's loop fills a result it first zeroes)
+            rest = [name for name in named if name not in products and name not in scoped]
+            assert scoped and all(branch == every_slot and name.endswith("/broadcast_in_dim") for name in rest)
+            assert all("/" + scope in name or "(" + scope + ")" in name for name in scoped)
+            booked.append({_program.phase_of(name) for name in scoped})
+        assert booked[1] - booked[0] <= {"recompute"}  # the fallback's backward pass runs each share again, and says so
+        phases.append(booked[0])
+    assert sorted(phases, key=sorted) == [{"bwd"}, {"fwd"}, {"recompute"}]
+
+
 def test_defaults_are_the_segmented_constants():
     assert flash_blocks.pick_block(SEQ, flash_blocks.SEGMENTED_BLOCK_Q) == flash_blocks.SEGMENTED_BLOCK_Q
     assert flash_blocks.pick_block(SEQ, flash_blocks.SEGMENTED_BLOCK_K) == flash_blocks.SEGMENTED_BLOCK_K
