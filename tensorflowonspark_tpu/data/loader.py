@@ -575,6 +575,7 @@ class ImagePipeline:
     def __iter__(self):
         from concurrent.futures import ThreadPoolExecutor
 
+        started = time.monotonic()  # until the first batch is handed over
         B = self.batch_size
         out_q = queue.Queue(maxsize=max(1, self.prefetch_batches))
         stop = threading.Event()  # consumer departed
@@ -617,6 +618,11 @@ class ImagePipeline:
             "data_consumer_wait_seconds_total",
             help="seconds the consumer waited on an empty prefetch queue "
             "(starvation: the input pipeline is the bottleneck)",
+        )
+        first_g = obs.gauge(
+            "data_first_batch_seconds",
+            help="seconds from the newest input iterator's start to its first batch "
+            "(producer start, first shard read, first pack or decode)",
         )
         native_c = obs.counter(
             "decode_native_total",
@@ -1067,6 +1073,9 @@ class ImagePipeline:
                     return
                 if isinstance(item, BaseException):
                     raise item
+                if started is not None:
+                    first_g.set(time.monotonic() - started)
+                    started = None
                 consumed_c.inc()
                 depth_g.set(out_q.qsize())
                 prev = item

@@ -228,6 +228,7 @@ class TextPipeline(ImagePipeline):
     def __iter__(self):
         from concurrent.futures import ThreadPoolExecutor
 
+        started = time.monotonic()  # until the first batch is handed over
         B, L = self.batch_size, self.seq_len
         out_q = queue.Queue(maxsize=max(1, self.prefetch_batches))
         stop = threading.Event()  # consumer departed
@@ -267,6 +268,11 @@ class TextPipeline(ImagePipeline):
             "data_consumer_wait_seconds_total",
             help="seconds the consumer waited on an empty prefetch queue "
             "(starvation: the input pipeline is the bottleneck)",
+        )
+        first_g = obs.gauge(
+            "data_first_batch_seconds",
+            help="seconds from the newest input iterator's start to its first batch "
+            "(producer start, first shard read, first pack or decode)",
         )
         tok_err_c = obs.counter(
             "text_tokenize_errors_total",
@@ -625,6 +631,9 @@ class TextPipeline(ImagePipeline):
                     return
                 if isinstance(item, BaseException):
                     raise item
+                if started is not None:
+                    first_g.set(time.monotonic() - started)
+                    started = None
                 consumed_c.inc()
                 depth_g.set(out_q.qsize())
                 yield item

@@ -51,6 +51,9 @@ tracing analogue of chaos-obs-coverage):
 ``control_decision``       marker span for a Controller knob move
 ``child_import_jax``       the jax child's ``import jax`` (gauge ``node_import_jax_seconds``)
 ``child_backend_start``    the jax child joining the world and starting the backend (gauge ``node_backend_start_seconds``)
+``compile_trace``          JAX tracing one program to a jaxpr (retroactive; ``program`` names it)
+``compile_lower``          JAX lowering one program to a StableHLO module (retroactive)
+``compile_backend``        one program's cache key, then its load from the compile cache or its compilation (retroactive)
 ``h2d_place``              ``shard_batch`` placing one host batch on the mesh (per step)
 ``batch_wait``             the training loop waiting on the input pipeline's queue (per step)
 ``producer_read``          the input pipeline's reader opening a shard or reading a chunk
@@ -65,6 +68,13 @@ the registry keeps of them — no event, no histogram — and they take a span
 id only while a flight shard is open. Every span, of either kind, is also a
 ``tos.<name>`` ``TraceAnnotation`` in a ``jax.profiler`` trace when the
 process has jax imported (:mod:`~tensorflowonspark_tpu.obs.trace`).
+
+The three ``compile_*`` spans are :func:`record_span`'s: JAX reports a stage
+with its start and end when it is over (``util.place_compile_cache``
+registers the listener), so they are written to the flight shard alone, with
+the span the compiling thread had open as parent — the ``step_dispatch`` of
+the call that traced the step, ``node_main`` for a program of set-up — and
+only those of a millisecond or more.
 """
 
 import os

@@ -107,7 +107,11 @@ class TrainStep:
 
     Each call is one ``tos.step_dispatch`` step annotation in a profiler
     trace and is counted (``train_steps_dispatched_total``,
-    ``train_step_dispatch_seconds_total``). The interval since the call
+    ``train_step_dispatch_seconds_total``). The first call's share of those
+    seconds, which holds the step's tracing, lowering and compilation or
+    cache load, is kept apart (``train_step_first_call_seconds``; the compile
+    listener of ``util.place_compile_cache`` books its stages,
+    ``train_step_*_seconds``). The interval since the call
     before goes to a :class:`StallMeter` only when both calls found the step
     before them still running: the device's queue was never empty, so the
     loop came back at the device's pace, and an interval the meter finds long
@@ -148,7 +152,13 @@ class TrainStep:
         self._seconds = obs.counter(
             "train_step_dispatch_seconds_total",
             help="host seconds inside calls of a compiled train step (dispatch, "
-            "and the first call's compilation or cache load)",
+            "and the first call's compilation or cache load: "
+            "train_step_first_call_seconds is that call's share)",
+        )
+        self._first_call = obs.gauge(
+            "train_step_first_call_seconds",
+            help="host seconds inside the first call of a compiled train step: its "
+            "tracing, lowering, cache key, load or compilation, and the dispatch",
         )
         self._stalls = obs.counter(
             "train_step_stalls_total",
@@ -177,8 +187,10 @@ class TrainStep:
         self._last_at = now
         self._dispatched += 1
         self._steps.inc()
-        with obs.span("step_dispatch", seconds_total=self._seconds, step_num=self._dispatched):
+        with obs.span("step_dispatch", seconds_total=self._seconds, step_num=self._dispatched) as dispatch:
             out = self._jitted(state, batch)
+        if self._dispatched == 1:
+            self._first_call.set(dispatch.dur_s)
         self._last_loss = out[1]["loss"]
         carried = {k: v for k, v in out[1].items() if k.startswith(obs.CARRIED)}
         if carried:

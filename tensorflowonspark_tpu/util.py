@@ -8,7 +8,9 @@ Spark tasks landing on the same executor can reconnect to the running jax
 process (reference: util.py:77-86 + TFSparkNode.py:97-123).
 """
 
+import collections
 import errno
+import functools
 import json
 import logging
 import multiprocessing
@@ -16,8 +18,10 @@ import os
 import socket
 import sys
 import threading
+import time
 
 from tensorflowonspark_tpu import durable, obs
+from tensorflowonspark_tpu.obs import flight, tracing
 
 logger = logging.getLogger(__name__)
 
@@ -177,8 +181,11 @@ def place_compile_cache():
     complaint for every executable it loads back.
 
     Where jax is imported by then, the process also starts keeping what JAX
-    reports of its compilations and cache loads (``compile_cache_*`` and
-    ``compile_backend_seconds`` gauges).
+    reports of each stage of its compilations — tracing, lowering, the
+    cache's key, the load or the compilation — for all programs
+    (``compile_*`` gauges) and for the train step by its name
+    (``train_step_*`` gauges), and writes the stages as ``compile_*`` spans
+    where a flight shard is open.
     """
     placed = os.environ.get(COMPILE_CACHE_ENV)
     if placed:
@@ -200,60 +207,169 @@ def place_compile_cache():
 
 
 _CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: the train step's program as each stage's event names it (the function's
+#: name is fixed in ``SyncDataParallel._jit_train_step`` for this)
+_TRAIN_STEP_PROGRAMS = ("tos_train_step", "jit(tos_train_step)", "jit_tos_train_step")
+#: a compile stage shorter than this leaves no span in the flight shard: the
+#: step's trace holds thousands of jnp functions' own, 0.1 ms each
+_COMPILE_SPAN_FLOOR_S = 1e-3
+#: a thread remembers this many ended stages: every stage that ends inside
+#: another is forgotten with it, so what stays is one entry a program
+_STAGES_KEPT = 1 << 16
 _compile_thread = threading.local()
 _listening = False
 
 
 def _listen_to_compiles(jax):
-    """Register, once in a process, the listener that keeps what JAX reports
-    of its compilations in four gauges (gauges, because set-up is over before
-    anybody takes a window's delta of counters)."""
+    """Register, once in a process, the two listeners that keep what JAX
+    reports of each stage of its compilations: durations in gauges (gauges,
+    because set-up is over before anybody takes a window's delta of
+    counters), and each stage's start and end as a span of the flight shard."""
     global _listening
     if _listening:
         return
     _listening = True
-    for gauge in _compile_gauges():  # a process that never loads reads 0, not nothing
-        gauge.inc(0)
+    _compile_gauges()  # a process that never loads reads 0, not nothing
     jax.monitoring.register_event_duration_secs_listener(_note_compile_event)
+    jax.monitoring.register_event_time_span_listener(_note_compile_span)
 
 
+@functools.cache
 def _compile_gauges():
-    return (
-        obs.gauge(
+    """``(all programs', the train step's)``: each a dict of gauges by stage,
+    created at 0 at the first call."""
+    every = {
+        "trace": obs.gauge(
+            "compile_trace_seconds",
+            help="seconds tracing programs to jaxprs, a trace inside another "
+            "(an inner jit, an operation run while tracing) counted once",
+        ),
+        "lower": obs.gauge(
+            "compile_lower_seconds",
+            help="seconds lowering jaxprs to StableHLO modules, less what other "
+            "stages took inside them",
+        ),
+        "lookup": obs.gauge(
+            "compile_cache_lookup_seconds",
+            help="seconds loaded programs spent beside their load: the cache "
+            "key (the module canonicalised and hashed) and the options",
+        ),
+        "load": obs.gauge(
             "compile_cache_load_seconds",
             help="seconds spent loading executables from the persistent compile cache",
         ),
-        obs.gauge(
+        "hits": obs.gauge(
             "compile_cache_hits", help="programs loaded from the persistent compile cache"
         ),
-        obs.gauge(
+        "backend": obs.gauge(
             "compile_backend_seconds",
             help="seconds the backend spent compiling programs it did not load",
         ),
-        obs.gauge(
+        "misses": obs.gauge(
             "compile_cache_misses",
             help="programs the backend compiled, not loaded from the cache",
         ),
-    )
+    }
+    step = {
+        "traces": obs.gauge(
+            "train_step_traces",
+            help="programs built of the train step in this process: 1, and one "
+            "more for every call whose arguments' types, shardings or layouts "
+            "were new (its lowerings: JAX reports a trace for a cached one too)",
+        ),
+        "trace": obs.gauge(
+            "train_step_trace_seconds", help="seconds tracing the train step, as JAX reports them"
+        ),
+        "lower": obs.gauge(
+            "train_step_lower_seconds", help="seconds lowering the train step to a StableHLO module"
+        ),
+        "lookup": obs.gauge(
+            "train_step_cache_lookup_seconds",
+            help="seconds a loaded train step spent beside its load (the cache key)",
+        ),
+        "load": obs.gauge(
+            "train_step_cache_load_seconds",
+            help="seconds loading the train step from the persistent compile cache",
+        ),
+        "backend": obs.gauge(
+            "train_step_backend_compile_seconds",
+            help="seconds the backend spent compiling the train step (0 on a warm start)",
+        ),
+    }
+    return every, step
 
 
-def _note_compile_event(event, secs, **_kw):
-    """A program loaded from the persistent cache reports its retrieval and
-    then, on the same thread, a "backend compile" that holds nothing else;
-    one that was compiled reports the latter alone."""
-    if event not in (_CACHE_LOAD_EVENT, _BACKEND_COMPILE_EVENT):
-        return
-    load_seconds, hits, backend_seconds, misses = _compile_gauges()
+def _note_compile_event(event, secs, fun_name=None, **_kw):
+    """Book one stage of one program, as JAX reports it when the stage ends.
+
+    A program is traced, lowered, then handed to the backend. The "backend
+    compile" event of one that is loaded from the persistent cache holds the
+    retrieval, which JAX reports first, on the same thread and without the
+    program's name, and before it the cache's key: the module stripped,
+    serialised and hashed. A compiled program reports the backend compile
+    alone. The train step's stages are booked a second time by its name.
+
+    Stages lie inside each other (a jnp function is traced inside the step's
+    trace, an operation on constants is compiled and run inside it), so for
+    all programs a trace or a lowering counts less what ended inside it: the
+    stages' gauges add up to host time. What ended inside is what this thread
+    noted after the stage began, by this listener's own clock.
+    """
     if event == _CACHE_LOAD_EVENT:
-        _compile_thread.loaded = True
-        load_seconds.inc(secs)
-        hits.inc()
-    elif getattr(_compile_thread, "loaded", False):
-        _compile_thread.loaded = False
-    else:
-        backend_seconds.inc(secs)
-        misses.inc()
+        every, _ = _compile_gauges()
+        _compile_thread.loaded = secs
+        every["load"].inc(secs)
+        every["hits"].inc()
+        return
+    if event not in (_TRACE_EVENT, _LOWER_EVENT, _BACKEND_COMPILE_EVENT):
+        return
+    every, step = _compile_gauges()
+    step = step if fun_name in _TRAIN_STEP_PROGRAMS else None
+    now = time.monotonic()
+    ended = getattr(_compile_thread, "ended", None)
+    if ended is None:
+        ended = _compile_thread.ended = collections.deque(maxlen=_STAGES_KEPT)
+    inside = 0.0
+    while ended and ended[-1][0] > now - secs:
+        inside += ended.pop()[1]
+    ended.append((now, secs))
+    if event == _BACKEND_COMPILE_EVENT:
+        loaded = _compile_thread.__dict__.pop("loaded", None)
+        if loaded is None:
+            every["backend"].inc(secs)
+            every["misses"].inc()
+            if step:
+                step["backend"].inc(secs)
+        else:
+            every["lookup"].inc(secs - loaded)
+            if step:
+                step["load"].inc(loaded)
+                step["lookup"].inc(secs - loaded)
+        return
+    stage = "trace" if event == _TRACE_EVENT else "lower"
+    every[stage].inc(max(secs - inside, 0.0))
+    if step:
+        step[stage].inc(secs)
+        if stage == "lower":
+            step["traces"].inc()
+
+
+def _note_compile_span(event, start, end, fun_name=None, **_kw):
+    """The same stages with JAX's own start and end (``time.time()``, the
+    flight shard's clock), as spans under whatever span the compiling thread
+    has open: ``step_dispatch`` for the step's, ``node_main`` for most others.
+    Nothing is written unless a flight shard is open (``TOS_TRACE_DIR``)."""
+    if not flight.is_open() or end - start < _COMPILE_SPAN_FLOOR_S:
+        return
+    if event == _TRACE_EVENT:
+        tracing.record_span("compile_trace", start, end - start, program=fun_name)
+    elif event == _LOWER_EVENT:
+        tracing.record_span("compile_lower", start, end - start, program=fun_name)
+    elif event == _BACKEND_COMPILE_EVENT:
+        tracing.record_span("compile_backend", start, end - start, program=fun_name)
 
 
 def single_node_env(num_cpu_devices=None, platform=None):

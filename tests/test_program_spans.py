@@ -5,6 +5,7 @@ counters, the compile listener's gauges, and the named scopes that let a
 device operation's ``op_name`` say its phase."""
 
 import glob
+import json
 import os
 import re
 import subprocess
@@ -322,3 +323,254 @@ def test_a_process_off_jax_with_the_cache_placed_outside_stays_off_jax(tmp_path)
     out = subprocess.run([sys.executable, "-c", code, str(tmp_path)], capture_output=True, text=True, timeout=120,
                          cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path)))
     assert out.returncode == 0, out.stderr
+
+
+# -- a start's compile work, stage by stage (PR 36) ---------------------------
+
+OLD_GAUGES = ("compile_cache_load_seconds", "compile_cache_hits", "compile_backend_seconds", "compile_cache_misses")
+STAGE_GAUGES = ("compile_trace_seconds", "compile_lower_seconds", "compile_cache_lookup_seconds")
+STEP_GAUGES = ("train_step_traces", "train_step_trace_seconds", "train_step_lower_seconds",
+               "train_step_cache_lookup_seconds", "train_step_cache_load_seconds",
+               "train_step_backend_compile_seconds")
+TRACE, LOWER = util._TRACE_EVENT, util._LOWER_EVENT
+LOAD, BACKEND = util._CACHE_LOAD_EVENT, util._BACKEND_COMPILE_EVENT
+
+
+def _feed(monkeypatch, script):
+    """Hand the listener ``(time it ends, event, seconds, program)`` in turn,
+    on a scripted clock; returns what every compile gauge moved by."""
+    names = OLD_GAUGES + STAGE_GAUGES + STEP_GAUGES
+    before = {n: _value(n) for n in names}
+    at = iter([t for t, _, _, _ in script if t is not None])
+    monkeypatch.setattr(util, "time", types.SimpleNamespace(monotonic=lambda: next(at)))
+    monkeypatch.setattr(util, "_compile_thread", util.threading.local())
+    for _, event, secs, program in script:
+        if program is None:  # the retrieval event carries no name, and reads no clock
+            util._note_compile_event(event, secs)
+        else:
+            util._note_compile_event(event, secs, fun_name=program)
+    return {n: _value(n) - before[n] for n in names}
+
+
+def _retrieval(secs):
+    return (None, LOAD, secs, None)
+
+
+def test_compile_listener_books_every_stage_of_every_program(monkeypatch):
+    """A loaded train step, another loaded program and a compiled one, as JAX
+    reports them: each stage when it ends, what ran inside the step's trace
+    (a jnp function's own trace, an operation loaded and run on constants)
+    before the trace that holds it."""
+    script = [
+        (8.0, TRACE, 1.0, "add"),  # 7 → 8, inside the step's trace (6 → 10)
+        (9.0, TRACE, 0.25, "iota"), (9.125, LOWER, 0.125, "jit(iota)"),
+        _retrieval(0.25), (9.5, BACKEND, 0.375, "jit(iota)"),
+        (10.0, TRACE, 4.0, "tos_train_step"),
+        (13.0, LOWER, 3.0, "jit(tos_train_step)"),
+        _retrieval(5.0), (20.0, BACKEND, 6.5, "jit(tos_train_step)"),
+        (21.0, TRACE, 0.5, "leaf_norms"), (22.0, LOWER, 0.25, "jit(leaf_norms)"),
+        _retrieval(0.5), (23.0, BACKEND, 0.75, "jit(leaf_norms)"),
+        (30.0, TRACE, 1.0, "f"), (31.0, LOWER, 0.5, "jit(f)"), (40.0, BACKEND, 8.0, "jit(f)"),
+    ]
+    moved = _feed(monkeypatch, script)
+    assert moved == {
+        "compile_cache_load_seconds": 5.75, "compile_cache_hits": 3, "compile_backend_seconds": 8.0,
+        "compile_cache_misses": 1,
+        # the step's 4 s hold 1 + 0.25 + 0.125 + 0.375 s of other stages: every second once
+        "compile_trace_seconds": 1.0 + 0.25 + (4.0 - 1.75) + 0.5 + 1.0,
+        "compile_lower_seconds": 0.125 + 3.0 + 0.25 + 0.5,
+        "compile_cache_lookup_seconds": 0.125 + 1.5 + 0.25,
+        # the step's own, whole, as JAX reports them
+        "train_step_traces": 1, "train_step_trace_seconds": 4.0, "train_step_lower_seconds": 3.0,
+        "train_step_cache_lookup_seconds": 1.5, "train_step_cache_load_seconds": 5.0,
+        "train_step_backend_compile_seconds": 0.0,
+    }
+
+
+@pytest.mark.parametrize("program,is_step", [
+    ("jit(tos_train_step)", True), ("jit_tos_train_step", True), ("tos_train_step", True),
+    ("jit(tos_train_step_loop)", False), ("pmap(tos_train_step)", False),
+])
+def test_the_train_step_is_told_by_its_programs_name(monkeypatch, program, is_step):
+    """A cold start: the step compiled, under each form of its name."""
+    moved = _feed(monkeypatch, [(5.0, LOWER, 2.0, program), (100.0, BACKEND, 90.0, program)])
+    assert (moved["compile_lower_seconds"], moved["compile_backend_seconds"], moved["compile_cache_misses"]) == (2.0, 90.0, 1)
+    step = (moved["train_step_traces"], moved["train_step_lower_seconds"], moved["train_step_backend_compile_seconds"])
+    assert step == ((1, 2.0, 90.0) if is_step else (0, 0.0, 0.0))
+
+
+@pytest.fixture
+def listening():
+    util._listen_to_compiles(jax)  # once in the process, whoever asks
+
+
+def test_lowering_the_step_again_on_seen_arguments_is_no_new_program(listening):
+    """``step.lower`` after a call (the benchmark's memory account): JAX
+    reports a trace, its cache's hit, and no lowering; the count stays."""
+    _, state, step, batch = _linear_job(donate=False)
+    state, metrics = step(state, batch)
+    jax.block_until_ready(metrics)
+    traces, seconds = _value("train_step_traces"), _value("train_step_trace_seconds")
+    step.lower(state, batch)
+    assert _value("train_step_traces") == traces
+    assert seconds < _value("train_step_trace_seconds") < seconds + 0.05
+
+
+def test_three_calls_trace_the_step_once_and_new_avals_trace_it_again(listening):
+    """A real step on the CPU: call 1 traces, lowers and compiles; calls 2 and
+    3 dispatch; a batch of another size is a second program. The first
+    call's gauge holds call 1 alone."""
+    _, state, step, batch = _linear_job(donate=False)
+    names = STEP_GAUGES + ("compile_trace_seconds", "compile_lower_seconds")
+    before = {n: _value(n) for n in names}
+    after = []
+    for _ in range(3):
+        state, metrics = step(state, batch)
+        jax.block_until_ready(metrics)
+        after.append(({n: _value(n) - before[n] for n in names}, _value("train_step_first_call_seconds")))
+    moved, first_call = after[0]
+    assert after[1] == after[2] == after[0]
+    assert moved["train_step_traces"] == 1
+    assert moved["train_step_trace_seconds"] > 0 and moved["train_step_lower_seconds"] > 0
+    # nothing of the CPU's is loaded (no cache): the step was compiled
+    assert moved["train_step_backend_compile_seconds"] > 0 and moved["train_step_cache_load_seconds"] == 0
+    assert first_call >= sum(moved[n] for n in STEP_GAUGES[1:])
+    # all programs' trace seconds hold the step's once, not the jnp functions' inside it twice
+    assert moved["train_step_trace_seconds"] <= moved["compile_trace_seconds"] < 2 * moved["train_step_trace_seconds"]
+    wider = SyncDataParallel(_mesh()).shard_batch(
+        {"x": np.ones((16, 4), np.float32), "y": np.ones((16, 1), np.float32)})
+    state, metrics = step(state, wider)
+    jax.block_until_ready(metrics)
+    assert _value("train_step_traces") - before["train_step_traces"] == 2
+    assert _value("train_step_first_call_seconds") == first_call
+
+
+def _compile_spans(shard):
+    records, _ = flight.read_shard(shard)
+    spans = [r for r in records if r.get("kind") == "span"]
+    return [r for r in spans if r["name"].startswith("compile_")], [r for r in spans if r["name"] == "step_dispatch"]
+
+
+def test_compile_stages_are_spans_under_the_call_that_caused_them(listening, tmp_path, monkeypatch):
+    _, state, step, batch = _linear_job()
+    tracing.reset()
+    try:
+        monkeypatch.setenv(flight.TRACE_DIR_ENV, str(tmp_path))
+        tracing.mint(proc="unit")
+        t0 = util.time.time()
+        state, metrics = step(state, batch)
+        jax.block_until_ready(metrics)
+        t1 = util.time.time()
+        # a stage under the floor (a jnp function's trace inside the step's) leaves nothing
+        util._note_compile_span(TRACE, t1, t1 + util._COMPILE_SPAN_FLOOR_S / 2, fun_name="add")
+        shard = flight.current().shard_dir
+    finally:
+        tracing.reset()
+    stages, (dispatch,) = _compile_spans(shard)
+    assert {"compile_trace", "compile_lower", "compile_backend"} == {r["name"] for r in stages}
+    own = {r["name"]: r for r in stages if r["attrs"]["program"] in util._TRAIN_STEP_PROGRAMS}
+    assert set(own) == {"compile_trace", "compile_lower", "compile_backend"}
+    for record in own.values():
+        assert record["parent"] == dispatch["span"] and record["trace"] == dispatch["trace"]
+        assert t0 <= record["ts"] and record["ts"] + record["dur_s"] <= t1 and record["dur_s"] > 0
+    assert own["compile_trace"]["ts"] < own["compile_lower"]["ts"] < own["compile_backend"]["ts"]
+    assert all(r["attrs"]["program"] != "add" for r in stages)
+
+
+def test_compile_stages_write_nothing_without_a_flight_shard(tmp_path, monkeypatch):
+    """``TOS_TRACE_DIR`` names a directory but no shard is open (nobody
+    minted a trace): the listener must not be the one that opens it."""
+    tracing.reset()
+    monkeypatch.setenv(flight.TRACE_DIR_ENV, str(tmp_path))
+    try:
+        for event in (TRACE, LOWER, BACKEND):
+            util._note_compile_span(event, 100.0, 101.0, fun_name="jit(tos_train_step)")
+        assert not flight.is_open() and os.listdir(str(tmp_path)) == []
+    finally:
+        tracing.reset()
+
+
+def test_disabled_collection_books_no_compile_stage(listening, monkeypatch):
+    """``TOS_OBS=0``: the listener's gauges stay, the step callable is the
+    bare jitted function and keeps no first call."""
+    names = OLD_GAUGES + STAGE_GAUGES + STEP_GAUGES + ("train_step_first_call_seconds",)
+    obs.set_enabled(False)
+    try:
+        _, state, step, batch = _linear_job()
+        before = {n: _value(n) for n in names}
+        state, metrics = step(state, batch)
+        jax.block_until_ready(metrics)
+        fed = _feed(monkeypatch, [(10.0, TRACE, 4.0, "tos_train_step"), _retrieval(5.0),
+                                  (20.0, BACKEND, 6.5, "jit(tos_train_step)")])
+    finally:
+        obs.set_enabled(True)
+    assert int(state.step) == 1 and not any(fed.values())
+    assert before == {n: _value(n) for n in names}
+
+
+def test_placing_the_cache_twice_registers_each_listener_once():
+    code = (
+        "import json\n"
+        "from tensorflowonspark_tpu import obs, util\n"
+        "util.place_compile_cache(); util.place_compile_cache()\n"
+        "from jax._src import monitoring\n"
+        "n = [sum(1 for f in monitoring.get_event_duration_listeners() if f is util._note_compile_event),\n"
+        "     sum(1 for f in monitoring.get_event_time_span_listeners() if f is util._note_compile_span)]\n"
+        "gauges = obs.snapshot()['gauges']\n"
+        "print(json.dumps([n, sorted(k for k, v in gauges.items() if v['value'] == 0.0)]))\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=120, cwd=ROOT,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr
+    registered, zero = json.loads(out.stdout.strip().splitlines()[-1])
+    # every gauge is there at 0 before any program: a reader tells "no seconds" from "does not count"
+    assert registered == [1, 1] and set(OLD_GAUGES + STAGE_GAUGES + STEP_GAUGES) <= set(zero)
+
+
+def _text_pipeline(tmp_path):
+    from tensorflowonspark_tpu import tfrecord
+    from tensorflowonspark_tpu.data import TextPipeline, Tokenizer
+
+    path = str(tmp_path / "part-00000")
+    with tfrecord.TFRecordWriter(path) as w:
+        for i in range(200):
+            w.write(" ".join(["spark", "text", "plane", str(i)] * (1 + i % 5)).encode())
+    return TextPipeline([path], Tokenizer(kind="word", vocab_size=128), seq_len=48, batch_size=4, seed=7, epochs=None)
+
+
+def _image_pipeline(tmp_path):
+    from tensorflowonspark_tpu import tfrecord
+    from tensorflowonspark_tpu.data import ImagePipeline
+
+    path = str(tmp_path / "part-00000")
+    with tfrecord.TFRecordWriter(path) as w:
+        for i in range(200):
+            w.write(str(i).encode())
+    return ImagePipeline([path], lambda rec: (np.full((4, 4, 1), int(rec) % 251, np.uint8), int(rec)),
+                         batch_size=8, seed=3, epochs=None)
+
+
+@pytest.mark.parametrize("make", [_text_pipeline, _image_pipeline], ids=["text", "image"])
+def test_the_first_batch_is_timed_once_an_iterator(make, tmp_path):
+    """From the iterator's start (the first ``next``) to its first batch;
+    later batches leave the gauge alone, a new iterator sets it anew."""
+    obs.gauge("data_first_batch_seconds").set(-1.0)
+    stream = iter(make(tmp_path))
+    try:
+        t0 = util.time.monotonic()
+        next(stream)
+        took = util.time.monotonic() - t0
+        first = _value("data_first_batch_seconds")
+        assert 0 < first <= took
+        for _ in range(3):
+            next(stream)
+        assert _value("data_first_batch_seconds") == first
+    finally:
+        stream.close()
+    again = iter(make(tmp_path))
+    try:
+        obs.gauge("data_first_batch_seconds").set(-1.0)
+        next(again)
+        assert _value("data_first_batch_seconds") > 0
+    finally:
+        again.close()

@@ -80,6 +80,8 @@ class TestTraceDiscipline:
         assert "context manager" in findings[0].message
 
     def test_record_span_is_with_exempt(self):
+        """The form ``util``'s compile listener uses for its ``compile_*``
+        spans: a literal name, explicit times, no ``with``."""
         findings = run_rule_multi("trace-discipline", {
             TRACING_PATH: TRACING_MODULE,
             "tensorflowonspark_tpu/feeder.py": _src("""

@@ -12,7 +12,11 @@ from the code.  Three invariants:
    held in a variable and entered by hand can leak past an exception,
    leaving the thread-local parent stack corrupted for every later span
    on that thread.  :func:`record_span` is exempt — it is retroactive by
-   design (explicit ``ts``/``dur_s``, never enters the stack).
+   design (explicit ``ts``/``dur_s``, never enters the stack).  Its caller
+   in the package is ``util``'s compile listener, which JAX tells of a
+   stage when the stage is over: three literal calls (``compile_trace``,
+   ``compile_lower``, ``compile_backend``), which check 1 and check 3 hold
+   to the table like any other site.
 3. Every literal span name fired in the tree appears in the "Span sites"
    table of ``obs/tracing.py``'s docstring, and every documented site is
    fired somewhere — drift in either direction is a bug.
