@@ -7,6 +7,37 @@ recomputes probabilities per block from the saved log-sum-exp and accumulates
 dq / dk / dv. Two kernels a call, one forward and one backward, while HBM
 traffic stays O(L·D).
 
+**What a computed block issues** (PR 37; ``scripts/flash_bundles.py`` reads
+it from the chip's compiler, PERF.md §7 has the tables). A block's scores
+stay in raw units, q·k, masked as ever: the forward tracks the running
+maximum ``m`` in those units and computes ``p = exp2((s - m) · c)``, ``c =
+scale · log2 e`` a constant of the trace, where ``exp(s · scale - m)`` costs a
+multiplication more an element before the exponential and another inside
+it; the backward computes ``p = exp2(s · c - lse · log2 e)`` and ``ds = p ·
+(dp - delta)`` without ``scale``, which multiplies dk and dq once, float32,
+as they are written out. The log-sum-exp leaves the forward as it always
+did, in natural-log units of the scaled scores (``m · scale + log l``).
+The forward's ``m`` and ``l`` are ``[block_q, 128]`` scratch (what
+``[block_q, 1]`` float32 occupies in VMEM anyway): ``m`` the same in every
+lane; ``l`` a lane's share of the row's sum, the block's probabilities added
+register onto register, summed across lanes once, when the q block is
+written out. The query side's segment ids (the second rule's three marks)
+ride ``[rows, L, 128]`` (``3 · 128``), a position's value in every lane
+(what ``_STAT_W`` lanes pad to in HBM, and in the DMA, anyway). So every
+block-wide use of a row's value is whole registers repeated
+(``pltpu.repeat``) or a lane slice, for values up to 256 lanes wide, and
+never a broadcast out of one lane, which costs a pass through the XLU and a
+masked store a register. The forward's schedule for a 512 x 512 block fell
+from 1,962 bundles, of which no unit filled half, to 1,105, 85% of them
+filled by the MXU (64/64; 2,452 to 1,639 at 192/128; 1,981 to 1,099 under
+the second rule). The backward is bound by the MXU, five products a block,
+each half-filling a 128-wide array at head size 64 (83 to 90% full): the
+fold took 5% of its bundles at 64/64 and 15% under the second rule, and
+what is left is the formulation's. ``m`` starts at ``_M_NONE``, above the
+masked scores: a row that sees no key (padding under the second rule, a q
+block the map gives nothing) has probabilities of exactly 0, an output of
+0 and no gradient, at any scale.
+
 **One backward kernel.** A block's scores, masks, exponentials, ``dp`` and
 ``ds`` are computed once and feed all three gradients (PR 27; two kernels,
 q-major for dq and kv-major for dk/dv, each recomputed them). The kernel is
@@ -43,8 +74,8 @@ grid bound; the interpreter is given the shape's bound,
 ``causal``), and a row with a shorter list parks on its last item: a parked
 step names the blocks already resident, so Pallas copies nothing, and
 computes nothing. A skipped block would have contributed exactly 0
-(``exp(_NEG_BIG - m)``), so outputs and gradients are bit-identical to the
-dense grid's. Without segment ids the list is the causal triangle (or
+(``exp2((_NEG_BIG - m) · c)``), so outputs and gradients are bit-identical to
+the dense grid's. Without segment ids the list is the causal triangle (or
 everything). A computed block is masked as before, causal and fence both:
 choosing a body by what a block needs (no mask below the diagonal inside
 one document) was built and read on the chip in PR 25, where it ran 2%
@@ -62,8 +93,8 @@ names the limit (:func:`_work`).
 **What a recomputed layer keeps.** The call hands its backward its operands,
 its output ``o`` and the log-sum-exp, one float32 a position and head (the
 kernels read and write the statistic ``_STAT_W`` lanes wide, which pad to
-128 in HBM: it is narrowed where it leaves the forward and widened again where
-the backward, as ``delta``, is fed). Both are passed through
+128 in HBM: it is narrowed where it leaves the forward and widened again,
+times log2 e, where the backward, as ``delta``, is fed). Both are passed through
 ``jax.ad_checkpoint.checkpoint_name`` (:data:`KEPT_O`, :data:`KEPT_LSE`) and
 are the call's only results, so a model that recomputes its layers under
 :data:`REMAT_POLICY` rebuilds q, k and v in the recomputed pass and does not
@@ -93,7 +124,8 @@ block-diffusion rule (:mod:`~tensorflowonspark_tpu.ops.flash_blocks`: a row
 holds a clean and a noised copy of every document; ``labels`` beside the
 segment ids say which block and which copy a position is). The marks of a
 position (four int32: :func:`flash_blocks.bd_marks`) ride where the segment
-ids ride, the mask is two comparisons and an equality on a block's marks,
+ids ride (the query side's three 128 lanes each), the mask is two
+comparisons and an equality on a block's marks,
 never an ``[L, L]`` array, and the work lists hold the blocks the rule needs,
 on either side of the diagonal (their stride is the square). The kernels are
 named ``flash_fwd_bd`` and ``flash_bwd_dkv_bd``.
@@ -117,6 +149,21 @@ from tensorflowonspark_tpu.ops import flash_blocks
 from tensorflowonspark_tpu.ops.flash_blocks import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q
 
 _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+#: the running maximum of a query row that has seen no key yet, in the scores'
+#: raw units: far below any score and above the masked scores' ``_NEG_BIG``, so
+#: a block that is all fence to a row gives it probabilities of exactly 0
+#: (``exp2((_NEG_BIG - _M_NONE) · c)``; no ``inf - inf`` whatever the scale),
+#: and a row that sees nothing at all an output of 0 and no gradient
+_M_NONE = 0.5 * _NEG_BIG
+
+_LOG2_E = math.log2(math.e)
+
+#: lanes of a vector register. The forward's row statistics and the query
+#: side's ids are kept this wide, every lane of a row the same value, so a
+#: block-wide use is whole registers repeated and never a broadcast out of one
+#: lane through the XLU
+_LANES = 128
 
 #: the names (``jax.ad_checkpoint.checkpoint_name``) of what a call hands its
 #: backward besides its operands: the output and the log-sum-exp, one float32
@@ -180,31 +227,33 @@ def _causal_mask(s, iq, ik, block_q, block_k):
 
 def _segment_mask(s, sq_ref, sk_ref):
     """Packed-sequence fence: scores survive only where the query's segment
-    id equals the key's. ``sq_ref`` blocks are [block_q, _STAT_W] (the same
-    broadcast-lane trick as the row statistics); ``sk_ref`` blocks come from
-    the pre-transposed [rows, _STAT_W, L] layout so the kernel reads a
+    id equals the key's. ``sq_ref`` blocks are [block_q, _LANES], a row's id
+    in every lane (as the forward's row statistics); ``sk_ref`` blocks come
+    from the pre-transposed [rows, _STAT_W, L] layout so the kernel reads a
     [1, block_k] row directly — no in-kernel transpose."""
-    seg_q = sq_ref[0][:, :1]  # [bq, 1]
+    seg_q = _lanes(sq_ref[0], s.shape[1])  # [bq, bk]
     seg_k = sk_ref[0][:1, :]  # [1, bk]
     return jnp.where(seg_q == seg_k, s, _NEG_BIG)
 
 
 def _block_diffusion_mask(s, sq_ref, sk_ref):
     """The block-diffusion rule on a block's marks
-    (:func:`flash_blocks.bd_marks`): lanes 0-2 of the query side hold ``lo``,
-    ``hi`` and ``own``, the key side holds ``key``."""
-    marks = sq_ref[0]
-    lo, hi, own = marks[:, 0:1], marks[:, 1:2], marks[:, 2:3]  # [bq, 1]
+    (:func:`flash_blocks.bd_marks`): the query side holds ``lo``, ``hi`` and
+    ``own``, ``_LANES`` lanes each, the key side holds ``key``."""
+    marks, block_k = sq_ref[0], s.shape[1]
+    lo, hi, own = (_lanes(marks[:, i * _LANES:(i + 1) * _LANES], block_k) for i in range(3))  # [bq, bk]
     key = sk_ref[0][:1, :]  # [1, bk]
     return jnp.where(((key >= lo) & (key <= hi)) | (key == own), s, _NEG_BIG)
 
 
-def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k, rule="causal"):
+def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, causal, block_q, block_k, rule="causal"):
+    """A block's masked scores in raw units, q·k: ``scale`` never touches a
+    block-sized array (the kernels fold it into the exponent)."""
     s = jax.lax.dot_general(
         q_ref[0], k_ref[0],
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * scale
+    )
     if rule == "block_diffusion":
         return _block_diffusion_mask(s, sq_ref, sk_ref)
     if causal:
@@ -214,28 +263,53 @@ def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_
     return s
 
 
+def _lanes(stat, width):
+    """``[rows, _LANES]`` with a row's value in every lane as ``[rows,
+    width]``: whole registers repeated, or a lane slice."""
+    if width > _LANES:
+        stat = pltpu.repeat(stat, -(-width // _LANES), axis=1)
+    return stat if stat.shape[1] == width else stat[:, :width]
+
+
+def _lane_sums(p):
+    """``[rows, _LANES]`` whose lanes sum to ``p``'s rows' sums: ``p``'s
+    registers added onto one another, no reduction across lanes (a block
+    that is not whole registers wide puts the row's sum in lane 0)."""
+    rows, width = p.shape
+    if width % _LANES:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+        return jnp.where(lane == 0, jnp.sum(p, axis=1, keepdims=True), 0.0)
+    return sum(p[:, at:at + _LANES] for at in range(0, width, _LANES))
+
+
 def _fwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps, rule="causal"):
+    """The online softmax in the scores' raw units (the module's text):
+    ``m`` the running maximum of q·k, the same in every lane; ``l`` a lane's
+    share of the row's sum, summed over the lanes once, when the q block is
+    written out."""
     if segmented:
         q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref, acc, m, l = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l = refs
         sq_ref = sk_ref = None
     iq, ik, item = _here(items_ref, heads, steps)
+    c = scale * _LOG2_E
 
     @pl.when(_flag(item, flash_blocks.ITEM_FIRST))
     def _init():
         acc[:] = jnp.zeros_like(acc)
-        m[:] = jnp.full_like(m, _NEG_BIG)
+        m[:] = jnp.full_like(m, _M_NONE)
         l[:] = jnp.zeros_like(l)
 
     @pl.when(_flag(item, flash_blocks.ITEM_COMPUTE))
     def _block():
-        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k, rule)
-        m_new = jnp.maximum(m[:], jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m[:] - m_new)
-        p = jnp.exp(s - m_new)
-        l[:] = l[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
+        s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, causal, block_q, block_k, rule)
+        m_old = m[:]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp2((m_old - m_new) * c)
+        p = jnp.exp2((s - _lanes(m_new, block_k)) * c)
+        l[:] = l[:] * corr + _lane_sums(p)
+        acc[:] = acc[:] * _lanes(corr, acc.shape[1]) + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0],
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -244,10 +318,13 @@ def _fwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, he
 
     @pl.when(_flag(item, flash_blocks.ITEM_LAST))
     def _finish():
-        # a q block that no kv block served still writes finite rows
-        denom = jnp.maximum(l[:], 1e-30)
+        # a q block that no kv block served still writes finite rows: zeros
+        denom = jnp.maximum(jnp.sum(l[:], axis=1, keepdims=True), 1e-30)
         o_ref[0] = (acc[:] / denom).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(m[:] + jnp.log(denom), (l.shape[0], _STAT_W))
+        # natural-log units of the scaled scores, as the backward and a
+        # recomputed layer's policy keep it; finite whatever the scale
+        lse = jnp.maximum(m[:, :1] * scale, _M_NONE) + jnp.log(denom)
+        lse_ref[0] = jnp.broadcast_to(lse, (l.shape[0], _STAT_W))
 
 
 def _bwd_refs(refs, segmented):
@@ -265,8 +342,10 @@ def _bwd_pair(inputs, scratch, iq, ik, at_k, scale, causal, block_q, block_k, ru
     kv block's rows of a whole-row one)."""
     q_ref, k_ref, v_ref, sq_ref, sk_ref, do_ref, lse_ref, delta_ref = inputs
     dq_acc, dk_acc, dv_acc = scratch
-    s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, scale, causal, block_q, block_k, rule)
-    p = jnp.exp(s - lse_ref[0][:, :1])  # [bq, bk]
+    s = _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, causal, block_q, block_k, rule)
+    # the forward's probabilities again, ``scale`` folded into the exponent
+    # likewise: lse_ref holds the log-sum-exp times log2 e
+    p = jnp.exp2(s * (scale * _LOG2_E) - lse_ref[0][:, :1])  # [bq, bk]
     dv_acc[at_k] += jax.lax.dot_general(
         p.astype(do_ref.dtype), do_ref[0],
         dimension_numbers=(((0,), (0,)), ((), ())),
@@ -277,7 +356,8 @@ def _bwd_pair(inputs, scratch, iq, ik, at_k, scale, causal, block_q, block_k, ru
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    ds = (p * (dp - delta_ref[0][:, :1]) * scale).astype(q_ref.dtype)  # [bq, bk]
+    # d(scaled scores): ``scale`` multiplies dk and dq once, as they are written out
+    ds = (p * (dp - delta_ref[0][:, :1])).astype(q_ref.dtype)  # [bq, bk]
     dk_acc[at_k] += jax.lax.dot_general(
         ds, q_ref[0],
         dimension_numbers=(((0,), (0,)), ((), ())),
@@ -320,12 +400,12 @@ def _bwd_kernel(items_ref, *refs, scale, causal, segmented, block_q, block_k, he
 
     @pl.when(_flag(item, flash_blocks.ITEM_LAST))
     def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
     @pl.when(t == pl.num_programs(1) - 1)
     def _finish_row():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_kernel_grouped(items_ref, *refs, scale, causal, segmented, block_q, block_k, heads, steps, rule="causal"):
@@ -356,11 +436,11 @@ def _bwd_kernel_grouped(items_ref, *refs, scale, causal, segmented, block_q, blo
 
     @pl.when(last)
     def _finish_row():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
     @pl.when(last & (g == pl.num_programs(1) - 1))
     def _finish_group():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
@@ -482,9 +562,9 @@ class _GroupSpecs:
     def kv_rows(self, block_k, width):
         return pl.BlockSpec((1, block_k, width), lambda b, g, t, items: (b, _outer(self._item(b, t, items)), 0))
 
-    def ids_q(self, block_q):
+    def ids_q(self, block_q, width):
         return pl.BlockSpec(
-            (1, block_q, _STAT_W),
+            (1, block_q, width),
             lambda b, g, t, items: (_row(b, self.kv_heads), _inner(self._item(b, t, items)), 0))
 
     def ids_k(self, block_k):
@@ -502,18 +582,19 @@ class _GroupSpecs:
 
 def _seg_inputs(seg, rule="causal"):
     """Segment-id operands for the kernels, one set per batch row: query ids
-    broadcast onto the [rows, L, _STAT_W] row-statistics layout, key ids
-    pre-transposed to [rows, _STAT_W, L] so a kv block is a
-    directly-loadable row vector. Under the block-diffusion rule ``seg`` is
-    the marks ``[rows, 4, L]``: ``lo``, ``hi``, ``own`` in the query side's
-    first three lanes, ``key`` on the key side."""
+    ``[rows, L, _LANES]``, a position's id in every lane (what ``[rows, L,
+    _STAT_W]`` pads to in HBM anyway), key ids pre-transposed to ``[rows,
+    _STAT_W, L]`` so a kv block is a directly-loadable row vector. Under the
+    block-diffusion rule ``seg`` is the marks ``[rows, 4, L]``: the query
+    side ``[rows, L, 3 · _LANES]``, ``lo``, ``hi`` and ``own`` ``_LANES``
+    lanes each, ``key`` on the key side."""
     if rule == "block_diffusion":
         rows, _, seq = seg.shape
-        lanes = jnp.concatenate([seg[:, :3], jnp.zeros((rows, _STAT_W - 3, seq), jnp.int32)], axis=1)
-        return lanes.transpose(0, 2, 1), jnp.broadcast_to(seg[:, 3:4], (rows, _STAT_W, seq))
+        lanes = jnp.repeat(seg[:, :3].transpose(0, 2, 1), _LANES, axis=2)
+        return lanes, jnp.broadcast_to(seg[:, 3:4], (rows, _STAT_W, seq))
     rows, seq = seg.shape
     seg = seg.astype(jnp.int32)
-    seg_q = jnp.broadcast_to(seg[:, :, None], (rows, seq, _STAT_W))
+    seg_q = jnp.broadcast_to(seg[:, :, None], (rows, seq, _LANES))
     seg_k = jnp.broadcast_to(seg[:, None, :], (rows, _STAT_W, seq))
     return seg_q, seg_k
 
@@ -618,8 +699,8 @@ def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret, rule="c
                 at.rows(block_k, d_v, inner=True, shared=True)]
     operands = [q, k, v]
     if segmented:
-        in_specs += [at.rows(block_q, _STAT_W, ids=True), at.seg_k(block_k, inner=True)]
         operands += _seg_inputs(seg, rule)
+        in_specs += [at.rows(block_q, operands[3].shape[2], ids=True), at.seg_k(block_k, inner=True)]
     o, lse = _call(
         kernel, "fwd", work, bh, in_specs,
         out_specs=[at.rows(block_q, d_v), at.rows(block_q, _STAT_W)],
@@ -629,8 +710,8 @@ def _flash_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret, rule="c
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d_v), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         operands=operands, segmented=segmented, interpret=interpret, rule=rule,
     )
@@ -645,6 +726,7 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
     work = _work(seg, bh // heads, n_q, n_k, block_q, block_k, causal, True, rule)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[:, :, None], (bh, l_q, _STAT_W))
+    lse = lse * _LOG2_E  # the kernel's exponent is base 2
     if group > 1:
         return _flash_bwd_grouped(
             q, k, v, seg, do, lse, delta, work, group, heads // group, scale, causal, block_q, block_k, interpret, rule)
@@ -657,8 +739,8 @@ def _flash_bwd(q, k, v, seg, do, o, lse, scale, causal, block_q, block_k, interp
     in_specs = [at.rows(block_q, d, inner=True), at.rows(block_k, d), at.rows(block_k, d_v)]
     operands = [q, k, v]
     if segmented:
-        in_specs += [at.rows(block_q, _STAT_W, inner=True, ids=True), at.seg_k(block_k)]
         operands += _seg_inputs(seg, rule)
+        in_specs += [at.rows(block_q, operands[3].shape[2], inner=True, ids=True), at.seg_k(block_k)]
     in_specs += [
         at.rows(block_q, d_v, inner=True),
         at.rows(block_q, _STAT_W, inner=True),
@@ -698,8 +780,8 @@ def _flash_bwd_grouped(q, k, v, seg, do, lse, delta, work, group, kv_heads, scal
     in_specs = [at.q_rows(block_q, d), at.kv_rows(block_k, d), at.kv_rows(block_k, d_v)]
     operands = [q, k, v]
     if segmented:
-        in_specs += [at.ids_q(block_q), at.ids_k(block_k)]
         operands += _seg_inputs(seg, rule)
+        in_specs += [at.ids_q(block_q, operands[3].shape[2]), at.ids_k(block_k)]
     in_specs += [at.q_rows(block_q, d_v), at.q_rows(block_q, _STAT_W), at.q_rows(block_q, _STAT_W)]
     return _call(
         kernel, "bwd_dkv", work, bkv, in_specs,

@@ -589,3 +589,88 @@ def test_lists_too_long_for_smem_are_refused_by_name():
         jax.eval_shape(packed, q, q, q, ids)
     q, ids = jax.ShapeDtypeStruct((19, 1, 81920, 64), jnp.bfloat16), jax.ShapeDtypeStruct((19, 81920), jnp.int32)
     assert jax.eval_shape(jax.grad(packed, argnums=(0, 1, 2)), q, q, q, ids)[0].shape == q.shape
+
+
+# -- the scale folded into the exponent, the row statistics lane-wide (PR 37)
+
+#: ((q/k width, v width), the caller's scale or None for 1/sqrt(width)): scales that are no powers of two,
+#: 1.0 (``_NEG_BIG · scale · log2 e`` overflows float32: it must only ever meet finite numbers), and value heads
+#: of 64, 128 and 256 lanes
+_FOLDED = [((128, 128), None), ((192, 128), None), ((64, 64), 1.0), ((64, 256), None), ((24, 64), 0.3)]
+
+
+_INTERPRETED = functools.partial(flash_attention, block_q=_BLOCK, block_k=_BLOCK, interpret=True)
+
+
+def _with_scale(fn, causal, seg, scale):
+    def run(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: fn(q, k, v, causal=causal, segment_ids=seg, scale=scale), q, k, v)
+        return (o,) + vjp(do)
+
+    return jax.jit(run)
+
+
+@pytest.mark.parametrize("mask", ["segmented_causal", "segmented", "causal"])
+@pytest.mark.parametrize("widths,scale", _FOLDED, ids=["{}/{}-scale-{}".format(w[0], w[1], s) for w, s in _FOLDED])
+def test_folded_scale_matches_plain(widths, scale, mask):
+    """o, dq, dk and dv against the float32 reference where the kernels
+    keep the scores in raw units: the probabilities are ``exp2((s - m) · scale
+    · log2 e)``, and ``scale`` meets dq and dk once, as they are written."""
+    q, k, v, do = _case(_L, _L, *widths, seed=widths[0] + len(mask))
+    q = q * (0.35 if scale == 1.0 else 1.0)  # scores of a trained model's size, not of sqrt(width) times it
+    seg = jnp.asarray(ROWS["padded_tail"][None]) if mask.startswith("segmented") else None
+    causal = mask != "segmented"
+    got = _with_scale(_INTERPRETED, causal, seg, scale)(q, k, v, do)
+    want = _with_scale(plain_attention, causal, seg, scale)(q, k, v, do)
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        assert g.shape == w.shape and np.isfinite(np.asarray(g)).all(), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-3, rtol=1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("widths,scale", _FOLDED, ids=["{}/{}-scale-{}".format(w[0], w[1], s) for w, s in _FOLDED])
+def test_kept_lse_is_the_natural_log_sum_exp_of_the_scaled_scores(widths, scale):
+    """What a call keeps for its backward (``KEPT_LSE``) is in natural-log
+    units of the scaled scores, whatever units the kernel tracks its maximum in."""
+    q, k, v, _ = _case(_L, _L, *widths, seed=7)
+    q = q * (0.35 if scale == 1.0 else 1.0)
+    ids = ROWS["short_documents"]
+    used = widths[0] ** -0.5 if scale is None else scale
+    merge = lambda t: t.reshape(-1, *t.shape[2:])  # noqa: E731
+    _, (_, _, _, _, _, lse) = fa._flash_attention_fwd(
+        merge(q), merge(k), merge(v), jnp.asarray(ids[None]), 2, used, True, _BLOCK, _BLOCK, True)
+    scores = np.einsum("bhqd,bhkd->bhqk", np.asarray(q, np.float64), np.asarray(k, np.float64)) * used
+    seen = (ids[:, None] == ids[None, :]) & (np.arange(_L)[:, None] >= np.arange(_L)[None, :])
+    scores = np.where(seen[None, None], scores, -np.inf)
+    top = scores.max(-1)
+    want = top + np.log(np.exp(scores - top[..., None]).sum(-1))
+    np.testing.assert_allclose(np.asarray(lse), want.reshape(-1, _L), rtol=2e-6, atol=2e-5)
+
+
+@pytest.mark.parametrize("scale", [None, 1.0, 3.0], ids=["default", "scale-1", "scale-3"])
+@pytest.mark.parametrize("starved", [1, 2], ids=["q_block", "kv_block"])
+def test_rows_that_see_nothing_give_zeros_at_any_scale(patch_rule, starved, scale):
+    """The running maximum starts above the masked scores (``_M_NONE``), so
+    a query row that is served no key, because its q block gets no kv block
+    or because the one kv block that held its document is withheld, ends with
+    an output of exactly 0 and no gradient, and puts nothing into dk or dv.
+    At scale 1 ``_NEG_BIG · scale · log2 e`` is past float32 and at 3 so is
+    ``_M_NONE · scale``: nothing may come to ``inf - inf``."""
+    patch_rule(_starved_of(starved))
+    rows = ROWS["short_documents"]
+    q, k, v, do = _case(_L, _L, 32, 32, seed=9)
+    q = q * (1.0 if scale is None else 0.35 / scale)
+    run = _with_scale(_INTERPRETED, True, jnp.asarray(rows[None]), scale)  # traced below, under the patched rule
+    got = run(q, k, v, do)
+    for t, name in zip(got, ("o", "dq", "dk", "dv")):
+        assert np.isfinite(np.asarray(t)).all(), name
+    block, blind = _block_rows(1), np.zeros(_L, bool)
+    # all of q block 1, or its rows whose document starts inside it: causal, so every key of theirs was in the withheld block
+    blind[block] = True if starved == 1 else rows[block] != rows[block.start - 1]
+    assert blind.any() and not blind.all()
+    o, dq = np.asarray(got[0]), np.asarray(got[1])
+    assert not o[:, :, blind].any() and not dq[:, :, blind].any()
+    assert o[:, :, ~blind].any() and dq[:, :, ~blind].any()
+    # and the rows that do see keys are what they are without the blind rows' cotangent
+    again = run(q, k, v, do * jnp.asarray(~blind, do.dtype)[None, None, :, None])
+    for g, w, name in zip(got, again, ("o", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=name)

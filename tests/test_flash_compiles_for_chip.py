@@ -110,7 +110,8 @@ def test_segmented_kernels_compile_at_the_cells_widths(one_chip, no_compile_cach
     assert triangle < n_q * n_k and _walks_a_list_of(text, ROWS * triangle)
     # per head the operands are the benchmark's; the ids ride per batch row
     assert "bf16[{},{},{}]".format(ROWS * HEADS, SEQ, HEAD_DIM) in text
-    assert "s32[{},{},8]".format(ROWS * HEADS, SEQ) not in text
+    assert "s32[{},{},128]".format(ROWS, SEQ) in text  # the query side's, an id in every lane
+    assert "s32[{},{},128]".format(ROWS * HEADS, SEQ) not in text
     assert "s32[{},8,{}]".format(ROWS * HEADS, SEQ) not in text
 
 
@@ -123,6 +124,16 @@ def test_segmented_kernels_compile_at_latent_attention_widths(one_chip, no_compi
     assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
     assert "bf16[32,8192,192]" in text and "bf16[32,8192,128]" in text
     assert _walks_a_list_of(text, 136)  # 16 x 17 / 2 of 256 blocks
+
+
+def test_segmented_kernels_compile_at_a_value_head_of_256(one_chip, no_compile_cache):
+    """Values twice a register's lanes wide, the widest the module documents:
+    the forward's lane-wide row statistics meet an accumulator of two
+    registers a row (``pltpu.repeat``), the backward a dv block as wide."""
+    text = _compiled_text(one_chip, True, shape=(1, 8, SEQ, 128), value_dim=256)
+    assert _kernels(text) == ["flash_bwd_dkv_seg", "flash_fwd_seg"]
+    assert "bf16[8,4096,128]" in text and "bf16[8,4096,256]" in text
+    assert _walks_a_list_of(text, 36)
 
 
 def test_segmented_kernels_compile_at_one_row_of_16k(one_chip, no_compile_cache):
@@ -153,7 +164,8 @@ def test_block_diffusion_kernels_compile_at_the_cells_widths(one_chip, no_compil
     the block-diffusion rule. What only the chip's compiler can say: the
     grouped backward's three-dimensional grid, its whole-row dk and dv scratch
     beside the dq row (40 MiB of VMEM under the limit the call sets), the
-    marks riding where the ids ride, and the key/value operands at 4 heads:
+    marks riding where the ids ride (the backward fetches 768 KiB of them a
+    step), and the key/value operands at 4 heads:
     nothing repeated in HBM."""
     q = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((2, 4, 8192, 128), jnp.bfloat16, sharding=one_chip)
@@ -167,7 +179,8 @@ def test_block_diffusion_kernels_compile_at_the_cells_widths(one_chip, no_compil
     assert _kernels(text) == ["flash_bwd_dkv_bd", "flash_fwd_bd"]
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     # one table of 2 rows x the square of 16 blocks, then q at 32 heads and k, v at 4
-    opening = "operand_layout_constraints={s32[], s32[512]{0}, bf16[64,8192,128]{2,1,0}, bf16[8,8192,128]{2,1,0}, bf16[8,8192,128]{2,1,0}, s32[2,8192,8]"
+    # the query side's three marks a position, 128 lanes each (a row's value in every lane)
+    opening = "operand_layout_constraints={s32[], s32[512]{0}, bf16[64,8192,128]{2,1,0}, bf16[8,8192,128]{2,1,0}, bf16[8,8192,128]{2,1,0}, s32[2,8192,384]"
     assert len(calls) == 2 and all(opening in line for line in calls)
     backward = next(line for line in calls if "flash_bwd_dkv_bd" in line)
     # dq at the 32 query heads, dk and dv at the 4 key/value heads: summed over the group inside the kernel
