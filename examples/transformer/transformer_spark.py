@@ -23,7 +23,12 @@ TPU-native capabilities the framework adds on top of reference parity:
   (``examples/transformer/sdar_toy.json``: grouped-query attention, softmax
   top-k experts) trains by diffusion over blocks: the text plane noises
   every packed row and the model reads the clean copy beside the noised one
-  under the block-diffusion mask.
+  under the block-diffusion mask. A file with ``layer_types``
+  (``examples/transformer/laguna_toy.json``: windowed layers of one head
+  count among full ones of another, rotary by layer type, a sigmoid gate a
+  head, a shared expert beside softmax top-k ones) trains its sliding layers
+  under the attention's window rule, and the text plane counts their blocks
+  apart (``flash_win_*``).
 
 Data is real: TFRecord text shards stream through the sequence-packing
 :class:`~tensorflowonspark_tpu.data.TextPipeline` (per-worker file shards,
@@ -43,6 +48,10 @@ Usage (single host):
     # block diffusion (the objective is the configuration's):
     python examples/transformer/transformer_spark.py --model decoder \
         --model_config examples/transformer/sdar_toy.json --seq_len 128 \
+        --tokenizer word --platform cpu
+    # windowed layers among full ones (the layers' types are the configuration's):
+    python examples/transformer/transformer_spark.py --model decoder \
+        --model_config examples/transformer/laguna_toy.json --seq_len 128 \
         --tokenizer word --platform cpu
 """
 
@@ -187,6 +196,8 @@ def main_fun(args, ctx, observer=None):
         seed=ctx.executor_id, epochs=None, max_bad_records=args.max_bad_records,
         pack_workers=args.pack_workers, slab_cache_dir=args.slab_cache_dir,
         block_diffusion={"block_length": model.cfg.block_length, "mask_id": model.cfg.mask_id} if diffusion else None,
+        # a model with windowed layers has the text plane count their attention blocks beside the full layers'
+        attention_window=getattr(model.cfg, "sliding_window", None),
     )
     stream = iter(pipe)
 

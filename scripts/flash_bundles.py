@@ -13,7 +13,7 @@ inside a walk that PR 29 read on the chip (PERF.md §7). A unit's column
 summed over the region, over the unit's slots a bundle, is the bundles that
 unit would need alone: the region can be no shorter than the fullest.
 
-    python scripts/flash_bundles.py                 # the three LM cells' shapes
+    python scripts/flash_bundles.py                 # the four LM cells' shapes
     python scripts/flash_bundles.py --cell lm1024   # one of them
     python scripts/flash_bundles.py --repo <dir>    # another checkout's kernels
 
@@ -40,7 +40,9 @@ CELLS = {
     "lm1024": ((4, 16, 4096, 64), None, None, "causal"),  # lm1024.packed4k
     "xing4-a4b": ((1, 32, 8192, 192), None, 128, "causal"),  # xing4-a4b.packed8k
     "sdar-30b-a3b": ((2, 32, 8192, 128), 4, None, "block_diffusion"),  # sdar-30b-a3b.bd4-packed4k
+    "laguna-s-2-1": ((1, 72, 8192, 128), 8, None, "window"),  # laguna-s-2-1.code8k's sliding layers
 }
+WINDOW = 512  # the ``window`` rule's
 
 #: a bundle's line: its number, a control target's mark if it is one, the loop's depth, the operations
 _BUNDLE = re.compile(r"^\s*(0x[0-9a-f]+|\d+)\s+(LH|LB|LE|PB|PF|CT)?:[\s>]*\{")
@@ -68,6 +70,8 @@ def _compile(cell):
     def loss(q, k, v, seg, labels):
         if rule == "block_diffusion":
             o = flash_attention(q, k, v, segment_ids=seg, labels=labels, rule=rule)
+        elif rule == "window":
+            o = flash_attention(q, k, v, causal=True, segment_ids=seg, rule=rule, window=WINDOW)
         else:
             o = flash_attention(q, k, v, causal=True, segment_ids=seg)
         return (o.astype(jnp.float32) ** 2).sum()
@@ -145,7 +149,7 @@ def measure(cell, repo, keep=None):
 
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    parser.add_argument("--cell", action="append", choices=sorted(CELLS), help="default: all three")
+    parser.add_argument("--cell", action="append", choices=sorted(CELLS), help="default: all four")
     parser.add_argument("--repo", default=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                         help="the checkout whose kernels are compiled (default: this one)")
     parser.add_argument("--keep", help="a directory to copy the kernels' final schedules into")

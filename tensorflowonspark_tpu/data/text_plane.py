@@ -57,6 +57,13 @@ block-diffusion rule). Timed by the span ``producer_noise``
 (``data_producer_noise_seconds_total``) and counted in
 ``bd_positions_masked_total`` / ``bd_tokens_real_total``.
 
+**A model with windowed layers** (``attention_window=``: the window its
+windowed layers attend within) reads the same rows under two rules, and its
+windowed layers need other blocks of them than its full ones. The producer
+then also feeds the ``flash_win_*`` counters, by the numpy twin of the
+kernels' third rule: blocks needed, the triangle's, the grid steps, and the
+pairs the rule shows over the pairs of the blocks computed.
+
 Chaos sites native to this stage: ``data.tokenize_error`` poisons a
 record's bytes producer-side so the tokenizer rejects it (charged against
 ``max_bad_records``, identically in every pack mode) and
@@ -147,6 +154,8 @@ class TextPipeline(ImagePipeline):
     - ``block_diffusion`` (a dict: ``block_length``, ``mask_id``, ``t_min``
       default 1e-3) adds the noising stage and its batch keys (the module's
       text);
+    - ``attention_window`` (the window of the model's windowed layers) adds
+      the ``flash_win_*`` counters beside the ``flash_*`` ones;
     - ``cache="decoded"`` and ``recycle_buffers`` are not supported (the
       decoded-pair cache is image-geometry machinery; packed rows already
       have the packed-slab cache).
@@ -184,6 +193,7 @@ class TextPipeline(ImagePipeline):
         store=None,
         prefetch=None,
         block_diffusion=None,
+        attention_window=None,
     ):
         if cache == "decoded":
             raise ValueError(
@@ -217,6 +227,9 @@ class TextPipeline(ImagePipeline):
         self.tokenizer = tokenizer
         self.seq_len = seq_len
         self.pack_ahead = float(pack_ahead)
+        self.attention_window = None if attention_window is None else int(attention_window)
+        if self.attention_window is not None and block_diffusion is not None:
+            raise ValueError("attention_window counts next-token rows; block_diffusion reads its rows under a rule of its own")
         self.block_diffusion = None
         if block_diffusion is not None:
             self.block_diffusion = dict({"t_min": 1e-3}, **block_diffusion)
@@ -328,6 +341,28 @@ class TextPipeline(ImagePipeline):
             help="grid steps the segmented flash kernels take for the emitted rows, per head "
             "and pass: every row of a batch walks as many as the batch's longest list of needed blocks",
         )
+
+        attention_window = self.attention_window
+        if attention_window is not None:
+            win_c = {
+                "blocks_needed": obs.counter(
+                    "flash_win_blocks_needed_total",
+                    help="attention blocks the windowed flash kernels compute for the emitted rows (some query "
+                    "shares a document with some key inside its window), per head and pass"),
+                "blocks_dense": obs.counter(
+                    "flash_win_blocks_dense_total",
+                    help="blocks of the causal triangle over the emitted rows, as flash_blocks_dense_total"),
+                "grid_steps": obs.counter(
+                    "flash_win_grid_steps_total",
+                    help="grid steps the windowed flash kernels take for the emitted rows, per head and pass"),
+                "pairs_visible": obs.counter(
+                    "flash_win_pairs_visible_total",
+                    help="query-key pairs the window rule shows in the emitted rows, per head"),
+                "pairs_in_blocks": obs.counter(
+                    "flash_win_pairs_in_blocks_total",
+                    help="query-key pairs of the blocks the windowed flash kernels compute for the emitted rows, "
+                    "per head and pass"),
+            }
 
         # the pack plane forks its workers HERE, before any pipeline thread
         # exists (fork-with-threads is the one mp lifecycle hazard)
@@ -527,7 +562,14 @@ class TextPipeline(ImagePipeline):
                         twice, np.concatenate([2 * block, 2 * block + 1], axis=1))
                 else:
                     # the columns the LM attends (make_loss_fn feeds [:, :-1])
-                    needed, dense, steps = flash_blocks.attended_blocks(buf[:rows, 1, :-1])
+                    attended = buf[:rows, 1, :-1]
+                    needed, dense, steps = flash_blocks.attended_blocks(attended)
+                    if attention_window is not None:
+                        in_window = flash_blocks.attended_blocks(attended, window=attention_window)
+                        for name, value in zip(("blocks_needed", "blocks_dense", "grid_steps"), in_window):
+                            win_c[name].inc(value)
+                        win_c["pairs_visible"].inc(flash_blocks.visible_pairs(attended, attention_window))
+                        win_c["pairs_in_blocks"].inc(in_window[0] * flash_blocks.block_pairs(attended))
                 emitted_batches[0] += 1
                 blocks_needed_c.inc(needed)
                 blocks_dense_c.inc(dense)

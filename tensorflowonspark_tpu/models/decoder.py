@@ -14,13 +14,22 @@ attention    ``mla``     :class:`LatentAttention`: low-rank query and key/value
 attention    ``gqa``     :class:`GroupedQueryAttention`: q/k/v/o projections,
                          ``num_key_value_heads`` key/value heads each read by
                          a group of query heads, an RMSNorm with a learned
-                         weight on every head of q and k, rotary positions
-                         over the whole head (halves rotated together)
+                         weight on every head of q and k (``qk_norm``), rotary
+                         positions over the whole head or, by the layer's
+                         type, YaRN's over a part of it (halves rotated
+                         together), optionally a sigmoid gate a head on the
+                         heads' outputs (``gating: per-head``); where ``layer_types`` says
+                         ``sliding_attention`` the layer has its own head
+                         count (``num_attention_heads_per_layer``), its own
+                         rotary (``rope_parameters``) and the attention's
+                         third rule, causal within ``sliding_window``
+                         positions: the layer's :class:`HeadsPlan`
 feed-forward ``swiglu``  :class:`SwiGLU`: the dense gated MLP
 feed-forward ``moe``     :class:`RoutedExperts`: scores (``scoring_func``:
                          ``sigmoid`` with a selection bias, ``noaux_tc``; or
                          ``softmax`` over all experts, no bias), top-k, the
-                         chosen scores renormalised, the experts *held here*
+                         chosen scores renormalised and scaled, the experts
+                         *held here*
                          applied to the slots routed to them (nothing
                          dropped), beside shared experts, if any, that see
                          every token
@@ -38,11 +47,21 @@ The configuration is a dict with the published ``config.json``'s keys
 (:class:`DecoderConfig`), in either of two dialects: the one that says
 ``n_routed_experts`` (latent attention where ``kv_lora_rank`` is given,
 sigmoid ``noaux_tc`` routing) and the one that says ``num_experts`` (``gqa``,
-softmax routing without a bias; ``decoder_sparse_step`` 1, no
-``mlp_only_layers``, no sliding window). ``layer_plan`` lists the layers'
-kinds and defaults to ``first_k_dense_replace`` dense layers followed by
-routed ones, with ``mla`` where the configuration has a ``kv_lora_rank`` and
-``gqa`` where it has none.
+softmax routing without a bias; ``decoder_sparse_step`` 1). The second may
+say more, layer by layer: ``layer_types`` (``full_attention`` /
+``sliding_attention`` with ``sliding_window``), ``num_attention_heads_per_layer``,
+``rope_parameters`` by layer type (``rope_type`` ``default`` or ``yarn``,
+``partial_rotary_factor``), ``gating`` (``per-head`` is the one implemented),
+``mlp_only_layers`` / ``mlp_layer_types`` (which layers are dense),
+``shared_expert_intermediate_size`` and ``moe_routed_scaling_factor``; a list a
+layer is read as far as ``num_hidden_layers``, so a cut in depth keeps the
+published lists. ``layer_plan`` lists the layers' kinds and defaults to the
+published pattern: ``first_k_dense_replace`` (or the named) dense layers and
+routed ones after, with ``mla`` where the configuration has a
+``kv_lora_rank`` and ``gqa`` otherwise; what differs from one ``gqa`` layer
+to the next (head count, window, rotary, gate) is the layer's
+:class:`HeadsPlan` (:meth:`DecoderConfig.heads_plan`, from ``layer_types``
+and the lists beside it).
 
 ``objective`` is ``next_token`` or ``block_diffusion`` (with ``block_length``
 and ``mask_token_id``: a published configuration gives neither, so the job
@@ -69,7 +88,8 @@ position and head, so the flash forward kernel runs once a layer
 hyper-connections' reading is computed again.
 
 Device scopes (``jax.named_scope``, in every operation's ``op_name``):
-``tos.mla``, ``tos.gqa``, ``tos.moe_route`` (router, top-k, sort, gather, combine),
+``tos.mla``, ``tos.gqa`` (a windowed layer's attention: ``tos.swa``), ``tos.attn_gate``
+inside both, ``tos.moe_route`` (router, top-k, sort, gather, combine),
 ``tos.moe_experts`` (the grouped products), ``tos.moe_shared``,
 ``tos.dense_mlp``, ``tos.mhc``. What the routed layers count in a step is
 sown into the ``counters`` collection (``moe_slots_routed``,
@@ -109,11 +129,32 @@ _IGNORED_KEYS = (
 )
 _REQUIRED_VALUES = {
     "attention_bias": False, "hidden_act": "silu", "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
-    "decoder_sparse_step": 1, "mlp_only_layers": [], "use_sliding_window": False, "sliding_window": None,
+    "decoder_sparse_step": 1, "use_sliding_window": False, "moe_router_logit_softcapping": 0,
+    "moe_apply_router_weight_on_input": False,
+}
+LAYER_TYPES = ("full_attention", "sliding_attention")
+ROPE_TYPES = ("default", "yarn")
+#: the keys a published config may give a layer's rotary positions by
+_ROPE_KEYS = {
+    "rope_type", "rope_theta", "partial_rotary_factor", "factor", "original_max_position_embeddings", "beta_fast",
+    "beta_slow", "attention_factor",
 }
 #: the routing each dialect's ``scoring_func`` goes with
 _TOPK_METHODS = {"sigmoid": "noaux_tc", "softmax": "greedy"}
 OBJECTIVES = ("next_token", "block_diffusion")
+
+
+@dataclasses.dataclass(frozen=True)
+class HeadsPlan:
+    """What one layer's grouped-query attention is where the layers differ."""
+
+    heads: int
+    #: the third rule's window; None: the whole document
+    window: int = None
+    #: the layer type's ``rope_parameters`` as sorted items; (): ``rope_theta`` over the whole head
+    rope: tuple = ()
+    #: a sigmoid gate a head on the heads' outputs
+    gate: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,9 +169,20 @@ class DecoderConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # grouped-query attention (``gqa``); ``mla`` reads neither
+    # grouped-query attention (``gqa``); ``mla`` reads none of these
     num_key_value_heads: int = 0
     head_dim: int = 0
+    #: an RMSNorm with a learned weight on every head of q and of k
+    qk_norm: bool = True
+    #: per layer ``full_attention`` / ``sliding_attention``; (): every layer full
+    layer_types: tuple = ()
+    sliding_window: int = None
+    #: per layer; (): ``num_attention_heads`` everywhere
+    num_attention_heads_per_layer: tuple = ()
+    #: ((layer type, the type's rotary parameters as sorted items), …)
+    rope_parameters: tuple = ()
+    #: ``per-head``: a sigmoid gate a head on the attention's output; None: none
+    gating: str = None
     rope_theta: float = 10000.0
     #: the ``rope_scaling`` dict (``type: yarn``) as sorted items, or ()
     rope_scaling: tuple = ()
@@ -145,8 +197,19 @@ class DecoderConfig:
     #: ``sigmoid`` (top-k of the biased scores) or ``softmax`` (no bias)
     scoring_func: str = "sigmoid"
     n_shared_experts: int = 0
+    #: the shared expert's width where the configuration names it (else
+    #: ``n_shared_experts`` times the routed experts')
+    shared_expert_intermediate_size: int = 0
     routed_scaling_factor: float = 1.0
+    #: padding positions (segment id 0) are routed like any token; False: they
+    #: take no expert's slot (a packed row's padding is one token at one
+    #: position: it all goes to the same experts, and where one is held here
+    #: the layer's held slots pass the compact slot buffer)
+    padding_slots: bool = True
     first_k_dense_replace: int = 0
+    #: the layers that are dense whatever their place (with the first
+    #: ``first_k_dense_replace``)
+    mlp_only_layers: tuple = ()
     # residual path
     hc_mult: int = 1
     hc_sinkhorn_iters: int = 20
@@ -179,6 +242,9 @@ class DecoderConfig:
         if "num_experts" in cfg:  # the dialect of softmax routers: no scoring_func key, no bias
             cfg["n_routed_experts"] = cfg.pop("num_experts")
             cfg.setdefault("scoring_func", "softmax")
+        if "moe_routed_scaling_factor" in cfg:
+            cfg["routed_scaling_factor"] = cfg.pop("moe_routed_scaling_factor")
+        _per_layer(cfg, cfg.get("num_hidden_layers", 0))
         scoring = cfg.setdefault("scoring_func", "sigmoid")
         if scoring not in _TOPK_METHODS or cfg.pop("topk_method", _TOPK_METHODS[scoring]) != _TOPK_METHODS[scoring]:
             raise ValueError("decoder: scoring_func/topk_method must be one of {}".format(sorted(_TOPK_METHODS.items())))
@@ -192,6 +258,7 @@ class DecoderConfig:
             cfg["experts_held"] = tuple(cfg["experts_held"])
         if cfg.get("layer_plan") is not None:
             cfg["layer_plan"] = tuple(tuple(layer) for layer in cfg["layer_plan"])
+        cfg["rope_parameters"] = _rope_parameters(cfg.pop("rope_parameters", None) or {})
         unknown = set(cfg) - {f.name for f in dataclasses.fields(cls)}
         if unknown:
             raise ValueError("decoder: unknown configuration keys {}".format(sorted(unknown)))
@@ -209,9 +276,10 @@ class DecoderConfig:
             plan = self.layer_plan
         else:
             residual = "mhc" if self.hc_mult > 1 else "add"
-            attention = "mla" if self.kv_lora_rank else "gqa"
             plan = tuple(
-                (attention, "swiglu" if i < self.first_k_dense_replace or not self.n_routed_experts else "moe", residual)
+                ("mla" if self.kv_lora_rank else "gqa",
+                 "swiglu" if (i < self.first_k_dense_replace or i in self.mlp_only_layers
+                              or not self.n_routed_experts) else "moe", residual)
                 for i in range(self.num_hidden_layers))
         if len(plan) != self.num_hidden_layers:
             raise ValueError("decoder: layer_plan has {} layers, num_hidden_layers is {}".format(
@@ -224,6 +292,21 @@ class DecoderConfig:
                 raise ValueError("decoder: an 'add' residual carries one stream (hc_mult 1)")
         return plan
 
+    def heads_plan(self, index):
+        """Layer ``index``'s :class:`HeadsPlan` (kind ``gqa``)."""
+        windowed = bool(self.layer_types) and self.layer_types[index] == "sliding_attention"
+        per_layer = self.num_attention_heads_per_layer
+        return HeadsPlan(
+            heads=per_layer[index] if per_layer else self.num_attention_heads,
+            window=self.sliding_window if windowed else None,
+            rope=dict(self.rope_parameters).get(LAYER_TYPES[windowed], ()),
+            gate=self.gating is not None)
+
+    @property
+    def shared_width(self):
+        """The shared expert's width; 0: the routed layers have none."""
+        return self.shared_expert_intermediate_size or self.moe_intermediate_size * self.n_shared_experts
+
     @property
     def mask_id(self):
         return self.vocab_size - 1 if self.mask_token_id is None else self.mask_token_id
@@ -232,6 +315,48 @@ class DecoderConfig:
     def held(self):
         """(first, count) of the routed experts held here."""
         return self.experts_held if self.experts_held is not None else (0, self.n_routed_experts)
+
+
+def _per_layer(cfg, layers):
+    """The keys of a published configuration that say something layer by
+    layer, checked and cut to the ``layers`` the model has (in place)."""
+    for key, allowed in (("layer_types", LAYER_TYPES), ("gating_types", ("per_head",)),
+                         ("mlp_layer_types", ("dense", "sparse"))):
+        if cfg.get(key) is not None:
+            cfg[key] = tuple(cfg[key][:layers])
+            if len(cfg[key]) != layers or set(cfg[key]) - set(allowed):
+                raise ValueError("decoder: {} must name {} layers, each one of {}".format(key, layers, allowed))
+    if cfg.pop("gating_types", None) is not None:
+        cfg.setdefault("gating", "per-head")
+    if cfg.get("gating") not in (None, "per-head"):
+        raise ValueError("decoder: gating {!r} is not implemented (per-head is)".format(cfg["gating"]))
+    if cfg.get("num_attention_heads_per_layer") is not None:
+        cfg["num_attention_heads_per_layer"] = tuple(cfg["num_attention_heads_per_layer"][:layers])
+        if len(cfg["num_attention_heads_per_layer"]) != layers:
+            raise ValueError("decoder: num_attention_heads_per_layer must name {} layers".format(layers))
+    dense = tuple(i for i in cfg.get("mlp_only_layers") or () if i < layers)
+    by_type = cfg.pop("mlp_layer_types", None)
+    if by_type is not None:
+        named = tuple(i for i, kind in enumerate(by_type) if kind == "dense")
+        if "mlp_only_layers" in cfg and named != dense:
+            raise ValueError("decoder: mlp_layer_types and mlp_only_layers name different dense layers")
+        dense = named
+    cfg["mlp_only_layers"] = dense
+    windowed = "sliding_attention" in (cfg.get("layer_types") or ())
+    if windowed != bool(cfg.get("sliding_window")):
+        raise ValueError("decoder: sliding_window goes with sliding_attention layers in layer_types, and they with it")
+
+
+def _rope_parameters(by_type):
+    """``rope_parameters`` (a dict a layer type) as the hashable the
+    configuration keeps, each type's keys and kind checked."""
+    out = []
+    for kind, params in sorted(by_type.items()):
+        if kind not in LAYER_TYPES or set(params) - _ROPE_KEYS or params.get("rope_type", "default") not in ROPE_TYPES:
+            raise ValueError("decoder: rope_parameters[{!r}] = {} is not implemented (layer types {}, rope_type {}, "
+                             "keys {})".format(kind, params, LAYER_TYPES, ROPE_TYPES, sorted(_ROPE_KEYS)))
+        out.append((kind, tuple(sorted(params.items()))))
+    return tuple(out)
 
 
 def yarn_inv_freq(dim, theta, scaling):
@@ -268,6 +393,31 @@ def _rope_interleaved(x, positions, inv_freq, scale):
     even, odd = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def _rope_halves(x, positions, inv_freq, scale):
+    """Rotary positions over the first ``2 * len(inv_freq)`` of the last dim
+    of ``x`` ``[B, L, H, D]``, that part's halves rotated together, cos and
+    sin times ``scale``; the rest of the head passes as it is."""
+    half = inv_freq.shape[0]
+    angles = positions[:, :, None].astype(jnp.float32) * inv_freq  # [B, L, half]
+    cos, sin = (jnp.cos(angles) * scale)[:, :, None, :], (jnp.sin(angles) * scale)[:, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., 2 * half:]], axis=-1).astype(x.dtype)
+
+
+def _rotary(cfg, rope, width):
+    """``rope(t, positions)`` of a ``gqa`` layer whose type's
+    ``rope_parameters`` are ``rope`` ({}: the model's ``rope_theta`` over the
+    whole head of ``width``)."""
+    if not rope:
+        return lambda t, positions: transformer._rope(t, positions, cfg.rope_theta)
+    yarn = rope.get("rope_type", "default") == "yarn"
+    inv_freq = yarn_inv_freq(
+        int(width * rope.get("partial_rotary_factor", 1.0)), rope.get("rope_theta", cfg.rope_theta),
+        rope if yarn else None)
+    scale = rope.get("attention_factor", yarn_mscale(rope["factor"], 1.0)) if yarn else 1.0
+    return lambda t, positions: _rope_halves(t, positions, inv_freq, scale)
 
 
 def _norm(cfg, name):
@@ -323,42 +473,62 @@ class LatentAttention(nn.Module):
             return nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out)
 
 
-def _rule(labels):
-    """The attention's rule and labels: the second rule where the model was
-    given labels, else the call as it was."""
+def _rule(labels, window=None):
+    """The attention's rule with what it takes: the second rule where the
+    model was given labels, the third in a windowed layer, else the call as
+    it was."""
+    if window is not None:
+        if labels is not None:
+            raise ValueError("decoder: a windowed layer knows no block-diffusion objective")
+        return {"rule": "window", "window": window}
     return {} if labels is None else {"rule": "block_diffusion", "labels": labels}
 
 
 class GroupedQueryAttention(nn.Module):
-    """``num_attention_heads`` query heads over ``num_key_value_heads``
-    key/value heads of ``head_dim``: query head ``h`` reads key/value head
-    ``h // (heads / kv heads)`` (the flash kernels read it in place). Every
-    head of q and of k goes through an RMSNorm over its ``head_dim`` with one
-    learned weight for all heads, then rotary positions over the whole head,
-    its halves rotated together; scores times ``head_dim ** -0.5``."""
+    """``layer.heads`` query heads over ``num_key_value_heads`` key/value
+    heads of ``head_dim``: query head ``h`` reads key/value head ``h //
+    (heads / kv heads)`` (the flash kernels read it in place). Under
+    ``qk_norm`` every head of q and of k goes through an RMSNorm over its
+    ``head_dim`` with one learned weight for all heads. Then rotary
+    positions, halves rotated together: over the whole head at
+    ``rope_theta``, or as the layer's ``rope`` says (its own base, YaRN's
+    frequencies with cos and sin times ``attention_factor``, the first
+    ``partial_rotary_factor`` of the head alone). Scores times ``head_dim **
+    -0.5`` under the layer's rule: the whole document, or ``layer.window``
+    positions of it. Under ``layer.gate`` head ``h``'s output is multiplied by
+    ``sigmoid(x W_g)[h]``, ``x`` the sub-layer's normed input, before the
+    output projection."""
 
     cfg: DecoderConfig
-    mesh: object = None
+    mesh: object
+    layer: HeadsPlan
 
     PARAM_RULES = (
         (r"attn/(q|k|v)/kernel$", ("fsdp", "tp", None)),  # [d, heads, head_dim]
+        (r"attn/gate/kernel$", ("fsdp", "tp")),  # [d, heads]
         (r"attn/o/kernel$", ("tp", None, "fsdp")),  # [H, head_dim, d]
     )
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, labels=None):
-        cfg, dt = self.cfg, self.cfg.compute_dtype
-        heads, width = cfg.num_attention_heads, cfg.head_dim or cfg.hidden_size // cfg.num_attention_heads
+        cfg, dt, layer = self.cfg, self.cfg.compute_dtype, self.layer
+        heads, width = layer.heads, cfg.head_dim or cfg.hidden_size // cfg.num_attention_heads
         kv_heads = cfg.num_key_value_heads or heads
-        with jax.named_scope("tos.gqa"):
+        with jax.named_scope("tos.gqa" if layer.window is None else "tos.swa"):
             dense = lambda n, name: nn.DenseGeneral((n, width), use_bias=False, dtype=dt, name=name)  # noqa: E731
             q, k, v = dense(heads, "q")(x), dense(kv_heads, "k")(x), dense(kv_heads, "v")(x)  # [B, L, ·, width]
-            q = transformer._rope(_norm(cfg, "q_norm")(q), positions, cfg.rope_theta)
-            k = transformer._rope(_norm(cfg, "k_norm")(k), positions, cfg.rope_theta)
+            norm = (lambda t, name: _norm(cfg, name)(t)) if cfg.qk_norm else (lambda t, name: t)
+            rope = _rotary(cfg, dict(layer.rope), width)
+            q = rope(norm(q, "q_norm"), positions)
+            k = rope(norm(k, "k_norm"), positions)
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, ·, L, width]
             out = transformer._dispatch_attention(
-                q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids, **_rule(labels))
+                q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids, **_rule(labels, layer.window))
             out = out.transpose(0, 2, 1, 3)  # [B, L, H, width]
+            if layer.gate:
+                with jax.named_scope("tos.attn_gate"):
+                    gate = nn.Dense(heads, use_bias=False, dtype=dt, name="gate")(x)  # [B, L, H]
+                    out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)[..., None]
             return nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out)
 
 
@@ -390,7 +560,10 @@ class RoutedExperts(nn.Module):
     chosen: the top-k of ``s + b`` (``b`` the selection bias: it picks, it
     does not weigh, and no gradient reaches it). ``softmax``: ``s =
     softmax(x W_r)`` over all the experts, the k largest, and no bias (the
-    layer has no such parameter). Weights: ``s`` at the chosen, over their
+    layer has no such parameter); either kind may stand beside a shared
+    expert (``cfg.shared_width``). Where ``padding_slots`` is off, a padding
+    position (segment id 0) chooses no expert: its routed term is zero, and
+    only the shared expert sees it. Weights: ``s`` at the chosen, over their
     sum, times ``routed_scaling_factor``. Every slot whose expert is held here is
     computed — no capacity, nothing dropped; a slot whose expert lives on
     another chip adds nothing here (nor is anything put in its place).
@@ -416,7 +589,7 @@ class RoutedExperts(nn.Module):
     ) + SwiGLU.PARAM_RULES
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, segment_ids=None):
         cfg = self.cfg
         batch, length, d = x.shape
         tokens, k = batch * length, cfg.num_experts_per_tok
@@ -436,6 +609,9 @@ class RoutedExperts(nn.Module):
                 _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
             picked = jnp.take_along_axis(scores, chosen, axis=-1)
             weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+            if not cfg.padding_slots and segment_ids is not None:
+                # an expert past the router's last: held nowhere
+                chosen = jnp.where(segment_ids.reshape(tokens, 1) > 0, chosen, cfg.n_routed_experts)
             order, group_sizes = gm.sort_slots(chosen.reshape(-1), first, held)
             place, rows_used = gm.slot_places(order), jnp.sum(group_sizes)
 
@@ -458,7 +634,7 @@ class RoutedExperts(nn.Module):
             counts.update(layers_compact=fits.astype(jnp.float32), layers_at_bound=1.0 - fits)
 
         with jax.named_scope("tos.moe_shared"):
-            shared = SwiGLU(cfg, width * cfg.n_shared_experts, name="shared")(flat) if cfg.n_shared_experts else 0
+            shared = SwiGLU(cfg, cfg.shared_width, name="shared")(flat) if cfg.shared_width else 0
         return (routed + shared).reshape(batch, length, d), counts
 
 
@@ -624,21 +800,25 @@ class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     kinds: tuple
     mesh: object = None
+    #: what the layer's attention is where its kind is ``gqa``
+    heads: HeadsPlan = None
 
     @nn.compact
     def __call__(self, streams, positions, segment_ids=None, labels=None):
         cfg = self.cfg
         attention, feed_forward, residual = self.kinds
         path = _RESIDUALS[residual]
+        attend = (LatentAttention(cfg, self.mesh, name="attn") if attention == "mla"
+                  else GroupedQueryAttention(cfg, self.mesh, self.heads, name="attn"))
 
         h, maps = path(cfg, self.mesh, name="res_attn")(streams)
-        y = _ATTENTIONS[attention](cfg, self.mesh, name="attn")(_norm(cfg, "ln1")(h), positions, segment_ids, labels)
+        y = attend(_norm(cfg, "ln1")(h), positions, segment_ids, labels)
         streams = path.merge(streams, maps, y, self.mesh)
 
         h, maps = path(cfg, self.mesh, name="res_mlp")(streams)
         h, counts = _norm(cfg, "ln2")(h), {}
         if feed_forward == "moe":
-            y, counts = RoutedExperts(cfg, name="moe")(h)
+            y, counts = RoutedExperts(cfg, name="moe")(h, segment_ids)
         else:
             with jax.named_scope("tos.dense_mlp"):
                 y = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
@@ -671,7 +851,7 @@ class Decoder(nn.Module):
         layer = nn.remat(DecoderLayer, static_argnums=(), policy=REMAT_POLICY) if cfg.remat else DecoderLayer
         counted = []
         for i, kinds in enumerate(cfg.plan):
-            streams, counts = layer(cfg, kinds, self.mesh, name="layer_{}".format(i))(
+            streams, counts = layer(cfg, kinds, self.mesh, cfg.heads_plan(i), name="layer_{}".format(i))(
                 streams, positions, segment_ids, labels)
             streams = self._constrain(streams)
             if counts:

@@ -120,11 +120,12 @@ def _batch_axes(mesh, batch):
     return axes[0] if len(axes) == 1 else tuple(axes)
 
 
-def _flash(q, k, v, segment_ids, mesh, interpret, scale=None, rule="causal", labels=None):
+def _flash(q, k, v, segment_ids, mesh, interpret, scale=None, rule="causal", labels=None, window=None):
     """The pallas flash kernel on ``[B, H, L, D]`` (``k`` and ``v`` may have
     fewer heads: key/value groups), padded to the kernel's 128-row granule
     and run per shard. ``scale`` is the softmax scale where it is not ``D **
-    -0.5``; ``rule`` and ``labels`` are the kernel's (block diffusion).
+    -0.5``; ``rule`` with ``labels`` (block diffusion) or ``window`` are the
+    kernel's.
 
     A Mosaic custom call has no partitioning rule, so under pjit XLA would
     gather q/k/v and run the whole global batch's attention on every chip.
@@ -147,7 +148,8 @@ def _flash(q, k, v, segment_ids, mesh, interpret, scale=None, rule="causal", lab
     def local(q, k, v, seg=None, labels=None):
         # positions play no part in the second rule: its mask is the labels'
         return flash_attention(
-            q, k, v, causal=rule == "causal", scale=scale, segment_ids=seg, interpret=interpret, rule=rule, labels=labels)
+            q, k, v, causal=rule != "block_diffusion", scale=scale, segment_ids=seg, interpret=interpret, rule=rule,
+            labels=labels, window=window)
 
     if mesh is None or mesh.size == 1:
         out = local(q, k, v, segment_ids, labels)
@@ -186,7 +188,7 @@ def _masked_attention(q, k, v, mask, scale=None):
     return out.reshape(batch, heads, length, v.shape[-1]).astype(q.dtype)
 
 
-def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None, rule="causal", labels=None):
+def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None, rule="causal", labels=None, window=None):
     """Pick the attention path. ``auto``: ring over ``sp`` when the mesh
     shards the sequence, else the pallas flash kernel on TPU (plain below
     ``TOS_FLASH_MIN_SEQ``), else plain XLA attention. Forcing
@@ -209,6 +211,8 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None, rule=
     second mask (:mod:`~tensorflowonspark_tpu.ops.flash_blocks`): the flash
     paths hand both to the kernels, ``plain`` writes the mask out, and the
     ring path, whose blocks know the causal rule alone, refuses it.
+    ``rule="window"`` with ``window`` (a static integer) is the third: causal,
+    and at most ``window - 1`` positions back; the same three answers.
     """
     if impl not in _ATTENTION_IMPLS:
         raise ValueError(
@@ -220,6 +224,9 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None, rule=
     def plain(q, k, v):
         if rule == "block_diffusion":
             return _masked_attention(q, k, v, flash_blocks.bd_mask(segment_ids, labels, xp=jnp), scale)
+        if rule == "window":
+            ids = jnp.ones((q.shape[0], q.shape[2]), jnp.int32) if segment_ids is None else segment_ids
+            return _masked_attention(q, k, v, flash_blocks.window_mask(ids, window, xp=jnp), scale)
         group = q.shape[1] // k.shape[1]
         if group > 1:
             k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
@@ -233,7 +240,7 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None, rule=
             raise ValueError("ring attention knows the causal rule and one key/value head a query head")
         return ring_attention_sharded(q, k, v, mesh, causal=True, scale=scale, segment_ids=segment_ids)
     if impl == "flash_interpret":
-        return _flash(q, k, v, segment_ids, mesh, interpret=True, scale=scale, rule=rule, labels=labels)
+        return _flash(q, k, v, segment_ids, mesh, interpret=True, scale=scale, rule=rule, labels=labels, window=window)
     on_tpu = jax.default_backend() == "tpu"
     if impl == "flash" and not on_tpu:
         raise RuntimeError(
@@ -242,7 +249,7 @@ def _dispatch_attention(q, k, v, impl, mesh, segment_ids=None, scale=None, rule=
             "interpreter, or 'auto'/'plain'".format(jax.default_backend())
         )
     if on_tpu and (impl == "flash" or q.shape[2] >= _FLASH_MIN_SEQ):
-        return _flash(q, k, v, segment_ids, mesh, interpret=False, scale=scale, rule=rule, labels=labels)
+        return _flash(q, k, v, segment_ids, mesh, interpret=False, scale=scale, rule=rule, labels=labels, window=window)
     return plain(q, k, v)
 
 

@@ -130,6 +130,16 @@ never an ``[L, L]`` array, and the work lists hold the blocks the rule needs,
 on either side of the diagonal (their stride is the square). The kernels are
 named ``flash_fwd_bd`` and ``flash_bwd_dkv_bd``.
 
+**A third rule.** ``rule="window"`` with a static ``window`` is the causal
+rule within a window: a query sees the keys of its own document at most
+``window - 1`` positions before it, and itself. The mask is the causal one
+with a second comparison of the same two position arrays, the work lists
+hold the blocks of the band that the ids need (a q block's first needed kv
+block moves with it; :func:`flash_blocks.window_blocks`), and their stride is
+the band's length, not the triangle's. Inside this module the rule travels as
+the pair ``("window", window)``, one static value where the other rules are a
+string. The kernels are named ``flash_fwd_win`` and ``flash_bwd_dkv_win``.
+
 This is the single-device analogue of
 :mod:`tensorflowonspark_tpu.parallel.ring_attention` (same math, blocks
 streamed from local HBM instead of rotated over ICI). ``interpret=True`` runs
@@ -219,9 +229,18 @@ def _here(items_ref, heads, steps, axis=1):
     return _outer(item), _inner(item), item
 
 
-def _causal_mask(s, iq, ik, block_q, block_k):
+def _window(rule):
+    """The window of the third rule (``rule`` is ``("window", window)``), or
+    None under the other two."""
+    return rule[1] if isinstance(rule, tuple) else None
+
+
+def _causal_mask(s, iq, ik, block_q, block_k, window=None):
     q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if window is not None:
+        behind = q_pos - k_pos
+        return jnp.where((behind >= 0) & (behind < window), s, _NEG_BIG)
     return jnp.where(q_pos >= k_pos, s, _NEG_BIG)
 
 
@@ -257,7 +276,7 @@ def _scores(q_ref, k_ref, sq_ref, sk_ref, iq, ik, causal, block_q, block_k, rule
     if rule == "block_diffusion":
         return _block_diffusion_mask(s, sq_ref, sk_ref)
     if causal:
-        s = _causal_mask(s, iq, ik, block_q, block_k)
+        s = _causal_mask(s, iq, ik, block_q, block_k, _window(rule))
     if sq_ref is not None:
         s = _segment_mask(s, sq_ref, sk_ref)
     return s
@@ -465,8 +484,11 @@ def _block_map(seg, n_q, n_k, block_q, block_k, causal, rule="causal"):
             bounds = (zq, zq, zk, zk)
         else:
             bounds = flash_blocks.block_bounds(seg, block_q, block_k, xp=jnp)
-        needed = flash_blocks.blocks_needed(bounds, block_q, block_k, causal, xp=jnp)
-    fwd_steps, bwd_steps = _steps(n_q, n_k, block_q, block_k, causal)
+        if _window(rule) is None:
+            needed = flash_blocks.blocks_needed(bounds, block_q, block_k, causal, xp=jnp)
+        else:
+            needed = flash_blocks.window_blocks_needed(bounds, block_q, block_k, _window(rule), xp=jnp)
+    fwd_steps, bwd_steps = _steps(n_q, n_k, block_q, block_k, causal, rule)
     forward, fwd_lengths = flash_blocks.work_list(needed, fwd_steps, xp=jnp)
     backward, bwd_lengths = flash_blocks.work_list(needed.swapaxes(1, 2), bwd_steps, xp=jnp)
     return (forward.reshape(-1), fwd_lengths.max()), (backward.reshape(-1), bwd_lengths.max())
@@ -477,11 +499,11 @@ def _block_map(seg, n_q, n_k, block_q, block_k, causal, rule="causal"):
 _SMEM_MOST = 960 * 2 ** 10
 
 
-def _steps(n_q, n_k, block_q, block_k, causal):
+def _steps(n_q, n_k, block_q, block_k, causal, rule="causal"):
     """``(forward, backward)`` lengths of the accumulating grid axis: the
     longest work list the shape allows (:func:`flash_blocks.work_bound`: the
-    causal triangle, or the square), Python integers."""
-    dense = flash_blocks.dense_blocks(n_q, n_k, block_q, block_k, causal)
+    window's band, the causal triangle, or the square), Python integers."""
+    dense = flash_blocks.dense_blocks(n_q, n_k, block_q, block_k, causal, _window(rule))
     return flash_blocks.work_bound(dense), flash_blocks.work_bound(dense.T)
 
 
@@ -490,7 +512,7 @@ def _work(seg, rows, n_q, n_k, block_q, block_k, causal, backward, rule="causal"
     its table of work lists (the shape's bound), the table, and the batch's
     longest list. Refuses a call whose lists would not fit: the lists of all
     batch rows ride in SMEM, and an item names a block in 14 bits."""
-    steps = _steps(n_q, n_k, block_q, block_k, causal)[backward]
+    steps = _steps(n_q, n_k, block_q, block_k, causal, rule)[backward]
     if max(n_q, n_k) > flash_blocks.ITEM_BLOCKS_MOST or 4 * rows * steps > _SMEM_MOST:
         raise ValueError(
             "flash attention walks a list of the blocks it needs, kept in SMEM: {} rows x {} blocks ({} x {} "
@@ -626,6 +648,8 @@ def _kernel_name(which, segmented, rule="causal"):
     find by name, and a new name would drop the whole backward from them."""
     if rule == "block_diffusion":
         return "flash_{}_bd".format(which)
+    if _window(rule) is not None:
+        return "flash_{}_win".format(which)
     return "flash_{}{}".format(which, "_seg" if segmented else "")
 
 
@@ -846,7 +870,7 @@ _flash_attention_bhld.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 def flash_attention(
     q, k, v, causal=False, scale=None, segment_ids=None,
-    block_q=None, block_k=None, interpret=False, rule="causal", labels=None,
+    block_q=None, block_k=None, interpret=False, rule="causal", labels=None, window=None,
 ):
     """Flash attention over ``[batch, heads, seq, head_dim]`` arrays. ``v``
     (and the output) may have a head size of its own: latent attention's
@@ -859,6 +883,11 @@ def flash_attention(
     block + half``, the block counted from the document's start, half 0 the
     clean copy and 1 the noised; :mod:`~tensorflowonspark_tpu.ops.flash_blocks`
     states the rule). Positions play no part in it, so ``causal`` must be off.
+
+    ``rule="window"`` (static) with ``window`` (a static integer, at least 1)
+    is ``causal`` within a window: a query sees the keys of its document at
+    most ``window - 1`` positions before it, and itself; ``causal`` must be
+    on, ``segment_ids`` are optional as under the first rule.
 
     Drop-in replacement for
     :func:`tensorflowonspark_tpu.parallel.ring_attention.plain_attention`
@@ -885,6 +914,8 @@ def flash_attention(
         raise ValueError("flash attention: unknown rule {!r}; expected one of {}".format(rule, flash_blocks.RULES))
     if rule == "block_diffusion" and (causal or not segmented or labels is None):
         raise ValueError("flash attention: rule 'block_diffusion' takes segment_ids and labels, and causal=False")
+    if (rule == "window") != (window is not None) or (rule == "window" and (not causal or int(window) < 1)):
+        raise ValueError("flash attention: rule 'window' takes a window of at least 1 and causal=True, no other rule one")
     if block_q is None:
         block_q = flash_blocks.SEGMENTED_BLOCK_Q if segmented else DEFAULT_BLOCK_Q
     if block_k is None:
@@ -894,6 +925,6 @@ def flash_attention(
         seg = jnp.stack(flash_blocks.bd_marks(seg, labels, xp=jnp), axis=1)  # [batch, 4, seq]
     o = _flash_attention_bhld(
         merge(q), merge(k), merge(v), seg, h, float(scale), bool(causal),
-        int(block_q), int(block_k), bool(interpret), rule,
+        int(block_q), int(block_k), bool(interpret), ("window", int(window)) if rule == "window" else rule,
     )
     return o.reshape(b, h, l_q, v.shape[3])

@@ -49,6 +49,18 @@ fill whole blocks (the text plane's ``[clean ; noised]`` rows) it keeps
 exactly the blocks with a visible pair, and on any other marks never drops
 one. The lists' stride is then the square: which side of the diagonal a
 needed block lies on is the layout's business, not the rule's.
+
+**A third rule: a window.** ``rule="window"`` with a static ``window`` is the
+first rule, causal, with one more condition: key ``j`` is visible to query
+``i`` when both are of one document, ``j <= i`` and ``i - j < window`` (the
+window counts the query itself). A q block's first needed kv block then moves
+with it: a block pair is needed when its id intervals overlap, it is not
+above the diagonal and its nearest pair, the q block's first position and the
+kv block's last, lies inside the window (:func:`window_blocks`). For ids that
+do not decrease along the row that keeps exactly the blocks with a visible
+pair (the q block's first position and the kv block's last then share a
+document whenever the intervals overlap), for any ids it drops none, and the
+lists' stride is the band (:func:`dense_blocks`), not the triangle.
 """
 
 import numpy as np
@@ -116,6 +128,16 @@ def causal_blocks(n_q, n_k, block_q, block_k, xp=np):
     return first_k <= last_q
 
 
+def window_blocks(n_q, n_k, block_q, block_k, window, xp=np):
+    """``bool [n_q, n_k]``: blocks holding a key at or before a query and
+    fewer than ``window`` positions behind it (the band a windowed call can
+    need, whatever its ids): inside the triangle, and the q block's first
+    position within ``window`` of the kv block's last."""
+    last_k = xp.arange(n_k)[None, :] * block_k + (block_k - 1)
+    first_q = xp.arange(n_q)[:, None] * block_q
+    return causal_blocks(n_q, n_k, block_q, block_k, xp) & (first_q - last_k < window)
+
+
 def blocks_needed(bounds, block_q, block_k, causal=True, xp=np):
     """The rule, on :func:`block_bounds`' four tables: ``bool [rows, n_q,
     n_k]``, True where a q block's and a kv block's id intervals overlap
@@ -127,20 +149,55 @@ def blocks_needed(bounds, block_q, block_k, causal=True, xp=np):
     return needed
 
 
-def needed_blocks(segment_ids, block_q, block_k, causal=True, labels=None):
+def window_blocks_needed(bounds, block_q, block_k, window, xp=np):
+    """The window rule on :func:`block_bounds`' four tables: the blocks whose
+    id intervals overlap, inside the band of :func:`window_blocks`."""
+    band = window_blocks(bounds[0].shape[1], bounds[2].shape[1], block_q, block_k, window, xp)
+    return blocks_needed(bounds, block_q, block_k, causal=False, xp=xp) & band[None]
+
+
+def needed_blocks(segment_ids, block_q, block_k, causal=True, labels=None, window=None):
     """``bool [rows, L // block_q, L // block_k]``: True where the kernels
     compute the block for ``segment_ids`` ``[rows, L]`` (numpy, on the host);
-    with ``labels``, by the block-diffusion rule."""
+    with ``labels``, by the block-diffusion rule; with ``window``, by the
+    window rule."""
     if labels is not None:
         return bd_blocks_needed(bd_bounds(bd_marks(np.asarray(segment_ids), np.asarray(labels)), block_q, block_k))
     bounds = block_bounds(np.asarray(segment_ids), block_q, block_k)
+    if window is not None:
+        return window_blocks_needed(bounds, block_q, block_k, window)
     return blocks_needed(bounds, block_q, block_k, causal)
+
+
+def window_mask(segment_ids, window, xp=np):
+    """The window rule written out, ``bool [rows, L, L]`` (query, key): what
+    the kernels are held to, and the mask of the paths that materialise one
+    (``plain`` attention, small rows only). Padding (id 0) sees and is seen
+    by nothing."""
+    seg = segment_ids.astype(xp.int32)
+    at = xp.arange(seg.shape[1], dtype=xp.int32)
+    behind = at[None, :, None] - at[None, None, :]
+    return (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] > 0) & (behind >= 0) & (behind < window)
+
+
+def visible_pairs(segment_ids, window=None):
+    """Query-key pairs the causal rule (with ``window``: the window rule)
+    shows in rows of ids that do not decrease along the row, as the text
+    plane's (numpy; padding, id 0, shows none): a document's ``p``-th token
+    sees ``min(p, window)`` keys, itself among them."""
+    seg = np.asarray(segment_ids)
+    at = np.arange(seg.shape[1], dtype=np.int64)[None, :]
+    starts = np.concatenate([np.ones_like(seg[:, :1], bool), seg[:, 1:] != seg[:, :-1]], axis=1)
+    seen = at - np.maximum.accumulate(np.where(starts, at, 0), axis=1) + 1
+    if window is not None:
+        seen = np.minimum(seen, window)
+    return int(seen[seg > 0].sum())
 
 
 #: the rules a call may mask and skip by: ``causal`` is the module's first
 #: (with or without ids, with or without the triangle), ``block_diffusion``
-#: the second
-RULES = ("causal", "block_diffusion")
+#: the second, ``window`` the third (causal, and within a static window)
+RULES = ("causal", "block_diffusion", "window")
 
 #: what a noised key's mark lies above its clean copy's, and how a mark packs
 #: document and block: ``id << BD_BLOCK_BITS | block``
@@ -218,9 +275,11 @@ ITEM_COMPUTE, ITEM_FIRST, ITEM_LAST = 1, 2, 4
 ITEM_BLOCKS_MOST = 1 << (ITEM_OUTER_SHIFT - ITEM_INNER_SHIFT)
 
 
-def dense_blocks(n_q, n_k, block_q, block_k, causal=True):
+def dense_blocks(n_q, n_k, block_q, block_k, causal=True, window=None):
     """``bool [n_q, n_k]`` (numpy): every block a call of that shape can need,
-    whatever its ids: the causal triangle, or the square."""
+    whatever its ids: the window's band, the causal triangle, or the square."""
+    if window is not None:
+        return window_blocks(n_q, n_k, block_q, block_k, window)
     return causal_blocks(n_q, n_k, block_q, block_k) if causal else np.ones((n_q, n_k), bool)
 
 
@@ -265,13 +324,14 @@ def work_list(needed, steps, xp=np):
     return xp.where(at < lengths[:, None], items, parked), lengths
 
 
-def attended_blocks(segment_ids, labels=None):
+def attended_blocks(segment_ids, labels=None, window=None):
     """``(needed, dense, steps)`` counts of one packed batch as the segmented
     kernels see it: rows padded to :data:`GRANULE`, the block sizes the
     kernels pick for that length, causal; with ``labels``, the rows as a
-    block-diffusion model reads them (both copies) under that rule.
+    block-diffusion model reads them (both copies) under that rule; with
+    ``window``, under the window rule.
     ``needed`` blocks are computed; ``dense`` is the row's causal triangle
-    (under either rule: what a kernel that knew only the triangle would
+    (under every rule: what a kernel that knew only the triangle would
     walk); ``steps`` are the grid steps a kernel takes a head: every row
     walks as many as the batch's longest :func:`work_list` has items, and a
     row with fewer parks for the rest."""
@@ -284,8 +344,18 @@ def attended_blocks(segment_ids, labels=None):
         labels = None if labels is None else np.pad(np.asarray(labels), ((0, 0), (0, pad)))
     block_q = pick_block(seg.shape[1], SEGMENTED_BLOCK_Q)
     block_k = pick_block(seg.shape[1], SEGMENTED_BLOCK_K)
-    needed = needed_blocks(seg, block_q, block_k, labels=labels)
+    needed = needed_blocks(seg, block_q, block_k, labels=labels, window=window)
     n_q, n_k = needed.shape[1:]
     dense = work_bound(causal_blocks(n_q, n_k, block_q, block_k))
-    _, lengths = work_list(needed, work_bound(dense_blocks(n_q, n_k, block_q, block_k, causal=labels is None)))
+    _, lengths = work_list(
+        needed, work_bound(dense_blocks(n_q, n_k, block_q, block_k, causal=labels is None, window=window)))
     return int(needed.sum()), dense * seg.shape[0], int(lengths.max()) * seg.shape[0]
+
+
+def block_pairs(segment_ids):
+    """Query-key pairs inside one block of the segmented kernels for rows
+    ``[rows, L]`` (as :func:`attended_blocks` pads and blocks them): what a
+    computed block computes, visible or not."""
+    seq = np.asarray(segment_ids).shape[1]
+    seq += (-seq) % GRANULE
+    return pick_block(seq, SEGMENTED_BLOCK_Q) * pick_block(seq, SEGMENTED_BLOCK_K)

@@ -220,7 +220,7 @@ def test_the_rule_is_refused_without_its_labels_and_heads_must_divide():
     with pytest.raises(ValueError, match="block_diffusion"):
         fa.flash_attention(q, q, q, rule="block_diffusion", segment_ids=jnp.ones((1, 128), jnp.int32), interpret=True)
     with pytest.raises(ValueError, match="unknown rule"):
-        fa.flash_attention(q, q, q, rule="window", interpret=True)
+        fa.flash_attention(q, q, q, rule="dilated", interpret=True)
     with pytest.raises(ValueError, match="do not divide"):
         fa.flash_attention(q, q[:, :3], q[:, :3], causal=True, interpret=True)
 
@@ -266,7 +266,7 @@ def test_grouped_query_attention_matches_reference(params, impl):
     p = params["layer_1"]["attn"]
 
     def program(p, x):
-        return decoder.GroupedQueryAttention(cfg).apply({"params": p}, x, positions, ids, labels)
+        return decoder.GroupedQueryAttention(cfg, None, cfg.heads_plan(0)).apply({"params": p}, x, positions, ids, labels)
 
     def plain(p, x):
         return reference.attention(x, p, positions, ids, block, noised, REF)

@@ -187,6 +187,36 @@ def test_block_diffusion_kernels_compile_at_the_cells_widths(one_chip, no_compil
     assert "(bf16[64,8192,128]{2,1,0:T(8,128)(2,1)}, bf16[8,8192,128]{2,1,0:T(8,128)(2,1)}, bf16[8,8192,128]{2,1,0:T(8,128)(2,1)})" in backward
 
 
+def test_window_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache):
+    """``laguna-s-2-1.code8k``'s sliding layers: one row of 8192, 72 query
+    heads over 8 key/value heads of 128 (nine a group through the grouped
+    backward: the dq row beside whole-row dk and dv, 40 MiB of VMEM under the
+    limit the call sets), under the window rule at 512. The lists' stride is
+    the band, 31 blocks of the triangle's 136, and the kernels bear the names
+    the ``swa_*`` readers find them by; the full layers' call at 48 over 8 keeps
+    the causal names and the triangle."""
+    q = jax.ShapeDtypeStruct((1, 72, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, seg, **rule):
+        o = flash_attention(q, k, v, causal=True, segment_ids=seg, **rule)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    def compiled(q, **rule):
+        step = jax.jit(jax.grad(jax.checkpoint(lambda *a: loss(*a, **rule)), argnums=(0, 1, 2)))
+        return step.lower(q, kv, kv, ids).compile().as_text()
+
+    text = compiled(q, rule="window", window=512)
+    assert _kernels(text) == ["flash_bwd_dkv_win", "flash_fwd_win"]
+    assert _walks_a_list_of(text, 31)  # the diagonal's 16 blocks and the 15 beside it
+    backward = next(line for line in text.splitlines() if "flash_bwd_dkv_win" in line and "tpu_custom_call" in line)
+    assert "(bf16[72,8192,128]{2,1,0:T(8,128)(2,1)}, bf16[8,8192,128]{2,1,0:T(8,128)(2,1)}, bf16[8,8192,128]{2,1,0:T(8,128)(2,1)})" in backward
+    full = compiled(jax.ShapeDtypeStruct((1, 48, 8192, 128), jnp.bfloat16, sharding=one_chip))
+    assert _kernels(full) == ["flash_bwd_dkv_seg", "flash_fwd_seg"] and _walks_a_list_of(full, 136)
+    assert "bf16[48,8192,128]" in full and "bf16[8,8192,128]" in full
+
+
 def _model_step(one_chip, model, rows, seq):
     """``(compiled loss-and-gradient of ``model`` on packed rows, its compiler's text)``,
     under the scope the train step gives it."""
