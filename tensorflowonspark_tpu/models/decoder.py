@@ -80,12 +80,24 @@ router's scores, the hyper-connection maps, the Sinkhorn iterations and the
 softmax statistics are float32. The model plugs into
 ``transformer.make_init_fn`` / ``make_loss_fn`` and ``SyncDataParallel`` as
 the dense LM does (``models.get_model("decoder", **config)``).
-``remat=True`` recomputes each layer in the backward pass; a layer keeps its
-input (the streams, 2 · hc_mult · hidden_size bytes a token), the
-attention's output (2 · heads · v_head_dim bytes a token) and one float32 a
-position and head, so the flash forward kernel runs once a layer
-(:data:`~tensorflowonspark_tpu.ops.flash_attention.REMAT_POLICY`); the
-hyper-connections' reading is computed again.
+``remat=True`` recomputes each layer in the backward pass from what the layer
+keeps (:data:`~tensorflowonspark_tpu.ops.flash_attention.REMAT_POLICY`), in
+bfloat16 a token and layer: its input (the streams, 2 · hc_mult · hidden_size
+bytes), the attention's output (2 · heads · v_head_dim, or heads · head_dim)
+and one float32 a position and head, so the flash forward kernel runs once a
+layer; and the attention sub-layer's products, named where the modules
+compute them: under ``gqa`` the q, k and v projections' results as the
+projections return them, before any head norm (2 · (heads + 2 · kv heads) ·
+head_dim bytes: 10,240 in ``sdar-30b-a3b``, 22,528 in a 72-head layer of
+``laguna-s-2-1``), under ``mla`` the two latents (2 · (q_lora_rank +
+kv_lora_rank + qk_rope_head_dim): 2688 in ``xing4-a4b``; the up-projections
+``q_b`` and ``kv_b`` run again from them), and under both the sub-layer's
+result after the output projection (2 · hidden_size). The recomputed pass
+runs none of those products: of attention it runs the norms, rotary, the
+gate's small product and the transposes, then the feed-forward from its norm
+on; the hyper-connections' reading is computed again. A job that fitted its
+chip by less than these bytes fails at compile time with XLA's out-of-memory
+message.
 
 Device scopes (``jax.named_scope``, in every operation's ``op_name``):
 ``tos.mla``, ``tos.gqa`` (a windowed layer's attention: ``tos.swa``), ``tos.attn_gate``
@@ -110,12 +122,13 @@ import math
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.models import register, transformer
 from tensorflowonspark_tpu.ops import grouped_matmul as gm
 from tensorflowonspark_tpu.ops import hyper_connection
-from tensorflowonspark_tpu.ops.flash_attention import REMAT_POLICY
+from tensorflowonspark_tpu.ops.flash_attention import KEPT_ATTENDED, KEPT_PROJECTED, REMAT_POLICY
 
 ATTENTION_KINDS = ("mla", "gqa")
 FEED_FORWARD_KINDS = ("swiglu", "moe")
@@ -448,9 +461,13 @@ class LatentAttention(nn.Module):
         heads, nope, rope, v_dim = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         scaling = dict(cfg.rope_scaling)
         with jax.named_scope("tos.mla"):
-            c_q = _norm(cfg, "q_norm")(nn.Dense(cfg.q_lora_rank, use_bias=False, dtype=dt, name="q_a")(x))
-            q = nn.DenseGeneral((heads, nope + rope), use_bias=False, dtype=dt, name="q_b")(c_q)  # [B, L, H, 192]
-            kv = nn.Dense(cfg.kv_lora_rank + rope, use_bias=False, dtype=dt, name="kv_a")(x)
+            # a recomputed layer keeps the two latents (REMAT_POLICY), q_rank + kv_rank + rope values a
+            # token, and runs the up-projections again from them
+            c_q = checkpoint_name(nn.Dense(cfg.q_lora_rank, use_bias=False, dtype=dt, name="q_a")(x), KEPT_PROJECTED)
+            q = nn.DenseGeneral((heads, nope + rope), use_bias=False, dtype=dt, name="q_b")(
+                _norm(cfg, "q_norm")(c_q))  # [B, L, H, 192]
+            kv = checkpoint_name(
+                nn.Dense(cfg.kv_lora_rank + rope, use_bias=False, dtype=dt, name="kv_a")(x), KEPT_PROJECTED)
             c_kv = _norm(cfg, "kv_norm")(kv[..., :cfg.kv_lora_rank])
             k_rope = kv[..., cfg.kv_lora_rank:]  # [B, L, rope]: one for all heads
             up = nn.DenseGeneral((heads, nope + v_dim), use_bias=False, dtype=dt, name="kv_b")(c_kv)
@@ -470,7 +487,8 @@ class LatentAttention(nn.Module):
             out = transformer._dispatch_attention(
                 q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids, scale=softmax_scale, **_rule(labels))
             out = out.transpose(0, 2, 1, 3)  # [B, L, H, v]
-            return nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out)
+            return checkpoint_name(
+                nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out), KEPT_ATTENDED)
 
 
 def _rule(labels, window=None):
@@ -516,7 +534,9 @@ class GroupedQueryAttention(nn.Module):
         kv_heads = cfg.num_key_value_heads or heads
         with jax.named_scope("tos.gqa" if layer.window is None else "tos.swa"):
             dense = lambda n, name: nn.DenseGeneral((n, width), use_bias=False, dtype=dt, name=name)  # noqa: E731
-            q, k, v = dense(heads, "q")(x), dense(kv_heads, "k")(x), dense(kv_heads, "v")(x)  # [B, L, ·, width]
+            # named before any head norm, whose backward reads them: what a recomputed layer keeps (REMAT_POLICY)
+            q, k, v = (checkpoint_name(dense(n, name)(x), KEPT_PROJECTED)
+                       for n, name in ((heads, "q"), (kv_heads, "k"), (kv_heads, "v")))  # [B, L, ·, width]
             norm = (lambda t, name: _norm(cfg, name)(t)) if cfg.qk_norm else (lambda t, name: t)
             rope = _rotary(cfg, dict(layer.rope), width)
             q = rope(norm(q, "q_norm"), positions)
@@ -529,7 +549,8 @@ class GroupedQueryAttention(nn.Module):
                 with jax.named_scope("tos.attn_gate"):
                     gate = nn.Dense(heads, use_bias=False, dtype=dt, name="gate")(x)  # [B, L, H]
                     out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)[..., None]
-            return nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out)
+            return checkpoint_name(
+                nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out), KEPT_ATTENDED)
 
 
 class SwiGLU(nn.Module):

@@ -13,15 +13,21 @@ parallelism (``tp``: attention heads / MLP hidden sharded) with FSDP
 constraints so XLA keeps activations distributed across dp/sp/tp instead of
 gathering them.
 
-``remat=True`` recomputes each block in the backward pass (``nn.remat``). A
-block then keeps its input (2 · d_model bytes a token in bfloat16) and, on the
-flash path, what the attention kernel hands its backward
-(:data:`~tensorflowonspark_tpu.ops.flash_attention.REMAT_POLICY`): the
-attention's output with its heads merged, another 2 · d_model bytes a token,
-and one float32 a position and head. The forward kernel therefore runs once a
-layer, not twice; a job that fitted the chip by less than that fails at compile
-time with XLA's out-of-memory message. The ``plain`` and ``ring`` paths keep
-the block's input alone.
+``remat=True`` recomputes each block in the backward pass (``nn.remat``) from
+what the block keeps (:data:`~tensorflowonspark_tpu.ops.flash_attention.REMAT_POLICY`),
+in bfloat16 a token and layer: its input (2 · d_model bytes), the results of
+the q, k and v projections (3 · 2 · d_model), the attention sub-layer's result
+after the output projection (2 · d_model) and, on the flash path, what the
+attention kernel hands its backward: its output with its heads merged (another
+2 · d_model) and one float32 a position and head. 12 · d_model + 4 · n_heads
+bytes a token and layer in all, 201 MB a layer at d_model 1024 and 4 rows of
+4096. The recomputed pass therefore runs neither the forward kernel nor any of
+the attention's four products (on the ``plain`` and ``ring`` paths: none of the
+four products; the attention itself again): it begins, in effect, at the
+mid-block residual, with the second norm and the MLP's first product. A job
+that fitted its chip by less than that fails at compile time with XLA's
+out-of-memory message; what it saves is the recomputation of a third of a
+block's products at d_ff = 4 · d_model.
 """
 
 import dataclasses
@@ -32,10 +38,11 @@ import jax
 import jax.numpy as jnp
 import optax
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from tensorflowonspark_tpu.models import register
 from tensorflowonspark_tpu.ops import flash_blocks
-from tensorflowonspark_tpu.ops.flash_attention import REMAT_POLICY, flash_attention
+from tensorflowonspark_tpu.ops.flash_attention import KEPT_ATTENDED, KEPT_PROJECTED, REMAT_POLICY, flash_attention
 from tensorflowonspark_tpu.parallel.ring_attention import (
     plain_attention,
     ring_attention_sharded,
@@ -52,8 +59,9 @@ class TransformerConfig:
     max_seq_len: int = 2048
     dtype: str = "float32"  # compute dtype; params stay float32
     #: recompute each block in the backward pass, FLOPs for HBM: a block keeps
-    #: its input and, on the flash path, the attention's output and one float32
-    #: a position and head (the module's text has the bytes)
+    #: its input, the attention sub-layer's products (q, k, v and the
+    #: sub-layer's result) and, on the flash path, the attention's output and
+    #: one float32 a position and head (the module's text has the bytes)
     remat: bool = False
     #: "auto" — ring over sp when the mesh has it, else the pallas flash
     #: kernel on TPU, else plain XLA attention; or force "flash" (TPU only),
@@ -264,15 +272,16 @@ class Attention(nn.Module):
         dense = lambda name: nn.DenseGeneral(  # noqa: E731
             (cfg.n_heads, cfg.head_dim), axis=-1, use_bias=False, dtype=dt, name=name
         )
-        q, k, v = dense("q")(x), dense("k")(x), dense("v")(x)  # [B, L, H, D]
+        # named as the projections return them: what a recomputed block keeps (REMAT_POLICY)
+        q, k, v = (checkpoint_name(dense(name)(x), KEPT_PROJECTED) for name in ("q", "k", "v"))  # [B, L, H, D]
         q = _rope(q, positions)
         k = _rope(k, positions)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, H, L, D]
         out = _dispatch_attention(q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids)
         out = out.transpose(0, 2, 1, 3)  # [B, L, H, D]
-        return nn.DenseGeneral(
+        return checkpoint_name(nn.DenseGeneral(
             cfg.d_model, axis=(-2, -1), use_bias=False, dtype=dt, name="o"
-        )(out)
+        )(out), KEPT_ATTENDED)
 
 
 class Mlp(nn.Module):

@@ -105,6 +105,19 @@ bfloat16 (the kernel's own ``[batch·heads, L, d_v]`` pads 64 lanes to 128,
 twice the bytes, and read 1.3 ms a layer slower on the chip, ``PERF.md`` §6
 PR 32). The way back into the kernel's layout folds against the model's own
 merge in the forward pass and is one transposition in the recomputed one.
+:data:`REMAT_POLICY` also keeps two names that the models give, not this
+module: :data:`KEPT_PROJECTED`, the results of the attention sub-layer's
+projections of its input (q, k and v as the projections return them, before
+any head norm, whose backward reads them: 2 · (heads + 2 · kv heads) · d
+bytes a token in bfloat16; under latent attention the two latents, 2 · (q
+rank + kv rank + rotary width), the up-projections running again), and
+:data:`KEPT_ATTENDED`, the sub-layer's result after its output projection (2
+· hidden bytes a token). With them the recomputed pass multiplies by none of
+those matrices, on any attention path: it rebuilds q, k and v for the
+backward kernel by norms, rotary and transposes alone. What that costs is
+memory: the dense LM at d_model 1024 keeps 4 · 2 · 1024 bytes a token and
+layer more (3.2 GB over 24 layers at 4 rows of 4096), and a job that fitted
+its chip by less fails at compile time with XLA's out-of-memory message.
 
 **Key/value groups.** ``k`` and ``v`` may have fewer heads than ``q``: query
 head ``h`` of ``G = heads / kv heads`` a group reads key/value head ``h //
@@ -181,10 +194,18 @@ _LANES = 128
 KEPT_O = "tos.flash_o"
 KEPT_LSE = "tos.flash_lse"
 
+#: the names the models give their attention sub-layer's products where they
+#: compute them: what the sub-layer's input is projected to (q, k and v as the
+#: projections return them; under latent attention the two latents), and the
+#: sub-layer's result after its output projection
+KEPT_PROJECTED = "tos.attn_projected"
+KEPT_ATTENDED = "tos.attn_result"
+
 #: the policy of a model that recomputes its layers (``nn.remat``): a layer
-#: keeps its input, as always, and these two, so the recomputed pass rebuilds
-#: q, k and v for the backward kernel and does not run the forward kernel again
-REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(KEPT_O, KEPT_LSE)
+#: keeps its input, as always, and these four, so the recomputed pass runs
+#: neither the forward kernel nor any of the attention's products whose
+#: results are named: it starts, in effect, at the mid-layer residual
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(KEPT_O, KEPT_LSE, KEPT_PROJECTED, KEPT_ATTENDED)
 
 #: row-statistics (lse/delta) are stored [BH, L, _STAT_W]: TPU block shapes
 #: need a tileable trailing dim, and a trailing dim equal to the full array

@@ -1,16 +1,22 @@
-"""What a recomputed layer keeps of its flash call (``flash_attention.REMAT_POLICY``):
-the output and the log-sum-exp, so the gradient of a model of ``L`` recomputed
-layers runs the forward kernel ``L`` times and not ``2 L``, and computes what it
-computed before, bit for bit. The kernels run in the Pallas interpreter."""
+"""What a recomputed layer keeps (``flash_attention.REMAT_POLICY``): of its
+flash call the output and the log-sum-exp, so the gradient of a model of ``L``
+recomputed layers runs the forward kernel ``L`` times and not ``2 L``; of its
+attention sub-layer the projections' results (under latent attention the two
+latents) and the sub-layer's own, so the recomputed pass multiplies by none of
+their matrices; and it computes what it computed before, bit for bit. The
+kernels run in the Pallas interpreter."""
 
 import collections
+import json
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from decoder_testutil import REF, packed_batch, program_config
+from decoder_testutil import REF, ROOT, packed_batch, program_config
+from tensorflowonspark_tpu.data import text_plane
 from tensorflowonspark_tpu.models import decoder, get_model, transformer
 from tensorflowonspark_tpu.ops import flash_attention as fa
 
@@ -51,14 +57,34 @@ def _primitives(jaxpr, found=None):
     return found
 
 
-def _flash_calls(grad, params, batch):
-    found = _primitives(jax.make_jaxpr(grad)(params, batch).jaxpr)
+def _kernels(found):
     return {name: count for name, count in found.items() if name.startswith("flash_")}
 
 
-def _without_policy(monkeypatch):
+def _flash_calls(grad, params, batch):
+    return _kernels(_primitives(jax.make_jaxpr(grad)(params, batch).jaxpr))
+
+
+def _same_numbers(loss, grads, want_loss, want):
+    assert np.asarray(loss) == np.asarray(want_loss) and np.isfinite(loss)
+    flat, flat_want = jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree.leaves(want)
+    assert len(flat) == len(flat_want)
+    for (path, got), leaf in zip(flat, flat_want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(leaf), err_msg=jax.tree_util.keystr(path))
+    assert any(np.asarray(leaf).any() for leaf in flat_want)
+
+
+def _with_policy(monkeypatch, policy):
     for module in (transformer, decoder):
-        monkeypatch.setattr(module, "REMAT_POLICY", None)
+        monkeypatch.setattr(module, "REMAT_POLICY", policy)
+
+
+def _without_policy(monkeypatch):
+    _with_policy(monkeypatch, None)
+
+
+#: the policy before the attention sub-layer's products were kept
+_FLASH_ALONE = jax.checkpoint_policies.save_only_these_names(fa.KEPT_O, fa.KEPT_LSE)
 
 
 @pytest.mark.parametrize("segmented", [True, False], ids=["segmented", "unsegmented"])
@@ -73,26 +99,90 @@ def test_recomputed_layers_run_the_forward_kernel_once(family, segmented, monkey
     grad, _, _ = _case(family, segmented)
     assert _flash_calls(grad, params, batch) == {"flash_fwd" + suffix: 2 * LAYERS, "flash_bwd_dkv" + suffix: LAYERS}
     want_loss, want = jax.jit(grad)(params, batch)
-    assert np.asarray(loss) == np.asarray(want_loss) and np.isfinite(loss)
-    flat, flat_want = jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree.leaves(want)
-    assert len(flat) == len(flat_want)
-    for (path, got), leaf in zip(flat, flat_want):
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(leaf), err_msg=jax.tree_util.keystr(path))
-    assert any(np.asarray(leaf).any() for leaf in flat_want)
+    _same_numbers(loss, grads, want_loss, want)
 
 
-@pytest.mark.parametrize("family", ["transformer", "decoder"])
-def test_a_model_that_recomputes_nothing_is_untouched_by_the_policy(family, monkeypatch):
-    """Without ``remat`` no policy is consulted and the names are identities:
-    the same program whatever the policy says, one kernel call each way a layer."""
-    grad, params, batch = _case(family, True, remat=False)
+with open(os.path.join(ROOT, "examples", "transformer", "laguna_toy.json")) as _f:
+    _LAGUNA = json.load(_f)
+#: ``gqa`` at toy widths: 4 query heads a key/value head, top-2 of 8 experts of which 4 are held
+_GQA = {
+    "vocab_size": 96, "hidden_size": 32, "num_hidden_layers": LAYERS, "num_attention_heads": 4, "num_key_value_heads": 1,
+    "head_dim": 8, "rope_theta": 1000000, "moe_intermediate_size": 16, "num_experts": 8, "experts_held": [2, 4],
+    "num_experts_per_tok": 2, "norm_topk_prob": True, "rms_norm_eps": 1e-6, "model_type": "sdar_moe",
+}
+#: attention kind: (the decoder's configuration or None for ``models/transformer``, the attention products a layer
+#: whose results a recomputed layer keeps, the kernels of a gradient ``{name: calls}``)
+KINDS = {
+    # q, k, v, o
+    "transformer": (None, 4, {"flash_fwd_seg": LAYERS, "flash_bwd_dkv_seg": LAYERS}),
+    # q_a, kv_a, o: the up-projections q_b and kv_b run again from the latents
+    "mla": (program_config(TOY), 3, {"flash_fwd_seg": LAYERS, "flash_bwd_dkv_seg": LAYERS}),
+    # q, k, v, o, named before the head norms
+    "gqa_qk_norm": (dict(_GQA, qk_norm=True), 4, {"flash_fwd_seg": LAYERS, "flash_bwd_dkv_seg": LAYERS}),
+    # the example's toy plan narrowed: two full layers round three windowed ones, a gate a head (its product stays)
+    "gqa_window_gate": (
+        dict(_LAGUNA, vocab_size=96, hidden_size=32, intermediate_size=80, head_dim=8, moe_intermediate_size=16,
+             shared_expert_intermediate_size=24),
+        4, {"flash_fwd_seg": 2, "flash_bwd_dkv_seg": 2, "flash_fwd_win": 3, "flash_bwd_dkv_win": 3}),
+    "block_diffusion": (
+        dict(_GQA, objective="block_diffusion", block_length=4, mask_token_id=95),
+        4, {"flash_fwd_bd": LAYERS, "flash_bwd_dkv_bd": LAYERS}),
+}
+
+
+def _kind(kind, remat=True):
+    """``(gradient function, parameters, batch, kept products a layer, kernel calls)``
+    of the attention kind's toy model."""
+    cfg, products, kernels = KINDS[kind]
+    if cfg is None:
+        return _case("transformer", True, remat) + (products, kernels)
+    model = get_model("decoder", **dict(cfg, attention="flash_interpret", dtype="float32", remat=remat))
+    batch = packed_batch()
+    if cfg.get("objective") == "block_diffusion":
+        tokens, seg, pos = (np.asarray(batch[k])[:, :-1] for k in ("tokens", "segment_ids", "positions"))
+        noised, weights = text_plane.noise_blocks(
+            tokens, seg, pos, cfg["block_length"], cfg["mask_token_id"], 0.05, np.random.default_rng(5))
+        batch = {"tokens": tokens, "noised_tokens": noised, "loss_weights": weights, "segment_ids": seg, "positions": pos}
+    params = model.init(jax.random.PRNGKey(3), jnp.asarray(batch["tokens"])[:, :-1])["params"]
+    loss_fn = transformer.make_loss_fn(model)
+    return jax.value_and_grad(lambda p, b: loss_fn(p, b)[0]), params, batch, products, kernels
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_recomputed_layers_run_no_attention_projection(kind, monkeypatch):
+    """Against the policy that kept the flash call's two results alone, the
+    gradient holds as many ``dot_general``s fewer as the kind has attention
+    products whose results are kept now, the kernels are called as often
+    (once each way a layer), and against no policy at all every number is the
+    same."""
+    grad, params, batch, products, kernels = _kind(kind)
+    found = _primitives(jax.make_jaxpr(grad)(params, batch).jaxpr)
+    assert _kernels(found) == kernels
+    loss, grads = jax.jit(grad)(params, batch)
+
+    _with_policy(monkeypatch, _FLASH_ALONE)
+    before = _primitives(jax.make_jaxpr(_kind(kind)[0])(params, batch).jaxpr)
+    assert _kernels(before) == kernels
+    assert before["dot_general"] - found["dot_general"] == products * sum(kernels.values()) // 2
+
+    _without_policy(monkeypatch)
+    want_loss, want = jax.jit(_kind(kind)[0])(params, batch)
+    _same_numbers(loss, grads, want_loss, want)
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_model_that_recomputes_nothing_is_untouched_by_the_policy(kind, monkeypatch):
+    """Without ``remat`` no policy is consulted and the names (the flash
+    call's two, the attention sub-layer's two) are identities: the same
+    program whatever the policy says, one kernel call each way a layer."""
+    grad, params, batch, _, kernels = _kind(kind, remat=False)
     jaxpr = jax.make_jaxpr(grad)(params, batch)
     found = _primitives(jaxpr.jaxpr)
     assert "checkpoint" not in found
-    assert (found["flash_fwd_seg"], found["flash_bwd_dkv_seg"]) == (LAYERS, LAYERS)
-    _without_policy(monkeypatch)
-    grad, _, _ = _case(family, True, remat=False)
-    assert str(jax.make_jaxpr(grad)(params, batch)) == str(jaxpr)
+    assert _kernels(found) == kernels
+    for policy in (None, _FLASH_ALONE):
+        _with_policy(monkeypatch, policy)
+        assert str(jax.make_jaxpr(_kind(kind, remat=False)[0])(params, batch)) == str(jaxpr)
 
 
 def test_the_backward_is_handed_one_float32_a_position():
