@@ -198,6 +198,8 @@ def main_fun(args, ctx, observer=None):
         block_diffusion={"block_length": model.cfg.block_length, "mask_id": model.cfg.mask_id} if diffusion else None,
         # a model with windowed layers has the text plane count their attention blocks beside the full layers'
         attention_window=getattr(model.cfg, "sliding_window", None),
+        # one with state-space layers has it count what their scans walk and restart on
+        scan_restarts=any(layer[0] == "mamba" for layer in getattr(model.cfg, "plan", ())),
     )
     stream = iter(pipe)
 

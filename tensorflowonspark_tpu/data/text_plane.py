@@ -64,6 +64,14 @@ then also feeds the ``flash_win_*`` counters, by the numpy twin of the
 kernels' third rule: blocks needed, the triangle's, the grid steps, and the
 pairs the rule shows over the pairs of the blocks computed.
 
+**A model with state-space layers** (``scan_restarts=True``) scans every row
+position by position and starts anew wherever a document starts. The producer
+then counts what those scans walk and restart on, from the rows' ids alone:
+``ssm_scan_positions_total`` (the positions the model reads, a row's
+``seq_len - 1``) and ``ssm_scan_restarts_total`` (the positions whose id
+differs from the one before, and every row's first: the rule of
+:func:`~tensorflowonspark_tpu.ops.selective_scan.restarts`).
+
 Chaos sites native to this stage: ``data.tokenize_error`` poisons a
 record's bytes producer-side so the tokenizer rejects it (charged against
 ``max_bad_records``, identically in every pack mode) and
@@ -156,6 +164,8 @@ class TextPipeline(ImagePipeline):
       text);
     - ``attention_window`` (the window of the model's windowed layers) adds
       the ``flash_win_*`` counters beside the ``flash_*`` ones;
+    - ``scan_restarts`` (the model has state-space layers) adds
+      ``ssm_scan_positions_total`` and ``ssm_scan_restarts_total``;
     - ``cache="decoded"`` and ``recycle_buffers`` are not supported (the
       decoded-pair cache is image-geometry machinery; packed rows already
       have the packed-slab cache).
@@ -194,6 +204,7 @@ class TextPipeline(ImagePipeline):
         prefetch=None,
         block_diffusion=None,
         attention_window=None,
+        scan_restarts=False,
     ):
         if cache == "decoded":
             raise ValueError(
@@ -230,6 +241,9 @@ class TextPipeline(ImagePipeline):
         self.attention_window = None if attention_window is None else int(attention_window)
         if self.attention_window is not None and block_diffusion is not None:
             raise ValueError("attention_window counts next-token rows; block_diffusion reads its rows under a rule of its own")
+        self.scan_restarts = bool(scan_restarts)
+        if self.scan_restarts and block_diffusion is not None:
+            raise ValueError("scan_restarts counts next-token rows; block_diffusion reads its rows under a rule of its own")
         self.block_diffusion = None
         if block_diffusion is not None:
             self.block_diffusion = dict({"t_min": 1e-3}, **block_diffusion)
@@ -362,6 +376,18 @@ class TextPipeline(ImagePipeline):
                     "flash_win_pairs_in_blocks_total",
                     help="query-key pairs of the blocks the windowed flash kernels compute for the emitted rows, "
                     "per head and pass"),
+            }
+
+        scan_c = None
+        if self.scan_restarts:
+            scan_c = {
+                "positions": obs.counter(
+                    "ssm_scan_positions_total",
+                    help="positions of the emitted rows that a state-space layer's scan walks, per layer and pass"),
+                "restarts": obs.counter(
+                    "ssm_scan_restarts_total",
+                    help="positions of the emitted rows at which a state-space layer's scan starts anew (a document's "
+                    "first, a row's first), per layer and pass"),
             }
 
         # the pack plane forks its workers HERE, before any pipeline thread
@@ -570,6 +596,10 @@ class TextPipeline(ImagePipeline):
                             win_c[name].inc(value)
                         win_c["pairs_visible"].inc(flash_blocks.visible_pairs(attended, attention_window))
                         win_c["pairs_in_blocks"].inc(in_window[0] * flash_blocks.block_pairs(attended))
+                    if scan_c is not None:
+                        scan_c["positions"].inc(attended.size)
+                        scan_c["restarts"].inc(
+                            int(np.count_nonzero(attended[:, 1:] != attended[:, :-1])) + attended.shape[0])
                 emitted_batches[0] += 1
                 blocks_needed_c.inc(needed)
                 blocks_dense_c.inc(dense)

@@ -2,16 +2,17 @@
 
 :mod:`~tensorflowonspark_tpu.models.transformer` is one block repeated; the
 open models of 2025-26 are not. Here a model is a list of layers, and a layer
-names three kinds — its attention, its feed-forward and its residual path —
-each a small module of its own with its own placement rules:
+names three kinds — its mixer (an attention, a state-space layer, a gated
+memory unit), its feed-forward and its residual path — each a small module
+of its own with its own placement rules:
 
 ===========  ==========  ======================================================
 part         kind        module
 ===========  ==========  ======================================================
-attention    ``mla``     :class:`LatentAttention`: low-rank query and key/value
+mixer        ``mla``     :class:`LatentAttention`: low-rank query and key/value
                          paths, one rotary key shared by all heads (YaRN
                          frequencies), queries and keys wider than values
-attention    ``gqa``     :class:`GroupedQueryAttention`: q/k/v/o projections,
+mixer        ``gqa``     :class:`GroupedQueryAttention`: q/k/v/o projections,
                          ``num_key_value_heads`` key/value heads each read by
                          a group of query heads, an RMSNorm with a learned
                          weight on every head of q and k (``qk_norm``), rotary
@@ -24,6 +25,23 @@ attention    ``gqa``     :class:`GroupedQueryAttention`: q/k/v/o projections,
                          rotary (``rope_parameters``) and the attention's
                          third rule, causal within ``sliding_window``
                          positions: the layer's :class:`HeadsPlan`
+mixer        ``gqa``     … in **differential form** where the layer's plan says
+                         ``lambda_init`` (two softmax maps a query pair,
+                         subtracted, a norm over the pair: the class's text),
+                         with biases (``attention_bias``) and without rotary
+                         positions (``rotary`` false) where the configuration
+                         says so
+mixer        ``cross``   the same module reading the keys and values an
+                         earlier ``gqa`` layer handed on: a query projection
+                         and no key or value projection of its own
+mixer        ``mamba``   :class:`MambaMixer`: in projection, short causal
+                         convolution, low-rank step and state projections,
+                         the selective scan of ``ops/selective_scan.py``
+                         (float32 state, restarting at every document of a
+                         packed row), a SiLU gate, out projection; its scan
+                         output is handed on where the plan says
+mixer        ``gmu``     :class:`GatedMemory`: ``(silu(u W_1) * m) W_2``, ``m``
+                         the scan output an earlier ``mamba`` layer handed on
 feed-forward ``swiglu``  :class:`SwiGLU`: the dense gated MLP
 feed-forward ``moe``     :class:`RoutedExperts`: scores (``scoring_func``:
                          ``sigmoid`` with a selection bias, ``noaux_tc``; or
@@ -44,10 +62,22 @@ residual     ``mhc``     :class:`HyperConnection`: ``hc_mult`` residual
 ===========  ==========  ======================================================
 
 The configuration is a dict with the published ``config.json``'s keys
-(:class:`DecoderConfig`), in either of two dialects: the one that says
+(:class:`DecoderConfig`), in one of three dialects: the one that says
 ``n_routed_experts`` (latent attention where ``kv_lora_rank`` is given,
-sigmoid ``noaux_tc`` routing) and the one that says ``num_experts`` (``gqa``,
-softmax routing without a bias; ``decoder_sparse_step`` 1). The second may
+sigmoid ``noaux_tc`` routing), the one that says ``num_experts`` (``gqa``,
+softmax routing without a bias; ``decoder_sparse_step`` 1) and the one that
+says ``mb_per_layer`` (the decoder-hybrid-decoder of arXiv:2507.06607,
+``model_type`` ``phi4flash``: with ``N`` layers, every even layer up to ``N /
+2`` is ``mamba`` and every even layer after it ``gmu``, every odd layer up to
+``N / 2 + 1`` differential ``gqa`` — within ``sliding_window`` before ``N /
+2``, the whole document at ``N / 2 + 1`` — and every odd layer after it
+``cross``; layer ``N / 2``'s scan output and layer ``N / 2 + 1``'s keys and
+values are handed on; LayerNorm at ``layer_norm_eps``, no positional
+encoding, biases on the attention's projections; ``first_layer`` and
+``model_layers`` say which of the published layers are held here, so that a
+cut in depth keeps each layer's own kind and ``lambda_init``;
+``tie_word_embeddings`` is read in every dialect: true shares the embedding's
+matrix with the head). The second may
 say more, layer by layer: ``layer_types`` (``full_attention`` /
 ``sliding_attention`` with ``sliding_window``), ``num_attention_heads_per_layer``,
 ``rope_parameters`` by layer type (``rope_type`` ``default`` or ``yarn``,
@@ -103,7 +133,17 @@ Device scopes (``jax.named_scope``, in every operation's ``op_name``):
 ``tos.mla``, ``tos.gqa`` (a windowed layer's attention: ``tos.swa``), ``tos.attn_gate``
 inside both, ``tos.moe_route`` (router, top-k, sort, gather, combine),
 ``tos.moe_experts`` (the grouped products), ``tos.moe_shared``,
-``tos.dense_mlp``, ``tos.mhc``. What the routed layers count in a step is
+``tos.dense_mlp``, ``tos.mhc``; ``tos.mamba`` with ``tos.ssm_conv`` and
+``tos.ssm_scan`` (both rules of the scan's ``custom_vjp``) inside it,
+``tos.gmu``, ``tos.cross_attn``, and ``tos.diff_attn`` (lambda, the
+subtraction and the sub-norm) inside ``tos.gqa`` / ``tos.swa`` /
+``tos.cross_attn``. **What crosses layers beside the residual streams** (a
+``mamba`` layer's scan output, a ``gqa`` layer's keys and values, where
+:class:`HeadsPlan` says ``hands_on``) leaves the layer that makes it as a
+result and enters its readers as an argument (:class:`DecoderLayer`'s third
+result and last argument): under ``remat`` it is kept once and no reader
+computes it again; its bytes a step are sown as ``ssm_state_carried_bytes``
+(``ssm_state_carried_bytes_total``). What the routed layers count in a step is
 sown into the ``counters`` collection (``moe_slots_routed``,
 ``moe_slots_held``; where a chip holds under half the experts also
 ``moe_layers_compact`` and ``moe_layers_at_bound``: the layers that ran on
@@ -127,23 +167,33 @@ from jax.ad_checkpoint import checkpoint_name
 from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.models import register, transformer
 from tensorflowonspark_tpu.ops import grouped_matmul as gm
-from tensorflowonspark_tpu.ops import hyper_connection
-from tensorflowonspark_tpu.ops.flash_attention import KEPT_ATTENDED, KEPT_PROJECTED, REMAT_POLICY
+from tensorflowonspark_tpu.ops import hyper_connection, selective_scan
+from tensorflowonspark_tpu.ops.flash_attention import KEPT_ATTENDED, KEPT_LSE, KEPT_O, KEPT_PROJECTED
 
-ATTENTION_KINDS = ("mla", "gqa")
+#: what a recomputed layer keeps: ``ops.flash_attention.REMAT_POLICY``'s four names and the scan's two results
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    KEPT_O, KEPT_LSE, KEPT_PROJECTED, KEPT_ATTENDED, selective_scan.KEPT_SCANNED, selective_scan.KEPT_SCAN_STATE)
+
+#: a state-space layer's sizes, the family's (its published configuration names none): channels a hidden
+#: unit, states a channel, the short convolution's taps
+MAMBA_EXPAND, MAMBA_STATES, MAMBA_TAPS = 2, 16, 4
+
+#: a layer's first sub-layer, its mixer (the name under which ``layer_plan`` gives it stays "attention")
+MIXER_KINDS = ("mla", "gqa", "mamba", "gmu", "cross")
 FEED_FORWARD_KINDS = ("swiglu", "moe")
 RESIDUAL_KINDS = ("add", "mhc")
 
 #: keys of a published ``config.json`` that say nothing this module computes
 #: from, and the values the ones it does not implement must have
 _IGNORED_KEYS = (
-    "model_type", "ep_size", "moe_layer_freq", "max_position_embeddings", "tie_word_embeddings",
-    "num_nextn_predict_layers", "max_window_layers",
+    "model_type", "ep_size", "moe_layer_freq", "max_position_embeddings", "num_nextn_predict_layers",
+    "max_window_layers",
 )
 _REQUIRED_VALUES = {
-    "attention_bias": False, "hidden_act": "silu", "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+    "hidden_act": "silu", "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
     "decoder_sparse_step": 1, "use_sliding_window": False, "moe_router_logit_softcapping": 0,
-    "moe_apply_router_weight_on_input": False,
+    "moe_apply_router_weight_on_input": False, "embd_pdrop": 0, "resid_pdrop": 0, "mlp_bias": False,
+    "lm_head_bias": False,
 }
 LAYER_TYPES = ("full_attention", "sliding_attention")
 ROPE_TYPES = ("default", "yarn")
@@ -159,7 +209,9 @@ OBJECTIVES = ("next_token", "block_diffusion")
 
 @dataclasses.dataclass(frozen=True)
 class HeadsPlan:
-    """What one layer's grouped-query attention is where the layers differ."""
+    """What one layer's mixer is where the layers differ: a grouped-query
+    attention's head count, window, rotary, gate and form; of any mixer,
+    whether later layers read what it makes."""
 
     heads: int
     #: the third rule's window; None: the whole document
@@ -168,6 +220,10 @@ class HeadsPlan:
     rope: tuple = ()
     #: a sigmoid gate a head on the heads' outputs
     gate: bool = False
+    #: differential attention's ``lambda_init`` of this layer; None: one softmax map a head
+    lambda_init: float = None
+    #: later layers read what this one makes (a ``mamba`` layer's scan output, a ``gqa`` layer's keys and values)
+    hands_on: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,6 +280,20 @@ class DecoderConfig:
     #: ``first_k_dense_replace``)
     mlp_only_layers: tuple = ()
     # residual path
+    # the decoder-hybrid-decoder dialect (``mb_per_layer``): every ``mb_per_layer``-th layer a state-space or
+    # gated-memory mixer, the others differential attention, the second half reading what the first half's last
+    # two layers made
+    mb_per_layer: int = 0
+    #: the place in the published model of the first layer held here, and the published depth (None:
+    #: ``num_hidden_layers``): a cut in depth keeps each layer's own kind and ``lambda_init``
+    first_layer: int = 0
+    model_layers: int = None
+    #: LayerNorm (weight and bias) at this epsilon in place of RMSNorm; None: RMSNorm at ``rms_norm_eps``
+    layer_norm_eps: float = None
+    #: biases on the attention's projections (q, k, v and the output's)
+    attention_bias: bool = False
+    #: the head is the embedding's matrix (there is no ``lm_head`` parameter)
+    tie_word_embeddings: bool = False
     hc_mult: int = 1
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -257,6 +327,8 @@ class DecoderConfig:
             cfg.setdefault("scoring_func", "softmax")
         if "moe_routed_scaling_factor" in cfg:
             cfg["routed_scaling_factor"] = cfg.pop("moe_routed_scaling_factor")
+        if "mb_per_layer" in cfg:
+            _hybrid_dialect(cfg)
         _per_layer(cfg, cfg.get("num_hidden_layers", 0))
         scoring = cfg.setdefault("scoring_func", "sigmoid")
         if scoring not in _TOPK_METHODS or cfg.pop("topk_method", _TOPK_METHODS[scoring]) != _TOPK_METHODS[scoring]:
@@ -287,6 +359,12 @@ class DecoderConfig:
         ``first_k_dense_replace`` dense layers, then routed ones."""
         if self.layer_plan is not None:
             plan = self.layer_plan
+        elif self.mb_per_layer:
+            half = self.depth // 2
+            plan = tuple(
+                (("mamba" if at <= half else "gmu") if at % self.mb_per_layer == 0
+                 else ("gqa" if at <= half + 1 else "cross"), "swiglu", "add")
+                for at in range(self.first_layer, self.first_layer + self.num_hidden_layers))
         else:
             residual = "mhc" if self.hc_mult > 1 else "add"
             plan = tuple(
@@ -298,22 +376,54 @@ class DecoderConfig:
             raise ValueError("decoder: layer_plan has {} layers, num_hidden_layers is {}".format(
                 len(plan), self.num_hidden_layers))
         for attention, feed_forward, residual in plan:
-            if (attention not in ATTENTION_KINDS or feed_forward not in FEED_FORWARD_KINDS
+            if (attention not in MIXER_KINDS or feed_forward not in FEED_FORWARD_KINDS
                     or residual not in RESIDUAL_KINDS):
                 raise ValueError("decoder: unknown layer kinds {}".format((attention, feed_forward, residual)))
             if residual == "add" and self.hc_mult != 1:
                 raise ValueError("decoder: an 'add' residual carries one stream (hc_mult 1)")
+        for reader, maker in (("gmu", "mamba"), ("cross", "gqa")):
+            kinds = [layer[0] for layer in plan]
+            made = [i for i, kind in enumerate(kinds) if kind == maker and self.heads_plan(i).hands_on]
+            if reader in kinds and (not made or kinds.index(reader) < made[0]):
+                raise ValueError(
+                    "decoder: a {!r} layer reads what a {!r} layer before it hands on, and none of the layers held "
+                    "here does (first_layer {}, {} layers of {})".format(
+                        reader, maker, self.first_layer, self.num_hidden_layers, self.depth))
         return plan
+
+    @property
+    def depth(self):
+        """The published model's layers (of which ``num_hidden_layers`` are held here)."""
+        return self.model_layers or self.num_hidden_layers
 
     def heads_plan(self, index):
         """Layer ``index``'s :class:`HeadsPlan` (kind ``gqa``)."""
         windowed = bool(self.layer_types) and self.layer_types[index] == "sliding_attention"
         per_layer = self.num_attention_heads_per_layer
+        at = self.first_layer + index
         return HeadsPlan(
             heads=per_layer[index] if per_layer else self.num_attention_heads,
             window=self.sliding_window if windowed else None,
             rope=dict(self.rope_parameters).get(LAYER_TYPES[windowed], ()),
-            gate=self.gating is not None)
+            gate=self.gating is not None,
+            # the family's rule: 0.8 - 0.6 exp(-0.3 l), l the layer's place in the published model
+            lambda_init=0.8 - 0.6 * math.exp(-0.3 * at) if self.mb_per_layer else None,
+            # the first half's last state-space layer and the one full-attention layer after it
+            hands_on=bool(self.mb_per_layer) and at in (self.depth // 2, self.depth // 2 + 1))
+
+    @property
+    def rotary(self):
+        """Rotary positions on q and k; the ``mb_per_layer`` dialect has no positional encoding at all."""
+        return not self.mb_per_layer
+
+    @property
+    def dt_rank(self):
+        """The rank of a state-space layer's step projection: the family's ``ceil(hidden_size / 16)``."""
+        return -(-self.hidden_size // 16)
+
+    @property
+    def d_inner(self):
+        return MAMBA_EXPAND * self.hidden_size
 
     @property
     def shared_width(self):
@@ -328,6 +438,27 @@ class DecoderConfig:
     def held(self):
         """(first, count) of the routed experts held here."""
         return self.experts_held if self.experts_held is not None else (0, self.n_routed_experts)
+
+
+def _hybrid_dialect(cfg):
+    """The keys of a published ``mb_per_layer`` configuration (``model_type``
+    ``phi4flash``: the decoder-hybrid-decoder of arXiv:2507.06607) as this
+    module reads them, in place: what the family's code fixes and the file
+    does not say (no norm on q's and k's heads, biases on the attention's
+    projections, no positional encoding), and ``layer_types`` for the layers
+    held here: the odd layers of the first half attend within
+    ``sliding_window``."""
+    if cfg["mb_per_layer"] != 2:
+        raise ValueError("decoder: mb_per_layer {!r} is not implemented (2 is)".format(cfg["mb_per_layer"]))
+    for key, value in (("qk_norm", False), ("attention_bias", True)):
+        cfg.setdefault(key, value)
+    first, held = cfg.get("first_layer", 0), cfg.get("num_hidden_layers", 0)
+    half = (cfg.get("model_layers") or held) // 2
+    cfg["layer_types"] = [
+        "sliding_attention" if at % 2 and at < half and cfg.get("sliding_window") else "full_attention"
+        for at in range(first, first + held)]
+    if "sliding_attention" not in cfg["layer_types"]:
+        cfg.pop("sliding_window", None)  # the layers held here hold none of the windowed ones
 
 
 def _per_layer(cfg, layers):
@@ -434,6 +565,8 @@ def _rotary(cfg, rope, width):
 
 
 def _norm(cfg, name):
+    if cfg.layer_norm_eps is not None:
+        return nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.compute_dtype, name=name)
     return nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=cfg.compute_dtype, name=name)
 
 
@@ -515,7 +648,34 @@ class GroupedQueryAttention(nn.Module):
     -0.5`` under the layer's rule: the whole document, or ``layer.window``
     positions of it. Under ``layer.gate`` head ``h``'s output is multiplied by
     ``sigmoid(x W_g)[h]``, ``x`` the sub-layer's normed input, before the
-    output projection."""
+    output projection. ``cfg.attention_bias`` puts a bias on all four
+    projections; without ``cfg.rotary`` q and k carry no positions at all.
+
+    **Differential form** (``layer.lambda_init``; arXiv:2410.05258 as the
+    decoder-hybrid-decoder family computes it). Query heads ``2i, 2i + 1``
+    are pair ``i``; key heads ``2j, 2j + 1`` and ``V_j = [v_2j, v_2j+1]``
+    (twice ``head_dim`` wide) are key/value pair ``j``, read by the query
+    pairs ``i`` with ``i // (pairs / kv pairs) = j``. ``o_i = RMSNorm((A1 -
+    lambda A2) V_j) (1 - lambda_init)``, ``A1 = softmax(q_2i k_2j^T)``, ``A2 =
+    softmax(q_2i+1 k_2j+1^T)`` under the layer's rule, ``lambda = exp(lq1 .
+    lk1) - exp(lq2 . lk2) + lambda_init`` from four learned vectors of
+    ``head_dim``, the norm with a learned weight over the pair's ``2 *
+    head_dim`` (eps 1e-5); lambda, the subtraction and the norm in float32
+    (scope ``tos.diff_attn``). **Two maps a pair through the flash kernels
+    as they are**: the query heads are put in the order (key/value pair,
+    parity, pair within it), so that the heads reading key head ``2j + p``
+    are neighbours, a group of the kernels' (nothing repeated on the key
+    side); every key head is given the pair's whole ``V_j``, keys of
+    ``head_dim`` under values of twice that (``V_j`` is in HBM twice, once a
+    parity); ``A1 V_j`` and ``A2 V_j`` come back as two heads' outputs and
+    are subtracted outside the kernel.
+
+    ``layer.hands_on``: the call returns ``(y, {"k": k, "v": v})``, its keys
+    and values as the projections return them ``[B, L, kv heads, head_dim]``
+    (what a recomputed layer keeps anyway). ``shared`` (such a dict, handed on
+    by an earlier layer): the layer is a **cross attention** — it has a query
+    projection and no key or value projection of its own, reads ``shared``'s,
+    and runs under ``tos.cross_attn``."""
 
     cfg: DecoderConfig
     mesh: object
@@ -528,29 +688,174 @@ class GroupedQueryAttention(nn.Module):
     )
 
     @nn.compact
-    def __call__(self, x, positions, segment_ids=None, labels=None):
+    def __call__(self, x, positions, segment_ids=None, labels=None, shared=None):
         cfg, dt, layer = self.cfg, self.cfg.compute_dtype, self.layer
         heads, width = layer.heads, cfg.head_dim or cfg.hidden_size // cfg.num_attention_heads
         kv_heads = cfg.num_key_value_heads or heads
-        with jax.named_scope("tos.gqa" if layer.window is None else "tos.swa"):
-            dense = lambda n, name: nn.DenseGeneral((n, width), use_bias=False, dtype=dt, name=name)  # noqa: E731
+        scope = "tos.cross_attn" if shared is not None else "tos.gqa" if layer.window is None else "tos.swa"
+        with jax.named_scope(scope):
+            dense = lambda n, name: nn.DenseGeneral(  # noqa: E731
+                (n, width), use_bias=cfg.attention_bias, dtype=dt, name=name)
             # named before any head norm, whose backward reads them: what a recomputed layer keeps (REMAT_POLICY)
-            q, k, v = (checkpoint_name(dense(n, name)(x), KEPT_PROJECTED)
-                       for n, name in ((heads, "q"), (kv_heads, "k"), (kv_heads, "v")))  # [B, L, ·, width]
+            q = checkpoint_name(dense(heads, "q")(x), KEPT_PROJECTED)  # [B, L, heads, width]
+            k, v = (shared["k"], shared["v"]) if shared is not None else (
+                checkpoint_name(dense(kv_heads, name)(x), KEPT_PROJECTED) for name in ("k", "v"))
+            made = {"k": k, "v": v}
             norm = (lambda t, name: _norm(cfg, name)(t)) if cfg.qk_norm else (lambda t, name: t)
-            rope = _rotary(cfg, dict(layer.rope), width)
+            rope = _rotary(cfg, dict(layer.rope), width) if cfg.rotary else (lambda t, positions: t)
             q = rope(norm(q, "q_norm"), positions)
             k = rope(norm(k, "k_norm"), positions)
-            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, ·, L, width]
-            out = transformer._dispatch_attention(
-                q, k, v, cfg.attention, self.mesh, segment_ids=segment_ids, **_rule(labels, layer.window))
-            out = out.transpose(0, 2, 1, 3)  # [B, L, H, width]
+            attend = functools.partial(
+                transformer._dispatch_attention, impl=cfg.attention, mesh=self.mesh, segment_ids=segment_ids,
+                **_rule(labels, layer.window))
+            if layer.lambda_init is None:
+                q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, ·, L, width]
+                out = attend(q, k, v).transpose(0, 2, 1, 3)  # [B, L, H, width]
+            else:
+                out = self._differential(attend, q, k, v)  # [B, L, H / 2, 2 width]
             if layer.gate:
                 with jax.named_scope("tos.attn_gate"):
                     gate = nn.Dense(heads, use_bias=False, dtype=dt, name="gate")(x)  # [B, L, H]
                     out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)[..., None]
+            y = checkpoint_name(
+                nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=cfg.attention_bias, dtype=dt, name="o")(out),
+                KEPT_ATTENDED)
+            return (y, made) if layer.hands_on else y
+
+    def _differential(self, attend, q, k, v):
+        """The differential form (the class's text) of ``q`` ``[B, L, H,
+        width]`` on ``k``, ``v`` ``[B, L, K, width]``: ``[B, L, H / 2, 2
+        width]``, the pairs in the published order."""
+        dt, lambda_init = self.cfg.compute_dtype, self.layer.lambda_init
+        batch, length, heads, width = q.shape
+        kv_pairs, per = k.shape[2] // 2, heads // k.shape[2]  # query pairs a key/value pair
+        # query head 2 (j per + r) + p -> (j, p, r): the readers of key head 2j + p side by side
+        q = q.reshape(batch, length, kv_pairs, per, 2, width).transpose(0, 2, 4, 3, 1, 5).reshape(
+            batch, heads, length, width)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.reshape(batch, length, kv_pairs, 2 * width).transpose(0, 2, 1, 3)  # V_j [B, J, L, 2 width]
+        v = jnp.broadcast_to(v[:, :, None], (batch, kv_pairs, 2, length, 2 * width)).reshape(
+            batch, 2 * kv_pairs, length, 2 * width)
+        out = attend(q, k, v).reshape(batch, kv_pairs, 2, per, length, 2 * width)
+        with jax.named_scope("tos.diff_attn"):
+            vectors = [self.param("lambda_" + name, nn.initializers.normal(0.1), (width,), jnp.float32)
+                       for name in ("q1", "k1", "q2", "k2")]
+            lam = (jnp.exp(jnp.sum(vectors[0] * vectors[1])) - jnp.exp(jnp.sum(vectors[2] * vectors[3]))
+                   + lambda_init)
+            diff = out[:, :, 0].astype(jnp.float32) - lam * out[:, :, 1].astype(jnp.float32)  # [B, J, per, L, 2w]
+            diff = nn.RMSNorm(epsilon=1e-5, dtype=jnp.float32, name="subln")(diff) * (1.0 - lambda_init)
+            return diff.astype(dt).reshape(batch, heads // 2, length, 2 * width).transpose(0, 2, 1, 3)
+
+
+def _scan_rows(dt, x, b, c, *rest, interpret):
+    """:func:`~tensorflowonspark_tpu.ops.selective_scan.selective_scan` in
+    :func:`_per_shard`'s order: what is split over the rows (the segment
+    ids, if any, as ``[B, L, 1]``), then ``a`` and ``skip``, whole."""
+    *ids, a, skip = rest
+    return selective_scan.selective_scan(dt, x, b, c, a, skip, ids[0][..., 0] if ids else None, interpret=interpret)
+
+
+def causal_conv(xs, kernel, bias, segment_ids=None):
+    """``silu(bias + sum_j kernel[j] * xs_{t-j})`` a channel, ``j`` under
+    ``len(kernel)``: the state-space layer's short convolution on ``xs``
+    ``[B, L, D]`` (``kernel`` ``[taps, D]``, row ``j`` the tap ``j``
+    positions back). A term from before the row's start or from another
+    document of a packed row (``segment_ids``) is zero. Float32 inside."""
+    length = xs.shape[1]
+    wide = xs.astype(jnp.float32)
+    total = bias.astype(jnp.float32) + kernel[0] * wide
+    for back in range(1, kernel.shape[0]):
+        term = jnp.pad(wide, ((0, 0), (back, 0), (0, 0)))[:, :length]
+        if segment_ids is not None:
+            same = jnp.pad(segment_ids, ((0, 0), (back, 0)), constant_values=-1)[:, :length] == segment_ids
+            term = jnp.where(same[..., None], term, 0.0)
+        total = total + kernel[back] * term
+    return nn.silu(total).astype(xs.dtype)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The family's: the inverse softplus of a step drawn log-uniform in [1e-3, 0.1]."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype) * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+class MambaMixer(nn.Module):
+    """A selective state-space layer (Mamba, arXiv:2312.00752) as the
+    decoder-hybrid-decoder family holds it, ``D = MAMBA_EXPAND * hidden``
+    channels, ``N = MAMBA_STATES`` states, ``R = dt_rank``: ``[xs, z] = u
+    W_in``; ``c = causal_conv(xs)`` (:func:`causal_conv`, scope
+    ``tos.ssm_conv``); ``[r, B, C] = c W_x``; ``dt = r W_dt + b_dt``; ``A =
+    -exp(A_log)``; ``y`` the selective scan of
+    :mod:`~tensorflowonspark_tpu.ops.selective_scan` (``Delta =
+    softplus(dt)``, float32 state, the skip ``D * c`` included, restarting
+    with the convolution at every document of a packed row; scope
+    ``tos.ssm_scan``); result ``(y * silu(z)) W_out``. Returns ``(result,
+    made)``: ``made`` is ``{"memory": y}`` (before the gate) where later
+    layers read it (``layer.hands_on``), else empty. A recomputed layer keeps
+    ``y``, the scan's boundary states and the result
+    (:data:`REMAT_POLICY`); the products before the scan run again."""
+
+    cfg: DecoderConfig
+    mesh: object = None
+    layer: HeadsPlan = None
+
+    PARAM_RULES = (
+        (r"mamba/in_proj/kernel$", ("fsdp", "tp")),  # [d, 2 D]
+        (r"mamba/conv_kernel$", (None, "tp")),  # [taps, D]
+        (r"mamba/(conv_bias|skip)$", ("tp",)),  # [D]
+        (r"mamba/x_proj/kernel$", ("tp", None)),  # [D, R + 2 N]
+        (r"mamba/dt_proj/kernel$", (None, "tp")),  # [R, D]
+        (r"mamba/dt_proj/bias$", ("tp",)),
+        (r"mamba/a_log$", ("tp", None)),  # [D, N]
+        (r"mamba/out_proj/kernel$", ("tp", "fsdp")),  # [D, d]
+    )
+
+    @nn.compact
+    def __call__(self, x, segment_ids=None):
+        cfg, dt = self.cfg, self.cfg.compute_dtype
+        inner, states, rank = cfg.d_inner, MAMBA_STATES, cfg.dt_rank
+        with jax.named_scope("tos.mamba"):
+            xz = nn.Dense(2 * inner, use_bias=False, dtype=dt, name="in_proj")(x)
+            xs, z = xz[..., :inner], xz[..., inner:]
+            with jax.named_scope("tos.ssm_conv"):
+                c = causal_conv(
+                    xs, self.param("conv_kernel", nn.initializers.normal(MAMBA_TAPS ** -0.5),
+                                   (MAMBA_TAPS, inner), jnp.float32),
+                    self.param("conv_bias", nn.initializers.zeros, (inner,), jnp.float32), segment_ids)
+            low = nn.Dense(rank + 2 * states, use_bias=False, dtype=dt, name="x_proj")(c)
+            step = nn.Dense(inner, dtype=dt, bias_init=_dt_bias_init, name="dt_proj")(low[..., :rank])
+            a_log = self.param(
+                "a_log", lambda key, shape, dtype: jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape), (inner, states), jnp.float32)
+            skip = self.param("skip", nn.initializers.ones, (inner,), jnp.float32)
+            ids = () if segment_ids is None else (segment_ids[..., None],)
+            y = _per_shard(
+                _scan_rows, self.mesh, (step, c, low[..., rank:rank + states], low[..., rank + states:]) + ids,
+                (-jnp.exp(a_log), skip))
+            out = nn.Dense(cfg.hidden_size, use_bias=False, dtype=dt, name="out_proj")(y * nn.silu(z))
+            return checkpoint_name(out, KEPT_ATTENDED), ({"memory": y} if self.layer.hands_on else {})
+
+
+class GatedMemory(nn.Module):
+    """The cross-decoder's gated memory unit: ``(silu(u W_1) * m) W_2``,
+    ``m`` the scan output an earlier state-space layer handed on (``[B, L,
+    MAMBA_EXPAND * hidden]``, before that layer's own gate)."""
+
+    cfg: DecoderConfig
+
+    PARAM_RULES = (
+        (r"gmu/in_proj/kernel$", ("fsdp", "tp")),  # [d, D]
+        (r"gmu/out_proj/kernel$", ("tp", "fsdp")),  # [D, d]
+    )
+
+    @nn.compact
+    def __call__(self, x, memory):
+        cfg, dt = self.cfg, self.cfg.compute_dtype
+        with jax.named_scope("tos.gmu"):
+            gate = nn.Dense(cfg.d_inner, use_bias=False, dtype=dt, name="in_proj")(x)
             return checkpoint_name(
-                nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt, name="o")(out), KEPT_ATTENDED)
+                nn.Dense(cfg.hidden_size, use_bias=False, dtype=dt, name="out_proj")(nn.silu(gate) * memory),
+                KEPT_ATTENDED)
 
 
 class SwiGLU(nn.Module):
@@ -707,15 +1012,16 @@ def sinkhorn(logits, iters, eps):
 
 
 def _per_shard(fn, mesh, sharded, whole=()):
-    """``fn(*sharded, *whole)`` of :mod:`~tensorflowonspark_tpu.ops.hyper_connection`,
-    its kernels interpreted anywhere but on a TPU (the flash kernels'
-    convention). A Mosaic call has no partitioning rule (see
-    :func:`transformer._flash`): on a mesh it runs per shard of the batch,
-    ``sharded`` and every result (all ``[B, L, width]``) split over the data
-    axes as :meth:`Decoder._constrain` leaves the streams, ``whole`` (the
-    maps' small parameters) on every chip. Tokens are independent of one
-    another, so nothing is exchanged inside; the parameters' gradients are
-    summed over the shards by ``shard_map``'s own transpose."""
+    """``fn(*sharded, *whole)`` of :mod:`~tensorflowonspark_tpu.ops.hyper_connection`
+    or :mod:`~tensorflowonspark_tpu.ops.selective_scan`, its kernels
+    interpreted anywhere but on a TPU (the flash kernels' convention). A
+    Mosaic call has no partitioning rule (see :func:`transformer._flash`): on
+    a mesh it runs per shard of the batch, ``sharded`` and every result (all
+    ``[B, L, width]``) split over the data axes as :meth:`Decoder._constrain`
+    leaves the streams, ``whole`` (small parameters) on every chip. Rows are
+    independent of one another, so nothing is exchanged inside; the
+    parameters' gradients are summed over the shards by ``shard_map``'s own
+    transpose."""
     run = functools.partial(fn, interpret=jax.default_backend() != "tpu")
     if mesh is None or mesh.size == 1:
         return run(*sharded, *whole)
@@ -810,30 +1116,46 @@ class AddResidual(nn.Module):
 
 
 _RESIDUALS = {"add": AddResidual, "mhc": HyperConnection}
-_ATTENTIONS = {"mla": LatentAttention, "gqa": GroupedQueryAttention}
+_MIXERS = {"mla": LatentAttention, "gqa": GroupedQueryAttention, "cross": GroupedQueryAttention,
+           "mamba": MambaMixer, "gmu": GatedMemory}
 
 
 class DecoderLayer(nn.Module):
-    """One layer of the plan: pre-norm attention, then pre-norm feed-forward,
-    each inside the layer's residual path. Returns ``(streams, counts)``,
-    ``counts`` what a routed feed-forward counted (else empty)."""
+    """One layer of the plan: a pre-norm mixer (an attention, a state-space
+    layer, a gated memory unit), then a pre-norm feed-forward, each inside
+    the layer's residual path. ``carried``: what earlier layers handed on
+    (``memory``; ``k`` and ``v``), read by the kinds ``gmu`` and ``cross``.
+    Returns ``(streams, counts, made)``: ``counts`` what a routed
+    feed-forward counted (else empty), ``made`` what this layer hands on to
+    later ones (else empty): it leaves the layer as a result and enters its
+    readers as an argument, so under ``nn.remat`` it is kept once and no
+    reader computes it again."""
 
     cfg: DecoderConfig
     kinds: tuple
     mesh: object = None
-    #: what the layer's attention is where its kind is ``gqa``
+    #: what the layer's mixer is where the layers differ
     heads: HeadsPlan = None
 
     @nn.compact
-    def __call__(self, streams, positions, segment_ids=None, labels=None):
+    def __call__(self, streams, positions, segment_ids=None, labels=None, carried=None):
         cfg = self.cfg
-        attention, feed_forward, residual = self.kinds
+        mixer, feed_forward, residual = self.kinds
         path = _RESIDUALS[residual]
-        attend = (LatentAttention(cfg, self.mesh, name="attn") if attention == "mla"
-                  else GroupedQueryAttention(cfg, self.mesh, self.heads, name="attn"))
 
         h, maps = path(cfg, self.mesh, name="res_attn")(streams)
-        y = attend(_norm(cfg, "ln1")(h), positions, segment_ids, labels)
+        u, made = _norm(cfg, "ln1")(h), {}
+        if mixer == "mla":
+            y = LatentAttention(cfg, self.mesh, name="attn")(u, positions, segment_ids, labels)
+        elif mixer == "mamba":
+            y, made = MambaMixer(cfg, self.mesh, self.heads, name="mamba")(u, segment_ids)
+        elif mixer == "gmu":
+            y = GatedMemory(cfg, name="gmu")(u, carried["memory"])
+        else:
+            y = GroupedQueryAttention(cfg, self.mesh, self.heads, name="attn")(
+                u, positions, segment_ids, labels, shared=carried if mixer == "cross" else None)
+            if self.heads.hands_on:
+                y, made = y
         streams = path.merge(streams, maps, y, self.mesh)
 
         h, maps = path(cfg, self.mesh, name="res_mlp")(streams)
@@ -843,7 +1165,7 @@ class DecoderLayer(nn.Module):
         else:
             with jax.named_scope("tos.dense_mlp"):
                 y = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
-        return path.merge(streams, maps, y, self.mesh), counts
+        return path.merge(streams, maps, y, self.mesh), counts, made
 
 
 class Decoder(nn.Module):
@@ -865,18 +1187,27 @@ class Decoder(nn.Module):
         rule; ``head_from`` (static) is the first position whose logits are
         wanted (the final norm and the head run from there on)."""
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.compute_dtype, name="embed")(tokens)
+        embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.compute_dtype, name="embed")
+        x = embed(tokens)
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None, :], tokens.shape)
         streams = self._constrain(jnp.tile(x, (1, 1, cfg.hc_mult)))  # vec(X): the embedding copied to every stream
         layer = nn.remat(DecoderLayer, static_argnums=(), policy=REMAT_POLICY) if cfg.remat else DecoderLayer
-        counted = []
+        counted, carried = [], {}
         for i, kinds in enumerate(cfg.plan):
-            streams, counts = layer(cfg, kinds, self.mesh, cfg.heads_plan(i), name="layer_{}".format(i))(
-                streams, positions, segment_ids, labels)
+            streams, counts, made = layer(cfg, kinds, self.mesh, cfg.heads_plan(i), name="layer_{}".format(i))(
+                streams, positions, segment_ids, labels, carried)
             streams = self._constrain(streams)
+            carried = dict(carried, **made)
             if counts:
                 counted.append(counts)
+        if carried:
+            obs.counter(
+                "ssm_state_carried_bytes_total",
+                help="bytes that crossed layers beside the residual stream: a state-space layer's scan output and a "
+                "full layer's keys and values, handed to the layers that read them")
+            self.sow("counters", "ssm_state_carried_bytes",
+                     jnp.float32(sum(t.size * t.dtype.itemsize for t in carried.values())))
         if counted:
             # registered here, by name and with their help; the step carries the
             # values out and TrainStep books them (obs.book_carried)
@@ -902,7 +1233,10 @@ class Decoder(nn.Module):
                 self.sow("counters", "moe_" + name, sum(c.get(name, 0.0) for c in counted))
         summed = sum(jnp.split(streams[:, head_from:].astype(jnp.float32), cfg.hc_mult, axis=-1))
         x = _norm(cfg, "ln_f")(summed.astype(cfg.compute_dtype))
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.compute_dtype, name="lm_head")(x)
+        if cfg.tie_word_embeddings:
+            logits = embed.attend(x)  # x E^T: the embedding's matrix is the head's
+        else:
+            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.compute_dtype, name="lm_head")(x)
         return logits.astype(jnp.float32)
 
 
@@ -916,7 +1250,7 @@ def param_rules(cfg):
     """The placement rules of the kinds ``cfg``'s plan uses."""
     rules = []
     for attention, feed_forward, residual in cfg.plan:
-        for module in (_ATTENTIONS[attention], RoutedExperts if feed_forward == "moe" else SwiGLU, _RESIDUALS[residual]):
+        for module in (_MIXERS[attention], RoutedExperts if feed_forward == "moe" else SwiGLU, _RESIDUALS[residual]):
             rules += [rule for rule in module.PARAM_RULES if rule not in rules]
     return tuple(rules) + _SHARED_RULES
 

@@ -321,6 +321,62 @@ def test_recomputed_decoder_runs_the_forward_kernel_once_a_layer(one_chip, no_co
     assert 95e6 < kept < 125e6
 
 
+def test_scan_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache):
+    """``phi-4-mini-flash.reason8k``'s selective scan: one row of 8192, 5120
+    channels by 16 states, bfloat16 operands, the state float32 in VMEM:
+    chunks of 256, channel blocks of 512 forward and 256 backward (the
+    chunk's states, ``[257, 16, 256]`` float32, beside the widened operands'
+    blocks: under the 64 MiB the calls ask for), the kernels under the names
+    the readers find them by, the boundary states ``[1, 32, 16, 5120]``."""
+    from tensorflowonspark_tpu.ops.selective_scan import selective_scan
+
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    wide, narrow = on_chip((1, 8192, 5120), jnp.bfloat16), on_chip((1, 8192, 16), jnp.bfloat16)
+    args = (wide, wide, narrow, narrow, on_chip((5120, 16), jnp.float32), on_chip((5120,), jnp.float32))
+
+    def loss(dt, x, b, c, a, skip, ids):
+        return (selective_scan(dt, x, b, c, a, skip, ids).astype(jnp.float32) ** 2).sum()
+
+    step = jax.jit(jax.grad(jax.checkpoint(loss), argnums=tuple(range(6))))
+    text = step.lower(*args, on_chip((1, 8192), jnp.int32)).compile().as_text()
+    assert _kernels(text) == ["ssm_scan_bwd", "ssm_scan_fwd"]
+    assert "f32[1,32,16,5120]" in text  # a state a chunk, not a position
+    assert "f32[1,8192,16,128]" in text  # B and C ride widened to the lanes
+    assert "f32[1,8192,16,5120]" not in text and "f32[1,8192,5120,16]" not in text  # no state a position in HBM
+
+
+def test_hybrid_decoder_compiles_at_the_cells_shape(one_chip, no_compile_cache, monkeypatch):
+    """``phi-4-mini-flash.reason8k`` whole, loss and gradients, one row of
+    8192 recomputed: two scans, one windowed differential layer (40 query
+    heads in groups of 2 over 20 key heads of 64, each under a value of 128)
+    and the full and the cross layer through the causal kernels; every
+    forward kernel once (the scan's results and the flash kernels' are what
+    a recomputed layer keeps), none inside a recomputed pass; the step's
+    temporaries leave room beside 8.4 GB of parameters and moments."""
+    import json
+
+    from benchmarks.families import ssm_lm
+    from tensorflowonspark_tpu.models import get_model
+
+    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", "phi-4-mini-flash.json")) as f:
+        cfg = ssm_lm.model_config(json.load(f), remat=True)
+    cfg.update(attention="flash")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, text = _model_step(one_chip, get_model("decoder", **cfg), 1, 8192)
+    assert _kernels(text) == sorted(
+        ["ssm_scan_fwd", "ssm_scan_bwd"] * 2 + ["flash_fwd_win", "flash_bwd_dkv_win"]
+        + ["flash_fwd_seg", "flash_bwd_dkv_seg"] * 2)
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert not any("rematted_computation" in name for name in names)
+    forward = [name for name in names if "_fwd" in name]
+    assert [_program.phase_of(name) for name in forward] == ["fwd"] * 5
+    assert [_program.phase_of(name) for name in names if name not in forward] == ["bwd"] * 5
+    assert all("tos.ssm_scan" in name for name in names if "ssm_scan" in name)
+    assert "bf16[40,8192,64]" in text and "bf16[20,8192,64]" in text and "bf16[20,8192,128]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.5e9
+
+
 def test_hyper_connection_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache):
     """``xing4-a4b.packed8k``'s residual path (kept in this file: one process
     may hold the TPU library): one row of 8192 tokens, four streams of 3584

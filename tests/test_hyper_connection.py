@@ -166,7 +166,7 @@ def test_streams_keep_the_batch_sharding_through_both_passes(params):  # noqa: F
 
     def layer(mesh):
         def run(p, streams):
-            out, _ = decoder.DecoderLayer(cfg, cfg.plan[1], mesh).apply({"params": p}, streams, pos, seg)
+            out, _, _ = decoder.DecoderLayer(cfg, cfg.plan[1], mesh).apply({"params": p}, streams, pos, seg)
             return out
 
         return run
