@@ -85,6 +85,7 @@ MODULES = [
     "tensorflowonspark_tpu.ops.fused_bn",
     "tensorflowonspark_tpu.ops.grouped_matmul",
     "tensorflowonspark_tpu.ops.hyper_connection",
+    "tensorflowonspark_tpu.ops.moe_combine",
     "tensorflowonspark_tpu.ops.selective_scan",
     "tensorflowonspark_tpu.backends",
     "tensorflowonspark_tpu.backends.local",

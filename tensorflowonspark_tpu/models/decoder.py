@@ -147,10 +147,14 @@ computes it again; its bytes a step are sown as ``ssm_state_carried_bytes``
 sown into the ``counters`` collection (``moe_slots_routed``,
 ``moe_slots_held``; where a chip holds under half the experts also
 ``moe_layers_compact`` and ``moe_layers_at_bound``: the layers that ran on
-the compact slot buffer and those that fell back to the whole one) and the
-``gauges`` collection (``moe_expert_load_max_over_mean``), and registered
-where it is sown (``moe_slots_routed_total``, ``moe_slots_held_total``,
-``moe_layers_compact_total``, ``moe_layers_at_bound_total``, the gauge);
+the compact slot buffer and those that fell back to the whole one, and
+``moe_combine_rows_fetched``: the buffer rows one call of the way back to
+token order brings in, a layer on the compact buffer, which over
+``moe_slots_held`` is how many times over that kernel reads what it needs)
+and the ``gauges`` collection (``moe_expert_load_max_over_mean``), and
+registered where it is sown (``moe_slots_routed_total``,
+``moe_slots_held_total``, ``moe_layers_compact_total``,
+``moe_layers_at_bound_total``, ``moe_combine_rows_fetched_total``, the gauge);
 ``make_loss_fn`` carries both out in the step's metrics and
 :class:`~tensorflowonspark_tpu.train.TrainStep` books them by name.
 """
@@ -167,7 +171,7 @@ from jax.ad_checkpoint import checkpoint_name
 from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.models import register, transformer
 from tensorflowonspark_tpu.ops import grouped_matmul as gm
-from tensorflowonspark_tpu.ops import hyper_connection, selective_scan
+from tensorflowonspark_tpu.ops import hyper_connection, moe_combine, selective_scan
 from tensorflowonspark_tpu.ops.flash_attention import KEPT_ATTENDED, KEPT_LSE, KEPT_O, KEPT_PROJECTED
 
 #: what a recomputed layer keeps: ``ops.flash_attention.REMAT_POLICY``'s four names and the scan's two results
@@ -904,9 +908,13 @@ class RoutedExperts(nn.Module):
     a time, all their slots, and gives what one pass over ``T * k`` rows
     gives): that fallback, not a bound on the routing, is what "nothing
     dropped" rests on. ``counts`` then also says which of the two ran
-    (``layers_compact`` / ``layers_at_bound``, one of them 1)."""
+    (``layers_compact`` / ``layers_at_bound``, one of them 1) and what the way
+    back to token order read of the compact buffer (``combine_rows_fetched``).
+    ``mesh``: the step's devices, for that kernel alone (on more than one chip
+    a Mosaic call runs under a ``shard_map``: ``gm._sum_over_slots``)."""
 
     cfg: DecoderConfig
+    mesh: object = None
 
     PARAM_RULES = (
         (r"moe/router$", (None, None)),  # [d, E]: whole on every chip
@@ -952,20 +960,24 @@ class RoutedExperts(nn.Module):
             "load_max_over_mean": jnp.max(group_sizes) * held / jnp.maximum(rows_used, 1).astype(jnp.float32),
         }
         compact = gm.compact_rows(slots, held, cfg.n_routed_experts)
+        on_rows = _experts_on_rows if self.mesh is None or self.mesh.size == 1 else _on_mesh(self.mesh)
         if compact == slots:
-            routed = _experts_on_rows(slots, *per_token, *shared, *indices)
+            routed = on_rows(slots, *per_token, *shared, *indices)
         else:
             fits = rows_used <= compact
-            routed = gm.either(_experts_on_rows, compact, fits, per_token, shared, *indices)
-            counts.update(layers_compact=fits.astype(jnp.float32), layers_at_bound=1.0 - fits)
+            routed = gm.either(on_rows, compact, fits, per_token, shared, *indices)
+            with jax.named_scope("tos.moe_route"):  # the kernel's own list of steps, counted beside it
+                fetched = moe_combine.rows_fetched(order[:compact] // k, group_sizes, tokens)
+            counts.update(layers_compact=fits.astype(jnp.float32), layers_at_bound=1.0 - fits,
+                          combine_rows_fetched=jnp.where(fits, fetched, 0.0))
 
         with jax.named_scope("tos.moe_shared"):
             shared = SwiGLU(cfg, cfg.shared_width, name="shared")(flat) if cfg.shared_width else 0
         return (routed + shared).reshape(batch, length, d), counts
 
 
-@functools.partial(jax.jit, static_argnums=0, inline=True)
-def _experts_on_rows(rows, flat, weights, gate, up, down, order, place, group_sizes):
+@functools.partial(jax.jit, static_argnums=0, static_argnames="mesh", inline=True)
+def _experts_on_rows(rows, flat, weights, gate, up, down, order, place, group_sizes, mesh=None):
     """The held experts over the first ``rows`` of the sorted slots, weighed
     and summed into their tokens: ``[T, d]``. ``flat`` ``[T, d]``, ``weights``
     ``float32 [T, k]``, the experts' float32 matrices, and
@@ -976,21 +988,29 @@ def _experts_on_rows(rows, flat, weights, gate, up, down, order, place, group_si
     once for all the layers, branches and passes of a step that call it at
     one length, and that trace spliced in at each (traced anew at each, a
     warm start of ``sdar-30b-a3b`` took 8 s longer; a call that XLA inlines
-    would hand its name to the grouped products' kernels: PERF.md §6, PR 35)."""
+    would hand its name to the grouped products' kernels: PERF.md §6, PR 35).
+    ``mesh``: the devices of a step on more than one, for the kernel on the
+    way back to token order (``gm._sum_over_slots``)."""
     dt, k = flat.dtype, weights.shape[1]
     with jax.named_scope("tos.moe_route"):
         head = order[:rows]
-        sorted_in = gm.rows_to_slots(flat, head, place, k)  # [rows, d]
+        sorted_in = gm.rows_to_slots(flat, head, place, group_sizes, k, mesh)  # [rows, d]
         sorted_weights = weights.reshape(-1)[head]
     with jax.named_scope("tos.moe_experts"):
         hidden = nn.silu(gm.grouped_matmul(sorted_in, gate.astype(dt), group_sizes)) * gm.grouped_matmul(
             sorted_in, up.astype(dt), group_sizes)
         sorted_out = gm.grouped_matmul(hidden, down.astype(dt), group_sizes)  # [rows, d]
     with jax.named_scope("tos.moe_route"):
-        # weighted where it lies, then each token's k slots found by their
-        # places and summed (a slot not among the rows finds a zero row)
+        # weighted where it lies, then each token's slots among the rows summed
         weighted = (sorted_out.astype(jnp.float32) * sorted_weights[:, None]).astype(dt)
-        return gm.slots_to_tokens(weighted, head, place, k)
+        return gm.slots_to_tokens(weighted, head, place, group_sizes, k, mesh)
+
+
+@functools.lru_cache(maxsize=None)
+def _on_mesh(mesh):
+    """:func:`_experts_on_rows` on ``mesh``, one object a mesh: ``gm.either`` and its fallback are traced once a
+    function they are handed."""
+    return functools.partial(_experts_on_rows, mesh=mesh)
 
 
 def _kernel_init(batch_axis=()):
@@ -1161,7 +1181,7 @@ class DecoderLayer(nn.Module):
         h, maps = path(cfg, self.mesh, name="res_mlp")(streams)
         h, counts = _norm(cfg, "ln2")(h), {}
         if feed_forward == "moe":
-            y, counts = RoutedExperts(cfg, name="moe")(h, segment_ids)
+            y, counts = RoutedExperts(cfg, self.mesh, name="moe")(h, segment_ids)
         else:
             with jax.named_scope("tos.dense_mlp"):
                 y = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
@@ -1229,7 +1249,11 @@ class Decoder(nn.Module):
             obs.counter(
                 "moe_layers_at_bound_total",
                 help="routed layers of a step that fell back to the whole slot buffer (tokens x experts per token rows)")
-            for name in ("layers_compact", "layers_at_bound"):
+            obs.counter(
+                "moe_combine_rows_fetched_total",
+                help="slot buffer rows that one call a routed layer of the way back to token order brought in (128-row "
+                "windows visited); over moe_slots_held_total, the read's amplification")
+            for name in ("layers_compact", "layers_at_bound", "combine_rows_fetched"):
                 self.sow("counters", "moe_" + name, sum(c.get(name, 0.0) for c in counted))
         summed = sum(jnp.split(streams[:, head_from:].astype(jnp.float32), cfg.hc_mult, axis=-1))
         x = _norm(cfg, "ln_f")(summed.astype(cfg.compute_dtype))

@@ -18,8 +18,11 @@ gives what one pass over ``S`` rows gives): no capacity, nothing dropped,
 whatever the routing. What stays ``S`` long: the int32 /
 float32 vectors (the sort, ``place``, the weights). The way back to token
 order (:func:`slots_to_tokens` forward, :func:`rows_to_slots` backward)
-reads the buffer through ``place``, a zero row behind it for the slots that
-are not in it, as ``k`` gathers of ``[T, d]``.
+reads the compact buffer's rows and nothing else: inside an expert's group
+the rows stand in token order, so a tile of tokens finds its slots of one
+expert in one range of rows, and one kernel
+(:mod:`~tensorflowonspark_tpu.ops.moe_combine`) sums each token's held slots
+window by window.
 
 The product is ``jax.lax.ragged_dot``: on a TPU, XLA lowers it to its own
 Mosaic kernels (a metadata pass over the group sizes and a tiled product
@@ -39,6 +42,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from tensorflowonspark_tpu.ops import moe_combine
 
 
 def sort_slots(expert_of_slot, first, held):
@@ -83,65 +88,84 @@ def _rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
-def _sum_over_slots(buffer, place, k):
-    """``float32 [T, d]``: each token's ``k`` slots found in ``buffer`` (``[R,
-    d]``: the head of the sorted order) by their ``place`` there and summed
-    in slot order; a slot that is not in the buffer (its place is past the
-    end) adds a zero row. From the
-    whole buffer (``R = T * k``) that is one gather back to slot order and a
-    sum over ``[T, k, d]``; from a shorter one, ``k`` gathers of ``[T, d]``
-    added up, so that nothing is ``T * k`` rows long (read on the chip against
-    one gather of ``[T * k, d]`` and against a scatter-add of the ``R`` rows:
-    PERF.md §6, PR 35)."""
-    rows = buffer.shape[0]
-    if rows == place.shape[0]:
+def _sum_over_slots(buffer, head, place, group_sizes, k, mesh):
+    """``[T, d]`` in ``buffer``'s type: each token's ``k`` slots found in
+    ``buffer`` (``[R, d]``: the head of the sorted order) and summed in
+    float32; a slot that is not in the buffer adds nothing. From the whole
+    buffer (``R = T * k``: every slot is there) that is one gather back to slot
+    order by ``place`` and a sum over ``[T, k, d]``, in slot order. From a
+    shorter one it is :func:`moe_combine.combine
+    <tensorflowonspark_tpu.ops.moe_combine.combine>`, a kernel (interpreted
+    anywhere but on a TPU) that reads the buffer's rows a window at a time by
+    ``head`` and ``group_sizes`` and sums a token's held slots in held-expert
+    order: no zero row behind the buffer, nothing ``T * k`` rows long, no
+    gather (``k`` gathers of ``[T, d]`` before PR 42, seven in eight of their
+    rows the zero row where a chip holds an eighth of the experts; read on the
+    chip against them, against one gather of ``[T * k, d]`` and against a
+    scatter-add of the ``R`` rows: PERF.md §6, PR 35 and PR 42).
+
+    A Mosaic call has no partitioning rule, and JAX refuses to lower one on
+    more than one chip outside a ``shard_map``: on a ``mesh`` of several
+    devices the kernel runs under one. The sum is independent column by
+    column and by nothing else (``head`` names tokens of the whole batch), so
+    the columns are split over ``tp`` where every shard keeps whole lanes and
+    every other operand is whole on every chip."""
+    if buffer.shape[0] == place.shape[0]:
         per_slot = _rows(buffer, place)
-        return jnp.sum(per_slot.reshape(-1, k, per_slot.shape[-1]), axis=1, dtype=jnp.float32)
-    padded = jnp.concatenate([buffer, jnp.zeros((1,) + buffer.shape[1:], buffer.dtype)])
-    at = jnp.minimum(place, rows).reshape(-1, k)
-    total = _rows(padded, at[:, 0]).astype(jnp.float32)
-    for j in range(1, k):
-        total = total + _rows(padded, at[:, j]).astype(jnp.float32)
-    return total
+        return jnp.sum(per_slot.reshape(-1, k, per_slot.shape[-1]), axis=1, dtype=jnp.float32).astype(buffer.dtype)
+    run = functools.partial(moe_combine.combine, tokens=place.shape[0] // k, interpret=jax.default_backend() != "tpu")
+    if mesh is None or mesh.size == 1:
+        return run(buffer, head // k, group_sizes)
+    from jax.sharding import PartitionSpec as P
+
+    from tensorflowonspark_tpu.parallel.collectives import shard_map
+
+    shards = dict(zip(mesh.axis_names, mesh.devices.shape)).get("tp", 1)
+    columns = P(None, "tp" if shards > 1 and buffer.shape[1] % (128 * shards) == 0 else None)
+    # check_vma off: pallas_call outputs carry no varying-axes type
+    return shard_map(run, mesh=mesh, in_specs=(columns, P(), P()), out_specs=columns, check_vma=False)(
+        buffer, head // k, group_sizes)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def rows_to_slots(rows, head, place, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def rows_to_slots(rows, head, place, group_sizes, k, mesh=None):
     """``[T, d]`` token rows to the head of the sorted slot buffer, ``[R,
     d]``: sorted slot ``i`` is slot ``head[i]`` (``head = order[:R]``), which
-    belongs to token ``head[i] // k``. The gradient comes back by the inverse
-    permutation (``place``, ``int32 [T * k]``) and a sum over each token's
-    ``k`` slots (:func:`_sum_over_slots`) — gathers, where the gather's own
-    transpose would be a scatter-add over repeated rows."""
+    belongs to token ``head[i] // k``. The gradient is the sum over each
+    token's slots that lie in the buffer (:func:`_sum_over_slots`, by the
+    inverse permutation ``place``, ``int32 [T * k]``, or by ``head`` and the
+    ``group_sizes``; ``mesh``: the devices the step runs on, if more than
+    one) where the gather's own transpose would be a scatter-add over
+    repeated rows."""
     return _rows(rows, head // k)
 
 
-def _rows_to_slots_fwd(rows, head, place, k):
-    return _rows(rows, head // k), (place,)
+def _rows_to_slots_fwd(rows, head, place, group_sizes, k, mesh):
+    return _rows(rows, head // k), (head, place, group_sizes)
 
 
-def _rows_to_slots_bwd(k, res, d_sorted):
-    return _sum_over_slots(d_sorted, res[0], k).astype(d_sorted.dtype), None, None
+def _rows_to_slots_bwd(k, mesh, res, d_sorted):
+    return _sum_over_slots(d_sorted, *res, k, mesh), None, None, None
 
 
 rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def slots_to_tokens(sorted_rows, head, place, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def slots_to_tokens(sorted_rows, head, place, group_sizes, k, mesh=None):
     """The head of the sorted buffer (``[R, d]``) summed into its tokens,
-    ``[T, d]`` (:func:`_sum_over_slots`, then back to the buffer's type). The
-    gradient is ``R`` rows gathered from ``[T, d]`` by ``head // k`` (no ``[T,
-    k, d]`` broadcast)."""
-    return _sum_over_slots(sorted_rows, place, k).astype(sorted_rows.dtype)
+    ``[T, d]`` in the buffer's type (:func:`_sum_over_slots`). The gradient is
+    ``R`` rows gathered from ``[T, d]`` by ``head // k`` (no ``[T, k, d]``
+    broadcast)."""
+    return _sum_over_slots(sorted_rows, head, place, group_sizes, k, mesh)
 
 
-def _slots_to_tokens_fwd(sorted_rows, head, place, k):
-    return slots_to_tokens(sorted_rows, head, place, k), (head,)
+def _slots_to_tokens_fwd(sorted_rows, head, place, group_sizes, k, mesh):
+    return slots_to_tokens(sorted_rows, head, place, group_sizes, k, mesh), (head,)
 
 
-def _slots_to_tokens_bwd(k, res, d_tokens):
-    return _rows(d_tokens, res[0] // k), None, None
+def _slots_to_tokens_bwd(k, mesh, res, d_tokens):
+    return _rows(d_tokens, res[0] // k), None, None, None
 
 
 slots_to_tokens.defvjp(_slots_to_tokens_fwd, _slots_to_tokens_bwd)

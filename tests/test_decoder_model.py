@@ -264,7 +264,7 @@ def test_grouped_matmul_fences_the_rows_past_its_groups():
     assert float(jnp.abs(d_lhs[9:]).max()) == 0.0 and float(jnp.abs(d_lhs[:9]).min()) > 0.0
     place = gm.slot_places(order)
     rows = jax.random.normal(jax.random.PRNGKey(15), (8, 8), jnp.float32)
-    d_rows = jax.grad(lambda r: jnp.sum(gm.rows_to_slots(r, order, place, 2) * lhs))(rows)
+    d_rows = jax.grad(lambda r: jnp.sum(gm.rows_to_slots(r, order, place, sizes, 2) * lhs))(rows)
     close(d_rows, jax.grad(lambda r: jnp.sum(r[order // 2] * lhs))(rows))
-    d_sorted = jax.grad(lambda s: jnp.sum(gm.slots_to_tokens(s, order, place, 2) * rows))(lhs)
+    d_sorted = jax.grad(lambda s: jnp.sum(gm.slots_to_tokens(s, order, place, sizes, 2) * rows))(lhs)
     close(d_sorted, jax.grad(lambda s: jnp.sum(s[place].reshape(8, 2, 8).sum(1) * rows))(lhs))

@@ -415,63 +415,88 @@ def _computations(text):
     return found
 
 
-def _reached(computations, name, seen):
+def _reached(computations, name, seen, fused=False):
     """The lines of ``name`` and of every computation it calls; what a line
-    inside a fusion defines is never written to memory, so those are left out."""
-    if name in seen or name not in computations or name.startswith("fused_computation"):
+    inside a fusion defines is never written to memory, so those are left out
+    unless ``fused``."""
+    if name in seen or name not in computations or (name.startswith("fused_computation") and not fused):
         return []
     seen.add(name)
     lines = list(computations[name])
     for line in computations[name]:
         for callee in re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line):
-            lines += _reached(computations, callee, seen)
+            lines += _reached(computations, callee, seen, fused)
     return lines
 
 
-def test_routed_layer_compiles_a_compact_branch_at_the_cells_shape(one_chip, no_compile_cache):
-    """One routed layer of ``sdar-30b-a3b.bd4-packed4k`` (2 x 8192 positions,
-    top-8 of 128, 16 held: 131,072 slots, a compact buffer of 32,768),
+#: the three routed cells: configuration, family, rows and positions a row (``bd4-packed4k`` reads 8192 a row of 4096),
+#: whether the layer is told the segment ids (padding positions routed nowhere)
+ROUTED_CELLS = {
+    "sdar-30b-a3b.bd4-packed4k": ("sdar-30b-a3b", "bd_lm", 2, 8192, False),
+    "laguna-s-2-1.code8k": ("laguna-s-2-1", "swa_lm", 1, 8192, True),
+    "xing4-a4b.packed8k": ("xing4-a4b", "moe_lm", 1, 8192, False),
+}
+
+
+@pytest.mark.parametrize("cell", list(ROUTED_CELLS))
+def test_routed_layer_compiles_a_compact_branch_at_the_cells_shape(one_chip, no_compile_cache, monkeypatch, cell):
+    """One routed layer of each routed cell at the cell's shape
+    (``sdar-30b-a3b``: 2 x 8192 positions, top-8 of 128, 16 held: 131,072
+    slots, a compact buffer of 32,768; ``laguna-s-2-1``: 8192, top-10 of 256,
+    8 held, 5,120 rows; ``xing4-a4b``: 8192, top-4 of 64, 8 held, 8,192 rows),
     forward and backward under ``jax.checkpoint``. Three conditionals (the
     forward pass, the recomputed one because this loss reads the layer's
     result again, the backward one); in each, the branch for the compact
     buffer holds no array of the bound's length as wide as the model or an
-    expert (the way back to token order is ``k`` gathers of ``[T, d]``), and
-    nor does the other, the fallback, which runs a quarter of the tokens at a
-    time. In both branches the grouped products keep the bare name the
+    expert, and nor does the other, the fallback, which runs a share of the
+    tokens at a time. The way back to token order is the ``moe_combine``
+    kernel under ``tos.moe_route`` in every compact branch (forward,
+    recomputed and backward alike, once each): no copy of the buffer with a zero row behind
+    it (``C + 1`` rows) and no gather that writes ``[T, d]`` is left there.
+    In both branches the grouped products keep the bare name the
     readers find them by and every other kernel call sits under one of the
     mechanisms' scopes (the fallback's second sort too); the operations fall into the phases they fell into
     (the forward pass that the backward branch runs again is booked with it);
     and the ``conditional`` instructions carry none of the mechanisms'
     scopes, so that a reader which sums events by scope counts a branch's
     operations once."""
+    import importlib
     import json
 
-    from benchmarks.families import bd_lm
     from benchmarks.layer_metrics import _moe
     from tensorflowonspark_tpu.models import decoder
+    from tensorflowonspark_tpu.ops import grouped_matmul as gm
 
-    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", "sdar-30b-a3b.json")) as f:
-        cfg = decoder.DecoderConfig.from_dict(bd_lm.model_config(json.load(f), remat=True))
-    rows, seq, d, width = 2, 8192, cfg.hidden_size, cfg.moe_intermediate_size
-    slots = rows * seq * cfg.num_experts_per_tok
+    config, family, rows, seq, segmented = ROUTED_CELLS[cell]
+    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", config + ".json")) as f:
+        cfg = decoder.DecoderConfig.from_dict(
+            importlib.import_module("benchmarks.families." + family).model_config(json.load(f), remat=True))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel, not its interpreter
+    d, width, tokens = cfg.hidden_size, cfg.moe_intermediate_size, rows * seq
+    slots = tokens * cfg.num_experts_per_tok
+    buffer = gm.compact_rows(slots, cfg.held[1], cfg.n_routed_experts)
+    assert buffer < slots
     layer = decoder.RoutedExperts(cfg)
     on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)  # noqa: E731
     x = jax.ShapeDtypeStruct((rows, seq, d), jnp.bfloat16, sharding=one_chip)
+    ids = (jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one_chip),) if segmented else ()
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 128, d), jnp.bfloat16))["params"]))
 
-    def loss(p, x):
-        return jnp.sum(layer.apply({"params": p}, x)[0].astype(jnp.float32) ** 2)
+    def loss(p, x, *ids):
+        return jnp.sum(layer.apply({"params": p}, x, *ids)[0].astype(jnp.float32) ** 2)
 
-    def step(p, x):
+    def step(p, x, *ids):
         with jax.named_scope("tos.loss_and_grad"):
-            return jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1))(p, x)
+            return jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1))(p, x, *ids)
 
-    computations = _computations(jax.jit(step).lower(params, x).compile().as_text())
+    computations = _computations(jax.jit(step).lower(params, x, *ids).compile().as_text())
     conditionals = [line for lines in computations.values() for line in lines if " conditional(" in line]
     assert len(conditionals) == 3
     long_and_wide = re.compile(r"= \(?(?:bf16|f32|pred)\[{},({}|{})\]".format(slots, d, width))
-    phases = []
+    zero_row_behind = re.compile(r"\[{},{}\]".format(buffer + 1, d))
+    gathers_token_rows = re.compile(r"= \(?(?:bf16|f32)\[{},{}\]\S* gather\(".format(tokens, d))
+    phases, combines = [], []
     for line in conditionals:
         scope = re.search(r'op_name="([^"]*)"', line)
         assert scope is None or "tos.moe_" not in scope.group(1)
@@ -492,9 +517,98 @@ def test_routed_layer_compiles_a_compact_branch_at_the_cells_shape(one_chip, no_
             assert scoped and all(branch == every_slot and name.endswith("/broadcast_in_dim") for name in rest)
             assert all("/" + scope in name or "(" + scope + ")" in name for name in scoped)
             booked.append({_program.phase_of(name) for name in scoped})
+            kernel = [name for name in named if "/moe_combine" in name]
+            assert all(_moe.in_scope(name, "tos.moe_route") for name in kernel)
+            if branch == compact:
+                combines.append(len(kernel))
+                whole = _reached(computations, branch, set(), fused=True)
+                assert not [ln for ln in whole if zero_row_behind.search(ln)]
+                if buffer != tokens:  # where they are equal the dispatch's gather of [C, d] has the same shape
+                    assert not [ln for ln in whole if gathers_token_rows.search(ln)]
+            else:
+                assert not kernel  # a share's slots all fit its buffer: one gather back to slot order
         assert booked[1] - booked[0] <= {"recompute"}  # the fallback's backward pass runs each share again, and says so
         phases.append(booked[0])
     assert sorted(phases, key=sorted) == [{"bwd"}, {"fwd"}, {"recompute"}]
+    # forward; recomputed; the dispatch's gradient (the sum that the backward branch's forward pass ends in feeds nothing)
+    assert combines == [1, 1, 1]
+
+
+def test_routed_layer_compiles_for_four_chips(topo, no_compile_cache, monkeypatch):
+    """JAX refuses to lower a Mosaic call on more than one chip outside a
+    ``shard_map`` ("Mosaic kernels cannot be automatically partitioned"), which
+    no interpreted test sees. One routed layer of ``sdar-30b-a3b`` on the
+    described host's 2 x 2 mesh, the batch over ``dp``: the layer is told its
+    mesh, and the kernel runs on every chip on all the tokens and half the
+    columns (``tp`` 2), forward and backward."""
+    import json
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks.families import bd_lm
+    from tensorflowonspark_tpu.models import decoder
+
+    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", "sdar-30b-a3b.json")) as f:
+        cfg = decoder.DecoderConfig.from_dict(bd_lm.model_config(json.load(f), remat=True))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    rows, seq, d = 4, 2048, cfg.hidden_size
+    layer = decoder.RoutedExperts(cfg, mesh)
+    x = jax.ShapeDtypeStruct((rows, seq, d), jnp.bfloat16, sharding=NamedSharding(mesh, P("dp", None, None)))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(mesh, P())),
+        jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 128, d), jnp.bfloat16))["params"]))
+
+    def loss(p, x):
+        return jnp.sum(layer.apply({"params": p}, x)[0].astype(jnp.float32) ** 2)
+
+    def step(p, x):
+        return jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+
+    text = jax.jit(step).lower(params, x).compile().as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line and "/moe_combine" in line]
+    assert len(calls) == 2 and all("= bf16[{},{}]".format(rows * seq, d // 2) in line for line in calls)
+
+
+@pytest.mark.parametrize("cell", ["lm1024.packed4k", "phi-4-mini-flash.reason8k"])
+def test_a_model_without_routed_layers_traces_none_of_the_routing(monkeypatch, cell):
+    """The loss and gradients of the two language-model cells that have no
+    routed layer, traced at their cells' shapes with the routed layer and
+    everything of ``ops/grouped_matmul.py`` and ``ops/moe_combine.py``
+    replaced by a function that raises: they reach none of it, so what PR 42
+    changed there leaves their jaxprs what they were."""
+    from benchmarks import run
+    from benchmarks.families import ssm_lm
+    from tensorflowonspark_tpu.models import decoder, get_model, transformer
+    from tensorflowonspark_tpu.ops import grouped_matmul, moe_combine
+
+    def reached(*args, **kwargs):
+        raise AssertionError("a model without routed layers reached the routed experts' code")
+
+    for module in (grouped_matmul, moe_combine):
+        for name, value in list(vars(module).items()):
+            if callable(value) and getattr(value, "__module__", None) == module.__name__:
+                monkeypatch.setattr(module, name, reached)
+    monkeypatch.setattr(decoder.RoutedExperts, "__call__", reached)
+    monkeypatch.setattr(decoder, "_experts_on_rows", reached)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the models refuse attention="flash" off the chip
+    _, _, config, traffic = run.resolve(cell, False)
+    if config["family"] == "lm":  # as ``benchmarks/families/lm.build`` makes it
+        model = transformer.create_model(
+            vocab_size=config["vocab_size"], d_model=config["d_model"], n_layers=config["n_layers"],
+            n_heads=config["n_heads"], d_ff=config["d_ff"], max_seq_len=traffic["seq_len"], dtype=config["dtype"],
+            remat=traffic["remat"], attention="flash")
+    else:
+        model = get_model("decoder", **dict(ssm_lm.model_config(config, remat=traffic["remat"]), attention="flash"))
+    rows, seq = traffic["batch_per_chip"], traffic["seq_len"]
+    ids = jax.ShapeDtypeStruct((rows, seq + 1), jnp.int32)
+    batch = {"tokens": ids, "segment_ids": ids, "positions": ids}
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"])
+    loss_fn = transformer.make_loss_fn(model)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda p, b: loss_fn(p, b)[0]))(params, batch)
+    text = str(jaxpr)
+    assert "pallas_call" in text and "moe_combine" not in text and "ragged_dot" not in text
 
 
 def test_defaults_are_the_segmented_constants():

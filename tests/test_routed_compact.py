@@ -3,7 +3,9 @@
 quarter of the experts runs them on ``C`` rows (twice the even share) and
 falls back to all ``S = T * k`` on a step whose held slots do not fit. Both
 are held, bit for bit, to the layer compiled on ``S`` rows alone, and to the
-benchmark's dense references; the fallback is taken and counted; a model that
+benchmark's dense references; the kernel that sums a token's held slots out
+of the compact buffer (``ops/moe_combine.py``, interpreted) is held to the
+``k`` gathers of ``[T, d]`` it replaced, kept here as its reference; the fallback is taken and counted; a model that
 holds all its experts compiles no ``cond``; and inside the compact branch no
 array as wide as the model or an expert has more rows than the buffer."""
 
@@ -18,6 +20,7 @@ from decoder_testutil import REF, close, packed_batch, program_config, program_l
 from benchmarks.reference import bd_lm, moe_lm
 from tensorflowonspark_tpu.models import decoder
 from tensorflowonspark_tpu.ops import grouped_matmul as gm
+from tensorflowonspark_tpu.ops import moe_combine
 
 ROWS, SEQ = 2, 256  # 512 tokens
 
@@ -116,6 +119,132 @@ def test_compact_rows_equal_the_whole_buffer_and_the_reference(monkeypatch, scor
     tree_close(grads, want, 5e-4)
 
 
+def _k_gathers(buffer, place, k):
+    """What ``gm._sum_over_slots`` was on a buffer shorter than the slots
+    before PR 42, and is held to now: ``float32 [T, d]``, each token's ``k``
+    slots found in ``buffer`` by their ``place`` there and summed in slot
+    order, a slot that is not in the buffer (its place is past the end)
+    finding a zero row behind it."""
+    rows = buffer.shape[0]
+    padded = jnp.concatenate([buffer, jnp.zeros((1,) + buffer.shape[1:], buffer.dtype)])
+    at = jnp.minimum(place, rows).reshape(-1, k)
+    total = padded[at[:, 0]].astype(jnp.float32)
+    for j in range(1, k):
+        total = total + padded[at[:, j]].astype(jnp.float32)
+    return total
+
+
+#: 32 experts of which this chip holds 4, 5, 6 and 7: ``C = T * k / 4``; the kernel's tile is 512 tokens
+EXPERTS, FIRST, HELD, WIDE = 32, 4, 4, 128
+#: kind of routing: tokens, experts a token
+ROUTINGS = {
+    "even": (1024, 8), "no_held_slot_in_a_tile": (1536, 8), "one_expert_holds_a_tiles_slots": (1024, 10),
+    "held_slots_exactly_the_buffer": (1024, 4), "padding_routed_nowhere": (1024, 10),
+    "tokens_no_multiple_of_the_tile": (1300, 4),
+}
+
+
+def _routing(kind):
+    """``int32 [T, k]``: the experts each token chose."""
+    tokens, k = ROUTINGS[kind]
+    _, chosen = jax.lax.top_k(jax.random.normal(jax.random.PRNGKey(5), (tokens, EXPERTS)), k)
+    token = jnp.arange(tokens)[:, None]
+    elsewhere = FIRST + HELD + jnp.arange(k)[None, :]  # k experts held on other chips
+    if kind == "no_held_slot_in_a_tile":  # the second tile's tokens
+        chosen = jnp.where((token >= 512) & (token < 1024), elsewhere, chosen)
+    if kind == "one_expert_holds_a_tiles_slots":  # every token of the first tile: 512 rows of one expert, four windows and more
+        chosen = jnp.where(token < 512, elsewhere.at[0, 0].set(FIRST), chosen)
+    if kind == "held_slots_exactly_the_buffer":  # one held slot a token, T = C of them
+        chosen = elsewhere.at[0, 0].set(0) + jnp.where(jnp.arange(k)[None, :] == 0, FIRST + token % HELD, 0)
+    if kind == "padding_routed_nowhere":  # the row's last 300 positions, as ``RoutedExperts`` routes segment id 0
+        chosen = jnp.where(token >= tokens - 300, EXPERTS, chosen)
+    return chosen.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", list(ROUTINGS))
+def test_the_kernel_sums_what_the_k_gathers_summed(kind, dtype):
+    """Values and both custom VJPs (``slots_to_tokens`` forward, whose
+    gradient is a gather; ``rows_to_slots``, whose gradient is the kernel)
+    against the plain form and ``jax``'s own transposes of it in float32. The
+    kernel adds a token's float32 terms in held-expert order where the
+    gathers added them in slot order: equal to float32 rounding of the sum,
+    which in bfloat16 is at most one step of the result."""
+    tokens, k = ROUTINGS[kind]
+    chosen = _routing(kind)
+    order, sizes = gm.sort_slots(chosen.reshape(-1), FIRST, HELD)
+    place, used = gm.slot_places(order), int(jnp.sum(sizes))
+    compact = gm.compact_rows(tokens * k, HELD, EXPERTS)
+    assert compact < tokens * k and 0 < used <= compact and compact % moe_combine.WINDOW == 0
+    head = order[:compact]
+    # as ``gm.grouped_matmul`` leaves them: zeros past the last group, values and gradients
+    live = (jnp.arange(compact) < used)[:, None]
+    buffer = jnp.where(live, jax.random.normal(jax.random.PRNGKey(6), (compact, WIDE)), 0).astype(dtype)
+    d_buffer = jnp.where(live, jax.random.normal(jax.random.PRNGKey(7), (compact, WIDE)), 0).astype(dtype)
+    rows = jax.random.normal(jax.random.PRNGKey(8), (tokens, WIDE)).astype(dtype)
+    d_rows = jax.random.normal(jax.random.PRNGKey(9), (tokens, WIDE)).astype(dtype)
+    step = {"float32": 1e-6, "bfloat16": 2.0 ** -8}[dtype]
+
+    def held_to(got, want):
+        assert got.dtype == jnp.dtype(dtype)
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), rtol=step, atol=1e-5)
+
+    tile = moe_combine.token_tile(tokens)
+    tile_of_step, window, first, last, steps = map(np.asarray, moe_combine.work_list(head // k, sizes, tokens, tile))
+    assert tile == 512 and -(-tokens // tile) <= steps[0] <= len(tile_of_step) and np.all(np.diff(tile_of_step) >= 0)
+    ranges = set(zip(first[:steps[0]].tolist(), last[:steps[0]].tolist()))  # a range has a step a window it touches
+    assert sum(end - start for start, end in ranges) == used
+    if kind == "no_held_slot_in_a_tile":  # one step for the tile, its range empty
+        (start, end), = [(first[s], last[s]) for s in range(steps[0]) if tile_of_step[s] == 1]
+        assert start == end
+    if kind == "one_expert_holds_a_tiles_slots":  # the first expert's 512 rows of the first tile, window after window
+        assert list(window[:4]) == [0, 1, 2, 3] and (first[0], last[0]) == (0, 512) and int(sizes[0]) > 512
+    if kind == "held_slots_exactly_the_buffer":
+        assert used == compact == tokens
+    if kind == "tokens_no_multiple_of_the_tile":
+        assert tokens % tile and tile_of_step[steps[0] - 1] == tokens // tile
+
+    got, pull = jax.vjp(lambda b: gm.slots_to_tokens(b, head, place, sizes, k), buffer)
+    want, plain_pull = jax.vjp(lambda b: _k_gathers(b, place, k), buffer.astype(jnp.float32))
+    held_to(got, want)
+    held_to(pull(d_rows)[0], plain_pull(d_rows.astype(jnp.float32))[0])
+    if kind == "no_held_slot_in_a_tile":
+        assert float(jnp.abs(got[512:1024]).max()) == 0.0 and float(jnp.abs(got[:512]).max()) > 0.0
+    if kind == "padding_routed_nowhere":
+        assert float(jnp.abs(got[tokens - 300:]).max()) == 0.0
+
+    got, pull = jax.vjp(lambda r: gm.rows_to_slots(r, head, place, sizes, k), rows)
+    want, plain_pull = jax.vjp(lambda r: r[head // k], rows.astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want))
+    held_to(pull(d_buffer)[0], plain_pull(d_buffer.astype(jnp.float32))[0])
+    # the same sum by the other route: the gathers on the cotangent
+    held_to(pull(d_buffer)[0], _k_gathers(d_buffer, place, k))
+
+
+def test_the_kernels_list_of_steps_stays_under_its_bound_whatever_the_routing():
+    """Every slot to one held expert, then spread as unevenly as a sort
+    allows: the steps the device counts never pass ``C / 128 + tiles *
+    held``, the grid's static length; and the rows they fetch are counted."""
+    tokens, k, held, tile = 2048, 2, 4, 512
+    rows = 1024
+    for sizes in ([1024, 0, 0, 0], [0, 0, 0, 1024], [1, 511, 129, 383], [127, 129, 1, 0], [0, 0, 0, 0]):
+        sizes = jnp.array(sizes, jnp.int32)
+        # each group's tokens ascending, as a stable sort leaves them, and as far apart as they can be
+        token_of_row = jnp.concatenate([
+            jnp.linspace(0, tokens - 1, int(n)).astype(jnp.int32) for n in sizes]
+            + [jnp.zeros(rows - int(sizes.sum()), jnp.int32)])
+        tile_of_step, window, first, last, steps = map(np.asarray, moe_combine.work_list(token_of_row, sizes, tokens, tile))
+        assert len(tile_of_step) == rows // moe_combine.WINDOW + tokens // tile * held
+        assert tokens // tile <= steps[0] <= len(tile_of_step)
+        assert np.all(window >= 0) and np.all(window < rows // moe_combine.WINDOW)
+        fetched = float(moe_combine.rows_fetched(token_of_row, sizes, tokens))
+        assert fetched == steps[0] * moe_combine.WINDOW >= int(sizes.sum())
+        buffer = jnp.where((jnp.arange(rows) < sizes.sum())[:, None], 1.0, 7.0) * jnp.ones((rows, WIDE), jnp.bfloat16)
+        out = moe_combine.combine(buffer, token_of_row, sizes, tokens=tokens, interpret=True)
+        want = np.bincount(np.asarray(token_of_row)[:int(sizes.sum())], minlength=tokens)
+        np.testing.assert_array_equal(np.asarray(out[:, 0], np.float32), want)
+
+
 def test_the_rule_for_the_buffers_length():
     assert gm.compact_rows(131072, 16, 128) == 32768  # sdar-30b-a3b.bd4-packed4k: a quarter
     assert gm.compact_rows(32768, 8, 64) == 8192  # xing4-a4b.packed8k
@@ -126,11 +255,13 @@ def test_the_rule_for_the_buffers_length():
 
 
 def _eqns(jaxpr):
-    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold, a
+    kernel's body apart (its ``pl.when`` and loops are not the layer's)."""
     for eqn in jaxpr.eqns:
         yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _eqns(sub)
 
 
 def _primitives(jaxpr):
@@ -181,9 +312,14 @@ def test_the_compact_branch_holds_no_array_of_the_bounds_length(recomputed, dtyp
     assert len(conds) == 2
     for cond in conds:
         fallback, branch = cond.params["branches"]  # cond(fits, compact, fallback): index 1 is the true branch
-        # the way back to token order is k gathers of [T, d], never [S, d] or [T, k, d]
-        assert long_and_wide(branch.jaxpr) == [] and not _primitives(branch.jaxpr) & {"while", "scan"}
-        assert sum(name == "gather" and shape == (tokens, cfg.hidden_size) for name, shape in _arrays(branch.jaxpr)) >= 2
+        # the way back to token order is the kernel on the buffer's rows: never [S, d] or [T, k, d], no zero row
+        # behind the buffer, and no gather but the dispatch's (and its gradient's) [C, d] from [T, d]
+        assert long_and_wide(branch.jaxpr) == [] and not _primitives(branch.jaxpr) & {"while", "scan", "concatenate"}
+        kernels = [eqn for eqn in _eqns(branch.jaxpr) if eqn.primitive.name == "pallas_call"]
+        assert kernels and all(eqn.params["name"] == "moe_combine" for eqn in kernels)
+        assert {eqn.outvars[0].aval.shape for eqn in kernels} == {(tokens, cfg.hidden_size)}
+        gathered = [eqn for eqn in _eqns(branch.jaxpr) if eqn.primitive.name == "gather" and len(eqn.outvars[0].aval.shape) == 2]
+        assert gathered and all(eqn.outvars[0].aval.shape == (compact, cfg.hidden_size) for eqn in gathered)
         # the fallback: a share of the tokens at a time, a share's slots no more than the compact buffer's rows
         assert long_and_wide(fallback.jaxpr) == [] and "scan" in _primitives(fallback.jaxpr)
         # the products sit in no call (jnp's own small ones apart): XLA hands a call's name to what it inlines, and
@@ -201,25 +337,57 @@ def test_the_model_carries_the_two_counts_out_of_the_step():
     _, metrics = jax.jit(loss_fn)(variables["params"], batch)
     assert float(metrics["counter/moe_layers_compact"]) + float(metrics["counter/moe_layers_at_bound"]) == 2  # routed layers
     assert float(metrics["counter/moe_layers_compact"]) == 2
+    # what the way back to token order fetched: whole windows, no fewer rows than the held slots
+    fetched, held = float(metrics["counter/moe_combine_rows_fetched"]), float(metrics["counter/moe_slots_held"])
+    assert fetched % moe_combine.WINDOW == 0 and held <= fetched <= 2 * ROWS * SEQ + 2 * 2 * moe_combine.WINDOW
     _, metrics = jax.jit(program_loss(REF)[1])(moe_lm.init_params(jax.random.PRNGKey(7), REF), packed_batch())
     assert "counter/moe_slots_held" in metrics and "counter/moe_layers_compact" not in metrics  # C == S: no cond to count
+    assert "counter/moe_combine_rows_fetched" not in metrics  # and no kernel: one gather back to slot order
 
 
+@pytest.mark.parametrize("told", [False, True], ids=["mesh_not_told", "mesh_told"])
 @pytest.mark.parametrize("crowding", ["fits", "all_held"])
-def test_a_batch_sharded_over_a_mesh_gives_the_one_device_layer(crowding):
+def test_a_batch_sharded_over_a_mesh_gives_the_one_device_layer(crowding, told):
     """dp 2 x tp 2 on the CPU's virtual devices: the ``cond`` and its backward
-    pass partition as the rest of the layer does, on either branch."""
+    pass partition as the rest of the layer does, on either branch. A layer
+    that is told its mesh (as ``DecoderLayer`` tells it) runs the kernel under
+    a ``shard_map``, which a Mosaic call needs on more than one chip; one that
+    is not leaves the interpreted kernel to the partitioner."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     cfg, p, x, _ = _layer("sigmoid", crowding)
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
 
-    def program(p, x):
-        return decoder.RoutedExperts(cfg).apply({"params": p}, x)
+    def program(p, x, mesh=None):
+        return decoder.RoutedExperts(cfg, mesh).apply({"params": p}, x)
 
     want_y, _, _, want = _value_and_grads(program, p, x)
+    if told:
+        primitives = [_primitives(jax.make_jaxpr(lambda p, x: program(p, x, m)[0])(p, x).jaxpr) for m in (mesh, None)]
+        assert "shard_map" in primitives[0] and "shard_map" not in primitives[1]
     with mesh:
-        y, counts, _, grads = _value_and_grads(program, p, jax.device_put(x, NamedSharding(mesh, P("dp", None, None))))
+        y, counts, _, grads = _value_and_grads(
+            lambda p, x: program(p, x, mesh if told else None), p, jax.device_put(x, NamedSharding(mesh, P("dp", None, None))))
     assert float(counts["layers_at_bound"]) == float(crowding == "all_held")
     close(y, want_y)
     tree_close(grads, want, 5e-4)
+
+
+def test_on_a_mesh_the_kernel_takes_the_columns_a_tp_shard_at_a_time():
+    """256 columns over ``tp`` 2 are whole lanes a shard: each chip sums its
+    128 columns of every token, the other operands whole; 32 columns are not,
+    and stay whole. Either way what one device gives, bit for bit."""
+    from jax.sharding import Mesh
+
+    tokens, k = ROUTINGS["even"]
+    order, sizes = gm.sort_slots(_routing("even").reshape(-1), FIRST, HELD)
+    place, compact = gm.slot_places(order), gm.compact_rows(tokens * k, HELD, EXPERTS)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    for wide, split in ((256, True), (32, False)):
+        buffer = jnp.where((jnp.arange(compact) < jnp.sum(sizes))[:, None],
+                           jax.random.normal(jax.random.PRNGKey(6), (compact, wide)), 0).astype(jnp.bfloat16)
+        want = gm.slots_to_tokens(buffer, order[:compact], place, sizes, k)
+        on_mesh = jax.jit(lambda b: gm.slots_to_tokens(b, order[:compact], place, sizes, k, mesh))
+        np.testing.assert_array_equal(np.asarray(on_mesh(buffer), np.float32), np.asarray(want, np.float32))
+        kernels = [eqn for eqn in _eqns(jax.make_jaxpr(on_mesh)(buffer).jaxpr) if eqn.primitive.name == "pallas_call"]
+        assert [eqn.outvars[0].aval.shape for eqn in kernels] == [(tokens, wide // 2 if split else wide)]
