@@ -893,8 +893,9 @@ class RoutedExperts(nn.Module):
     layer has no such parameter); either kind may stand beside a shared
     expert (``cfg.shared_width``). Where ``padding_slots`` is off, a padding
     position (segment id 0) chooses no expert: its routed term is zero, and
-    only the shared expert sees it. Weights: ``s`` at the chosen, over their
-    sum, times ``routed_scaling_factor``. Every slot whose expert is held here is
+    only the shared expert sees it. Weights: ``s`` at the chosen (read by
+    :func:`_scores_at`, a masked sum and not a gather), over their sum, times
+    ``routed_scaling_factor``. Every slot whose expert is held here is
     computed — no capacity, nothing dropped; a slot whose expert lives on
     another chip adds nothing here (nor is anything put in its place).
 
@@ -941,19 +942,19 @@ class RoutedExperts(nn.Module):
                 bias = self.param("router_bias", nn.initializers.normal(0.02), (cfg.n_routed_experts,), jnp.float32)
                 scores = jax.nn.sigmoid(logits)
                 _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
-            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            picked = _scores_at(scores, chosen)
             weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
             if not cfg.padding_slots and segment_ids is not None:
                 # an expert past the router's last: held nowhere
                 chosen = jnp.where(segment_ids.reshape(tokens, 1) > 0, chosen, cfg.n_routed_experts)
-            order, group_sizes = gm.sort_slots(chosen.reshape(-1), first, held)
-            place, rows_used = gm.slot_places(order), jnp.sum(group_sizes)
+            order, group_sizes, local = gm.sort_slots(chosen.reshape(-1), first, held)
+            rows_used = jnp.sum(group_sizes)
 
         init = _kernel_init(batch_axis=(0,))
         gate = self.param("experts_gate", init, (held, d, width), jnp.float32)
         up = self.param("experts_up", init, (held, d, width), jnp.float32)
         down = self.param("experts_down", init, (held, width, d), jnp.float32)
-        per_token, shared, indices = (flat, weights), (gate, up, down), (order, place, group_sizes)
+        per_token, shared = (flat, weights), (gate, up, down)
         slots = tokens * k
         counts = {
             "slots_routed": jnp.float32(slots), "slots_held": rows_used.astype(jnp.float32),
@@ -962,10 +963,10 @@ class RoutedExperts(nn.Module):
         compact = gm.compact_rows(slots, held, cfg.n_routed_experts)
         on_rows = _experts_on_rows if self.mesh is None or self.mesh.size == 1 else _on_mesh(self.mesh)
         if compact == slots:
-            routed = on_rows(slots, *per_token, *shared, *indices)
+            routed = on_rows(slots, *per_token, *shared, order, group_sizes)
         else:
             fits = rows_used <= compact
-            routed = gm.either(on_rows, compact, fits, per_token, shared, *indices)
+            routed = gm.either(on_rows, compact, fits, per_token, shared, order, local, group_sizes)
             with jax.named_scope("tos.moe_route"):  # the kernel's own list of steps, counted beside it
                 fetched = moe_combine.rows_fetched(order[:compact] // k, group_sizes, tokens)
             counts.update(layers_compact=fits.astype(jnp.float32), layers_at_bound=1.0 - fits,
@@ -976,13 +977,33 @@ class RoutedExperts(nn.Module):
         return (routed + shared).reshape(batch, length, d), counts
 
 
+def _scores_at(scores, chosen):
+    """``scores[t, chosen[t, j]]``, ``float32 [T, k]`` out of ``[T, E]``, as
+    a masked sum over the experts: ``where``, not a product with a 0 / 1
+    mask, and one term a ``(t, j)``, so exactly what ``take_along_axis``
+    gives whatever the scores; its transpose is the same mask on the
+    cotangent, one term a ``(t, e)`` since a token's experts are distinct. XLA
+    makes one fusion of each (compare, select, reduce: ``[T, k, E]`` is never
+    written), where a TPU walks a gather and the scatter-add that is its
+    transpose index by index: 1.36 ms a call at ``sdar-30b-a3b.bd4-packed4k``'s
+    ``T, k, E`` = 16,384, 8, 128 for a few hundred kilobytes, against 0.42 ms
+    forward and 0.15 backward for the masked sum (a v5e; PERF.md §6, PR 43).
+    ``lax.top_k``'s own values would not do: its differentiation rule gathers
+    the tangent by the indices, and transposes to that scatter-add. The masked
+    sum is ``T * k * E`` selects, so its cost grows with ``E`` where a
+    gather's does not: right at the configurations' 64 to 256 experts; with
+    experts in the thousands a gather would be the cheaper program again."""
+    at_chosen = chosen[..., None] == jnp.arange(scores.shape[-1], dtype=chosen.dtype)
+    return jnp.sum(jnp.where(at_chosen, scores[:, None, :], 0.0), axis=-1)
+
+
 @functools.partial(jax.jit, static_argnums=0, static_argnames="mesh", inline=True)
-def _experts_on_rows(rows, flat, weights, gate, up, down, order, place, group_sizes, mesh=None):
+def _experts_on_rows(rows, flat, weights, gate, up, down, order, group_sizes, mesh=None):
     """The held experts over the first ``rows`` of the sorted slots, weighed
     and summed into their tokens: ``[T, d]``. ``flat`` ``[T, d]``, ``weights``
     ``float32 [T, k]``, the experts' float32 matrices, and
-    :func:`~tensorflowonspark_tpu.ops.grouped_matmul.sort_slots`' ``order``,
-    its inverse and the group sizes. Right for any ``rows`` that holds every
+    :func:`~tensorflowonspark_tpu.ops.grouped_matmul.sort_slots`' ``order``
+    and group sizes. Right for any ``rows`` that holds every
     held slot (``sum(group_sizes) <= rows``); its scopes open in here, so
     that a ``cond`` round it carries none. ``jax.jit(inline=True)``: traced
     once for all the layers, branches and passes of a step that call it at
@@ -993,9 +1014,8 @@ def _experts_on_rows(rows, flat, weights, gate, up, down, order, place, group_si
     way back to token order (``gm._sum_over_slots``)."""
     dt, k = flat.dtype, weights.shape[1]
     with jax.named_scope("tos.moe_route"):
-        head = order[:rows]
-        sorted_in = gm.rows_to_slots(flat, head, place, group_sizes, k, mesh)  # [rows, d]
-        sorted_weights = weights.reshape(-1)[head]
+        sorted_in = gm.rows_to_slots(flat, order, group_sizes, rows, k, mesh)  # [rows, d]
+        sorted_weights = weights.reshape(-1)[order[:rows]]
     with jax.named_scope("tos.moe_experts"):
         hidden = nn.silu(gm.grouped_matmul(sorted_in, gate.astype(dt), group_sizes)) * gm.grouped_matmul(
             sorted_in, up.astype(dt), group_sizes)
@@ -1003,7 +1023,7 @@ def _experts_on_rows(rows, flat, weights, gate, up, down, order, place, group_si
     with jax.named_scope("tos.moe_route"):
         # weighted where it lies, then each token's slots among the rows summed
         weighted = (sorted_out.astype(jnp.float32) * sorted_weights[:, None]).astype(dt)
-        return gm.slots_to_tokens(weighted, head, place, group_sizes, k, mesh)
+        return gm.slots_to_tokens(weighted, order, group_sizes, k, mesh)
 
 
 @functools.lru_cache(maxsize=None)

@@ -16,7 +16,10 @@ and on all ``S`` slots in any step whose held slots do not fit in ``C``
 a share of the tokens at a time, so it too holds ``C`` rows at most, and
 gives what one pass over ``S`` rows gives): no capacity, nothing dropped,
 whatever the routing. What stays ``S`` long: the int32 /
-float32 vectors (the sort, ``place``, the weights). The way back to token
+float32 vectors (the sort's key ``local``, its ``order``, the weights), all
+made by element-wise work, one sort and masked sums: the inverse of the order
+(:func:`slot_places`, a scatter) is computed only where the buffer is the
+whole ``S`` slots. The way back to token
 order (:func:`slots_to_tokens` forward, :func:`rows_to_slots` backward)
 reads the compact buffer's rows and nothing else: inside an expert's group
 the rows stand in token order, so a tile of tokens finds its slots of one
@@ -51,16 +54,18 @@ def sort_slots(expert_of_slot, first, held):
 
     ``expert_of_slot`` (``int32 [S]``) names each slot's expert among all
     the router's; this chip holds experts ``first … first + held - 1``.
-    Returns ``(order, group_sizes)``: ``order`` (``int32 [S]``) lists the
-    slots grouped by held expert, in expert order, the slots of experts not
-    held at the end; ``group_sizes`` (``int32 [held]``) counts each held
-    expert's slots."""
+    Returns ``(order, group_sizes, local)``: ``order`` (``int32 [S]``) lists
+    the slots grouped by held expert, in expert order, the slots of experts
+    not held at the end; ``group_sizes`` (``int32 [held]``) counts each held
+    expert's slots; ``local`` (``int32 [S]``, slot order) is the key it sorted
+    by, each slot's expert among the held ones, ``held`` for an expert held
+    elsewhere: all the fallback needs to sort a share of the slots again."""
     local = expert_of_slot - first
     local = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(local, stable=True).astype(jnp.int32)
     group_sizes = jnp.sum(
         local[:, None] == jnp.arange(held, dtype=local.dtype)[None, :], axis=0, dtype=jnp.int32)
-    return order, group_sizes
+    return order, group_sizes, local
 
 
 #: a compact slot buffer's length is a multiple of this many rows
@@ -79,7 +84,9 @@ def compact_rows(slots, held, experts):
 
 def slot_places(order):
     """The inverse of :func:`sort_slots`' ``order``: where each slot sits in
-    the sorted buffer."""
+    the sorted buffer. A scatter, which a TPU walks index by index (0.61 ms
+    a call at ``sdar-30b-a3b.bd4-packed4k``'s 131,072 slots: PERF.md §6, PR
+    43), so only :func:`_sum_over_slots` on a whole buffer computes it."""
     return jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype), unique_indices=True, mode="promise_in_bounds")
 
@@ -88,34 +95,38 @@ def _rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
-def _sum_over_slots(buffer, head, place, group_sizes, k, mesh):
+def _sum_over_slots(buffer, order, group_sizes, k, mesh):
     """``[T, d]`` in ``buffer``'s type: each token's ``k`` slots found in
-    ``buffer`` (``[R, d]``: the head of the sorted order) and summed in
-    float32; a slot that is not in the buffer adds nothing. From the whole
-    buffer (``R = T * k``: every slot is there) that is one gather back to slot
-    order by ``place`` and a sum over ``[T, k, d]``, in slot order. From a
-    shorter one it is :func:`moe_combine.combine
+    ``buffer`` (``[R, d]``: the first ``R`` of the sorted ``order``, ``int32
+    [T * k]``) and summed in float32; a slot that is not in the buffer adds
+    nothing. From the whole buffer (``R = T * k``: every slot is there) that
+    is one gather back to slot order by :func:`slot_places` and a sum over
+    ``[T, k, d]``, in slot order. From a shorter one it is
+    :func:`moe_combine.combine
     <tensorflowonspark_tpu.ops.moe_combine.combine>`, a kernel (interpreted
     anywhere but on a TPU) that reads the buffer's rows a window at a time by
-    ``head`` and ``group_sizes`` and sums a token's held slots in held-expert
+    ``order[:R]`` and ``group_sizes`` and sums a token's held slots in held-expert
     order: no zero row behind the buffer, nothing ``T * k`` rows long, no
     gather (``k`` gathers of ``[T, d]`` before PR 42, seven in eight of their
     rows the zero row where a chip holds an eighth of the experts; read on the
     chip against them, against one gather of ``[T * k, d]`` and against a
-    scatter-add of the ``R`` rows: PERF.md §6, PR 35 and PR 42).
+    scatter-add of the ``R`` rows: PERF.md §6, PR 35 and PR 42), and no
+    inverse of the order.
 
     A Mosaic call has no partitioning rule, and JAX refuses to lower one on
     more than one chip outside a ``shard_map``: on a ``mesh`` of several
     devices the kernel runs under one. The sum is independent column by
-    column and by nothing else (``head`` names tokens of the whole batch), so
+    column and by nothing else (the order names tokens of the whole batch), so
     the columns are split over ``tp`` where every shard keeps whole lanes and
     every other operand is whole on every chip."""
-    if buffer.shape[0] == place.shape[0]:
-        per_slot = _rows(buffer, place)
+    rows, slots = buffer.shape[0], order.shape[0]
+    if rows == slots:
+        per_slot = _rows(buffer, slot_places(order))
         return jnp.sum(per_slot.reshape(-1, k, per_slot.shape[-1]), axis=1, dtype=jnp.float32).astype(buffer.dtype)
-    run = functools.partial(moe_combine.combine, tokens=place.shape[0] // k, interpret=jax.default_backend() != "tpu")
+    token_of_row = order[:rows] // k
+    run = functools.partial(moe_combine.combine, tokens=slots // k, interpret=jax.default_backend() != "tpu")
     if mesh is None or mesh.size == 1:
-        return run(buffer, head // k, group_sizes)
+        return run(buffer, token_of_row, group_sizes)
     from jax.sharding import PartitionSpec as P
 
     from tensorflowonspark_tpu.parallel.collectives import shard_map
@@ -124,62 +135,67 @@ def _sum_over_slots(buffer, head, place, group_sizes, k, mesh):
     columns = P(None, "tp" if shards > 1 and buffer.shape[1] % (128 * shards) == 0 else None)
     # check_vma off: pallas_call outputs carry no varying-axes type
     return shard_map(run, mesh=mesh, in_specs=(columns, P(), P()), out_specs=columns, check_vma=False)(
-        buffer, head // k, group_sizes)
+        buffer, token_of_row, group_sizes)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def rows_to_slots(rows, head, place, group_sizes, k, mesh=None):
-    """``[T, d]`` token rows to the head of the sorted slot buffer, ``[R,
-    d]``: sorted slot ``i`` is slot ``head[i]`` (``head = order[:R]``), which
-    belongs to token ``head[i] // k``. The gradient is the sum over each
-    token's slots that lie in the buffer (:func:`_sum_over_slots`, by the
-    inverse permutation ``place``, ``int32 [T * k]``, or by ``head`` and the
-    ``group_sizes``; ``mesh``: the devices the step runs on, if more than
-    one) where the gather's own transpose would be a scatter-add over
-    repeated rows."""
-    return _rows(rows, head // k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def rows_to_slots(rows, order, group_sizes, length, k, mesh=None):
+    """``[T, d]`` token rows to the head of the sorted slot buffer,
+    ``[length, d]``: sorted slot ``i`` is slot ``order[i]``, which belongs to
+    token ``order[i] // k``. The gradient is the sum over each token's slots
+    that lie in the buffer (:func:`_sum_over_slots`, by ``order`` and the
+    ``group_sizes``; ``mesh``: the devices the step runs on, if more than one)
+    where the gather's own transpose would be a scatter-add over repeated
+    rows."""
+    return _rows(rows, order[:length] // k)
 
 
-def _rows_to_slots_fwd(rows, head, place, group_sizes, k, mesh):
-    return _rows(rows, head // k), (head, place, group_sizes)
+def _rows_to_slots_fwd(rows, order, group_sizes, length, k, mesh):
+    return rows_to_slots(rows, order, group_sizes, length, k, mesh), (order, group_sizes)
 
 
-def _rows_to_slots_bwd(k, mesh, res, d_sorted):
-    return _sum_over_slots(d_sorted, *res, k, mesh), None, None, None
+def _rows_to_slots_bwd(length, k, mesh, res, d_sorted):
+    return _sum_over_slots(d_sorted, *res, k, mesh), None, None
 
 
 rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def slots_to_tokens(sorted_rows, head, place, group_sizes, k, mesh=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def slots_to_tokens(sorted_rows, order, group_sizes, k, mesh=None):
     """The head of the sorted buffer (``[R, d]``) summed into its tokens,
     ``[T, d]`` in the buffer's type (:func:`_sum_over_slots`). The gradient is
-    ``R`` rows gathered from ``[T, d]`` by ``head // k`` (no ``[T, k, d]``
-    broadcast)."""
-    return _sum_over_slots(sorted_rows, head, place, group_sizes, k, mesh)
+    ``R`` rows gathered from ``[T, d]`` by ``order[:R] // k`` (no ``[T, k,
+    d]`` broadcast)."""
+    return _sum_over_slots(sorted_rows, order, group_sizes, k, mesh)
 
 
-def _slots_to_tokens_fwd(sorted_rows, head, place, group_sizes, k, mesh):
-    return slots_to_tokens(sorted_rows, head, place, group_sizes, k, mesh), (head,)
+def _slots_to_tokens_fwd(sorted_rows, order, group_sizes, k, mesh):
+    return slots_to_tokens(sorted_rows, order, group_sizes, k, mesh), (order[:sorted_rows.shape[0]],)
 
 
 def _slots_to_tokens_bwd(k, mesh, res, d_tokens):
-    return _rows(d_tokens, res[0] // k), None, None, None
+    return _rows(d_tokens, res[0] // k), None, None
 
 
 slots_to_tokens.defvjp(_slots_to_tokens_fwd, _slots_to_tokens_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def either(fn, compact, fits, per_token, shared, order, place, group_sizes):
-    """``fn(compact, *per_token, *shared, order, place, group_sizes)``, the
-    layer on the first ``compact`` rows of the sorted order, where ``fits`` (a
-    traced bool: every held slot is among them), and else the layer on all
-    the slots (:func:`_on_every_slot`): every held slot computed whatever the
-    routing. One ``jax.lax.cond``, differentiable in ``per_token`` (the
-    arguments with a row a token, ``[T, ...]``) and ``shared`` (the others:
-    the experts' matrices).
+def either(fn, compact, fits, per_token, shared, order, local, group_sizes):
+    """``fn(compact, *per_token, *shared, order, group_sizes)``, the layer on
+    the first ``compact`` rows of the sorted order, where ``fits`` (a traced
+    bool: every held slot is among them), and else the layer on all the slots
+    (:func:`_on_every_slot`): every held slot computed whatever the routing.
+    One ``jax.lax.cond``, differentiable in ``per_token`` (the arguments with
+    a row a token, ``[T, ...]``) and ``shared`` (the others: the experts'
+    matrices).
+
+    Each branch is handed what it reads of :func:`sort_slots`' three: the
+    compact one ``order`` and ``group_sizes``, the fallback ``local`` (it
+    sorts each share's slots for itself). The inverse of ``order``
+    (:func:`slot_places`, a scatter) is not among them: nothing on a compact
+    buffer reads it.
 
     JAX's own rule for a differentiated ``cond`` has every branch write, as
     zeros, whatever the other branch keeps for its backward pass, and hand
@@ -191,19 +207,19 @@ def either(fn, compact, fits, per_token, shared, order, place, group_sizes):
     reads the result; its operations carry the backward pass's ``op_name``
     (a ``jax.checkpoint`` round the branch would name them apart, and costs
     the step's tracing 1 to 1.5 s)."""
-    return _cond(fn, compact, fits, per_token, shared, order, place, group_sizes)
+    return _cond(fn, compact, fits, per_token, shared, order, local, group_sizes)
 
 
-def _cond(fn, compact, fits, per_token, shared, order, place, group_sizes, *d_out):
+def _cond(fn, compact, fits, per_token, shared, order, local, group_sizes, *d_out):
     """The ``cond``; with ``d_out``, the result's cotangent, the backward
     pass. Each branch sits under a scope of its name: the device trace says
     which ran, and ``jax.vjp`` wraps the outermost scope it meets into
     ``jvp(...)``, which must not be one that a reader looks for."""
     def compact_rows(per_token, shared):
-        return fn(compact, *per_token, *shared, order, place, group_sizes)
+        return fn(compact, *per_token, *shared, order, group_sizes)
 
     def every_slot(per_token, shared):
-        return _on_every_slot(fn, compact, per_token, shared, order, place, group_sizes)
+        return _on_every_slot(fn, compact, per_token, shared, local, group_sizes.shape[0])
 
     def branch(run):
         scoped = jax.named_scope(run.__name__)(run)
@@ -214,8 +230,8 @@ def _cond(fn, compact, fits, per_token, shared, order, place, group_sizes, *d_ou
     return jax.lax.cond(fits, branch(compact_rows), branch(every_slot))
 
 
-def _either_fwd(fn, compact, fits, per_token, shared, order, place, group_sizes):
-    kept = (fits, per_token, shared, order, place, group_sizes)
+def _either_fwd(fn, compact, fits, per_token, shared, order, local, group_sizes):
+    kept = (fits, per_token, shared, order, local, group_sizes)
     return _cond(fn, compact, *kept), kept
 
 
@@ -226,34 +242,30 @@ def _either_bwd(fn, compact, kept, d_out):
 either.defvjp(_either_fwd, _either_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
-def _on_every_slot(fn, compact, per_token, shared, order, place, group_sizes):
+@functools.partial(jax.jit, static_argnums=(0, 1, 5), inline=True)
+def _on_every_slot(fn, compact, per_token, shared, local, held):
     """The fallback: ``fn`` on all ``S`` slots, there to be right, not fast,
     and never to set the step's peak memory (a ``cond``'s branches share no
     temporaries: the peak is the larger branch's). The tokens are independent
     of each other, so it is ``fn`` on a share of the tokens at a time, as many
     shares as make a share's ``T / n * k`` slots fit in ``compact`` rows: each
-    share's slots are sorted for themselves (their held experts read back
-    from ``place`` and the group sizes) and every share is the whole layer on
-    its tokens, so the result and the per-token gradients are those of one
+    share's slots are sorted for themselves by ``local``, their expert among
+    the ``held`` ones as :func:`sort_slots` had it before it sorted (handed
+    over, not found back from the sorted order), and every share is the whole
+    layer on its tokens, so the result and the per-token gradients are those of one
     pass over ``S`` rows, the same sums in the same order, and the experts'
     gradients are float32 sums over the shares (each share's rounded to the
     products' type first: on the chip a bfloat16 step from one pass's, PERF.md
     §6, PR 35). Nothing is kept a share: the backward pass runs each again."""
-    tokens, slots = per_token[0].shape[0], order.shape[0]
+    tokens, slots = per_token[0].shape[0], local.shape[0]
     shares = next(n for n in range(-(-slots // compact), tokens + 1) if tokens % n == 0)
-    held = group_sizes.shape[0]
-    with jax.named_scope(SORT_SCOPE):
-        # a slot's expert among the held ones (``held``: an expert held elsewhere): the groups its place lies behind
-        local = jnp.searchsorted(jnp.cumsum(group_sizes), place, side="right").astype(jnp.int32)
 
     @jax.checkpoint
     def one_share(shared, rows):
         *per_token, local = rows
         with jax.named_scope(SORT_SCOPE):
-            order, sizes = sort_slots(local, 0, held)
-            place = slot_places(order)
-        return fn(local.shape[0], *per_token, *shared, order, place, sizes)
+            order, sizes, _ = sort_slots(local, 0, held)
+        return fn(local.shape[0], *per_token, *shared, order, sizes)
 
     split = lambda a: a.reshape((shares, -1) + a.shape[1:])  # noqa: E731
     _, out = jax.lax.scan(lambda _, rows: (None, one_share(shared, rows)), None, (*map(split, per_token), split(local)))

@@ -253,8 +253,9 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
 def test_grouped_matmul_fences_the_rows_past_its_groups():
     lhs = jax.random.normal(jax.random.PRNGKey(13), (16, 8), jnp.float32)
     rhs = jax.random.normal(jax.random.PRNGKey(14), (3, 8, 4), jnp.float32)
-    order, sizes = gm.sort_slots(jnp.array([5, 2, 9, 3, 3, 4, 0, 2, 7, 4, 4, 1, 2, 6, 3, 8], jnp.int32), 2, 3)
+    order, sizes, local = gm.sort_slots(jnp.array([5, 2, 9, 3, 3, 4, 0, 2, 7, 4, 4, 1, 2, 6, 3, 8], jnp.int32), 2, 3)
     assert sizes.tolist() == [3, 3, 3] and sorted(order.tolist()) == list(range(16))
+    assert local.tolist() == [3, 0, 3, 1, 1, 2, 3, 0, 3, 2, 2, 3, 0, 3, 1, 3]  # ``held`` for an expert held elsewhere
     assert order.tolist()[:9] == [1, 7, 12, 3, 4, 14, 5, 9, 10]
     out = gm.grouped_matmul(lhs, rhs, sizes)
     close(out[:3], lhs[:3] @ rhs[0])
@@ -264,7 +265,7 @@ def test_grouped_matmul_fences_the_rows_past_its_groups():
     assert float(jnp.abs(d_lhs[9:]).max()) == 0.0 and float(jnp.abs(d_lhs[:9]).min()) > 0.0
     place = gm.slot_places(order)
     rows = jax.random.normal(jax.random.PRNGKey(15), (8, 8), jnp.float32)
-    d_rows = jax.grad(lambda r: jnp.sum(gm.rows_to_slots(r, order, place, sizes, 2) * lhs))(rows)
+    d_rows = jax.grad(lambda r: jnp.sum(gm.rows_to_slots(r, order, sizes, 16, 2) * lhs))(rows)
     close(d_rows, jax.grad(lambda r: jnp.sum(r[order // 2] * lhs))(rows))
-    d_sorted = jax.grad(lambda s: jnp.sum(gm.slots_to_tokens(s, order, place, sizes, 2) * rows))(lhs)
+    d_sorted = jax.grad(lambda s: jnp.sum(gm.slots_to_tokens(s, order, sizes, 2) * rows))(lhs)
     close(d_sorted, jax.grad(lambda s: jnp.sum(s[place].reshape(8, 2, 8).sum(1) * rows))(lhs))

@@ -438,8 +438,47 @@ ROUTED_CELLS = {
 }
 
 
+@pytest.fixture(scope="module")
+def routed_layer(one_chip, no_compile_cache):
+    """``cell -> (cfg, tokens, computations)``: one routed layer of a routed
+    cell at the cell's shape, forward and backward under ``jax.checkpoint``,
+    compiled once a cell for all the tests that read its text."""
+    import functools
+    import importlib
+    import json
+
+    from tensorflowonspark_tpu.models import decoder
+
+    @functools.lru_cache(maxsize=None)
+    def compiled(cell):
+        config, family, rows, seq, segmented = ROUTED_CELLS[cell]
+        with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", config + ".json")) as f:
+            cfg = decoder.DecoderConfig.from_dict(
+                importlib.import_module("benchmarks.families." + family).model_config(json.load(f), remat=True))
+        d = cfg.hidden_size
+        layer = decoder.RoutedExperts(cfg)
+        on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)  # noqa: E731
+        x = jax.ShapeDtypeStruct((rows, seq, d), jnp.bfloat16, sharding=one_chip)
+        ids = (jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one_chip),) if segmented else ()
+        params = jax.tree.map(on_chip, jax.eval_shape(
+            lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 128, d), jnp.bfloat16))["params"]))
+
+        def loss(p, x, *ids):
+            return jnp.sum(layer.apply({"params": p}, x, *ids)[0].astype(jnp.float32) ** 2)
+
+        def step(p, x, *ids):
+            with jax.named_scope("tos.loss_and_grad"):
+                return jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1))(p, x, *ids)
+
+        with pytest.MonkeyPatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")  # the kernel, not its interpreter
+            return cfg, rows * seq, _computations(jax.jit(step).lower(params, x, *ids).compile().as_text())
+
+    return compiled
+
+
 @pytest.mark.parametrize("cell", list(ROUTED_CELLS))
-def test_routed_layer_compiles_a_compact_branch_at_the_cells_shape(one_chip, no_compile_cache, monkeypatch, cell):
+def test_routed_layer_compiles_a_compact_branch_at_the_cells_shape(routed_layer, cell):
     """One routed layer of each routed cell at the cell's shape
     (``sdar-30b-a3b``: 2 x 8192 positions, top-8 of 128, 16 held: 131,072
     slots, a compact buffer of 32,768; ``laguna-s-2-1``: 8192, top-10 of 256,
@@ -460,37 +499,14 @@ def test_routed_layer_compiles_a_compact_branch_at_the_cells_shape(one_chip, no_
     and the ``conditional`` instructions carry none of the mechanisms'
     scopes, so that a reader which sums events by scope counts a branch's
     operations once."""
-    import importlib
-    import json
-
     from benchmarks.layer_metrics import _moe
-    from tensorflowonspark_tpu.models import decoder
     from tensorflowonspark_tpu.ops import grouped_matmul as gm
 
-    config, family, rows, seq, segmented = ROUTED_CELLS[cell]
-    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", config + ".json")) as f:
-        cfg = decoder.DecoderConfig.from_dict(
-            importlib.import_module("benchmarks.families." + family).model_config(json.load(f), remat=True))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel, not its interpreter
-    d, width, tokens = cfg.hidden_size, cfg.moe_intermediate_size, rows * seq
+    cfg, tokens, computations = routed_layer(cell)
+    d, width = cfg.hidden_size, cfg.moe_intermediate_size
     slots = tokens * cfg.num_experts_per_tok
     buffer = gm.compact_rows(slots, cfg.held[1], cfg.n_routed_experts)
     assert buffer < slots
-    layer = decoder.RoutedExperts(cfg)
-    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)  # noqa: E731
-    x = jax.ShapeDtypeStruct((rows, seq, d), jnp.bfloat16, sharding=one_chip)
-    ids = (jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one_chip),) if segmented else ()
-    params = jax.tree.map(on_chip, jax.eval_shape(
-        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 128, d), jnp.bfloat16))["params"]))
-
-    def loss(p, x, *ids):
-        return jnp.sum(layer.apply({"params": p}, x, *ids)[0].astype(jnp.float32) ** 2)
-
-    def step(p, x, *ids):
-        with jax.named_scope("tos.loss_and_grad"):
-            return jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1))(p, x, *ids)
-
-    computations = _computations(jax.jit(step).lower(params, x, *ids).compile().as_text())
     conditionals = [line for lines in computations.values() for line in lines if " conditional(" in line]
     assert len(conditionals) == 3
     long_and_wide = re.compile(r"= \(?(?:bf16|f32|pred)\[{},({}|{})\]".format(slots, d, width))
@@ -532,6 +548,44 @@ def test_routed_layer_compiles_a_compact_branch_at_the_cells_shape(one_chip, no_
     assert sorted(phases, key=sorted) == [{"bwd"}, {"fwd"}, {"recompute"}]
     # forward; recomputed; the dispatch's gradient (the sum that the backward branch's forward pass ends in feeds nothing)
     assert combines == [1, 1, 1]
+
+
+@pytest.mark.parametrize("cell", list(ROUTED_CELLS))
+def test_routed_layers_bookkeeping_compiles_to_no_gather_and_no_scatter(routed_layer, cell):
+    """The same compiled layer, everything but the fallback's computations
+    (the router, the sort and the three compact branches): the chosen scores
+    are a select and a reduction over ``[T, k, E]`` that stays inside one
+    fusion forward, recomputed and backward (no array of that shape is written:
+    67 MB a layer in ``sdar-30b-a3b``, 336 MB in ``laguna-s-2-1``), so no gather
+    reads ``[T, E]`` and no scatter-add writes it; the inverse of the sort's
+    order is not computed (no int32 scatter). What is left walks an index at a
+    time for a reason: the dispatch's row gather ``[C, d]`` from ``[T, d]``
+    and its gradient's, the weights' gather ``float32 [C]`` from ``[T * k]``
+    and that gather's transpose, one scatter-add into ``float32 [T * k]``."""
+    from tensorflowonspark_tpu.ops import grouped_matmul as gm
+
+    cfg, tokens, computations = routed_layer(cell)
+    k, experts, d = cfg.num_experts_per_tok, cfg.n_routed_experts, cfg.hidden_size
+    slots = tokens * k
+    buffer = gm.compact_rows(slots, cfg.held[1], cfg.n_routed_experts)
+    fallback = set()
+    for line in (line for lines in computations.values() for line in lines if " conditional(" in line):
+        every_slot = re.search(r"branch_computations=\{%?([\w.\-]+),", line).group(1)  # cond(fits, compact, fallback): false is first
+        _reached(computations, every_slot, fallback, fused=True)
+    assert any(" scatter(" in line and "s32[" in line for name in fallback for line in computations[name])  # a share's own place
+    outside = {name: lines for name, lines in computations.items() if name not in fallback}
+    walked = set()
+    for line in (line for lines in outside.values() for line in lines):
+        found = re.search(r"= \(?(\w+\[[\d,]*\])\S* (gather|scatter)\(", line)
+        if found:
+            assert "tos.moe_route" in line
+            walked.add((found.group(2), found.group(1)))
+    assert walked == {("gather", "bf16[{},{}]".format(buffer, d)), ("gather", "f32[{}]".format(buffer)),
+                      ("scatter", "f32[{}]".format(slots))}
+    per_expert = re.compile(r"= \(?\w+\[{},{},{}\]".format(tokens, k, experts))
+    assert any(per_expert.search(line) for lines in outside.values() for line in lines)  # the mask is there, fused
+    assert not [line for name, lines in outside.items() if not name.startswith("fused_computation")
+                for line in lines if per_expert.search(line)]
 
 
 def test_routed_layer_compiles_for_four_chips(topo, no_compile_cache, monkeypatch):
@@ -576,8 +630,8 @@ def test_a_model_without_routed_layers_traces_none_of_the_routing(monkeypatch, c
     """The loss and gradients of the two language-model cells that have no
     routed layer, traced at their cells' shapes with the routed layer and
     everything of ``ops/grouped_matmul.py`` and ``ops/moe_combine.py``
-    replaced by a function that raises: they reach none of it, so what PR 42
-    changed there leaves their jaxprs what they were."""
+    replaced by a function that raises: they reach none of it, so what PRs 42
+    and 43 changed there leaves their jaxprs what they were."""
     from benchmarks import run
     from benchmarks.families import ssm_lm
     from tensorflowonspark_tpu.models import decoder, get_model, transformer
@@ -592,6 +646,7 @@ def test_a_model_without_routed_layers_traces_none_of_the_routing(monkeypatch, c
                 monkeypatch.setattr(module, name, reached)
     monkeypatch.setattr(decoder.RoutedExperts, "__call__", reached)
     monkeypatch.setattr(decoder, "_experts_on_rows", reached)
+    monkeypatch.setattr(decoder, "_scores_at", reached)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the models refuse attention="flash" off the chip
     _, _, config, traffic = run.resolve(cell, False)
     if config["family"] == "lm":  # as ``benchmarks/families/lm.build`` makes it

@@ -119,6 +119,97 @@ def test_compact_rows_equal_the_whole_buffer_and_the_reference(monkeypatch, scor
     tree_close(grads, want, 5e-4)
 
 
+def _parents_layer(cfg, p, x, segment_ids):
+    """``RoutedExperts`` as it stood before PR 43, on all ``S`` rows: the
+    chosen scores by ``take_along_axis``, the way back to slot order by
+    ``slot_places``' scatter, every gather XLA's own with its own transpose
+    (the dispatch's in float32, as the layer's gradient sums a token's slots)."""
+    dt, k, (first, held) = x.dtype, cfg.num_experts_per_tok, cfg.held
+    flat = x.reshape(-1, x.shape[-1])
+    logits = jnp.dot(flat.astype(jnp.float32), p["router"], precision=jax.lax.Precision.HIGHEST)
+    if cfg.scoring_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(scores, k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(p["router_bias"]), k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    if not cfg.padding_slots:
+        chosen = jnp.where(segment_ids.reshape(-1, 1) > 0, chosen, cfg.n_routed_experts)
+    order, sizes, _ = gm.sort_slots(chosen.reshape(-1), first, held)
+    place = gm.slot_places(order)
+    sorted_in = flat.astype(jnp.float32)[order // k].astype(dt)
+    hidden = jax.nn.silu(gm.grouped_matmul(sorted_in, p["experts_gate"].astype(dt), sizes)) * gm.grouped_matmul(
+        sorted_in, p["experts_up"].astype(dt), sizes)
+    sorted_out = gm.grouped_matmul(hidden, p["experts_down"].astype(dt), sizes)
+    weighted = (sorted_out.astype(jnp.float32) * weights.reshape(-1)[order][:, None]).astype(dt)
+    routed = jnp.sum(weighted[place].reshape(-1, k, flat.shape[-1]), axis=1, dtype=jnp.float32).astype(dt)
+    shared = decoder.SwiGLU(cfg, cfg.shared_width).apply({"params": p["shared"]}, flat) if cfg.shared_width else 0
+    return (routed + shared).reshape(x.shape)
+
+
+@pytest.mark.parametrize("padding_slots", [True, False], ids=["padding_routed", "padding_routed_nowhere"])
+@pytest.mark.parametrize("crowding", list(CROWDING))
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_layer_is_what_it_was_with_a_gather_and_a_scatter(scoring, crowding, padding_slots):
+    """On either branch, with padding positions in the batch routed like any
+    other or nowhere. The chosen scores are the parent's bit for bit, and in
+    float32 the layer's result, loss and every gradient are the parent's to
+    float32 rounding: XLA adds the chosen scores up for the weights' divisor
+    in another order once they are a reduction and not a gather (a float32
+    unit), and the kernel sums a token's slots in held-expert order. In
+    bfloat16 on float32 parameters, as the configurations run, such a unit
+    shows in the result where it crosses a bfloat16 rounding, seldom, and
+    then as one step."""
+    import dataclasses
+
+    segment_ids = jnp.ones((ROWS, SEQ), jnp.int32).at[:, SEQ - 40:].set(0)
+
+    def both(dtype):
+        cfg, p, x, _ = _layer(scoring, crowding, dtype)
+        cfg = dataclasses.replace(cfg, padding_slots=padding_slots)
+        got = _value_and_grads(lambda p, x: decoder.RoutedExperts(cfg).apply({"params": p}, x, segment_ids), p, x)
+        return got, _value_and_grads(lambda p, x: (_parents_layer(cfg, p, x, segment_ids), {}), p, x)
+
+    (y, counts, value, grads), (want_y, _, want_value, want) = both("float32")
+    assert float(counts["layers_at_bound"]) == float(counts["slots_held"] > counts["slots_routed"] / 2)  # C = S / 2
+    assert float(counts["layers_at_bound"]) == float(crowding != "fits") or not padding_slots
+    close(y, want_y, 2e-6)
+    close(value, want_value, 2e-6)
+    tree_close(grads, want, 1e-5)  # sums of hundreds of float32 terms, against the largest entry
+    (y, _, _, _), (want_y, _, _, _) = both("bfloat16")
+    y, want_y = np.asarray(y, np.float32), np.asarray(want_y, np.float32)
+    assert np.mean(y != want_y) < 1e-3
+    np.testing.assert_allclose(y, want_y, rtol=2.0 ** -7, atol=1e-6)
+
+
+def test_the_chosen_scores_are_a_masked_sum_and_not_a_gather():
+    """``decoder._scores_at`` against ``take_along_axis``: the values bit for
+    bit, the scores' gradient too (one term a ``(t, e)``), and neither a
+    gather nor a scatter in the jaxpr of value and gradient, where
+    ``take_along_axis`` has both and ``top_k``'s own values a scatter-add."""
+    tokens, experts, k = 512, 16, 4
+    scores = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(1), (tokens, experts)) * 3.0, axis=-1)
+    _, chosen = jax.lax.top_k(scores, k)
+    weigh = jax.random.normal(jax.random.PRNGKey(2), (tokens, k))
+
+    def value_and_grad(read):
+        return jax.value_and_grad(lambda s: jnp.sum(read(s) * weigh))
+
+    got, d_got = jax.jit(value_and_grad(lambda s: decoder._scores_at(s, chosen)))(scores)
+    want, d_want = jax.jit(value_and_grad(lambda s: jnp.take_along_axis(s, chosen, axis=-1)))(scores)
+    assert float(got) == float(want)
+    np.testing.assert_array_equal(np.asarray(d_got), np.asarray(d_want))
+    np.testing.assert_array_equal(
+        np.asarray(decoder._scores_at(scores, chosen)), np.asarray(jnp.take_along_axis(scores, chosen, axis=-1)))
+    walks = {"gather", "scatter", "scatter-add"}
+    assert not _primitives(jax.make_jaxpr(value_and_grad(lambda s: decoder._scores_at(s, chosen)))(scores).jaxpr) & walks
+    assert {"gather", "scatter-add"} <= _primitives(
+        jax.make_jaxpr(value_and_grad(lambda s: jnp.take_along_axis(s, chosen, axis=-1)))(scores).jaxpr)
+    assert "scatter-add" in _primitives(jax.make_jaxpr(value_and_grad(lambda s: jax.lax.top_k(s, k)[0]))(scores).jaxpr)
+
+
 def _k_gathers(buffer, place, k):
     """What ``gm._sum_over_slots`` was on a buffer shorter than the slots
     before PR 42, and is held to now: ``float32 [T, d]``, each token's ``k``
@@ -172,7 +263,7 @@ def test_the_kernel_sums_what_the_k_gathers_summed(kind, dtype):
     which in bfloat16 is at most one step of the result."""
     tokens, k = ROUTINGS[kind]
     chosen = _routing(kind)
-    order, sizes = gm.sort_slots(chosen.reshape(-1), FIRST, HELD)
+    order, sizes, _ = gm.sort_slots(chosen.reshape(-1), FIRST, HELD)
     place, used = gm.slot_places(order), int(jnp.sum(sizes))
     compact = gm.compact_rows(tokens * k, HELD, EXPERTS)
     assert compact < tokens * k and 0 < used <= compact and compact % moe_combine.WINDOW == 0
@@ -204,7 +295,7 @@ def test_the_kernel_sums_what_the_k_gathers_summed(kind, dtype):
     if kind == "tokens_no_multiple_of_the_tile":
         assert tokens % tile and tile_of_step[steps[0] - 1] == tokens // tile
 
-    got, pull = jax.vjp(lambda b: gm.slots_to_tokens(b, head, place, sizes, k), buffer)
+    got, pull = jax.vjp(lambda b: gm.slots_to_tokens(b, order, sizes, k), buffer)
     want, plain_pull = jax.vjp(lambda b: _k_gathers(b, place, k), buffer.astype(jnp.float32))
     held_to(got, want)
     held_to(pull(d_rows)[0], plain_pull(d_rows.astype(jnp.float32))[0])
@@ -213,7 +304,7 @@ def test_the_kernel_sums_what_the_k_gathers_summed(kind, dtype):
     if kind == "padding_routed_nowhere":
         assert float(jnp.abs(got[tokens - 300:]).max()) == 0.0
 
-    got, pull = jax.vjp(lambda r: gm.rows_to_slots(r, head, place, sizes, k), rows)
+    got, pull = jax.vjp(lambda r: gm.rows_to_slots(r, order, sizes, compact, k), rows)
     want, plain_pull = jax.vjp(lambda r: r[head // k], rows.astype(jnp.float32))
     np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want))
     held_to(pull(d_buffer)[0], plain_pull(d_buffer.astype(jnp.float32))[0])
@@ -254,14 +345,18 @@ def test_the_rule_for_the_buffers_length():
     assert list(inspect.signature(gm.grouped_matmul).parameters) == ["lhs", "rhs", "group_sizes"]
 
 
-def _eqns(jaxpr):
+def _eqns(jaxpr, fallbacks=True):
     """Every equation of ``jaxpr`` and of the jaxprs its equations hold, a
-    kernel's body apart (its ``pl.when`` and loops are not the layer's)."""
+    kernel's body apart (its ``pl.when`` and loops are not the layer's);
+    without ``fallbacks``, of a ``cond`` the compact branch alone (``cond(fits,
+    compact, fallback)``: index 1 is the true branch)."""
     for eqn in jaxpr.eqns:
         yield eqn
-        if eqn.primitive.name != "pallas_call":
+        if eqn.primitive.name == "cond" and not fallbacks:
+            yield from _eqns(eqn.params["branches"][1].jaxpr, fallbacks)
+        elif eqn.primitive.name != "pallas_call":
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from _eqns(sub)
+                yield from _eqns(sub, fallbacks)
 
 
 def _primitives(jaxpr):
@@ -328,6 +423,17 @@ def test_the_compact_branch_holds_no_array_of_the_bounds_length(recomputed, dtyp
             called = {eqn.primitive.name for call in _eqns(inside.jaxpr) if call.primitive.name in ("jit", "pjit")
                       for sub in jax.core.jaxprs_in_params(call.params) for eqn in _eqns(sub)}
             assert called and not any(name.startswith("ragged_dot") for name in called)
+    # the router's bookkeeping, anywhere but in the fallback: the chosen scores are read by no gather of [T, E] (and
+    # handed their gradient by no scatter-add), the order's inverse is not computed (no int32 scatter); the one
+    # scatter-add left is the transpose of the weights' gather, float32 [T * k]
+    outside = list(_eqns(jaxpr, fallbacks=False))
+    assert len([eqn for eqn in outside if eqn.primitive.name == "cond"]) == 2
+    scatters = [eqn.outvars[0].aval for eqn in outside if eqn.primitive.name.startswith("scatter")]
+    assert scatters and all((aval.shape, aval.dtype) == ((slots,), jnp.float32) for aval in scatters)
+    assert not [eqn for eqn in outside if eqn.primitive.name == "gather"
+                and eqn.invars[0].aval.shape == (tokens, cfg.n_routed_experts)]
+    assert any(eqn.primitive.name.startswith("scatter") and eqn.outvars[0].aval.dtype == jnp.int32
+               for eqn in _eqns(jaxpr))  # the fallback's shares each compute their own
 
 
 def test_the_model_carries_the_two_counts_out_of_the_step():
@@ -380,14 +486,14 @@ def test_on_a_mesh_the_kernel_takes_the_columns_a_tp_shard_at_a_time():
     from jax.sharding import Mesh
 
     tokens, k = ROUTINGS["even"]
-    order, sizes = gm.sort_slots(_routing("even").reshape(-1), FIRST, HELD)
-    place, compact = gm.slot_places(order), gm.compact_rows(tokens * k, HELD, EXPERTS)
+    order, sizes, _ = gm.sort_slots(_routing("even").reshape(-1), FIRST, HELD)
+    compact = gm.compact_rows(tokens * k, HELD, EXPERTS)
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
     for wide, split in ((256, True), (32, False)):
         buffer = jnp.where((jnp.arange(compact) < jnp.sum(sizes))[:, None],
                            jax.random.normal(jax.random.PRNGKey(6), (compact, wide)), 0).astype(jnp.bfloat16)
-        want = gm.slots_to_tokens(buffer, order[:compact], place, sizes, k)
-        on_mesh = jax.jit(lambda b: gm.slots_to_tokens(b, order[:compact], place, sizes, k, mesh))
+        want = gm.slots_to_tokens(buffer, order, sizes, k)
+        on_mesh = jax.jit(lambda b: gm.slots_to_tokens(b, order, sizes, k, mesh))
         np.testing.assert_array_equal(np.asarray(on_mesh(buffer), np.float32), np.asarray(want, np.float32))
         kernels = [eqn for eqn in _eqns(jax.make_jaxpr(on_mesh)(buffer).jaxpr) if eqn.primitive.name == "pallas_call"]
         assert [eqn.outvars[0].aval.shape for eqn in kernels] == [(tokens, wide // 2 if split else wide)]
