@@ -104,11 +104,9 @@ def main_fun(args, ctx):
     steps_per_loop = max(int(getattr(args, "steps_per_loop", 1) or 1), 1)
     if steps_per_loop > 1:
         # K steps fused into one lax.scan dispatch; transfers overlap compute.
-        # donate=True is state-only in both modes, safe for the synthetic
-        # path's re-fed device batch too.
-        loop = strategy.compile_train_loop(
-            loss_fn, optimizer, steps_per_loop, mutable=True, donate=True,
-        )
+        # The state alone is donated, which is safe for the synthetic path's
+        # re-fed device batch too.
+        loop = strategy.compile_train_loop(loss_fn, optimizer, steps_per_loop, mutable=True)
     step = strategy.compile_train_step(loss_fn, optimizer, mutable=True)
 
     if use_real:

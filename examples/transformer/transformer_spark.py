@@ -8,7 +8,6 @@ TPU-native capabilities the framework adds on top of reference parity:
 * sequence parallelism (`--mesh sp=2 ...` → ring attention over the ``sp``
   axis) for long context;
 * tensor parallelism (``--mesh tp=...``, `_TP_RULES` param placement);
-* mixture of experts (``--moe_experts N`` over an ``ep`` axis);
 * rematerialization (``--remat``) trading FLOPs for HBM;
 * any registered LM from a configuration file: ``--model <name>
   --model_config <file.json>`` builds the model through
@@ -134,7 +133,7 @@ def main_fun(args, ctx, observer=None):
             vocab_size=args.vocab_size, d_model=args.d_model,
             n_layers=args.n_layers, n_heads=args.n_heads, d_ff=args.d_ff,
             max_seq_len=args.seq_len, dtype=args.dtype, remat=args.remat,
-            moe_experts=args.moe_experts, attention=args.attention,
+            attention=args.attention,
         )
     param_specs = decoder.make_param_specs(model) if isinstance(model, decoder.Decoder) else transformer.param_specs
     strategy = SyncDataParallel(mesh, param_spec_fn=param_specs if "tp" in mesh.axis_names else None)
@@ -155,7 +154,7 @@ def main_fun(args, ctx, observer=None):
     steps_per_loop = max(args.steps_per_loop, 1)
     if steps_per_loop > 1:
         run = strategy.compile_train_loop(
-            loss_fn, optimizer, steps_per_loop, has_aux=True, donate="state"
+            loss_fn, optimizer, steps_per_loop, has_aux=True
         )
     else:
         run = strategy.compile_train_step(loss_fn, optimizer, has_aux=True)
@@ -266,7 +265,6 @@ def build_parser():
     parser.add_argument("--model_config", default=None,
                         help="JSON file of the model's configuration keys; replaces the size flags")
     parser.add_argument("--model_dir", default=None)
-    parser.add_argument("--moe_experts", type=int, default=0)
     parser.add_argument("--n_heads", type=int, default=8)
     parser.add_argument("--n_layers", type=int, default=2)
     parser.add_argument("--pack_workers", type=int, default=0,

@@ -26,9 +26,6 @@
 #   --perf-smoke  run only the perf_smoke marker leg: structural pipelining
 #                 assertions (sleep-staged IO/parse overlap — proves the
 #                 read-ahead actually overlaps, no absolute-throughput flake)
-#                 plus the adaptive-feed leg (sleep-staged data.device_link
-#                 latency: the autotuner must ratchet K up under injected
-#                 latency and bring it back down when the latency clears)
 #                 plus the async-checkpoint overlap leg (a ckpt.write_slow
 #                 stall holds the background writer while the training loop
 #                 keeps stepping — tests/test_ckpt_chaos.py::TestOverlap)
@@ -126,9 +123,9 @@ if [[ "$MULTICHIP" == "1" ]]; then
 fi
 
 if [[ "$PERF_SMOKE" == "1" ]]; then
-  # covers the IO/parse overlap proof, the autotune adaptation leg
-  # (tests/test_autotune.py::TestChaosDeviceLink) — both sleep-staged, no
-  # real accelerator or absolute-throughput assertion involved — and the
+  # covers the IO/parse overlap proof and the async-checkpoint overlap leg
+  # (tests/test_ckpt_chaos.py::TestOverlap) — both sleep-staged, no real
+  # accelerator or absolute-throughput assertion involved — and the
   # decode-plane GIL-release leg (tests/test_decode_plane.py::TestGilRelease:
   # the parse runs in the workers' own pids and fills the thread pool's
   # stream byte for byte; no clock)

@@ -65,11 +65,6 @@ site                            effect at the injection point
                                 per chunk, charged into shard-read time so
                                 ``classify_stalls`` sees io_bound and the
                                 ``ReadaheadAutotuner`` must deepen
-``data.device_link``            autotuned feed sleeps ``delay_s`` inside the
-                                timed region of every host->device transfer
-                                (probes and windows), so injected latency
-                                flows into the link estimate and the window
-                                size K must adapt
 ``data.tokenize_error``         text producer swaps one record for invalid
                                 UTF-8 bytes; the tokenizer rejects it and the
                                 skip is charged against ``max_bad_records``

@@ -8,8 +8,7 @@ pays the D2H copy; the orbax write happens behind it.
 Donation-safe by construction: the snapshot is a **new host buffer** — it
 never aliases device memory, so the device state handed back to the step
 loop can be donated into the next step while the writer is still
-serializing the copy (the same discipline the packed feed established for
-window buffers, ``data/autotune.py``). ``jax.device_get`` on a CPU backend
+serializing the copy. ``jax.device_get`` on a CPU backend
 can return a zero-copy *view* of the device buffer, which would break that
 guarantee — the copy below therefore always lands in memory this module
 owns.

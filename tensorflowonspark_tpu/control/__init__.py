@@ -1,23 +1,20 @@
 """One audited control core for every estimate→decide→patience→apply loop.
 
-Three subsystems grew the same controller shape independently:
-:class:`~tensorflowonspark_tpu.data.autotune.FeedAutotuner` (packed-window
-size K), :class:`~tensorflowonspark_tpu.data.autotune.ReadaheadAutotuner`
-(shard read-ahead depth) and
+Two per-process subsystems share the controller shape:
+:class:`~tensorflowonspark_tpu.data.autotune.ReadaheadAutotuner` (shard
+read-ahead depth) and
 :class:`~tensorflowonspark_tpu.data.decode_plane.DecodeAutotuner` (decode
 worker count). Each one estimates a signal, argues for a direction, applies
 **up-fast / down-slow hysteresis** (a stall is expensive *now*; releasing
 capacity can wait for proof), and moves its knob one rung at a time inside
-bounds. Hand-rolling that loop three times meant three slightly different
-streak bugs waiting to happen and zero shared observability.
+bounds. Hand-rolling that loop in each meant slightly different streak bugs
+waiting to happen and zero shared observability.
 
-This package extracts the loop once:
+This package holds the loop once:
 
-* :class:`~tensorflowonspark_tpu.control.core.EwmaEstimator` — the
-  seed-on-first-observation EWMA every estimator here builds on.
 * :class:`~tensorflowonspark_tpu.control.core.Controller` — the audited
-  move engine: an ordered ladder of values (explicit levels or an integer
-  range), ``up_patience``/``down_patience`` streaks, bound clamping, and a
+  move engine: an integer range of values,
+  ``up_patience``/``down_patience`` streaks, bound clamping, and a
   ``control_decisions_total`` counter plus a ``control_decision`` span on
   every applied move — so *why the knob moved* is visible in
   ``TFCluster.metrics()`` and on the merged timeline.
@@ -34,16 +31,14 @@ This package extracts the loop once:
   from capacity health plus the same stall classification, gating regrow
   restarts behind ``grow_patience`` and publishing ``target_world_size``.
 
-All three per-process autotuners are rebased on this core with their
-behavior pinned by their pre-existing test suites (tests/test_autotune.py,
-tests/test_decode_plane.py) — the extraction is a refactor, not a policy
-change.
+Both per-process autotuners are built on this core with their behavior
+pinned by their own test suites (tests/test_autotune.py,
+tests/test_decode_plane.py).
 """
 
 from tensorflowonspark_tpu.control.core import (  # noqa: F401
     Controller,
     DeltaTicker,
-    EwmaEstimator,
     StallRule,
     classify_stalls,
 )

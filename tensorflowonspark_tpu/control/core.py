@@ -4,7 +4,7 @@ Every controller in the tree follows the same discipline (see the package
 docstring): estimate from measurements, argue for a direction, move **up
 immediately** (by default) because a stall is costing throughput right now,
 move **down only after ``down_patience`` consecutive lower verdicts**
-because flapping a knob (recompiles, fork storms, cluster restarts) costs
+because flapping a knob (fork storms, cluster restarts) costs
 more than holding it one interval too long. :class:`Controller` is that
 discipline, once, with decisions counted and traced.
 """
@@ -25,35 +25,6 @@ def classify_stalls(read_s, parse_s, emit_s, wait_s):
     if emit_s >= wait_s:
         return "device_bound"
     return "decode_bound" if parse_s >= read_s else "io_bound"
-
-
-class EwmaEstimator:
-    """Seed-on-first-observation exponential moving average.
-
-    ``alpha`` weights the newest observation (0.3 default: responsive
-    within a handful of samples, yet one freak sample cannot swing a
-    decision by itself). ``value`` is None until the first observation —
-    the one-shot seeding contract every estimator in the family relies on
-    (:class:`~tensorflowonspark_tpu.data.autotune.LinkEstimator` seeds its
-    fixed-cost and bandwidth terms exactly this way).
-    """
-
-    def __init__(self, alpha=0.3):
-        if not 0.0 < alpha <= 1.0:
-            raise ValueError("alpha must be in (0, 1]")
-        self.alpha = alpha
-        self.value = None
-
-    def observe(self, sample):
-        """Blend one sample in (first sample seeds directly); returns the
-        updated value."""
-        self.value = self.blend(self.value, sample)
-        return self.value
-
-    def blend(self, old, new):
-        """The pure EWMA step on explicit operands — for estimators that
-        keep several blended terms under one alpha."""
-        return new if old is None else (1.0 - self.alpha) * old + self.alpha * new
 
 
 class StallRule:
@@ -83,11 +54,9 @@ class StallRule:
 class Controller:
     """The audited hysteresis move engine over an ordered value ladder.
 
-    The ladder is either an explicit ``levels`` tuple (the feed tuner's
-    power-of-two buckets) or the integer range ``[lo, hi]`` (worker
-    counts, depths, world sizes). :meth:`step` takes the current value and
-    a wanted direction (+1/0/−1) and returns the value the discipline
-    allows:
+    The ladder is the integer range ``[lo, hi]`` (worker counts, depths,
+    world sizes). :meth:`step` takes the current value and a wanted
+    direction (+1/0/−1) and returns the value the discipline allows:
 
     * **up**: after ``up_patience`` consecutive +1 verdicts (default 1 —
       immediate, the up-fast half), one rung up, clamped at the top.
@@ -105,19 +74,10 @@ class Controller:
     is process-global like every obs metric.
     """
 
-    def __init__(self, levels=None, lo=None, hi=None, up_patience=1,
-                 down_patience=2, name="controller"):
-        if levels is not None:
-            self.levels = tuple(sorted(set(levels)))
-            if not self.levels:
-                raise ValueError("levels must be non-empty")
-        else:
-            if lo is None or hi is None:
-                raise ValueError("give either levels or lo/hi bounds")
-            if int(hi) < int(lo):
-                raise ValueError("hi must be >= lo")
-            self.levels = None
-            self.lo, self.hi = int(lo), int(hi)
+    def __init__(self, lo, hi, up_patience=1, down_patience=2, name="controller"):
+        if int(hi) < int(lo):
+            raise ValueError("hi must be >= lo")
+        self.lo, self.hi = int(lo), int(hi)
         self.up_patience = max(1, int(up_patience))
         self.down_patience = max(1, int(down_patience))
         self.name = str(name)
@@ -127,20 +87,6 @@ class Controller:
             "control_decisions_total",
             help="knob moves applied by control.Controller instances",
         )
-
-    # -- ladder navigation ------------------------------------------------------
-
-    def floor(self):
-        return self.levels[0] if self.levels is not None else self.lo
-
-    def ceiling(self):
-        return self.levels[-1] if self.levels is not None else self.hi
-
-    def _rung(self, value, direction):
-        if self.levels is not None:
-            i = self.levels.index(value) + direction
-            return self.levels[max(0, min(len(self.levels) - 1, i))]
-        return max(self.lo, min(self.hi, int(value) + direction))
 
     # -- the discipline ---------------------------------------------------------
 
@@ -155,7 +101,7 @@ class Controller:
         discipline holds)."""
         if want > 0:
             self._down_streak = 0
-            if current >= self.ceiling():
+            if current >= self.hi:
                 self._up_streak = 0
                 return current
             self._up_streak += 1
@@ -165,7 +111,7 @@ class Controller:
             return self._move(current, +1)
         if want < 0:
             self._up_streak = 0
-            if current <= self.floor():
+            if current <= self.lo:
                 self._down_streak = 0
                 return current
             self._down_streak += 1
@@ -176,15 +122,8 @@ class Controller:
         self.reset()
         return current
 
-    def toward(self, current, recommended):
-        """Direction-from-target convenience: one :meth:`step` toward
-        ``recommended`` (the feed tuner's decide shape — the model argues
-        for a value, the discipline walks there one rung at a time)."""
-        want = (recommended > current) - (recommended < current)
-        return self.step(current, want)
-
     def _move(self, current, direction):
-        new = self._rung(current, direction)
+        new = max(self.lo, min(self.hi, int(current) + direction))
         if new != current:
             self._decisions.inc()
             with obs.span(

@@ -13,25 +13,13 @@ decode_plane` worker processes writing into shared-memory slabs — straight
 into preallocated batch buffers, and fixed-shape batches double-buffered
 onto the device mesh — static shapes and steady feed keep XLA and the MXU
 busy.
-The device placement itself is adaptive: :mod:`~tensorflowonspark_tpu.data.
-autotune` measures the host→device link online (fixed cost + bandwidth) and
-sizes the packed transfer window K to amortize the link's per-transfer
-fixed cost, instead of trusting an offline constant.
 """
 
 from tensorflowonspark_tpu.data.loader import (  # noqa: F401
     ImagePipeline,
     device_prefetch,
     loop_prefetch,
-    packed_place,
-    packed_prefetch,
     shard_files,
-)
-from tensorflowonspark_tpu.data.autotune import (  # noqa: F401
-    AutotunedWindow,
-    FeedAutotuner,
-    LinkEstimator,
-    autotuned_prefetch,
 )
 from tensorflowonspark_tpu.data.decode_plane import (  # noqa: F401
     DecodeAutotuner,

@@ -29,8 +29,8 @@ The pieces:
   written with identical bytes, and acks are deduped by slot.
 * :class:`DecodeAutotuner` — self-sizes the worker count from the same
   stall counters operators read (``data_producer_parse_seconds_total`` vs
-  ``data_consumer_wait_seconds_total``), with the
-  :class:`~tensorflowonspark_tpu.data.autotune.FeedAutotuner` hysteresis
+  ``data_consumer_wait_seconds_total``), with
+  :class:`~tensorflowonspark_tpu.control.Controller`'s hysteresis
   discipline: grow immediately when the consumer starves on a
   parse-dominated producer, shrink only after ``down_patience``
   consecutive idle intervals.
@@ -464,7 +464,7 @@ class DecodePlane:
 class DecodeAutotuner:
     """Self-sizing controller for the decode worker count.
 
-    Mirrors :class:`~tensorflowonspark_tpu.data.autotune.FeedAutotuner`'s
+    Mirrors :class:`~tensorflowonspark_tpu.data.autotune.ReadaheadAutotuner`'s
     discipline on a different pair of measurements: the deltas of
     ``data_producer_parse_seconds_total`` (is the parse stage busy?) and
     ``data_consumer_wait_seconds_total`` (is the training loop starving?)
@@ -482,8 +482,8 @@ class DecodeAutotuner:
 
     Bounds: ``[min_workers, max_workers]`` (default 1 .. ``os.cpu_count()``).
     The counter reads are injectable (``read_counters``), so the decision
-    core is a pure function of its inputs in tests, like the feed
-    autotuner's injectable clock.
+    core is a pure function of its inputs in tests, like the read-ahead
+    autotuner's.
     """
 
     def __init__(

@@ -1140,47 +1140,3 @@ def loop_prefetch(batches, strategy, num_steps, depth=None):
     while len(buf) >= num_steps:
         yield [buf.popleft() for _ in range(num_steps)]
 
-
-def packed_place(window, strategy):
-    """Stack a list of host batches into ONE ``[K, B, ...]`` pytree and ship
-    it as a single sharded host→device transfer — the placement used by
-    :func:`packed_prefetch`."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from tensorflowonspark_tpu.parallel.sharding import data_axes
-
-    axes = data_axes(strategy.mesh)
-    spec = P(None, (axes if len(axes) > 1 else axes[0]) if axes else None)
-    sharding = NamedSharding(strategy.mesh, spec)
-    stacked = jax.tree.map(lambda *xs: np.stack(xs), *window)
-    if jax.process_count() == 1:
-        return jax.tree.map(lambda x: jax.device_put(x, sharding), stacked)
-    return jax.tree.map(
-        lambda x: jax.make_array_from_process_local_data(sharding, x), stacked
-    )
-
-
-def packed_prefetch(batches, strategy, num_steps, depth=1):
-    """Group host batches into device-resident ``[num_steps, B, ...]`` stacks,
-    each shipped as ONE host→device transfer, double-buffered ``depth``
-    windows ahead — for :meth:`compile_train_loop(packed=True)
-    <tensorflowonspark_tpu.train.SyncDataParallel.compile_train_loop>`.
-
-    Use this instead of :func:`loop_prefetch` when the device link has a
-    large per-transfer fixed cost (a host that is not co-located with its
-    device: ~250 ms per transfer was measured on one). One big transfer per window
-    amortizes that cost ``num_steps``×; the host-side ``np.stack`` is a
-    memcpy, cheap next to the wire. Short final windows are dropped.
-    """
-    buf = collections.deque()
-    it = iter(batches)
-    try:
-        while True:
-            while len(buf) < depth + 1:
-                buf.append(packed_place([next(it) for _ in range(num_steps)], strategy))
-            yield buf.popleft()
-    except StopIteration:
-        pass
-    while buf:
-        yield buf.popleft()

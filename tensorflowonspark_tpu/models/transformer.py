@@ -67,17 +67,6 @@ class TransformerConfig:
     #: kernel on TPU, else plain XLA attention; or force "flash" (TPU only),
     #: "flash_interpret" (the kernel in the Pallas interpreter), "plain", "ring"
     attention: str = "auto"
-    #: >0 switches every block's MLP to a switch-routed mixture of experts
-    #: sharded over the mesh's ``ep`` axis (expert parallelism)
-    moe_experts: int = 0
-    #: per-expert capacity per token group = factor * group_size / experts
-    moe_capacity_factor: float = 1.25
-    #: weight of the router load-balancing auxiliary loss
-    moe_aux_weight: float = 0.01
-    #: dispatch group size (GShard-style): dispatch/combine memory scales as
-    #: factor * tokens * group_size — fixed G keeps it LINEAR in sequence
-    #: length; rounded down to a divisor of the token count at trace time
-    moe_group_size: int = 256
 
     @property
     def head_dim(self):
@@ -295,75 +284,6 @@ class Mlp(nn.Module):
         return nn.Dense(self.cfg.d_model, use_bias=False, dtype=dt, name="wo")(h)
 
 
-class MoeMlp(nn.Module):
-    """Switch-routed (top-1) mixture-of-experts MLP with dense dispatch.
-
-    Expert parallelism the TPU way (absent from the reference — SURVEY.md
-    §2.7 row "Expert parallelism"): expert weights carry an ``ep``-sharded
-    leading dim and dispatch/combine are einsums against a static-shaped
-    [tokens, E, C] mask, so XLA derives the all-to-all over the ``ep`` axis
-    from the shardings — no hand-written collective, no dynamic shapes
-    (GShard/Switch dense-dispatch formulation, done with einsum + psum-free
-    code under pjit).
-    """
-
-    cfg: TransformerConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        dt = cfg.compute_dtype
-        E = cfg.moe_experts
-        B, S, D = x.shape
-        tokens = B * S
-        # GShard-style fixed-size token groups: capacity is per group, so the
-        # [G_n, G, E, C] dispatch mask is linear (not quadratic) in tokens;
-        # shrink G to a divisor of the static token count at trace time
-        group = min(cfg.moe_group_size or tokens, tokens)
-        while tokens % group:
-            group -= 1
-        n_groups = tokens // group
-        capacity = max(1, int(cfg.moe_capacity_factor * group / E))
-
-        xg = x.reshape(n_groups, group, D)
-        router = nn.Dense(E, use_bias=False, dtype=jnp.float32, name="router")
-        gates = jax.nn.softmax(router(xg.astype(jnp.float32)))  # [G_n, G, E]
-
-        expert_idx = jnp.argmax(gates, axis=-1)  # [G_n, G]
-        onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)  # [G_n, G, E]
-        gate = jnp.sum(gates * onehot, axis=-1)  # [G_n, G]
-
-        # position of each token within its expert's per-group capacity
-        # buffer; tokens beyond capacity drop (switch overflow semantics)
-        position = jnp.cumsum(onehot, axis=1) * onehot - 1.0  # [G_n, G, E]
-        keep = (position < capacity) & (onehot > 0)
-        pos_cap = jnp.clip(position, 0, capacity - 1).astype(jnp.int32)
-        dispatch = (
-            keep[..., None]
-            & (jax.nn.one_hot(pos_cap, capacity, dtype=jnp.bool_))
-        )  # [G_n, G, E, C]
-        combine = dispatch.astype(jnp.float32) * gate[..., None, None]
-
-        # load-balancing aux loss (Switch eq. 4): E * sum_e f_e * p_e
-        frac_tokens = jnp.mean(onehot, axis=(0, 1))
-        frac_probs = jnp.mean(gates, axis=(0, 1))
-        self.sow("losses", "moe_aux", E * jnp.sum(frac_tokens * frac_probs))
-
-        wi = self.param(
-            "wi", nn.initializers.lecun_normal(), (E, D, cfg.d_ff), jnp.float32
-        )
-        wo = self.param(
-            "wo", nn.initializers.lecun_normal(), (E, cfg.d_ff, D), jnp.float32
-        )
-        expert_in = jnp.einsum(
-            "gtec,gtd->gecd", dispatch.astype(dt), xg.astype(dt)
-        )  # [G_n, E, C, D] — E is ep-sharded: XLA inserts the all-to-all here
-        h = nn.gelu(jnp.einsum("gecd,edf->gecf", expert_in, wi.astype(dt)))
-        out_e = jnp.einsum("gecf,efd->gecd", h, wo.astype(dt))  # [G_n, E, C, D]
-        yg = jnp.einsum("gtec,gecd->gtd", combine.astype(dt), out_e)
-        return yg.reshape(B, S, D)
-
-
 class Block(nn.Module):
     cfg: TransformerConfig
     mesh: object = None
@@ -374,12 +294,9 @@ class Block(nn.Module):
             nn.RMSNorm(dtype=self.cfg.compute_dtype, name="ln1")(x), positions,
             segment_ids,
         )
-        mlp = (
-            MoeMlp(self.cfg, name="moe")
-            if self.cfg.moe_experts > 0
-            else Mlp(self.cfg, name="mlp")
+        x = x + Mlp(self.cfg, name="mlp")(
+            nn.RMSNorm(dtype=self.cfg.compute_dtype, name="ln2")(x)
         )
-        x = x + mlp(nn.RMSNorm(dtype=self.cfg.compute_dtype, name="ln2")(x))
         return x
 
 
@@ -437,9 +354,6 @@ _TP_RULES = (
     (r"attn/o/kernel$", ("tp", None, "fsdp")),  # [H, head_dim, d_model]
     (r"mlp/wi/kernel$", ("fsdp", "tp")),  # [d_model, d_ff]
     (r"mlp/wo/kernel$", ("tp", "fsdp")),  # [d_ff, d_model]
-    (r"moe/router/kernel$", (None, None)),  # [d_model, E] — replicated
-    (r"moe/wi$", ("ep", "fsdp", "tp")),  # [E, d_model, d_ff]
-    (r"moe/wo$", ("ep", "tp", "fsdp")),  # [E, d_ff, d_model]
     # vocab-parallel (Megatron-style): sharding d_model here instead forces
     # XLA to fully rematerialize the gather output to reach the activations'
     # P(batch, seq, None) layout (the round-1 dryrun's SPMD warning); with
@@ -501,9 +415,7 @@ def create_model(mesh=None, **cfg):
 
 def make_init_fn(model, sample_len=16):
     def init(rng):
-        variables = model.init(rng, jnp.zeros((1, sample_len), jnp.int32))
-        # sown collections (MoE aux losses) are per-step ephemera, not state
-        return {k: v for k, v in variables.items() if k not in ("losses", "intermediates")}
+        return model.init(rng, jnp.zeros((1, sample_len), jnp.int32))
 
     return init
 
@@ -542,7 +454,7 @@ def make_block_diffusion_loss_fn(model):
             {"params": params}, jnp.concatenate([tokens, batch["noised_tokens"]], axis=1),
             positions=twice(pos), segment_ids=twice(seg),
             labels=jnp.concatenate([2 * block, 2 * block + 1], axis=1), head_from=length,
-            mutable=["losses", "counters", "gauges"],
+            mutable=["counters", "gauges"],
         )
         losses = optax.softmax_cross_entropy_with_integer_labels(logits, tokens)
         weights = batch["loss_weights"].astype(losses.dtype)
@@ -557,8 +469,7 @@ def make_loss_fn(model):
     ``objective: block_diffusion`` (:func:`make_block_diffusion_loss_fn`).
 
     Next-token LM loss; batch = {"tokens": int32 [B, L]} (optionally with
-    {"mask": [B, L]} to exclude padding). MoE models contribute their sown
-    router load-balancing losses, weighted by ``cfg.moe_aux_weight``.
+    {"mask": [B, L]} to exclude padding).
 
     Packed batches from the text plane additionally carry ``segment_ids``
     and ``positions`` (``int32 [B, L]``): segments fence attention
@@ -578,7 +489,7 @@ def make_loss_fn(model):
             {"params": params}, tokens[:, :-1],
             positions=None if pos is None else pos[:, :-1],
             segment_ids=None if seg is None else seg[:, :-1],
-            mutable=["losses", "counters", "gauges"],
+            mutable=["counters", "gauges"],
         )
         targets = tokens[:, 1:]
         losses = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
@@ -596,12 +507,6 @@ def make_loss_fn(model):
             loss = (losses * mask).sum() / jnp.maximum(mask.sum(), 1)
         else:
             loss = losses.mean()
-        metrics = {"perplexity": jnp.exp(loss)}
-        aux = jax.tree.leaves(mods.get("losses", {}))
-        if aux:
-            moe_aux = sum(jnp.asarray(a).mean() for a in aux) / len(aux)
-            metrics["moe_aux"] = moe_aux
-            loss = loss + model.cfg.moe_aux_weight * moe_aux
-        return loss, _carried(metrics, mods)
+        return loss, _carried({"perplexity": jnp.exp(loss)}, mods)
 
     return loss_fn

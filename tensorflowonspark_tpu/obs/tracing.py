@@ -42,7 +42,6 @@ tracing analogue of chaos-obs-coverage):
 ``inference_wave``         one executor inference wave
 ``chaos_fault``            marker span for an injected chaos fault
 ``step_fetch``             training loop pulling the next host batch
-``h2d_transfer``           host→device transfer of a feed window
 ``step_compute``           one optimizer step (jit dispatch + wait)
 ``ckpt_snapshot``          checkpoint snapshot handoff to the async engine
 ``serving_route``          serving-mesh router handling one client request

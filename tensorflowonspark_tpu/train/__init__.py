@@ -11,7 +11,6 @@ over ICI, and checkpoint/resume is orbax.
 # checkpoint function is actually touched.
 _EXPORTS = {
     "SyncDataParallel": "strategy",
-    "PackedLoopCache": "strategy",
     "TrainState": "strategy",
     "steps_per_worker": "strategy",
     "run_steps": "strategy",

@@ -13,7 +13,6 @@ hand and no PS; sync DP over ICI serves both of the reference's modes
 """
 
 import collections
-import logging
 import statistics
 import time
 
@@ -29,8 +28,6 @@ from tensorflowonspark_tpu.parallel import (
     replicated,
     shard_batch,
 )
-
-logger = logging.getLogger(__name__)
 
 
 class TrainState:
@@ -464,7 +461,7 @@ class SyncDataParallel:
 
         return jax.jit(tos_train_step, donate_argnums=(0,) if donate else ())
 
-    def compile_train_loop(self, loss_fn, optimizer, num_steps, has_aux=False, mutable=False, donate=True, packed=False):
+    def compile_train_loop(self, loss_fn, optimizer, num_steps, has_aux=False, mutable=False, donate=True):
         """Compile ``loop(state, batches) -> (state, last_metrics)`` running
         ``num_steps`` train steps INSIDE one XLA program via ``lax.scan``.
 
@@ -484,47 +481,23 @@ class SyncDataParallel:
         training (no reference analogue: TF sessions had the same per-step
         host loop this removes).
 
-        With ``donate=True`` (default) only the state is donated —
-        ``donate=True`` and ``donate="state"`` are the same contract in
-        both modes. Batch stacks must not be offered for donation: the
-        input stack aliases no output (a uint8/f32 image stack cannot
-        alias the param leaves), so donating it only produced XLA's
-        "Some donated buffers were not usable: uint8[...]" warning and a
-        silent copy. The prefetch generators also keep window buffers referenced
-        for double-buffering, which donation would invalidate. Pass
-        ``donate="batches"`` to force donating the batch list anyway
-        (callers that truly consume their device batches and want the
-        HBM back a window early).
-
-        ``packed=True`` flips the input contract: ``loop(state, stacked)``
-        takes ONE device-resident pytree whose leaves carry a leading
-        ``num_steps`` axis (place with
-        :func:`tensorflowonspark_tpu.data.packed_prefetch`). For hosts behind
-        a high-latency device link, shipping the whole window as one transfer
-        amortizes the per-transfer fixed cost K× (~250 ms/transfer was
-        measured on a host that was not co-located with its device, which
-        dwarfs per-batch pipelining).
+        With ``donate=True`` (default) the state is donated and the batches
+        are not: a batch aliases no output (a uint8 image or an int32 token
+        leaf cannot alias the param leaves), so offering it only produced
+        XLA's "Some donated buffers were not usable" warning and a silent
+        copy, and :func:`~tensorflowonspark_tpu.data.loop_prefetch` keeps
+        the placed batches referenced while the loop runs.
         """
         step = self._jit_train_step(loss_fn, optimizer, has_aux, mutable, donate=False)
 
         def loop(state, batches):
-            if packed:
-                lead = {leaf.shape[0] for leaf in jax.tree.leaves(batches)}
-                if lead != {num_steps}:
-                    raise ValueError(
-                        "packed window has leading dims {}, loop compiled for {}".format(
-                            sorted(lead), num_steps
-                        )
-                    )
-                stacked = batches
-            elif len(batches) != num_steps:
+            if len(batches) != num_steps:
                 raise ValueError(
                     "got {} batches, loop compiled for {}".format(
                         len(batches), num_steps
                     )
                 )
-            else:
-                stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
+            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
 
             def body(carry, batch):
                 new_state, metrics = step(carry, batch)
@@ -534,12 +507,7 @@ class SyncDataParallel:
             # metrics of the LAST step (scan stacks them; take index -1)
             return state, jax.tree.map(lambda m: m[-1], metrics)
 
-        if donate is True:
-            donate = "state"
-        donate_argnums = {
-            "batches": (0, 1), "state": (0,), False: (),
-        }[donate]
-        return jax.jit(loop, donate_argnums=donate_argnums)
+        return jax.jit(loop, donate_argnums=(0,) if donate else ())
 
     def compile_eval_step(self, metric_fn):
         """Compile ``metric_fn(params, batch) -> metrics`` for sharded eval."""
@@ -549,65 +517,6 @@ class SyncDataParallel:
         """Compile ``apply_fn(params, batch) -> predictions``; outputs gather
         to fully-addressable arrays for host-side result queues."""
         return jax.jit(apply_fn, out_shardings=replicated(self.mesh))
-
-
-class PackedLoopCache:
-    """Per-K cache of packed train loops for the adaptive feed.
-
-    The :class:`~tensorflowonspark_tpu.data.autotune.FeedAutotuner` varies
-    the packed-window size K at runtime, but
-    :meth:`SyncDataParallel.compile_train_loop` compiles for a static
-    ``num_steps`` — so each bucket gets its own compiled program, built on
-    first use and reused forever after. With the bounded bucket set
-    (powers of two) that is at most one XLA compile per bucket for the
-    whole run; every compile increments the ``feed_recompiles_total``
-    counter so the trade shows up in ``TFCluster.metrics()``.
-
-    Loops are compiled with the packed donation contract (``donate="state"``
-    — the window buffers stay owned by the prefetch double buffer; see
-    :meth:`SyncDataParallel.compile_train_loop`)::
-
-        cache = PackedLoopCache(strategy, loss_fn, optimizer, mutable=True)
-        for window in autotuned_prefetch(pipe, strategy, tuner=tuner):
-            state, metrics = cache.run(state, window)
-    """
-
-    def __init__(self, strategy, loss_fn, optimizer, has_aux=False, mutable=False):
-        self.strategy = strategy
-        self.loss_fn = loss_fn
-        self.optimizer = optimizer
-        self.has_aux = has_aux
-        self.mutable = mutable
-        self._loops = {}
-
-    def loop_for(self, num_steps):
-        """The compiled packed loop for window size ``num_steps``."""
-        compiled = self._loops.get(num_steps)
-        if compiled is None:
-            from tensorflowonspark_tpu import obs
-
-            obs.counter(
-                "feed_recompiles_total",
-                help="packed train-loop compilations (bounded by the bucket set)",
-            ).inc()
-            logger.info("compiling packed train loop for window K=%d", num_steps)
-            compiled = self.strategy.compile_train_loop(
-                self.loss_fn, self.optimizer, num_steps,
-                has_aux=self.has_aux, mutable=self.mutable,
-                donate="state", packed=True,
-            )
-            self._loops[num_steps] = compiled
-        return compiled
-
-    def run(self, state, window):
-        """Run one :class:`~tensorflowonspark_tpu.data.autotune.AutotunedWindow`
-        (or any object with ``.data``/``.k``) through its bucket's loop."""
-        return self.loop_for(window.k)(state, window.data)
-
-    @property
-    def compiled_sizes(self):
-        """The buckets compiled so far (sorted)."""
-        return sorted(self._loops)
 
 
 def run_steps(step_fn, state, batches, engine=None, save_every_n=None, hooks=()):

@@ -87,44 +87,36 @@ class TestDeterminism:
     def test_seed_changes_the_stream(self, shards):
         assert _stream(shards, seed=1) != _stream(shards, seed=2)
 
-    def test_autotuned_delivery_invariant_to_buckets_and_threads(self, shards):
-        """The adaptive feed composes with the pipeline without touching the
-        record stream: whatever window sizes the controller picks and however
-        many parse threads feed it, the delivered batches are identical."""
+    def test_loop_prefetch_delivery_invariant_to_window_and_threads(self, shards):
+        """The windowed device feed composes with the pipeline without
+        touching the record stream: whatever the window size and however many
+        parse threads feed it, the delivered batches are the host stream's,
+        in order, up to the last whole window."""
         import jax
 
         from tensorflowonspark_tpu import parallel
-        from tensorflowonspark_tpu.data import FeedAutotuner, autotuned_prefetch
+        from tensorflowonspark_tpu.data import loop_prefetch
         from tensorflowonspark_tpu.train import SyncDataParallel
 
         strategy = SyncDataParallel(parallel.build_mesh({"dp": 8}))
 
-        def delivered(num_threads, buckets):
+        def delivered(num_threads, num_steps):
             pipe = ImagePipeline(
                 shards, _parse, batch_size=8, seed=3, epochs=1,
                 num_threads=num_threads,
             )
-            tuner = FeedAutotuner(buckets=buckets)
             out = []
-            for w in autotuned_prefetch(iter(pipe), strategy, tuner=tuner):
-                assert w.k in tuner.buckets
-                data = jax.device_get(w.data)
-                for i in range(w.k):
-                    out.append(
-                        (
-                            np.asarray(data["image"])[i].tobytes(),
-                            np.asarray(data["label"])[i].tolist(),
-                        )
-                    )
+            for window in loop_prefetch(iter(pipe), strategy, num_steps=num_steps):
+                assert len(window) == num_steps
+                for batch in jax.device_get(window):
+                    out.append((np.asarray(batch["image"]).tobytes(), np.asarray(batch["label"]).tobytes()))
             return out
 
-        base = delivered(1, (1,))
-        assert len(base) == 411 // 8
-        # the K=1 reference matches the raw host stream record for record
         host = _stream(shards, epochs=1, num_threads=1)
-        assert [img for img, _ in base] == [img for img, _ in host]
-        for threads, buckets in [(1, (1, 2, 4)), (8, (1,)), (8, (1, 2, 4)), (8, (1, 4, 16))]:
-            assert delivered(threads, buckets) == base, (threads, buckets)
+        assert len(host) == 411 // 8
+        for threads, num_steps in [(1, 1), (8, 1), (1, 2), (8, 2), (8, 4)]:
+            whole = len(host) - len(host) % num_steps
+            assert delivered(threads, num_steps) == host[:whole], (threads, num_steps)
 
     def test_invalid_cache_mode_rejected(self, shards):
         with pytest.raises(ValueError):

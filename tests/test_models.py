@@ -142,6 +142,12 @@ class TestTransformer:
         assert np.isfinite(float(loss))
         assert float(aux["perplexity"]) > 1
 
+    def test_a_key_the_model_does_not_have_fails_by_name(self):
+        """A caller or a configuration file that still asks for the
+        switch-routed layer is refused, not ignored."""
+        with pytest.raises(TypeError, match="moe_experts"):
+            transformer.create_model(vocab_size=100, d_model=32, n_layers=2, n_heads=4, d_ff=64, moe_experts=2)
+
     def test_causality(self):
         """Changing a future token must not affect earlier logits."""
         model = transformer.create_model(
@@ -306,82 +312,3 @@ class TestTransformer:
         # output lands directly in the activations' layout (no SPMD remat)
         assert specs["embed"]["embedding"] == P("fsdp", None)
 
-
-class TestMoE:
-    """Expert parallelism (SURVEY §2.7 row EP; absent from the reference):
-    switch-routed MoE MLP with dense dispatch, experts sharded over ``ep``."""
-
-    def test_single_expert_equals_dense_ffn(self):
-        import flax.linen as nn
-        import numpy as np
-
-        cfg = transformer.TransformerConfig(
-            vocab_size=64, d_model=16, n_layers=1, n_heads=2, d_ff=32,
-            moe_experts=1, moe_capacity_factor=4.0,
-        )
-        m = transformer.MoeMlp(cfg)
-        x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 8, 16)), jnp.float32)
-        variables = m.init(jax.random.PRNGKey(0), x)
-        # init also ran sow: pass params only so "losses" starts fresh
-        y, mods = m.apply({"params": variables["params"]}, x, mutable=["losses"])
-        wi = variables["params"]["wi"][0]
-        wo = variables["params"]["wo"][0]
-        dense = nn.gelu(x.reshape(-1, 16) @ wi) @ wo
-        np.testing.assert_allclose(
-            np.asarray(y).reshape(-1, 16), np.asarray(dense), rtol=2e-5, atol=2e-5
-        )
-        # one expert takes every token: aux loss is exactly E * 1 * 1 = 1
-        (aux,) = jax.tree.leaves(mods["losses"])
-        assert float(aux) == pytest.approx(1.0)
-
-    def test_capacity_drops_overflow_tokens(self):
-        import numpy as np
-
-        cfg = transformer.TransformerConfig(
-            vocab_size=64, d_model=8, n_layers=1, n_heads=2, d_ff=16,
-            moe_experts=2, moe_capacity_factor=0.25,
-        )
-        m = transformer.MoeMlp(cfg)
-        x = jnp.asarray(np.random.default_rng(1).standard_normal((1, 16, 8)), jnp.float32)
-        variables = m.init(jax.random.PRNGKey(0), x)
-        y, _ = m.apply(variables, x, mutable=["losses"])
-        # capacity = 0.25 * 16 / 2 = 2 per expert -> at most 4 tokens routed;
-        # dropped tokens contribute exactly zero output
-        nonzero_rows = np.count_nonzero(np.abs(np.asarray(y).reshape(16, 8)).sum(-1) > 1e-7)
-        assert nonzero_rows <= 4
-
-    def test_ep_sharded_train_step(self):
-        import numpy as np
-        import optax
-
-        from tensorflowonspark_tpu import parallel
-        from tensorflowonspark_tpu.train import SyncDataParallel
-
-        mesh = parallel.build_mesh({"dp": 2, "ep": 4})
-        model = transformer.create_model(
-            mesh=mesh, vocab_size=128, d_model=32, n_layers=2, n_heads=4,
-            d_ff=64, max_seq_len=32, moe_experts=4,
-        )
-        strategy = SyncDataParallel(mesh, param_spec_fn=transformer.param_specs)
-        opt = optax.adamw(1e-3)
-        state = strategy.create_state(
-            transformer.make_init_fn(model, sample_len=8), opt, jax.random.PRNGKey(0)
-        )
-        # expert weights actually sharded over ep
-        specs = transformer.param_specs(
-            jax.eval_shape(transformer.make_init_fn(model, 8), jax.random.PRNGKey(0))["params"],
-            mesh,
-        )
-        from jax.sharding import PartitionSpec as P
-
-        assert specs["layer_0"]["moe"]["wi"] == P("ep", None, None)
-        step = strategy.compile_train_step(
-            transformer.make_loss_fn(model), opt, has_aux=True
-        )
-        tokens = np.random.default_rng(0).integers(0, 128, (4, 17))
-        state, metrics = step(state, strategy.shard_batch({"tokens": tokens}))
-        jax.block_until_ready(metrics["loss"])
-        assert np.isfinite(float(metrics["loss"]))
-        assert "moe_aux" in metrics and np.isfinite(float(metrics["moe_aux"]))
-        # aux loss >= 1 by Cauchy-Schwarz (perfectly balanced -> exactly 1)
-        assert float(metrics["moe_aux"]) >= 0.99

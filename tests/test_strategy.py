@@ -186,21 +186,31 @@ def test_loop_prefetch_windows_and_drops_remainder():
         np.testing.assert_allclose(np.asarray(got["x"]), want["x"])
 
 
-def test_packed_prefetch_stacks_and_shards_windows():
-    """packed_place (packed_prefetch's placement): K host batches -> ONE
-    [K, B, ...] device tree, batch dim sharded over the data axes; short
-    final windows are dropped."""
-    from tensorflowonspark_tpu.data import packed_prefetch
+@pytest.mark.parametrize("depth", [None, 1, 3], ids=["depth-default", "depth-1", "depth-3"])
+@pytest.mark.parametrize("num_steps", [0, 2, 4], ids=["device_prefetch", "loop_prefetch-2", "loop_prefetch-4"])
+@pytest.mark.parametrize("n", [1, 7, 11, 16])
+def test_device_feeds_deliver_the_source_in_order(n, num_steps, depth):
+    """The stream is the source's, in order, short tails dropped: what
+    ``device_prefetch`` (``num_steps`` 0 here) and ``loop_prefetch`` hand out,
+    concatenated, is the source up to the last whole window, whatever the
+    depth they place ahead."""
+    from tensorflowonspark_tpu.data import device_prefetch, loop_prefetch
 
-    mesh = parallel.build_mesh({"dp": 8})
-    strategy = SyncDataParallel(mesh)
-    host = [{"x": np.full((8, 3), i, np.float32)} for i in range(5)]
-    windows = list(packed_prefetch(iter(host), strategy, num_steps=2, depth=1))
-    assert [w["x"].shape for w in windows] == [(2, 8, 3), (2, 8, 3)]
-    # contents: window w holds batches 2w and 2w+1, in order
-    np.testing.assert_allclose(np.asarray(windows[1]["x"][1]), host[3]["x"])
-    # the batch (second) dim is sharded over dp
-    assert "dp" in str(windows[0]["x"].sharding.spec)
+    strategy = SyncDataParallel(parallel.build_mesh({"dp": 8}))
+    rng = np.random.default_rng(n)
+    host = [{"x": rng.standard_normal((8, 2)).astype(np.float32), "i": np.full((8,), i, np.int32)} for i in range(n)]
+    kw = {} if depth is None else {"depth": depth}
+    if num_steps:
+        windows = list(loop_prefetch(iter(host), strategy, num_steps=num_steps, **kw))
+        assert all(len(w) == num_steps for w in windows)
+        got = [b for w in windows for b in w]
+    else:
+        got = list(device_prefetch(iter(host), strategy, **kw))
+    assert len(got) == n - n % (num_steps or 1)
+    for g, want in zip(got, host):
+        assert "dp" in str(g["x"].sharding.spec)
+        for key in ("x", "i"):
+            np.testing.assert_array_equal(np.asarray(g[key]), want[key])
 
 
 def test_restore_checkpoint_tolerates_missing_model_state(tmp_path):

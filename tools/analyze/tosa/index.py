@@ -540,7 +540,7 @@ class _FunctionExtractor(ast.NodeVisitor):
             if tail in ("jit", "pjit") or name.endswith("compile_train_loop"):
                 pos = _donate_positions(value)
                 if pos == "nodonate":
-                    # compile_train_loop(donate="state") donates the state
+                    # compile_train_loop(donate=True) donates the state
                     # (positional arg 0 of the compiled callable)
                     for kw in value.keywords:
                         if kw.arg == "donate" and not (
