@@ -87,6 +87,7 @@ MODULES = [
     "tensorflowonspark_tpu.ops.hyper_connection",
     "tensorflowonspark_tpu.ops.moe_combine",
     "tensorflowonspark_tpu.ops.selective_scan",
+    "tensorflowonspark_tpu.ops.ssd_scan",
     "tensorflowonspark_tpu.backends",
     "tensorflowonspark_tpu.backends.local",
     "tosa",

@@ -198,7 +198,7 @@ def main_fun(args, ctx, observer=None):
         # a model with windowed layers has the text plane count their attention blocks beside the full layers'
         attention_window=getattr(model.cfg, "sliding_window", None),
         # one with state-space layers has it count what their scans walk and restart on
-        scan_restarts=any(layer[0] == "mamba" for layer in getattr(model.cfg, "plan", ())),
+        scan_restarts=any(layer[0] in ("mamba", "mamba2") for layer in getattr(model.cfg, "plan", ())),
     )
     stream = iter(pipe)
 

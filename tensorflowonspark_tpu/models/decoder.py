@@ -4,7 +4,8 @@
 open models of 2025-26 are not. Here a model is a list of layers, and a layer
 names three kinds — its mixer (an attention, a state-space layer, a gated
 memory unit), its feed-forward and its residual path — each a small module
-of its own with its own placement rules:
+of its own with its own placement rules; a layer may name no mixer, or no
+feed-forward, and is then **a block of one sub-layer**:
 
 ===========  ==========  ======================================================
 part         kind        module
@@ -42,6 +43,17 @@ mixer        ``mamba``   :class:`MambaMixer`: in projection, short causal
                          output is handed on where the plan says
 mixer        ``gmu``     :class:`GatedMemory`: ``(silu(u W_1) * m) W_2``, ``m``
                          the scan output an earlier ``mamba`` layer handed on
+mixer        ``mamba2``  :class:`Mamba2Mixer`: one in projection to ``[z | xBC
+                         | dt]``, the short causal convolution over ``xBC``,
+                         heads of ``mamba_head_dim`` channels with one scalar
+                         decay each, in groups that share their ``B`` and
+                         ``C``, scanned in chunked matrix form
+                         (``ops/ssd_scan.py``: three products a chunk on the
+                         matrix unit, only the chunks' states carried;
+                         float32 decays and state, restarting at every
+                         document of a packed row), the gated norm a group
+                         (gate first), out projection
+mixer        None        no mixer: the block is its feed-forward alone
 feed-forward ``swiglu``  :class:`SwiGLU`: the dense gated MLP
 feed-forward ``moe``     :class:`RoutedExperts`: scores (``scoring_func``:
                          ``sigmoid`` with a selection bias, ``noaux_tc``; or
@@ -50,7 +62,16 @@ feed-forward ``moe``     :class:`RoutedExperts`: scores (``scoring_func``:
                          *held here*
                          applied to the slots routed to them (nothing
                          dropped), beside shared experts, if any, that see
-                         every token
+                         every token; under ``moe_latent_size`` the routed
+                         experts **work in a latent** (a projection down
+                         before the dispatch and one up after the combine: the
+                         slot buffer, the row gathers, the combine kernel and
+                         the grouped products all run that wide; the router
+                         and the shared expert read the model's width); under
+                         ``mlp_hidden_act: relu2`` an expert, the shared one
+                         too, is two matrices and no gate, ``relu(x W_up)^2
+                         W_down``
+feed-forward None        no feed-forward: the block is its mixer alone
 residual     ``add``     ``x + F(norm(x))``
 residual     ``mhc``     :class:`HyperConnection`: ``hc_mult`` residual
                          streams, three learned maps per sub-layer, the
@@ -62,7 +83,7 @@ residual     ``mhc``     :class:`HyperConnection`: ``hc_mult`` residual
 ===========  ==========  ======================================================
 
 The configuration is a dict with the published ``config.json``'s keys
-(:class:`DecoderConfig`), in one of three dialects: the one that says
+(:class:`DecoderConfig`), in one of four dialects: the one that says
 ``n_routed_experts`` (latent attention where ``kv_lora_rank`` is given,
 sigmoid ``noaux_tc`` routing), the one that says ``num_experts`` (``gqa``,
 softmax routing without a bias; ``decoder_sparse_step`` 1) and the one that
@@ -77,7 +98,17 @@ encoding, biases on the attention's projections; ``first_layer`` and
 ``model_layers`` say which of the published layers are held here, so that a
 cut in depth keeps each layer's own kind and ``lambda_init``;
 ``tie_word_embeddings`` is read in every dialect: true shares the embedding's
-matrix with the head). The second may
+matrix with the head) and the one that says ``hybrid_override_pattern``
+(``model_type`` ``nemotron_h``: a letter a block of the published model,
+``M`` a ``mamba2`` mixer, ``E`` routed experts, ``*`` ``gqa``, **each block
+one sub-layer** ``x + F(norm(x))``; RMSNorm at ``layer_norm_epsilon``, no
+positional encoding and no norm on q's and k's heads, sigmoid routing with a
+selection bias, ``moe_latent_size``, ``mlp_hidden_act`` and
+``moe_shared_expert_intermediate_size`` read as above, ``first_layer`` and
+``model_layers`` saying which of the published blocks are held here; a dense
+block ``-`` and the prediction module are not built, and ``moe_latent_size``
+or ``mlp_hidden_act: relu2`` in another dialect is refused by name, since its
+layers would ignore them). The second may
 say more, layer by layer: ``layer_types`` (``full_attention`` /
 ``sliding_attention`` with ``sliding_window``), ``num_attention_heads_per_layer``,
 ``rope_parameters`` by layer type (``rope_type`` ``default`` or ``yarn``,
@@ -103,7 +134,15 @@ and a noised copy). A chip
 that holds a share of a layer's experts says which (``experts_held``:
 first, count): the router stays as wide as the model's, and the layer adds
 only its own experts' terms — what expert parallelism asks of a layer, here
-without the exchange.
+without the exchange. One that holds a share of every mixer's heads says
+which (``heads_held``: index, shares): a ``mamba2`` mixer then holds that
+share of its heads in whole groups (so the grouped norm is exact), a ``gqa``
+layer that share of its query heads with the key/value heads they read (one
+head where the shares outnumber the key/value heads: the chips that hold its
+readers each hold it), each builds its projections that wide and returns
+**its heads' part of the output projection's sum** — what tensor parallelism
+asks of a mixer, here without the all-reduce: nothing stands in for the other
+shares' parts.
 
 Compute is ``dtype`` (bfloat16 on the chip) on float32 parameters; the
 router's scores, the hyper-connection maps, the Sinkhorn iterations and the
@@ -137,13 +176,16 @@ inside both, ``tos.moe_route`` (router, top-k, sort, gather, combine),
 ``tos.ssm_scan`` (both rules of the scan's ``custom_vjp``) inside it,
 ``tos.gmu``, ``tos.cross_attn``, and ``tos.diff_attn`` (lambda, the
 subtraction and the sub-norm) inside ``tos.gqa`` / ``tos.swa`` /
-``tos.cross_attn``. **What crosses layers beside the residual streams** (a
+``tos.cross_attn``; ``tos.mamba2`` with ``tos.ssm_conv`` and ``tos.ssd_scan``
+(both rules of that scan's ``custom_vjp``, and what XLA does round them)
+inside it; ``tos.moe_latent`` (both latent projections). **What crosses layers beside the residual streams** (a
 ``mamba`` layer's scan output, a ``gqa`` layer's keys and values, where
 :class:`HeadsPlan` says ``hands_on``) leaves the layer that makes it as a
 result and enters its readers as an argument (:class:`DecoderLayer`'s third
 result and last argument): under ``remat`` it is kept once and no reader
 computes it again; its bytes a step are sown as ``ssm_state_carried_bytes``
-(``ssm_state_carried_bytes_total``). What the routed layers count in a step is
+(``ssm_state_carried_bytes_total``); the chunks the ``mamba2`` layers' scans
+walk a step as ``ssd_scan_chunks`` (``ssd_scan_chunks_total``). What the routed layers count in a step is
 sown into the ``counters`` collection (``moe_slots_routed``,
 ``moe_slots_held``; where a chip holds under half the experts also
 ``moe_layers_compact`` and ``moe_layers_at_bound``: the layers that ran on
@@ -171,20 +213,27 @@ from jax.ad_checkpoint import checkpoint_name
 from tensorflowonspark_tpu import obs
 from tensorflowonspark_tpu.models import register, transformer
 from tensorflowonspark_tpu.ops import grouped_matmul as gm
-from tensorflowonspark_tpu.ops import hyper_connection, moe_combine, selective_scan
+from tensorflowonspark_tpu.ops import hyper_connection, moe_combine, selective_scan, ssd_scan
 from tensorflowonspark_tpu.ops.flash_attention import KEPT_ATTENDED, KEPT_LSE, KEPT_O, KEPT_PROJECTED
 
-#: what a recomputed layer keeps: ``ops.flash_attention.REMAT_POLICY``'s four names and the scan's two results
+#: what a recomputed layer keeps: ``ops.flash_attention.REMAT_POLICY``'s four names and either scan's two results
 REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
-    KEPT_O, KEPT_LSE, KEPT_PROJECTED, KEPT_ATTENDED, selective_scan.KEPT_SCANNED, selective_scan.KEPT_SCAN_STATE)
+    KEPT_O, KEPT_LSE, KEPT_PROJECTED, KEPT_ATTENDED, selective_scan.KEPT_SCANNED, selective_scan.KEPT_SCAN_STATE,
+    ssd_scan.KEPT_SCANNED, ssd_scan.KEPT_STATE)
 
 #: a state-space layer's sizes, the family's (its published configuration names none): channels a hidden
 #: unit, states a channel, the short convolution's taps
 MAMBA_EXPAND, MAMBA_STATES, MAMBA_TAPS = 2, 16, 4
 
-#: a layer's first sub-layer, its mixer (the name under which ``layer_plan`` gives it stays "attention")
-MIXER_KINDS = ("mla", "gqa", "mamba", "gmu", "cross")
-FEED_FORWARD_KINDS = ("swiglu", "moe")
+#: a layer's first sub-layer, its mixer (the name under which ``layer_plan`` gives it stays "attention"), and
+#: its second; None: the block has no such sub-layer (one of the two at most)
+MIXER_KINDS = ("mla", "gqa", "mamba", "gmu", "cross", "mamba2", None)
+FEED_FORWARD_KINDS = ("swiglu", "moe", None)
+#: ``hybrid_override_pattern``'s letters: a block is one sub-layer
+_PATTERN_KINDS = {"M": ("mamba2", None, "add"), "E": (None, "moe", "add"), "*": ("gqa", None, "add")}
+#: the feed-forwards' forms by ``mlp_hidden_act``: ``silu`` gated (``down(silu(gate x) * up x)``), ``relu2`` two
+#: matrices and no gate (``down(relu(up x) ** 2)``)
+MLP_ACTS = ("silu", "relu2")
 RESIDUAL_KINDS = ("add", "mhc")
 
 #: keys of a published ``config.json`` that say nothing this module computes
@@ -292,6 +341,31 @@ class DecoderConfig:
     #: ``num_hidden_layers``): a cut in depth keeps each layer's own kind and ``lambda_init``
     first_layer: int = 0
     model_layers: int = None
+    # the one-sub-layer dialect (``hybrid_override_pattern``, ``model_type`` ``nemotron_h``): a letter a block of the
+    # published model, ``M`` a Mamba-2 mixer, ``E`` routed experts, ``*`` grouped-query attention; the blocks held
+    # here are ``first_layer`` .. ``first_layer + num_hidden_layers``
+    hybrid_override_pattern: str = ""
+    # the Mamba-2 mixer (``mamba2``): heads of ``mamba_head_dim`` channels, a scalar decay each, in ``n_groups``
+    # groups that share their state projections of ``ssm_state_size``; scanned in chunks of ``chunk_size``
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = ssd_scan.DEFAULT_CHUNK
+    #: the step's bias is seeded as the inverse softplus of a step log-uniform in [min, max], floored (init only)
+    time_step_min: float = 1e-3
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    #: (index, shares): of every mixer's heads (a Mamba-2 mixer's groups, an attention's key/value heads with
+    #: them) this chip holds share ``index`` of ``shares`` equal ones; None = all. The mixer builds its
+    #: projections that wide and returns its heads' part of the output projection's sum
+    heads_held: tuple = None
+    #: the routed experts work in a latent of this width (a projection down before the dispatch, one up after
+    #: the combine; the router and the shared expert read the model's width); 0: at ``hidden_size``
+    moe_latent_size: int = 0
+    #: the routed and shared experts' form, one of :data:`MLP_ACTS`
+    mlp_hidden_act: str = "silu"
     #: LayerNorm (weight and bias) at this epsilon in place of RMSNorm; None: RMSNorm at ``rms_norm_eps``
     layer_norm_eps: float = None
     #: biases on the attention's projections (q, k, v and the output's)
@@ -333,6 +407,15 @@ class DecoderConfig:
             cfg["routed_scaling_factor"] = cfg.pop("moe_routed_scaling_factor")
         if "mb_per_layer" in cfg:
             _hybrid_dialect(cfg)
+        if "hybrid_override_pattern" in cfg:
+            _pattern_dialect(cfg)
+        else:
+            # read by the one-sub-layer dialect's experts alone: anywhere else they would change nothing
+            for key, idle in (("moe_latent_size", 0), ("mlp_hidden_act", "silu")):
+                if cfg.get(key, idle) != idle:
+                    raise ValueError(
+                        "decoder: {} {!r} is implemented in the hybrid_override_pattern dialect only; this "
+                        "configuration's layers would ignore it".format(key, cfg[key]))
         _per_layer(cfg, cfg.get("num_hidden_layers", 0))
         scoring = cfg.setdefault("scoring_func", "sigmoid")
         if scoring not in _TOPK_METHODS or cfg.pop("topk_method", _TOPK_METHODS[scoring]) != _TOPK_METHODS[scoring]:
@@ -343,10 +426,15 @@ class DecoderConfig:
         if scaling and scaling.get("type") != "yarn":
             raise ValueError("decoder: rope_scaling type {!r} is not implemented".format(scaling.get("type")))
         cfg["rope_scaling"] = tuple(sorted(scaling.items()))
-        if cfg.get("experts_held") is not None:
-            cfg["experts_held"] = tuple(cfg["experts_held"])
+        for key in ("experts_held", "heads_held"):
+            if cfg.get(key) is not None:
+                cfg[key] = tuple(cfg[key])
+        if cfg.get("heads_held") is not None and cfg.get("attention_bias"):
+            raise ValueError("decoder: heads_held with attention_bias would add the output's bias once a share")
         if cfg.get("layer_plan") is not None:
             cfg["layer_plan"] = tuple(tuple(layer) for layer in cfg["layer_plan"])
+        if cfg.get("mlp_hidden_act", "silu") not in MLP_ACTS:
+            raise ValueError("decoder: mlp_hidden_act must be one of {}".format(MLP_ACTS))
         cfg["rope_parameters"] = _rope_parameters(cfg.pop("rope_parameters", None) or {})
         unknown = set(cfg) - {f.name for f in dataclasses.fields(cls)}
         if unknown:
@@ -363,6 +451,9 @@ class DecoderConfig:
         ``first_k_dense_replace`` dense layers, then routed ones."""
         if self.layer_plan is not None:
             plan = self.layer_plan
+        elif self.hybrid_override_pattern:
+            plan = tuple(_PATTERN_KINDS[letter] for letter in self.hybrid_override_pattern[
+                self.first_layer:self.first_layer + self.num_hidden_layers])
         elif self.mb_per_layer:
             half = self.depth // 2
             plan = tuple(
@@ -381,7 +472,7 @@ class DecoderConfig:
                 len(plan), self.num_hidden_layers))
         for attention, feed_forward, residual in plan:
             if (attention not in MIXER_KINDS or feed_forward not in FEED_FORWARD_KINDS
-                    or residual not in RESIDUAL_KINDS):
+                    or residual not in RESIDUAL_KINDS or (attention is None and feed_forward is None)):
                 raise ValueError("decoder: unknown layer kinds {}".format((attention, feed_forward, residual)))
             if residual == "add" and self.hc_mult != 1:
                 raise ValueError("decoder: an 'add' residual carries one stream (hc_mult 1)")
@@ -406,7 +497,7 @@ class DecoderConfig:
         per_layer = self.num_attention_heads_per_layer
         at = self.first_layer + index
         return HeadsPlan(
-            heads=per_layer[index] if per_layer else self.num_attention_heads,
+            heads=self.share_of(per_layer[index] if per_layer else self.num_attention_heads, "attention heads"),
             window=self.sliding_window if windowed else None,
             rope=dict(self.rope_parameters).get(LAYER_TYPES[windowed], ()),
             gate=self.gating is not None,
@@ -417,8 +508,33 @@ class DecoderConfig:
 
     @property
     def rotary(self):
-        """Rotary positions on q and k; the ``mb_per_layer`` dialect has no positional encoding at all."""
-        return not self.mb_per_layer
+        """Rotary positions on q and k; the ``mb_per_layer`` and ``hybrid_override_pattern`` dialects have no
+        positional encoding at all."""
+        return not (self.mb_per_layer or self.hybrid_override_pattern)
+
+    @property
+    def share(self):
+        """``heads_held``, or the one share of one: (index, shares)."""
+        return self.heads_held or (0, 1)
+
+    def share_of(self, count, what):
+        """How many of a mixer's ``count`` heads (or groups) are held here (``heads_held``)."""
+        index, shares = self.share
+        if not 0 <= index < shares or count % shares:
+            raise ValueError("decoder: heads_held {} does not divide {} {}".format(self.heads_held, count, what))
+        return count // shares
+
+    @property
+    def kv_heads(self):
+        """The key/value heads a ``gqa`` layer holds here: its share of ``num_key_value_heads``, or one head
+        where the shares outnumber them (the chips that hold its readers each hold the head)."""
+        shares = self.share[1]
+        if self.num_key_value_heads and shares > self.num_key_value_heads:
+            if shares % self.num_key_value_heads:
+                raise ValueError("decoder: heads_held {} and {} key/value heads: neither divides the other".format(
+                    self.heads_held, self.num_key_value_heads))
+            return 1
+        return self.share_of(self.num_key_value_heads, "key/value heads")
 
     @property
     def dt_rank(self):
@@ -463,6 +579,52 @@ def _hybrid_dialect(cfg):
         for at in range(first, first + held)]
     if "sliding_attention" not in cfg["layer_types"]:
         cfg.pop("sliding_window", None)  # the layers held here hold none of the windowed ones
+
+
+#: the one-sub-layer dialect: keys of a published ``nemotron_h`` configuration that say nothing this module
+#: computes from (the attention blocks of the family's code read neither ``rope_theta`` nor
+#: ``partial_rotary_factor``; the prediction module is not built: ROADMAP M3), and the values the ones it does not
+#: implement must have
+_PATTERN_IGNORED = (
+    "rope_theta", "partial_rotary_factor", "mtp_hybrid_override_pattern", "num_logits_to_keep",
+    "rescale_prenorm_residual", "use_mamba_kernels", "intermediate_size",
+)
+_PATTERN_REQUIRED = {
+    "mamba_hidden_act": "silu", "mamba_proj_bias": False, "use_bias": False, "use_conv_bias": True,
+    "residual_in_fp32": False, "moe_shared_expert_overlap": False, "sliding_window": None, "n_shared_experts": 1,
+}
+
+
+def _pattern_dialect(cfg):
+    """The keys of a published ``hybrid_override_pattern`` configuration
+    (``model_type`` ``nemotron_h``) as this module reads them, in place: what
+    the family's code fixes and the file does not say (no norm on q's and k's
+    heads, no positional encoding), RMSNorm at ``layer_norm_epsilon`` (=
+    ``norm_eps``), the shared expert's width under this module's name, and the
+    pattern checked: as long as the published model (``model_layers``), of the
+    letters this module builds, ``expand`` times the hidden size the Mamba-2
+    heads' channels."""
+    for key in _PATTERN_IGNORED:
+        cfg.pop(key, None)
+    for key, want in _PATTERN_REQUIRED.items():
+        if cfg.pop(key, want) != want:
+            raise ValueError("decoder: {} must be {!r}".format(key, want))
+    pattern = cfg["hybrid_override_pattern"]
+    depth = cfg.setdefault("model_layers", cfg.get("num_hidden_layers"))
+    if len(pattern) != depth or set(pattern) - set(_PATTERN_KINDS):
+        raise ValueError("decoder: hybrid_override_pattern must name {} blocks, each one of {} ('-', a dense "
+                         "block, is not implemented)".format(depth, sorted(_PATTERN_KINDS)))
+    eps = {cfg.pop(key) for key in ("layer_norm_epsilon", "norm_eps") if key in cfg}
+    if len(eps) > 1:
+        raise ValueError("decoder: layer_norm_epsilon and norm_eps differ: {}".format(sorted(eps)))
+    if eps:
+        cfg["rms_norm_eps"] = eps.pop()
+    if "moe_shared_expert_intermediate_size" in cfg:
+        cfg["shared_expert_intermediate_size"] = cfg.pop("moe_shared_expert_intermediate_size")
+    inner = cfg.get("mamba_num_heads", 0) * cfg.get("mamba_head_dim", 0)
+    if cfg.pop("expand", None) not in (None, inner / cfg["hidden_size"]):
+        raise ValueError("decoder: expand times hidden_size is not mamba_num_heads x mamba_head_dim = {}".format(inner))
+    cfg.setdefault("qk_norm", False)
 
 
 def _per_layer(cfg, layers):
@@ -695,7 +857,7 @@ class GroupedQueryAttention(nn.Module):
     def __call__(self, x, positions, segment_ids=None, labels=None, shared=None):
         cfg, dt, layer = self.cfg, self.cfg.compute_dtype, self.layer
         heads, width = layer.heads, cfg.head_dim or cfg.hidden_size // cfg.num_attention_heads
-        kv_heads = cfg.num_key_value_heads or heads
+        kv_heads = cfg.kv_heads or heads
         scope = "tos.cross_attn" if shared is not None else "tos.gqa" if layer.window is None else "tos.swa"
         with jax.named_scope(scope):
             dense = lambda n, name: nn.DenseGeneral(  # noqa: E731
@@ -777,9 +939,12 @@ def causal_conv(xs, kernel, bias, segment_ids=None):
     return nn.silu(total).astype(xs.dtype)
 
 
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """The family's: the inverse softplus of a step drawn log-uniform in [1e-3, 0.1]."""
-    step = jnp.exp(jax.random.uniform(key, shape, dtype) * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+def _dt_bias_init(key, shape, dtype=jnp.float32, low=1e-3, high=0.1, floor=None):
+    """The family's: the inverse softplus of a step drawn log-uniform in
+    [``low``, ``high``], floored at ``floor`` if any."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype) * (math.log(high) - math.log(low)) + math.log(low))
+    if floor is not None:
+        step = jnp.maximum(step, floor)
     return step + jnp.log(-jnp.expm1(-step))
 
 
@@ -840,6 +1005,82 @@ class MambaMixer(nn.Module):
             return checkpoint_name(out, KEPT_ATTENDED), ({"memory": y} if self.layer.hands_on else {})
 
 
+def _ssd_rows(xs, delta, b, c, *rest, heads, groups, chunk, interpret):
+    """:func:`~tensorflowonspark_tpu.ops.ssd_scan.ssd_scan` in
+    :func:`_per_shard`'s order and shapes: everything a position has as
+    ``[B, L, width]`` (``xs`` the heads side by side, ``b`` and ``c`` the
+    groups; the segment ids, if any, as ``[B, L, 1]``), then ``a`` and
+    ``skip``, whole."""
+    *ids, a, skip = rest
+    by = lambda t, n: t.reshape(t.shape[:2] + (n, -1))  # noqa: E731
+    y = ssd_scan.ssd_scan(by(xs, heads), delta, a, by(b, groups), by(c, groups), skip,
+                          ids[0][..., 0] if ids else None, chunk=chunk, interpret=interpret)
+    return y.reshape(xs.shape)
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 layer (arXiv:2405.21060) as the ``nemotron_h`` family holds
+    it: ``H`` heads of ``P = mamba_head_dim`` channels in ``G`` groups, ``N =
+    ssm_state_size`` states, **the heads and groups held here**
+    (``cfg.heads_held``: whole groups). ``[z | xBC | dt] = u W_in`` (widths ``H
+    P``, ``H P + 2 G N``, ``H``; no bias); ``xBC = causal_conv(xBC)``
+    (:func:`causal_conv`, a bias, scope ``tos.ssm_conv``), split into ``x [H,
+    P]``, ``B`` and ``C [G, N]``; ``Delta = softplus(dt + b_dt)`` a head,
+    float32; ``a = -exp(A_log)`` one scalar a head; ``y`` the scan of
+    :mod:`~tensorflowonspark_tpu.ops.ssd_scan` (a head reads its group's ``B``
+    and ``C``; float32 decays, state and chunk sums; the skip ``D x``
+    included; restarting with the convolution at every document of a packed
+    row; scope ``tos.ssd_scan``); then the gated norm, **gate first, the norm
+    over each group's ``H P / G`` channels** with a learned weight, float32:
+    ``RMSNorm_group(y * silu(z))``; result ``y W_out``, **the held heads' part
+    of that sum** (nothing stands in for the other shares'). A recomputed
+    layer keeps the scan's ``y``, its chunk states and the result
+    (:data:`REMAT_POLICY`); the products before the scan run again."""
+
+    cfg: DecoderConfig
+    mesh: object = None
+
+    PARAM_RULES = (
+        # columns [z | x | B | C | dt], rows the held heads': whole over ``tp`` (the scan under it waits: ROADMAP M4)
+        (r"mamba2/in_proj/kernel$", ("fsdp", None)),  # [d, 2 H P + 2 G N + H]
+        (r"mamba2/out_proj/kernel$", (None, "fsdp")),  # [H P, d]
+    )
+
+    @nn.compact
+    def __call__(self, x, segment_ids=None):
+        cfg, dt = self.cfg, self.cfg.compute_dtype
+        heads, groups = cfg.share_of(cfg.mamba_num_heads, "Mamba-2 heads"), cfg.share_of(cfg.n_groups, "groups")
+        inner, states = heads * cfg.mamba_head_dim, groups * cfg.ssm_state_size
+        first = cfg.share[0] * heads  # the first held head's place in the model
+        with jax.named_scope("tos.mamba2"):
+            zxbcdt = nn.Dense(2 * inner + 2 * states + heads, use_bias=False, dtype=dt, name="in_proj")(x)
+            z, xbc, step = zxbcdt[..., :inner], zxbcdt[..., inner:2 * inner + 2 * states], zxbcdt[..., -heads:]
+            with jax.named_scope("tos.ssm_conv"):
+                xbc = causal_conv(
+                    xbc, self.param("conv_kernel", nn.initializers.normal(cfg.conv_kernel ** -0.5),
+                                    (cfg.conv_kernel, inner + 2 * states), jnp.float32),
+                    self.param("conv_bias", nn.initializers.zeros, (inner + 2 * states,), jnp.float32), segment_ids)
+            dt_bias = self.param("dt_bias", functools.partial(
+                _dt_bias_init, low=cfg.time_step_min, high=cfg.time_step_max, floor=cfg.time_step_floor),
+                (heads,), jnp.float32)
+            # the family's: A = 1 .. H over the model's heads, in their order
+            a_log = self.param(
+                "a_log", lambda key, shape, dtype: jnp.log(jnp.arange(first + 1, first + 1 + shape[0], dtype=dtype)),
+                (heads,), jnp.float32)
+            skip = self.param("skip", nn.initializers.ones, (heads,), jnp.float32)
+            delta = jax.nn.softplus(step.astype(jnp.float32) + dt_bias)
+            ids = () if segment_ids is None else (segment_ids[..., None],)
+            y = _per_shard(
+                functools.partial(_ssd_rows, heads=heads, groups=groups, chunk=cfg.chunk_size), self.mesh,
+                (xbc[..., :inner], delta, xbc[..., inner:inner + states], xbc[..., inner + states:]) + ids,
+                (-jnp.exp(a_log), skip))
+            gated = (y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))).reshape(y.shape[:2] + (groups, -1))
+            gated = gated * jax.lax.rsqrt(jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.rms_norm_eps)
+            gated = gated.reshape(y.shape) * self.param("norm_scale", nn.initializers.ones, (inner,), jnp.float32)
+            out = nn.Dense(cfg.hidden_size, use_bias=False, dtype=dt, name="out_proj")(gated.astype(dt))
+            return checkpoint_name(out, KEPT_ATTENDED)
+
+
 class GatedMemory(nn.Module):
     """The cross-decoder's gated memory unit: ``(silu(u W_1) * m) W_2``,
     ``m`` the scan output an earlier state-space layer handed on (``[B, L,
@@ -863,10 +1104,12 @@ class GatedMemory(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    """``down(silu(gate x) * up x)``: the dense MLP, and a shared expert."""
+    """``down(silu(gate x) * up x)``: the dense MLP, and a shared expert;
+    under ``act`` ``relu2`` two matrices and no gate, ``down(relu(up x) ** 2)``."""
 
     cfg: DecoderConfig
     width: int
+    act: str = "silu"
 
     PARAM_RULES = (
         (r"(mlp|shared)/(gate|up)/kernel$", ("fsdp", "tp")),  # [d, width]
@@ -876,9 +1119,13 @@ class SwiGLU(nn.Module):
     @nn.compact
     def __call__(self, x):
         dt = self.cfg.compute_dtype
-        gate = nn.Dense(self.width, use_bias=False, dtype=dt, name="gate")(x)
-        up = nn.Dense(self.width, use_bias=False, dtype=dt, name="up")(x)
-        return nn.Dense(self.cfg.hidden_size, use_bias=False, dtype=dt, name="down")(nn.silu(gate) * up)
+        if self.act == "relu2":
+            hidden = jnp.square(nn.relu(nn.Dense(self.width, use_bias=False, dtype=dt, name="up")(x)))
+        else:
+            gate = nn.Dense(self.width, use_bias=False, dtype=dt, name="gate")(x)
+            up = nn.Dense(self.width, use_bias=False, dtype=dt, name="up")(x)
+            hidden = nn.silu(gate) * up
+        return nn.Dense(self.cfg.hidden_size, use_bias=False, dtype=dt, name="down")(hidden)
 
 
 class RoutedExperts(nn.Module):
@@ -921,6 +1168,8 @@ class RoutedExperts(nn.Module):
         (r"moe/router$", (None, None)),  # [d, E]: whole on every chip
         (r"moe/experts_(gate|up)$", ("ep", "fsdp", "tp")),  # [held, d, width]
         (r"moe/experts_down$", ("ep", "tp", "fsdp")),  # [held, width, d]
+        (r"moe/latent_down/kernel$", ("fsdp", None)),  # [d, latent]
+        (r"moe/latent_up/kernel$", (None, "fsdp")),  # [latent, d]
     ) + SwiGLU.PARAM_RULES
 
     @nn.compact
@@ -931,6 +1180,7 @@ class RoutedExperts(nn.Module):
         first, held = cfg.held
         width = cfg.moe_intermediate_size
         flat = x.reshape(tokens, d)
+        gated = cfg.mlp_hidden_act != "relu2"
 
         with jax.named_scope("tos.moe_route"):
             router = self.param("router", _kernel_init(), (d, cfg.n_routed_experts), jnp.float32)
@@ -950,11 +1200,16 @@ class RoutedExperts(nn.Module):
             order, group_sizes, local = gm.sort_slots(chosen.reshape(-1), first, held)
             rows_used = jnp.sum(group_sizes)
 
+        routed_in, d_in = flat, d
+        if cfg.moe_latent_size:
+            d_in = cfg.moe_latent_size
+            with jax.named_scope("tos.moe_latent"):
+                routed_in = nn.Dense(d_in, use_bias=False, dtype=cfg.compute_dtype, name="latent_down")(flat)
         init = _kernel_init(batch_axis=(0,))
-        gate = self.param("experts_gate", init, (held, d, width), jnp.float32)
-        up = self.param("experts_up", init, (held, d, width), jnp.float32)
-        down = self.param("experts_down", init, (held, width, d), jnp.float32)
-        per_token, shared = (flat, weights), (gate, up, down)
+        gate = self.param("experts_gate", init, (held, d_in, width), jnp.float32) if gated else None
+        up = self.param("experts_up", init, (held, d_in, width), jnp.float32)
+        down = self.param("experts_down", init, (held, width, d_in), jnp.float32)
+        per_token, shared = (routed_in, weights), (gate, up, down)
         slots = tokens * k
         counts = {
             "slots_routed": jnp.float32(slots), "slots_held": rows_used.astype(jnp.float32),
@@ -972,8 +1227,11 @@ class RoutedExperts(nn.Module):
             counts.update(layers_compact=fits.astype(jnp.float32), layers_at_bound=1.0 - fits,
                           combine_rows_fetched=jnp.where(fits, fetched, 0.0))
 
+        if cfg.moe_latent_size:
+            with jax.named_scope("tos.moe_latent"):
+                routed = nn.Dense(d, use_bias=False, dtype=cfg.compute_dtype, name="latent_up")(routed)
         with jax.named_scope("tos.moe_shared"):
-            shared = SwiGLU(cfg, cfg.shared_width, name="shared")(flat) if cfg.shared_width else 0
+            shared = SwiGLU(cfg, cfg.shared_width, cfg.mlp_hidden_act, name="shared")(flat) if cfg.shared_width else 0
         return (routed + shared).reshape(batch, length, d), counts
 
 
@@ -1017,8 +1275,11 @@ def _experts_on_rows(rows, flat, weights, gate, up, down, order, group_sizes, me
         sorted_in = gm.rows_to_slots(flat, order, group_sizes, rows, k, mesh)  # [rows, d]
         sorted_weights = weights.reshape(-1)[order[:rows]]
     with jax.named_scope("tos.moe_experts"):
-        hidden = nn.silu(gm.grouped_matmul(sorted_in, gate.astype(dt), group_sizes)) * gm.grouped_matmul(
-            sorted_in, up.astype(dt), group_sizes)
+        if gate is None:  # ``relu2``: two matrices an expert
+            hidden = jnp.square(nn.relu(gm.grouped_matmul(sorted_in, up.astype(dt), group_sizes)))
+        else:
+            hidden = nn.silu(gm.grouped_matmul(sorted_in, gate.astype(dt), group_sizes)) * gm.grouped_matmul(
+                sorted_in, up.astype(dt), group_sizes)
         sorted_out = gm.grouped_matmul(hidden, down.astype(dt), group_sizes)  # [rows, d]
     with jax.named_scope("tos.moe_route"):
         # weighted where it lies, then each token's slots among the rows summed
@@ -1157,13 +1418,16 @@ class AddResidual(nn.Module):
 
 _RESIDUALS = {"add": AddResidual, "mhc": HyperConnection}
 _MIXERS = {"mla": LatentAttention, "gqa": GroupedQueryAttention, "cross": GroupedQueryAttention,
-           "mamba": MambaMixer, "gmu": GatedMemory}
+           "mamba": MambaMixer, "gmu": GatedMemory, "mamba2": Mamba2Mixer}
 
 
 class DecoderLayer(nn.Module):
     """One layer of the plan: a pre-norm mixer (an attention, a state-space
     layer, a gated memory unit), then a pre-norm feed-forward, each inside
-    the layer's residual path. ``carried``: what earlier layers handed on
+    the layer's residual path; **a block of one sub-layer** where the plan
+    names no mixer, or no feed-forward (``x + F(norm(x))``, ``F`` the one it
+    names; its norm keeps the name it has in a whole layer, ``ln1`` or
+    ``ln2``). ``carried``: what earlier layers handed on
     (``memory``; ``k`` and ``v``), read by the kinds ``gmu`` and ``cross``.
     Returns ``(streams, counts, made)``: ``counts`` what a routed
     feed-forward counted (else empty), ``made`` what this layer hands on to
@@ -1183,29 +1447,35 @@ class DecoderLayer(nn.Module):
         mixer, feed_forward, residual = self.kinds
         path = _RESIDUALS[residual]
 
-        h, maps = path(cfg, self.mesh, name="res_attn")(streams)
-        u, made = _norm(cfg, "ln1")(h), {}
-        if mixer == "mla":
-            y = LatentAttention(cfg, self.mesh, name="attn")(u, positions, segment_ids, labels)
-        elif mixer == "mamba":
-            y, made = MambaMixer(cfg, self.mesh, self.heads, name="mamba")(u, segment_ids)
-        elif mixer == "gmu":
-            y = GatedMemory(cfg, name="gmu")(u, carried["memory"])
-        else:
-            y = GroupedQueryAttention(cfg, self.mesh, self.heads, name="attn")(
-                u, positions, segment_ids, labels, shared=carried if mixer == "cross" else None)
-            if self.heads.hands_on:
-                y, made = y
-        streams = path.merge(streams, maps, y, self.mesh)
+        counts, made = {}, {}
+        if mixer is not None:
+            h, maps = path(cfg, self.mesh, name="res_attn")(streams)
+            u = _norm(cfg, "ln1")(h)
+            if mixer == "mla":
+                y = LatentAttention(cfg, self.mesh, name="attn")(u, positions, segment_ids, labels)
+            elif mixer == "mamba":
+                y, made = MambaMixer(cfg, self.mesh, self.heads, name="mamba")(u, segment_ids)
+            elif mixer == "mamba2":
+                y = Mamba2Mixer(cfg, self.mesh, name="mamba2")(u, segment_ids)
+            elif mixer == "gmu":
+                y = GatedMemory(cfg, name="gmu")(u, carried["memory"])
+            else:
+                y = GroupedQueryAttention(cfg, self.mesh, self.heads, name="attn")(
+                    u, positions, segment_ids, labels, shared=carried if mixer == "cross" else None)
+                if self.heads.hands_on:
+                    y, made = y
+            streams = path.merge(streams, maps, y, self.mesh)
 
-        h, maps = path(cfg, self.mesh, name="res_mlp")(streams)
-        h, counts = _norm(cfg, "ln2")(h), {}
-        if feed_forward == "moe":
-            y, counts = RoutedExperts(cfg, self.mesh, name="moe")(h, segment_ids)
-        else:
-            with jax.named_scope("tos.dense_mlp"):
-                y = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
-        return path.merge(streams, maps, y, self.mesh), counts, made
+        if feed_forward is not None:
+            h, maps = path(cfg, self.mesh, name="res_mlp")(streams)
+            h = _norm(cfg, "ln2")(h)
+            if feed_forward == "moe":
+                y, counts = RoutedExperts(cfg, self.mesh, name="moe")(h, segment_ids)
+            else:
+                with jax.named_scope("tos.dense_mlp"):
+                    y = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
+            streams = path.merge(streams, maps, y, self.mesh)
+        return streams, counts, made
 
 
 class Decoder(nn.Module):
@@ -1241,6 +1511,14 @@ class Decoder(nn.Module):
             carried = dict(carried, **made)
             if counts:
                 counted.append(counts)
+        scans = sum(kinds[0] == "mamba2" for kinds in cfg.plan)
+        if scans:
+            obs.counter(
+                "ssd_scan_chunks_total",
+                help="chunks the Mamba-2 layers' scans walked (rows x chunks a row, summed over the layers): each one "
+                "grid step a group of heads, forward and again backward")
+            self.sow("counters", "ssd_scan_chunks", jnp.float32(
+                scans * tokens.shape[0] * ssd_scan.chunks_of(tokens.shape[1], cfg.chunk_size)[1]))
         if carried:
             obs.counter(
                 "ssm_state_carried_bytes_total",
@@ -1294,7 +1572,9 @@ def param_rules(cfg):
     """The placement rules of the kinds ``cfg``'s plan uses."""
     rules = []
     for attention, feed_forward, residual in cfg.plan:
-        for module in (_MIXERS[attention], RoutedExperts if feed_forward == "moe" else SwiGLU, _RESIDUALS[residual]):
+        modules = [] if attention is None else [_MIXERS[attention]]
+        modules += [] if feed_forward is None else [RoutedExperts if feed_forward == "moe" else SwiGLU]
+        for module in modules + [_RESIDUALS[residual]]:
             rules += [rule for rule in module.PARAM_RULES if rule not in rules]
     return tuple(rules) + _SHARED_RULES
 

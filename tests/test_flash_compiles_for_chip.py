@@ -377,6 +377,60 @@ def test_hybrid_decoder_compiles_at_the_cells_shape(one_chip, no_compile_cache, 
     assert compiled.memory_analysis().temp_size_in_bytes < 5.5e9
 
 
+def test_ssd_scan_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache):
+    """``nemotron-3-super.agent8k``'s Mamba-2 scan: one row of 8192 in chunks
+    of 128, 32 heads of 64 channels in 2 groups of 128 states, bfloat16
+    operands, decays and state float32: one grid step a chunk and group (16
+    heads walked in it), the kernels under the names the readers find them
+    by, a state a chunk and head ``[1, 32, 64, 64, 128]`` and none a position."""
+    from tensorflowonspark_tpu.ops.ssd_scan import ssd_scan
+
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    narrow, heads = on_chip((1, 8192, 2, 128), jnp.bfloat16), on_chip((32,), jnp.float32)
+    args = (on_chip((1, 8192, 32, 64), jnp.bfloat16), on_chip((1, 8192, 32), jnp.float32), heads, narrow, narrow, heads)
+
+    def loss(x, delta, a, b, c, skip, ids):
+        return (ssd_scan(x, delta, a, b, c, skip, ids).astype(jnp.float32) ** 2).sum()
+
+    step = jax.jit(jax.grad(jax.checkpoint(loss), argnums=tuple(range(6))))
+    text = step.lower(*args, on_chip((1, 8192), jnp.int32)).compile().as_text()
+    assert _kernels(text) == ["ssd_scan_bwd", "ssd_scan_fwd"]
+    assert "f32[1,32,64,64,128]" in text  # a state a chunk
+    assert "f32[1,8192,32,64,128]" not in text and "f32[1,32,8192,64,128]" not in text  # none a position
+
+
+def test_one_sub_layer_decoder_compiles_at_the_cells_shape(one_chip, no_compile_cache, monkeypatch):
+    """``nemotron-3-super.agent8k`` whole, loss and gradients, one row of 8192
+    recomputed: five chunked scans, one attention block of 8 query heads on 1
+    key/value head through the causal kernels, five expert blocks on the
+    compact slot buffer in the latent's width; every forward kernel once (the
+    scans' and the flash kernels' results are what a recomputed block keeps),
+    none inside a recomputed pass; the step's temporaries leave room beside
+    9.3 GB of parameters and moments."""
+    import json
+
+    from benchmarks.families import ssd_lm
+    from tensorflowonspark_tpu.models import get_model
+
+    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs", "nemotron-3-super.json")) as f:
+        cfg = ssd_lm.model_config(json.load(f), remat=True)
+    cfg.update(attention="flash")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, text = _model_step(one_chip, get_model("decoder", **cfg), 1, 8192)
+    kernels = _kernels(text)
+    assert [k for k in kernels if k.startswith(("ssd", "flash"))] == sorted(
+        ["ssd_scan_fwd", "ssd_scan_bwd"] * 5 + ["flash_fwd_seg", "flash_bwd_dkv_seg"])
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    scans = [name for name in names if "ssd_scan" in name]
+    assert len(scans) == 10 and all("tos.mamba2/tos.ssd_scan" in name for name in scans)
+    assert not any("rematted_computation" in name for name in scans)
+    assert sorted(_program.phase_of(name) for name in scans) == ["bwd"] * 5 + ["fwd"] * 5
+    assert "bf16[8,8192,128]" in text and "bf16[1,8192,128]" in text  # 8 query heads on 1 key/value head
+    assert "bf16[5632,1024]" in text  # the compact slot buffer, in the latent's width
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.5e9
+
+
 def test_hyper_connection_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache):
     """``xing4-a4b.packed8k``'s residual path (kept in this file: one process
     may hold the TPU library): one row of 8192 tokens, four streams of 3584
